@@ -8,7 +8,6 @@ plus autotuning, fused optimizer, MoE expert parallelism, checkpointing, and
 an elastic launcher.
 """
 
-import bagua_tpu.compat  # noqa: F401  (must run first: grafts jax.shard_map/axis_size on old JAX)
 from bagua_tpu.version import __version__  # noqa: F401
 from bagua_tpu.defs import ReduceOp  # noqa: F401
 from bagua_tpu.mesh import MeshSpec  # noqa: F401

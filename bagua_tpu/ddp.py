@@ -61,9 +61,16 @@ class TrainState(NamedTuple):
     step: jnp.ndarray  # (size,) int32, rank-stacked like everything else
 
 
-def _stack(tree, n: int):
-    """Replicate a single-copy pytree into the rank-stacked layout."""
-    return jax.tree.map(lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), tree)
+def _place_replicas(tree, n: int, sharding):
+    """The rank-stacked layout of a single-copy pytree, each device sent its
+    own replica straight from the caller's copy.  Nothing is staged whole on
+    one device on the way."""
+    return jax.tree.map(
+        lambda x: jax.make_array_from_callback(
+            (n,) + x.shape, sharding, lambda _: x[None]
+        ),
+        tree,
+    )
 
 
 def _local(tree):
@@ -288,36 +295,43 @@ class DistributedDataParallel:
         self._tree_template = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), template
         )
-        # The state is built *inside* jit with explicit out_shardings over the
-        # group mesh — on multi-host groups every process computes exactly its
-        # addressable shards (the analog of the reference's per-node state
-        # setup after the rank-0 broadcast; with plain ``params`` every
-        # process must pass the same values, e.g. the same PRNG seed), and on
-        # every group the result is *committed* to the same sharding the step
-        # function emits.  An eagerly-built (uncommitted, single-device)
+        # The state is built with one explicit sharding over the group mesh
+        # (placed, or *inside* jit under out_shardings) — on multi-host groups
+        # every process makes exactly its addressable shards (the analog of
+        # the reference's per-node state setup after the rank-0 broadcast;
+        # with plain ``params`` every process must pass the same values, e.g.
+        # the same PRNG seed), and on every group the result is *committed*
+        # to the same sharding the step function emits.  An eagerly-built (uncommitted, single-device)
         # state would make the first step's jit signature differ from every
         # later step's, compiling the full step graph twice back-to-back
         # (~2x VGG16's compile latency at startup, measured on v5e).
         sharding = jax.sharding.NamedSharding(self.group.mesh, P(self.group.all_axes))
         if stacked_params is not None:
-            build_stacked = lambda sp: TrainState(
-                params=sp,
-                opt_state=jax.vmap(self._opt_init)(sp),
-                algo_state=jax.vmap(self.impl.init_state)(sp),
-                step=jnp.zeros((n,), jnp.int32),
-            )
-            return jax.jit(build_stacked, out_shardings=sharding)(stacked_params)
-        build = lambda p: TrainState(
-            params=_stack(p, n),
-            opt_state=_stack(self._opt_init(p), n),
-            algo_state=_stack(self.impl.init_state(p), n),
-            step=jnp.zeros((n,), jnp.int32),
+            return jax.jit(
+                lambda sp: TrainState(sp, *self._rest_of_state(sp)),
+                out_shardings=sharding,
+            )(stacked_params)
+        # The replicas are placed first and only the rest of the state is
+        # built from them under jit.  As a jit argument the host copy of the
+        # parameters is transferred whole to the first device and sits there
+        # beside that device's share of the result while the program runs:
+        # for BERT-Large on the v5e 1.71 GB on device 0 against 0.86 GB on
+        # the others (chip run, PR 21), out of memory before the first step
+        # for any state above half a chip.
+        replicas = _place_replicas(params, n, sharding)
+        return TrainState(
+            replicas,
+            *jax.jit(self._rest_of_state, out_shardings=sharding)(replicas),
         )
-        if self.group.spans_processes:
-            import numpy as np
 
-            params = jax.tree.map(np.asarray, params)
-        return jax.jit(build, out_shardings=sharding)(params)
+    def _rest_of_state(self, stacked_params):
+        """Optimizer state, algorithm state and step counter for rank-stacked
+        parameters, each rank's from its own replica (traced)."""
+        return (
+            jax.vmap(self._opt_init)(stacked_params),
+            jax.vmap(self.impl.init_state)(stacked_params),
+            jnp.zeros((self.group.size,), jnp.int32),
+        )
 
     def _opt_init(self, params):
         """Optimizer state for one rank: shard-sized under a sharded-update
@@ -333,13 +347,13 @@ class DistributedDataParallel:
         against after host-side resharding (``init_state`` built before a
         plan adoption may describe a different shard layout)."""
         n = self.group.size
-        build = lambda p: TrainState(
-            params=_stack(p, n),
-            opt_state=_stack(self._opt_init(p), n),
-            algo_state=_stack(self.impl.init_state(p), n),
-            step=jnp.zeros((n,), jnp.int32),
+        stacked = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct((n,) + x.shape, x.dtype),
+            self._tree_template,
         )
-        return jax.eval_shape(build, self._tree_template)
+        return jax.eval_shape(
+            lambda sp: TrainState(sp, *self._rest_of_state(sp)), stacked
+        )
 
     # -- execution mode -----------------------------------------------------
 
@@ -1788,19 +1802,21 @@ class DistributedDataParallel:
         return times
 
     def shard_batch(self, local_batch):
-        """Assemble the global batch from this process's local rows.
+        """Place the batch on the group mesh with the step's data sharding.
 
         On a multi-host group each process loads only its own slice of the
         global batch (the reference's per-node DataLoader shard); this glues
         the slices into one global array over the group mesh via
-        ``jax.make_array_from_process_local_data``.  Single-process groups
-        pass through unchanged — ``train_step`` accepts host arrays directly.
+        ``jax.make_array_from_process_local_data``.  On a single-process
+        group ``local_batch`` is the global batch and each chip receives its
+        rows directly — a batch built with ``jnp.asarray`` sits whole on
+        device 0 and is re-split across the chips on every step.
         """
+        sharding = jax.sharding.NamedSharding(self.group.mesh, P(self.group.data_axes))
         if not self.group.spans_processes:
-            return local_batch
+            return jax.device_put(local_batch, sharding)
         import numpy as np
 
-        sharding = jax.sharding.NamedSharding(self.group.mesh, P(self.group.data_axes))
         return jax.tree.map(
             lambda x: jax.make_array_from_process_local_data(
                 sharding, np.asarray(x)

@@ -70,7 +70,9 @@ def parse_args(argv=None):
     p.add_argument("--node_rank", type=int, default=0)
     p.add_argument(
         "--nproc_per_node", type=int, default=1,
-        help="worker processes per node (on TPU usually 1 process drives all local chips)",
+        help="worker processes per node.  On a TPU host this is 1: one "
+        "process drives every local chip, and the launcher does not divide "
+        "the chips among workers, so N > 1 workers race for all of them",
     )
     p.add_argument(
         "--min_replicas", type=int, default=None,

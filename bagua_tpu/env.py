@@ -290,40 +290,25 @@ def get_fleet_burst() -> float:
     return float(os.environ.get("BAGUA_FLEET_BURST", 200.0))
 
 
-def get_compile_cache_dir() -> Optional[str]:
-    """Directory for JAX's persistent (on-disk) compilation cache.
+def setup_compile_cache() -> str:
+    """The one rule for JAX's persistent compilation cache; returns the
+    directory in effect.
 
-    Resolution: ``BAGUA_COMPILE_CACHE_DIR`` > ``JAX_COMPILATION_CACHE_DIR`` >
-    None (disabled).  Setting either variable to the empty string disables
-    the cache explicitly even when the other is set.
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX read it at import and it stands —
+    this function makes no ``jax.config.update`` call at all.  Unset: the
+    cache lives at ``<checkout>/.jax_cache`` (git-ignored), derived from the
+    package's own location so ``Trainer``, the bench scripts,
+    ``chip_smoke.py``, the tests and the ``ci/`` drivers all share one
+    directory; the path is part of the cache key, so a directory that moves
+    never hits.  Call before the first compile that should be cached.
     """
-    for var in ("BAGUA_COMPILE_CACHE_DIR", "JAX_COMPILATION_CACHE_DIR"):
-        val = os.environ.get(var)
-        if val is not None:
-            return val or None
-    return None
-
-
-def setup_compile_cache(
-    default_dir: Optional[str] = None, min_compile_secs: float = 1.0
-) -> Optional[str]:
-    """Point JAX's persistent compilation cache at :func:`get_compile_cache_dir`.
-
-    A warm cache turns the multi-second XLA compile of the DDP train step
-    into a sub-second deserialization on every re-run (trainer restarts,
-    bench re-invocations, CI).  ``default_dir`` is used only when neither
-    env var is set; pass None to keep the cache disabled by default (the
-    Trainer does this — users opt in via ``BAGUA_COMPILE_CACHE_DIR``).
-
-    Idempotent; returns the directory in effect, or None when disabled.
-    """
-    path = get_compile_cache_dir()
-    if path is None:
-        path = default_dir
-    if not path:
-        return None
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env is not None:
+        return env
     import jax
 
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+    )
     jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_compile_secs)
     return path

@@ -14,47 +14,43 @@ the XLA-fused jnp path, 469.0 vs 471.9 samples/s).
 On non-TPU backends the jnp paths are always the default.
 """
 
+import functools
 import json
+import logging
 import os
 
-#: artifact name -> cached parse (the file is read at most once per process)
-_ARTIFACT_CACHE = {}
+logger = logging.getLogger(__name__)
 
 
 def _truthy(v: str) -> bool:
     return v.strip().lower() not in ("", "0", "false", "off", "no")
 
 
-def _artifact():
-    """The hardware validation record, or None.
+@functools.lru_cache(maxsize=None)
+def log_decline(kernel: str, shape: tuple, bound: str) -> None:
+    """A ``*_pallas`` entry point is about to return its jnp composition
+    instead of the kernel: say so at WARNING with the shape and the bound
+    that refused it — once per kernel and shape (the cache is the
+    once-filter), so a measurement labelled Pallas can be told from jnp."""
+    logger.warning(
+        "%s declines shape %s (%s): running the jnp composition", kernel, shape, bound
+    )
 
-    Two locations, repo-root first: ``PALLAS_TPU.json`` at the repo root is
-    the committed artifact a checkout carries (and what
-    ``ci/validate_pallas_tpu.py`` just wrote during a chip session — it must
-    win over a stale packaged copy).  The packaged copy
-    (``bagua_tpu/kernels/_pallas_validation.json``, shipped as package data)
-    is the fallback for non-editable wheel installs, where no repo root
-    exists; the validator refreshes both.
-    """
-    repo_root = os.path.join(
+
+@functools.lru_cache(maxsize=1)
+def _artifact():
+    """The hardware validation record — ``PALLAS_TPU.json`` at the repo root,
+    written by ``ci/validate_pallas_tpu.py`` on a real chip — or None.  Read
+    at most once per process; no other location is consulted."""
+    path = os.path.join(
         os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
         "PALLAS_TPU.json",
     )
-    packaged = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "_pallas_validation.json"
-    )
-    key = (repo_root, packaged)
-    if key not in _ARTIFACT_CACHE:
-        rec = None
-        for path in (repo_root, packaged):
-            try:
-                with open(path) as f:
-                    rec = json.load(f)
-                break
-            except Exception:
-                continue
-        _ARTIFACT_CACHE[key] = rec
-    return _ARTIFACT_CACHE[key]
+    try:
+        with open(path) as f:
+            return json.load(f)
+    except (OSError, ValueError):
+        return None
 
 
 def validated_on_hardware(kernel: str) -> bool:

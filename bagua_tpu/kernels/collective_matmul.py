@@ -43,6 +43,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from bagua_tpu.kernels._config import log_decline
+
 # TPU tiling: the MXU wants (8, 128)-aligned f32 tiles.  Interpret mode (the
 # CPU tier) accepts any tile shape, which is how the edge-tile sweep exercises
 # non-divisible M/N without an 8×128 floor.
@@ -259,7 +261,16 @@ def matmul_tile_pallas(x, w, interpret: bool = False, tile_m: int = None,
         tm = max(_SUBLANE, (tm // _SUBLANE) * _SUBLANE)
         tn = max(_LANE, (tn // _LANE) * _LANE)
     vmem = 4 * (tm * k + k * tn + tm * tn)
-    if x.dtype != jnp.float32 or w.dtype != jnp.float32 or vmem > _VMEM_TILE_BYTES:
+    if x.dtype != jnp.float32 or w.dtype != jnp.float32:
+        log_decline("matmul_tile_pallas", (x.shape, w.shape),
+                    f"dtypes {x.dtype} x {w.dtype}: the tile GEMM is f32 only")
+        return jnp.dot(x, w)
+    if vmem > _VMEM_TILE_BYTES:
+        log_decline(
+            "matmul_tile_pallas", (x.shape, w.shape),
+            f"whole-K tile {tm}x{k} + {k}x{tn} + {tm}x{tn} f32 = {vmem} bytes "
+            f"> {_VMEM_TILE_BYTES} VMEM budget",
+        )
         return jnp.dot(x, w)
     return _tile_matmul(x, w, bool(interpret), tm, tn)
 

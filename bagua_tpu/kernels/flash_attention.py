@@ -43,6 +43,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from bagua_tpu.kernels._config import log_decline
+
 NEG = -1e30  # large negative finite (a Python float: Pallas kernels cannot capture traced constants)
 
 
@@ -107,8 +109,14 @@ _VMEM_BUDGET_BYTES = 10 * 1024 * 1024
 def _tiles_fit_vmem(bq: int, bk: int, d_p: int) -> bool:
     tiles = (bq * d_p + 2 * 2 * bk * d_p + d_p * bq) * 4  # q + k,v (dbl-buf) + oT
     scores = bk * bq * 4 * 2  # s + p
-    mask = 2 * bk * bq  # int8, double-buffered
+    mask = 2 * bk * bq + 4 * bk * bq  # int8, double-buffered + its f32 form
     return tiles + scores + mask <= _VMEM_BUDGET_BYTES
+
+
+def _over_budget(block_q: int, block_k: int, d: int) -> str:
+    """The bound a declining flash wrapper names in its log line."""
+    return (f"tiles {block_q}x{block_k} at d={d} exceed the "
+            f"{_VMEM_BUDGET_BYTES}-byte VMEM budget")
 
 
 def _tile_edges(tq: int, tk: int, block_q: int, block_k: int):
@@ -164,7 +172,7 @@ def _bwd_tiles_fit_vmem(bq: int, bk: int, d_p: int) -> bool:
     dq (or dk+dv) accumulator out."""
     tiles = (2 * bq * d_p + 2 * 2 * bk * d_p + 2 * bq * d_p) * 4  # q,do + k,v(dbl) + out
     scores = bk * bq * 4 * 4  # sT, pT, dpT, dsT
-    mask = 2 * bk * bq
+    mask = 2 * bk * bq + 4 * bk * bq  # int8, double-buffered + its f32 form
     return tiles + scores + mask <= _VMEM_BUDGET_BYTES
 
 
@@ -182,6 +190,18 @@ def _pad_to(x, mult, axis):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
+
+
+def _mask_tile(mask_ref):
+    """The int8 mask tile as f32 zeros and ones (via i32: Mosaic has no
+    direct i8->f32 cast).  ``tile > 0`` is the select predicate, laid out
+    like the f32 tiles it selects between, and ``jnp.max(tile) > 0`` says
+    whether any entry is live.  ``jnp.any(mask != 0)`` would say the same,
+    but Mosaic (libtpu 0.0.34, on the chip) refuses Pallas's ``reduce_or``
+    lowering, a select between two splat constants ("Invalid relayout:
+    Non-singleton logical dimension is replicated in destination but not in
+    source")."""
+    return mask_ref[0].astype(jnp.int32).astype(jnp.float32)
 
 
 def _tiled_flash_kernel(q_ref, k_ref, v_ref, mask_ref, ot_ref, l_ref, m_ref):
@@ -207,13 +227,14 @@ def _tiled_flash_kernel(q_ref, k_ref, v_ref, mask_ref, ot_ref, l_ref, m_ref):
         l_ref[...] = jnp.zeros_like(l_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG)
 
-    mask = mask_ref[0]  # (BK, BQ) int8, transposed layout
+    mask = _mask_tile(mask_ref)  # (BK, BQ) f32 0/1, transposed layout
+    live = mask > 0.0
 
     # Fully-masked tiles leave the running state untouched (p would be all
     # zeros: m_new == m_prev, c == 1) — skip both MXU matmuls and the exp.
     # Under a causal mask ~half the tiles are dead, so causal long-context
     # forward compute halves with bit-identical results.
-    @pl.when(jnp.any(mask != 0))
+    @pl.when(jnp.max(mask) > 0.0)
     def _live_tile():
         q = q_ref[0]  # (BQ, d) f32, pre-scaled
         k = k_ref[0].astype(jnp.float32)  # (BK, d)
@@ -221,11 +242,11 @@ def _tiled_flash_kernel(q_ref, k_ref, v_ref, mask_ref, ot_ref, l_ref, m_ref):
         s = jax.lax.dot_general(
             k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (BK, BQ)
-        s = jnp.where(mask != 0, s, NEG)
+        s = jnp.where(live, s, NEG)
         m_prev = m_ref[0]  # (1, BQ)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
-        p = jnp.where(mask != 0, p, 0.0)
+        p = jnp.where(live, p, 0.0)
         c = jnp.exp(m_prev - m_new)  # (1, BQ) — rescale of the running state
         l_ref[0] = l_ref[0] * c + jnp.sum(p, axis=0, keepdims=True)
         ot_ref[0] = ot_ref[0] * c + jax.lax.dot_general(
@@ -264,6 +285,8 @@ def block_attention_pallas(
     if h % h_kv:
         raise ValueError(f"q heads ({h}) must divide by kv heads ({h_kv})")
     if not flash_block_supported(tq, tk, d, block_q, block_k):
+        log_decline("block_attention_pallas", (qf.shape, k_blk.shape),
+                    _over_budget(block_q, block_k, d))
         g = h // h_kv
         if g > 1:
             k_blk = jnp.repeat(k_blk, g, axis=2)
@@ -363,9 +386,10 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, m_ref, dl_ref, do_ref,
     def _init():
         dq_ref[...] = jnp.zeros_like(dq_ref)
 
-    mask = mask_ref[0]
+    mask = _mask_tile(mask_ref)
+    live = mask > 0.0
 
-    @pl.when(jnp.any(mask != 0))  # dead tiles contribute exactly zero
+    @pl.when(jnp.max(mask) > 0.0)  # dead tiles contribute exactly zero
     def _live_tile():
         q = q_ref[0]
         k = k_ref[0].astype(jnp.float32)
@@ -373,7 +397,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, m_ref, dl_ref, do_ref,
         sT = jax.lax.dot_general(
             k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (bk, bq)
-        pT = jnp.where(mask != 0, jnp.exp(sT - m_ref[0]), 0.0)
+        pT = jnp.where(live, jnp.exp(sT - m_ref[0]), 0.0)
         dpT = jax.lax.dot_general(
             v, do_ref[0], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) + dl_ref[0]  # (bk, bq): do.v per (key, query) + the l-path constant
@@ -398,9 +422,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, m_ref, dl_ref, do_ref,
         dk_ref[...] = jnp.zeros_like(dk_ref)
         dv_ref[...] = jnp.zeros_like(dv_ref)
 
-    mask = mask_ref[0]
+    mask = _mask_tile(mask_ref)
+    live = mask > 0.0
 
-    @pl.when(jnp.any(mask != 0))  # dead tiles contribute exactly zero
+    @pl.when(jnp.max(mask) > 0.0)  # dead tiles contribute exactly zero
     def _live_tile():
         q = q_ref[0]
         k = k_ref[0].astype(jnp.float32)
@@ -409,7 +434,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, m_ref, dl_ref, do_ref,
         sT = jax.lax.dot_general(
             k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (bk, bq)
-        pT = jnp.where(mask != 0, jnp.exp(sT - m_ref[0]), 0.0)
+        pT = jnp.where(live, jnp.exp(sT - m_ref[0]), 0.0)
         dv_ref[0] += jax.lax.dot_general(
             pT, do, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )  # (bk, d)
@@ -483,6 +508,8 @@ def flash_attention_bwd_pallas(
         # step.  Exact-vjp and stop-grad-m backwards differ per block but
         # agree on every composed (merge+normalize) gradient — see the
         # block_attention_fused docstring — so mixing them per shape is fine.
+        log_decline("flash_attention_bwd_pallas", (qf.shape, k_blk.shape),
+                    _over_budget(block_q, block_k, d))
         return _jnp_block_vjp(qf, k_blk, v_blk, mask, (do, dl, jnp.zeros_like(m)))
     bq, bk = _tile_edges(tq, tk, block_q, block_k)
 

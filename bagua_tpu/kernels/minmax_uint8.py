@@ -25,11 +25,14 @@ Two implementations with identical semantics:
 """
 
 import functools
+import logging
 import os
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+
+from bagua_tpu.kernels._config import log_decline
 
 EPS = 1e-7
 LEVELS = 255.0
@@ -110,6 +113,9 @@ _LANE = 128
 _ROW_ALIGN = 32  # uint8 min sublane tile
 
 
+_TILING_BOUND = f"chunk must be a multiple of {_LANE * _ROW_ALIGN} (uint8 tile)"
+
+
 def pallas_chunk_supported(chunk: int) -> bool:
     return chunk % (_LANE * _ROW_ALIGN) == 0
 
@@ -131,7 +137,16 @@ def _pick_block_chunks(nchunks: int, chunk: int, requested=None) -> int:
     Mosaic and is recorded as such).  Only the auto-pick respects the cap."""
     if requested is None:
         env = os.environ.get("BAGUA_PALLAS_MINMAX_BLOCK_CHUNKS")
-        requested = int(env) if env else None
+        if env:
+            try:
+                requested = int(env)
+            except ValueError:
+                # an ops knob must degrade, not crash every compress call
+                # (same contract as flash_attention._resolve_tiles)
+                logging.getLogger(__name__).warning(
+                    "BAGUA_PALLAS_MINMAX_BLOCK_CHUNKS=%r is not an integer; "
+                    "using the auto-pick", env,
+                )
     if requested is not None:
         bc = max(1, min(int(requested), nchunks))
         while nchunks % bc:
@@ -144,18 +159,36 @@ def _pick_block_chunks(nchunks: int, chunk: int, requested=None) -> int:
     return max(1, bc)
 
 
+def _requantize_tile(x, levels=LEVELS):
+    """Min/max-quantize one chunk held as a ``(rows, 128)`` f32 tile, the
+    requantize step the compress, fused-reduce and ring-hop kernels share.
+    Returns ``(q, mm, lower, scale)``: ``q`` the f32 levels ``0..levels``
+    (Mosaic has no direct f32->u8 cast: the caller goes through i32), ``mm``
+    the chunk's min and max as ``(1, 2)`` (VMEM refuses scalar stores: one
+    vector store), and ``(q + lower) / scale`` the dequantized tile.
+
+    The chunk's min and max stay scalars.  A block-wide form that carries
+    them as 1-D ``(bc,)`` vectors aborts the process in Mosaic's layout
+    inference (libtpu 0.0.34, on the chip: "Check failed: arr.size() >=
+    layout_rank(implicit_dim) (1 vs. 2)")."""
+    mn = jnp.min(x)
+    mx = jnp.max(x)
+    scale = _safe_scale(mn, mx, levels)
+    upper = jnp.round(mx * scale)
+    lower = upper - levels
+    q = jnp.minimum(jnp.round(x * scale), upper) - lower
+    return q, jnp.stack([mn, mx]).reshape(1, 2), lower, scale
+
+
 def _compress_kernel(x_ref, q_ref, mm_ref):
-    x = x_ref[...].astype(jnp.float32)  # (bc, rows, 128)
-    mn = jnp.min(x, axis=(1, 2))        # per-chunk reductions, (bc,)
-    mx = jnp.max(x, axis=(1, 2))
-    scale = _safe_scale(mn, mx)[:, None, None]
-    upper = jnp.round(mx[:, None, None] * scale)
-    lower = upper - LEVELS
-    level = jnp.minimum(jnp.round(x * scale), upper)
-    # Mosaic has no direct f32->u8 cast; go through i32.
-    q_ref[...] = (level - lower).astype(jnp.int32).astype(jnp.uint8)
-    # VMEM refuses scalar stores; write (bc, 1, 2) as one vector store.
-    mm_ref[...] = jnp.stack([mn, mx], axis=1).reshape(-1, 1, 2)
+    def chunk(i, carry):
+        q, mm_ref[i], _, _ = _requantize_tile(x_ref[i].astype(jnp.float32))
+        q_ref[i] = q.astype(jnp.int32).astype(jnp.uint8)
+        return carry
+
+    # a loop, not an unrolled block: an explicit block_chunks may ask for
+    # many chunks per grid step
+    jax.lax.fori_loop(0, x_ref.shape[0], chunk, 0)
 
 
 def _decompress_kernel(q_ref, mm_ref, x_ref):
@@ -179,6 +212,7 @@ def compress_minmax_uint8_pallas(
     pin is honored on every call, not baked at first trace."""
     nchunks, chunk = chunks.shape
     if not pallas_chunk_supported(chunk):
+        log_decline("compress_minmax_uint8_pallas", chunks.shape, _TILING_BOUND)
         return compress_minmax_uint8(chunks)
     bc = _pick_block_chunks(nchunks, chunk, block_chunks)
     return _compress_pallas_jit(chunks, interpret, bc)
@@ -217,6 +251,7 @@ def decompress_minmax_uint8_pallas(
 ) -> jnp.ndarray:
     nchunks, chunk = q.shape
     if not pallas_chunk_supported(chunk):
+        log_decline("decompress_minmax_uint8_pallas", q.shape, _TILING_BOUND)
         return decompress_minmax_uint8(q, minmax)
     bc = _pick_block_chunks(nchunks, chunk, block_chunks)
     return _decompress_pallas_jit(q, minmax, interpret, bc)
@@ -280,14 +315,8 @@ def _fused_reduce_kernel(q_ref, mm_ref, qo_ref, mmo_ref, *, n, average):
     red = jnp.sum(x, axis=0)             # (rows, 128)
     if average:
         red = red / n                    # division, matching the jnp oracle
-    mn2 = jnp.min(red)
-    mx2 = jnp.max(red)
-    scale2 = _safe_scale(mn2, mx2)
-    upper2 = jnp.round(mx2 * scale2)
-    lower2 = upper2 - LEVELS
-    level = jnp.minimum(jnp.round(red * scale2), upper2)
-    qo_ref[...] = (level - lower2).astype(jnp.int32).astype(jnp.uint8)[None]
-    mmo_ref[...] = jnp.stack([mn2, mx2]).reshape(1, 1, 2)
+    q2, mmo_ref[0], _, _ = _requantize_tile(red)
+    qo_ref[0] = q2.astype(jnp.int32).astype(jnp.uint8)
 
 
 def decompress_reduce_requantize_pallas(
@@ -303,7 +332,15 @@ def decompress_reduce_requantize_pallas(
     n, chunk = q.shape
     # resident bytes: u8 in (n*chunk) + f32 dequant (4*n*chunk) + f32 reduced
     # + u8 out (~5*chunk); stay within the double-buffered arena budget
-    if not pallas_chunk_supported(chunk) or (n + 1) * chunk * 5 > 2 * _VMEM_BLOCK_BYTES:
+    if not pallas_chunk_supported(chunk):
+        log_decline("decompress_reduce_requantize_pallas", q.shape, _TILING_BOUND)
+        return decompress_reduce_requantize(q, minmax, average=average)
+    if (n + 1) * chunk * 5 > 2 * _VMEM_BLOCK_BYTES:
+        log_decline(
+            "decompress_reduce_requantize_pallas", q.shape,
+            f"(n+1)*chunk*5 = {(n + 1) * chunk * 5} bytes resident > "
+            f"{2 * _VMEM_BLOCK_BYTES} VMEM budget",
+        )
         return decompress_reduce_requantize(q, minmax, average=average)
     return _fused_reduce_pallas_jit(q, minmax, bool(average), interpret)
 

@@ -56,8 +56,10 @@ from bagua_tpu.communication import (
     ppermute_shift,
     rank_id,
 )
+from bagua_tpu.kernels._config import log_decline
 from bagua_tpu.kernels.minmax_uint8 import (
     LEVELS,
+    _requantize_tile,
     _safe_scale,
     _LANE,
     _ROW_ALIGN,
@@ -176,53 +178,43 @@ def pallas_hop_supported(block: int, bits: int) -> bool:
     return block % (2 * _LANE * _ROW_ALIGN) == 0
 
 
-def _requant_block(s, levels):
-    """Per-block requantize of ``s`` (bc, rows, 128) -> (q f32, mn, mx)."""
-    mn = jnp.min(s, axis=(1, 2))
-    mx = jnp.max(s, axis=(1, 2))
-    scale = _safe_scale(mn, mx, levels)[:, None, None]
-    upper = jnp.round(mx[:, None, None] * scale)
-    lower = upper - levels
-    level = jnp.minimum(jnp.round(s * scale), upper)
-    return level - lower, mn, mx, scale, lower
-
-
-def _dequant_block(q, mm, levels):
-    """Blockwise dequantize ``q`` (bc, rows, 128) f32 levels with ``mm``
-    (bc, 1, 2) -> f32 values."""
-    mn = mm[:, :, 0:1]
-    mx = mm[:, :, 1:2]
+def _hop_block(q, mm, local, levels):
+    """One block of one hop: dequantize the f32 levels ``q`` (rows, 128) with
+    ``mm`` (1, 2), add ``local``, requantize.  Returns ``(q2, mm2, err)``
+    with ``q2`` still f32 levels and ``mm2`` (1, 2)."""
+    mn = mm[:, 0:1]
+    mx = mm[:, 1:2]
     scale = _safe_scale(mn, mx, levels)
-    lower = jnp.round(mx * scale) - levels
-    return (q + lower) / scale
+    s = (q + (jnp.round(mx * scale) - levels)) / scale + local
+    q2, mm2, lower2, scale2 = _requantize_tile(s, levels)
+    return q2, mm2, s - (q2 + lower2) / scale2
 
 
 def _hop_kernel8(q_ref, mm_ref, loc_ref, qo_ref, mmo_ref, err_ref):
-    q = q_ref[...].astype(jnp.int32).astype(jnp.float32)  # (bc, rows, 128)
-    x = _dequant_block(q, mm_ref[...], LEVELS)
-    s = x + loc_ref[...]
-    q2, mn2, mx2, scale2, lower2 = _requant_block(s, LEVELS)
-    qo_ref[...] = q2.astype(jnp.int32).astype(jnp.uint8)
-    mmo_ref[...] = jnp.stack([mn2, mx2], axis=1).reshape(-1, 1, 2)
-    x2 = (q2 + lower2) / scale2
-    err_ref[...] = s - x2
+    def block(i, carry):
+        q = q_ref[i].astype(jnp.int32).astype(jnp.float32)  # (rows, 128)
+        q2, mmo_ref[i], err_ref[i] = _hop_block(q, mm_ref[i], loc_ref[i], LEVELS)
+        qo_ref[i] = q2.astype(jnp.int32).astype(jnp.uint8)
+        return carry
+
+    jax.lax.fori_loop(0, loc_ref.shape[0], block, 0)
 
 
 def _hop_kernel4(q_ref, mm_ref, loc_ref, qo_ref, mmo_ref, err_ref):
-    # unpack: low nibble = first half of the block (sublane rows 0..h-1),
-    # high nibble = second half — a concat over sublanes, no strided lanes
-    p = q_ref[...].astype(jnp.int32)                       # (bc, rows/2, 128)
-    q = jnp.concatenate([p & 0xF, p >> 4], axis=1).astype(jnp.float32)
-    x = _dequant_block(q, mm_ref[...], LEVELS4)
-    s = x + loc_ref[...]
-    q2, mn2, mx2, scale2, lower2 = _requant_block(s, LEVELS4)
-    half = s.shape[1] // 2
-    lo = q2[:, :half].astype(jnp.int32)
-    hi = q2[:, half:].astype(jnp.int32)
-    qo_ref[...] = (lo | (hi << 4)).astype(jnp.uint8)
-    mmo_ref[...] = jnp.stack([mn2, mx2], axis=1).reshape(-1, 1, 2)
-    x2 = (q2 + lower2) / scale2
-    err_ref[...] = s - x2
+    def block(i, carry):
+        # unpack: low nibble = first half of the block (sublane rows
+        # 0..h-1), high nibble = second half — a concat over sublanes, no
+        # strided lanes
+        p = q_ref[i].astype(jnp.int32)                       # (rows/2, 128)
+        q = jnp.concatenate([p & 0xF, p >> 4], axis=0).astype(jnp.float32)
+        q2, mmo_ref[i], err_ref[i] = _hop_block(q, mm_ref[i], loc_ref[i], LEVELS4)
+        half = q2.shape[0] // 2
+        lo = q2[:half].astype(jnp.int32)
+        hi = q2[half:].astype(jnp.int32)
+        qo_ref[i] = (lo | (hi << 4)).astype(jnp.uint8)
+        return carry
+
+    jax.lax.fori_loop(0, loc_ref.shape[0], block, 0)
 
 
 def hop_dequant_add_requant_pallas(
@@ -233,9 +225,20 @@ def hop_dequant_add_requant_pallas(
     groups, the incoming payload + local partial + requantized output all
     resident in VMEM for one grid step — the ring's per-hop cost is one VMEM
     round-trip instead of three HBM passes.  Falls back to the jnp oracle
-    when the block size doesn't satisfy TPU tiling — semantics identical."""
+    when the block size doesn't satisfy TPU tiling — semantics identical.
+
+    The int4 hop needs the *packed half-block* on the uint8 tile, i.e. a
+    block that is a multiple of 8192: at ``DEFAULT_BLOCK`` (4096) the int4
+    hop is therefore ALWAYS the jnp composition, whatever the dispatch
+    selected — only ``BAGUA_QR_BLOCK`` / ``block=`` multiples of 8192 reach
+    the kernel."""
     nblocks, B = local.shape
     if not pallas_hop_supported(B, bits):
+        log_decline(
+            f"hop_dequant_add_requant_pallas(bits={bits})", local.shape,
+            f"block must be a multiple of "
+            f"{(1 if bits == 8 else 2) * _LANE * _ROW_ALIGN} (uint8 tile)",
+        )
         return hop_dequant_add_requant(q, minmax, local, bits=bits)
     bc = _pick_block_chunks(nblocks, B, block_chunks)
     return _hop_pallas_jit(q, minmax, local, bits, bc, interpret)

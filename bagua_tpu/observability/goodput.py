@@ -34,6 +34,7 @@ __all__ = [
     "LEDGER_BUCKETS",
     "PEAK_FLOPS_PER_CHIP",
     "TRAIN_FLOPS_MULTIPLIER",
+    "chip_peak_flops",
     "flops_from_cost_analysis",
     "mlp_fwd_flops",
     "model_flops_per_sample",
@@ -43,14 +44,32 @@ __all__ = [
     "vgg16_fwd_flops",
 ]
 
-#: per-chip peak throughput (FLOP/s) under the audit's MAC-counting
-#: convention — the denominators MFU is quoted against.  "v5e" matches the
-#: perf-audit roofline (197 bf16 TFLOP/s).
+#: Peak dense bf16 FLOP/s of one chip — the one table every MFU denominator
+#: reads — keyed by ``jax.devices()[0].device_kind``.  Source: Google Cloud
+#: documentation, "TPU v5e" (197 TFLOP/s bf16 per chip); the v5e reports
+#: itself as ``"TPU v5 lite"``.  A device that is not in the table is an
+#: error (:func:`chip_peak_flops`), never a default.
 PEAK_FLOPS_PER_CHIP = {
-    "v5e": 197e12,
-    "v4": 275e12,
-    "v5p": 459e12,
+    "TPU v5 lite": 197e12,
 }
+
+
+def chip_peak_flops(device_kind: Optional[str] = None) -> float:
+    """Peak FLOP/s for ``device_kind`` (default: the kind of
+    ``jax.devices()[0]``).  Raises ``KeyError`` for a kind the table does
+    not list — a utilization against a guessed peak is not a measurement."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return PEAK_FLOPS_PER_CHIP[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak FLOP/s on record for device_kind {device_kind!r}; "
+            f"PEAK_FLOPS_PER_CHIP lists {sorted(PEAK_FLOPS_PER_CHIP)}"
+        ) from None
+
 
 #: training FLOPs ≈ 3× the forward pass (backward re-computes both the
 #: activation and the weight gradient) — the perf-audit convention
@@ -305,8 +324,10 @@ class GoodputMeter:
             ``flops_per_sample`` directly (wins over ``model``), or
             calibrate later from a compiled step
             (:meth:`calibrate_from_compiled`).
-        peak_flops_per_chip: the MFU denominator (a number, or a key of
-            :data:`PEAK_FLOPS_PER_CHIP` such as ``"v5e"``).
+        peak_flops_per_chip: the MFU denominator: a number, a
+            ``device_kind`` key of :data:`PEAK_FLOPS_PER_CHIP`, or None
+            (default) for the device the process runs on — which must be
+            in the table.
         n_chips: chips the ``n_samples`` global batch spreads over — MFU is
             quoted per chip.
         cost_model: the planner's fitted
@@ -322,7 +343,7 @@ class GoodputMeter:
         model: Optional[str] = None,
         model_kwargs: Optional[Dict] = None,
         flops_per_sample: Optional[float] = None,
-        peak_flops_per_chip=197e12,
+        peak_flops_per_chip=None,
         n_chips: int = 1,
         cost_model=None,
         bucket_bytes: Optional[Sequence[float]] = None,
@@ -335,8 +356,8 @@ class GoodputMeter:
         if flops_per_sample is None and model is not None:
             flops_per_sample = model_flops_per_sample(model, **(model_kwargs or {}))
         self.flops_per_sample = flops_per_sample
-        if isinstance(peak_flops_per_chip, str):
-            peak_flops_per_chip = PEAK_FLOPS_PER_CHIP[peak_flops_per_chip]
+        if peak_flops_per_chip is None or isinstance(peak_flops_per_chip, str):
+            peak_flops_per_chip = chip_peak_flops(peak_flops_per_chip)
         self.peak_flops_per_chip = float(peak_flops_per_chip)
         self.n_chips = max(1, int(n_chips))
         self.cost_model = cost_model

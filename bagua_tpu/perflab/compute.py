@@ -126,7 +126,7 @@ def flops_census(closed_jaxpr) -> Dict[str, float]:
     return out
 
 
-def compute_time_s(flops: float, chip: str = "v5e", mfu: float = 0.3) -> float:
+def compute_time_s(flops: float, chip: str = "TPU v5 lite", mfu: float = 0.3) -> float:
     """Modeled compute span: traced FLOPs at ``mfu`` of the chip's peak.
 
     ``mfu`` is an explicit assumption (BENCH_MODELED.json records it) — the

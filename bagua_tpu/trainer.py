@@ -95,15 +95,11 @@ class Trainer:
         tp_axis=None,
         autopilot=None,
     ):
-        # Env-gated persistent compile cache (BAGUA_COMPILE_CACHE_DIR): a
-        # restarted trainer deserializes the step executable instead of
-        # paying the multi-second XLA compile again.  No default dir — the
-        # Trainer never writes a cache the user didn't ask for.
+        # Persistent compile cache: a restarted trainer deserializes the step
+        # executable instead of paying the XLA compile again.
         from bagua_tpu.env import setup_compile_cache
 
-        cache_dir = setup_compile_cache()
-        if cache_dir:
-            logger.info("persistent compilation cache at %s", cache_dir)
+        logger.info("persistent compilation cache at %s", setup_compile_cache())
         self.telemetry = telemetry
         self.health_monitor = health_monitor
         self.ddp = DistributedDataParallel(
@@ -137,6 +133,9 @@ class Trainer:
             if self.watchdog.digest_pusher is None:
                 self.watchdog.digest_pusher = self._push_flight_digest
         self._session: Optional[AutotuneSession] = None
+        #: per-rank losses of the most recent ``fit`` step (a device array,
+        #: not synced; None before the first step)
+        self.last_losses = None
         # xprof capture of steps [a, b) once compilation has settled
         # (docs/performance.md "profile -> fix -> repeat").
         self.profile_dir = profile_dir
@@ -259,7 +258,8 @@ class Trainer:
         return push_flight_digest(self._rendezvous_client(), fr)
 
     def fit(self, state, batches: Iterable, n_steps: Optional[int] = None, log_every: int = 100):
-        """Run the training loop; returns the final state."""
+        """Run the training loop; returns the final state.  The per-rank
+        losses of the last step taken are left in ``last_losses``."""
         losses = None
         for i, batch in enumerate(batches):
             if n_steps is not None and i >= n_steps:
@@ -286,6 +286,7 @@ class Trainer:
             n_samples = jax.tree.leaves(batch)[0].shape[0]
             with self.timer.step(n_samples):
                 state, losses = self.ddp.train_step(state, batch)
+            self.last_losses = losses
             if self._profiler is not None and i == self.profile_steps[1] - 1:
                 jax.block_until_ready((state, losses))
                 self._profiler.stop()

@@ -19,10 +19,7 @@ import time
 
 from _bench_common import BenchHarness
 
-HARNESS = BenchHarness(
-    "moe_samples_per_sec_per_chip", "samples/s/chip",
-    recorded_artifact="BENCH_MOE_TPU.json",
-)
+HARNESS = BenchHarness("moe_samples_per_sec_per_chip", "samples/s/chip")
 
 import flax.linen as nn
 import jax
@@ -192,4 +189,4 @@ def main():
 
 
 if __name__ == "__main__":
-    HARNESS.guard(main)
+    main()

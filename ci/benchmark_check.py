@@ -24,15 +24,15 @@ import sys
 import time
 
 # needs the package installed: `python ci/check_packaging.py` (once) or
-# `pip install -e . --no-deps`; ci/tpu_session.sh does this as step 0
+# `pip install -e . --no-deps`
 
 import jax
 
 # Persistent compilation cache: the determinism gate runs every model twice,
 # and the second run (plus future CI runs) should not pay the compile again.
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/bagua_ci_jax_cache")
-jax.config.update("jax_compilation_cache_dir", os.environ["JAX_COMPILATION_CACHE_DIR"])
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from bagua_tpu.env import setup_compile_cache
+
+setup_compile_cache()
 
 QADAM_WARMUP = 5
 

@@ -40,10 +40,10 @@ def main():
         params = init_mlp(jax.random.PRNGKey(0), [32, 64, 8])
         state = trainer.init_state(params)
         start = int(state.step[0])
-        print(f"starting at step {start}")
+        n = bagua_tpu.get_default_group().size
+        print(f"starting at step {start} on {n} {jax.devices()[0].platform} device(s)")
 
         rng = np.random.RandomState(0)
-        n = bagua_tpu.get_default_group().size
 
         def batches():
             for _ in range(args.steps - start):

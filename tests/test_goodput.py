@@ -43,6 +43,7 @@ from bagua_tpu.observability.goodput import (
 # the perf-audit hand-math constants (ci/perf_audit.py render_md)
 AUDIT_VGG16_TRAIN_GFLOP = 46.5e9
 AUDIT_V5E_PEAK = 197e12
+V5E = "TPU v5 lite"  # the chip's device_kind: the peak table's key
 
 
 # -- analytic estimators ------------------------------------------------------
@@ -60,7 +61,7 @@ def test_mfu_matches_audit_compute_floor():
     # audit: 32 img × 46.5 GFLOP = 1.49 TF/step/chip; 1.49/197 = 7.6 ms at
     # 100% MFU.  A step taking exactly the compute floor must report MFU≈1.
     reg = MetricsRegistry()
-    meter = GoodputMeter(model="vgg16", peak_flops_per_chip="v5e", n_chips=1,
+    meter = GoodputMeter(model="vgg16", peak_flops_per_chip=V5E, n_chips=1,
                          registry=reg)
     floor_s = 32 * AUDIT_VGG16_TRAIN_GFLOP / AUDIT_V5E_PEAK
     mfu = meter.on_step(wall_s=floor_s, n_samples=32)
@@ -71,7 +72,7 @@ def test_mfu_matches_audit_compute_floor():
     # half the throughput -> half the MFU; spread over 8 chips -> 1/8 each
     assert meter.on_step(wall_s=2 * floor_s, n_samples=32) == pytest.approx(
         mfu / 2, rel=1e-6)
-    meter8 = GoodputMeter(model="vgg16", peak_flops_per_chip="v5e", n_chips=8)
+    meter8 = GoodputMeter(model="vgg16", peak_flops_per_chip=V5E, n_chips=8)
     assert meter8.on_step(wall_s=floor_s, n_samples=32) == pytest.approx(
         mfu / 8, rel=1e-6)
 
@@ -86,7 +87,7 @@ def test_mlp_flops_and_registry():
         model_flops_per_sample("resnet9000")
     register_model_flops("toy", lambda width=2: 10.0 * width)
     assert model_flops_per_sample("toy", width=3) == pytest.approx(90.0)
-    assert "v5e" in PEAK_FLOPS_PER_CHIP and PEAK_FLOPS_PER_CHIP["v5e"] == AUDIT_V5E_PEAK
+    assert PEAK_FLOPS_PER_CHIP[V5E] == AUDIT_V5E_PEAK
 
 
 def test_flops_from_cost_analysis_shapes():
@@ -109,7 +110,7 @@ def test_flops_from_cost_analysis_shapes():
 
 
 def test_calibrate_from_compiled_adopts_xla_count():
-    meter = GoodputMeter(flops_per_sample=1.0)
+    meter = GoodputMeter(flops_per_sample=1.0, peak_flops_per_chip=V5E)
 
     class C:
         def cost_analysis(self):
@@ -170,7 +171,7 @@ def test_ledger_reattribute_never_overdraws():
 
 
 def test_on_restart_prices_lost_steps_at_p50():
-    meter = GoodputMeter(flops_per_sample=1.0)
+    meter = GoodputMeter(flops_per_sample=1.0, peak_flops_per_chip=V5E)
     for w in (0.1, 0.2, 0.3, 0.2, 0.2):
         meter.on_step(wall_s=w, n_samples=1)
     meter.on_restart(lost_steps=4)
@@ -194,14 +195,14 @@ def test_predicted_wire_time_and_efficiency_gauge():
     assert predicted == pytest.approx(sum(1e-6 + b / 1e9 for b in buckets))
 
     reg = MetricsRegistry()
-    meter = GoodputMeter(flops_per_sample=1.0, cost_model=cm,
+    meter = GoodputMeter(flops_per_sample=1.0, peak_flops_per_chip=V5E, cost_model=cm,
                          bucket_bytes=buckets, registry=reg)
     assert meter.predicted_wire_s() == pytest.approx(predicted)
     eff = meter.observe_wire(measured_wire_s=2 * predicted)
     assert eff == pytest.approx(0.5)
     assert reg.snapshot()["wire_efficiency"] == pytest.approx(0.5, abs=1e-6)
     # no cost model -> no gauge, no crash
-    bare = GoodputMeter(flops_per_sample=1.0)
+    bare = GoodputMeter(flops_per_sample=1.0, peak_flops_per_chip=V5E)
     assert bare.predicted_wire_s() is None
     assert bare.observe_wire(1.0) is None
 
@@ -213,7 +214,7 @@ def test_ledger_sums_to_wall_over_real_run(group, tmp_path):
     """Acceptance: buckets sum to wall time ±1% over a run with a forced
     recompile and a blocking snapshot ride-along."""
     meter = GoodputMeter(model="mlp", model_kwargs={"sizes": [12, 16, 16, 4]},
-                         n_chips=8)
+                         peak_flops_per_chip=V5E, n_chips=8)
     tel = Telemetry(metrics_jsonl=str(tmp_path / "m.jsonl"), goodput=meter)
     ddp = DistributedDataParallel(
         mse_loss, optax.sgd(0.1), GradientAllReduceAlgorithm(),
@@ -246,7 +247,7 @@ def test_ledger_sums_to_wall_over_real_run(group, tmp_path):
 
 
 def test_compile_wall_lands_in_histogram_and_detector(group, tmp_path):
-    meter = GoodputMeter(flops_per_sample=1.0)
+    meter = GoodputMeter(flops_per_sample=1.0, peak_flops_per_chip=V5E)
     tel = Telemetry(metrics_jsonl=str(tmp_path / "m.jsonl"), goodput=meter)
     ddp = DistributedDataParallel(
         mse_loss, optax.sgd(0.1), GradientAllReduceAlgorithm(),

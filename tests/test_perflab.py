@@ -18,7 +18,6 @@ from bagua_tpu.perflab import (
     DEFAULT_TOPOLOGY,
     flops_census,
     model_step_cell,
-    modeled_bench_rows,
     pallas_kernel_basis,
     t_collective,
     torus_dims,
@@ -220,16 +219,6 @@ def test_pallas_basis_fallback_without_chip_evidence(tmp_path):
     chip = pallas_kernel_basis("gradient_allreduce", "int8",
                                evidence_path=str(ev))
     assert chip["basis"] == "measured-chip"
-
-
-def test_modeled_bench_rows_read_committed_artifact():
-    rows = modeled_bench_rows("vgg16_img_per_sec_per_chip")
-    assert rows and rows[0]["mode"] == "modeled"
-    assert rows[0]["value"] > 0
-    assert rows[0]["trend"], "modeled trend rows missing"
-    eff = modeled_bench_rows("vgg16_dp_scaling_efficiency")
-    assert eff and 0 < eff[0]["value"] <= 1.0
-    assert modeled_bench_rows("no_such_metric") == []
 
 
 # ---------------------------------------------------------------------------
