@@ -20,6 +20,7 @@ from bagua_tpu.observability.annotations import (
     EXCHANGE_PREFIX,
     STEP_PREFIX,
     bucket_scope,
+    host_span,
     mp_scope,
     parse_exchange_label,
     parse_mp_label,
@@ -80,9 +81,10 @@ from bagua_tpu.observability.flight_recorder import (
 from bagua_tpu.observability.trace_analysis import (
     COLLECTIVE_OPS,
     analyze_trace,
-    find_trace_file,
+    find_capture,
     hlo_op_labels,
     load_trace_events,
+    summarize_capture,
 )
 from bagua_tpu.observability.tracing import (
     SPAN_SCHEMA,
@@ -108,6 +110,7 @@ __all__ = [
     "EXCHANGE_PREFIX",
     "STEP_PREFIX",
     "bucket_scope",
+    "host_span",
     "mp_scope",
     "step_scope",
     "parse_exchange_label",
@@ -164,9 +167,10 @@ __all__ = [
     # trace analysis
     "COLLECTIVE_OPS",
     "analyze_trace",
-    "find_trace_file",
+    "find_capture",
     "hlo_op_labels",
     "load_trace_events",
+    "summarize_capture",
     # distributed tracing
     "SPAN_SCHEMA",
     "Span",

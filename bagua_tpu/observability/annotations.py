@@ -3,9 +3,9 @@
 The overlap relaxations only pay off if each bucket's collective really
 rides the backward pass — and the only ground truth is the device trace.
 XLA carries a per-instruction ``op_name`` metadata string assembled from
-``jax.named_scope`` frames, and the profiler's trace events can be joined
-back to it through the instruction name (``args.hlo_op`` in
-``trace.json.gz``).  These helpers emit a *parseable* scope grammar so
+``jax.named_scope`` frames, and the events of a profiler capture
+(``.xplane.pb``) can be joined back to it through the instruction name and
+the compiled module's text.  These helpers emit a *parseable* scope grammar so
 :mod:`bagua_tpu.observability.trace_analysis` can attribute every
 collective span to its ``algo``/``bucket``/``phase`` (the transparent
 fine-grained tracking of T3, arXiv:2401.16677; the reference shipped the
@@ -14,6 +14,7 @@ host-side analog as OTel spans in ``bagua-opentelemetry``):
     bagua_ex/algo=gradient_allreduce/bucket=3/phase=overlap   (bucket exchanges)
     bagua_ex/axis=tp/phase=rs_ring                             (model-parallel)
     bagua_step/phase=optimizer                                 (step phases)
+    bagua_host/step/dispatch                                   (host spans)
 
 The second form labels *model-parallel* exchanges — the tensor-parallel
 ``psum``/ring ``ppermute``s and the MoE dispatch/combine all-to-alls — which
@@ -21,6 +22,11 @@ have no bucket index: they are keyed by the logical parallelism axis (``tp``
 or ``ep``) plus a phase naming the exchange (``row_psum``, ``ag_ring``,
 ``rs_ring``, ``row_allgather``, ``dispatch``, ``combine``).  The trace
 analyzer aggregates them into per-scope ``measured_overlap_frac`` rows.
+
+The last form is not HLO metadata but a ``jax.profiler.TraceAnnotation``
+(:func:`host_span`): the fit loop and the engine put their own host work on
+the capture's clock, so an idle gap of the device can be put down to what
+the host was doing when it began.  With no profiler active it is a flag test.
 
 ``named_scope`` only decorates metadata — it never changes the traced
 computation, so annotated and unannotated steps are bitwise-identical and
@@ -33,15 +39,21 @@ joiner, the flight recorder's record templates and the static verifier
 the ``jax.named_scope`` factories.
 """
 
+import time
+
 import jax
 
 from bagua_tpu.observability.scope_grammar import (
     EXCHANGE_PREFIX,
+    FIT_STEP,
+    HOST_PREFIX,
     STEP_PREFIX,
     format_exchange_label,
+    format_host_span,
     format_mp_label,
     format_step_label,
     parse_exchange_label,
+    parse_host_span,
     parse_mp_label,
     parse_step_phase,
 )
@@ -54,10 +66,15 @@ from bagua_tpu.observability.scope_grammar import STEP_RE as _STEP_RE  # noqa: F
 __all__ = [
     "EXCHANGE_PREFIX",
     "STEP_PREFIX",
+    "HOST_PREFIX",
     "bucket_scope",
     "step_scope",
     "mp_scope",
+    "host_span",
+    "timed_host_span",
+    "fit_step_span",
     "parse_exchange_label",
+    "parse_host_span",
     "parse_mp_label",
     "parse_step_phase",
 ]
@@ -90,3 +107,41 @@ def mp_scope(axis: str, phase: str):
     a context manager around the collective, exactly like
     :func:`bucket_scope`."""
     return jax.named_scope(format_mp_label(axis, phase))
+
+
+def host_span(name: str):
+    """Profiler annotation ``bagua_host/<name>`` around a stretch of the
+    host's own work (``fit/next_batch``, ``step/dispatch``): it lands on the
+    host's line of the capture, on the device trace's clock."""
+    return jax.profiler.TraceAnnotation(format_host_span(name))
+
+
+class timed_host_span:
+    """``host_span(f"{where}/{key}")`` that also adds the ``perf_counter``
+    time it was open to ``totals[key]`` and leaves it in ``elapsed``: the
+    span in the capture and the counter of the same name are one
+    measurement and cannot disagree."""
+
+    __slots__ = ("_span", "_totals", "_key", "_t0", "elapsed")
+
+    def __init__(self, where: str, key: str, totals: dict):
+        self._span = host_span(f"{where}/{key}")
+        self._totals, self._key = totals, key
+        self.elapsed = 0.0
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.elapsed = time.perf_counter() - self._t0
+        self._totals[self._key] += self.elapsed
+        self._span.__exit__(*exc)
+        return False
+
+
+def fit_step_span(step_num: int):
+    """The ``bagua_fit`` step annotation around one iteration of
+    ``Trainer.fit``; the capture keeps ``step_num`` as a statistic."""
+    return jax.profiler.StepTraceAnnotation(FIT_STEP, step_num=step_num)

@@ -19,6 +19,12 @@ The three label forms::
     bagua_ex/axis=tp/phase=rs_ring                            (model-parallel)
     bagua_step/phase=optimizer                                (engine step phases)
 
+and, on the host's side of the same capture, the spans the fit loop and the
+engine open around their own work (``jax.profiler.TraceAnnotation``, see
+:func:`~bagua_tpu.observability.annotations.host_span`)::
+
+    bagua_host/step/dispatch                                  (host spans)
+
 plus the quantized-ring sub-scopes nested *inside* a bucket-exchange frame
 (``qr8_quant``, ``qr8_hop3``, ``qr4_ag`` — see
 :mod:`bagua_tpu.kernels.quantized_ring`), the overlap backward anchor
@@ -40,6 +46,8 @@ __all__ = [
     "EXCHANGE_PREFIX",
     "STEP_PREFIX",
     "STALE_PREFIX",
+    "HOST_PREFIX",
+    "FIT_STEP",
     "EXCHANGE_RE",
     "STEP_RE",
     "MP_RE",
@@ -50,12 +58,14 @@ __all__ = [
     "format_mp_label",
     "format_step_label",
     "format_stale_scope",
+    "format_host_span",
     "parse_exchange_label",
     "parse_mp_label",
     "parse_step_phase",
     "parse_qr_scope",
     "parse_overlap_bwd",
     "parse_stale_scope",
+    "parse_host_span",
     "hlo_op_labels",
 ]
 
@@ -63,6 +73,10 @@ __all__ = [
 EXCHANGE_PREFIX = "bagua_ex"
 STEP_PREFIX = "bagua_step"
 STALE_PREFIX = "bagua_stale"
+#: host spans (profiler annotations, not HLO metadata)
+HOST_PREFIX = "bagua_host"
+#: the ``StepTraceAnnotation`` around one iteration of ``Trainer.fit``
+FIT_STEP = "bagua_fit"
 
 EXCHANGE_RE = re.compile(
     EXCHANGE_PREFIX + r"/algo=(?P<algo>[^/]+)/bucket=(?P<bucket>\d+)/phase=(?P<phase>[^/\"]+)"
@@ -104,6 +118,12 @@ def format_stale_scope(tau) -> str:
     traced under — the marker :func:`parse_stale_scope` (and through it the
     static verifier's sanction) recovers from the jaxpr name stack."""
     return f"{STALE_PREFIX}/tau={int(tau)}"
+
+
+def format_host_span(name: str) -> str:
+    """``bagua_host/<name>``; ``name`` is ``<where>/<what>`` (``fit/next_batch``,
+    ``step/dispatch``)."""
+    return f"{HOST_PREFIX}/{name}"
 
 
 # -- parsers ------------------------------------------------------------------
@@ -161,6 +181,13 @@ def parse_stale_scope(op_name: str) -> Optional[int]:
     """The staleness bound τ of a ``bagua_stale`` frame, if present."""
     m = STALE_RE.search(op_name or "")
     return int(m.group("tau")) if m else None
+
+
+def parse_host_span(event_name: str) -> Optional[str]:
+    """``step/dispatch`` from ``bagua_host/step/dispatch``; None for any
+    other event of the host's lines."""
+    head, _, name = (event_name or "").partition("/")
+    return name if head == HOST_PREFIX and name else None
 
 
 # -- the HLO join table -------------------------------------------------------

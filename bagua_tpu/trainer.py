@@ -4,8 +4,11 @@ analog — the reference integrates via pytorch-lightning, tested at
 one-stop entry is a small Trainer that wires the DDP engine, autotune,
 watchdog, speed metrics and checkpointing together)."""
 
+import contextlib
+import itertools
 import logging
 import os
+import time
 from typing import Callable, Iterable, Optional, Tuple
 
 import jax
@@ -13,6 +16,7 @@ import jax
 from bagua_tpu.algorithms.base import Algorithm
 from bagua_tpu.ddp import AutotuneSession, DistributedDataParallel
 from bagua_tpu.observability import StepTimer, Watchdog
+from bagua_tpu.observability.annotations import fit_step_span, host_span, timed_host_span
 
 logger = logging.getLogger(__name__)
 
@@ -42,7 +46,12 @@ class Trainer:
             ``[profile_steps[0], profile_steps[1])`` (half-open; default
             iterations 10-12, past compilation) into this directory.  One
             capture per Trainer, even across multiple ``fit()`` calls; a
-            window cut short by the end of an epoch is closed and kept.
+            window cut short by the end of an epoch is closed and kept.  The
+            ``fit`` call that held the capture reduces it before it returns
+            (:func:`~bagua_tpu.observability.trace_analysis.summarize_capture`:
+            the step's device time by phase, the exchange operation by
+            operation, host spans, idle gaps by host span) into
+            ``profile_summary``.
         telemetry: opt-in
             :class:`~bagua_tpu.observability.telemetry.Telemetry` hub, passed
             through to the DDP engine.  The trainer additionally tags the
@@ -142,6 +151,21 @@ class Trainer:
         self.profile_steps = profile_steps
         self._profiler = None
         self._profiled = False  # one capture per Trainer, across fit() calls
+        self._summary_due = False  # a capture has stopped and is not reduced yet
+        #: ``trace_analysis.summarize_capture`` of the capture, made at the
+        #: end of the ``fit`` call that held it (None before, and without
+        #: ``profile_dir``)
+        self.profile_summary = None
+        # the join from a captured operation to its scope labels runs through
+        # the compiled step's text, which the engine keeps only when asked
+        self.ddp.keep_step_text = profile_dir is not None
+        if profile_dir is not None:
+            # ... and the text has to be this program's: the persistent cache
+            # keys a program without its metadata, so an executable compiled
+            # for one that differs only in its labels (the same step before a
+            # scope was added or dropped) would answer, labels and all.  A
+            # profiling process keys its compiles with the metadata in.
+            jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
         # Resilience: async snapshotter + preemption watcher (tentpole).
         self.snapshot_dir = snapshot_dir
         self.snapshotter = None
@@ -259,54 +283,91 @@ class Trainer:
 
     def fit(self, state, batches: Iterable, n_steps: Optional[int] = None, log_every: int = 100):
         """Run the training loop; returns the final state.  The per-rank
-        losses of the last step taken are left in ``last_losses``."""
-        losses = None
-        for i, batch in enumerate(batches):
-            if n_steps is not None and i >= n_steps:
-                break
-            if self._session and not self._session.profiled:
-                # one-time measured execution-order profile for autotune
-                try:
-                    self._session.profile_and_report(state, batch)
-                except Exception as e:
-                    logger.warning("bucket-order profiling failed: %s", e)
-                    self._session.profiled = True
-            if (
-                self.profile_dir is not None
-                and i == self.profile_steps[0]
-                and not self._profiled
-                and self._profiler is None
-            ):
-                from bagua_tpu.observability import ProfilerSession
+        losses of the last step taken are left in ``last_losses``.
 
-                jax.block_until_ready(state)  # clean capture window
-                self._profiler = ProfilerSession(self.profile_dir)
-                self._profiler.start()
-                self._profiled = True
-            n_samples = jax.tree.leaves(batch)[0].shape[0]
-            with self.timer.step(n_samples):
-                state, losses = self.ddp.train_step(state, batch)
-            self.last_losses = losses
+        Each iteration is a ``bagua_fit`` step annotation in a profiler
+        capture and the work inside it a ``bagua_host/fit/…`` span, so a gap
+        of the device can be put down to what this loop was doing; the time
+        in ``next()`` on ``batches`` and in the rest of the loop body outside
+        ``train_step`` is counted in the engine's ``host_overhead`` under
+        ``next_batch`` and ``loop``."""
+        overhead = self.ddp.host_overhead
+        batches = iter(batches)
+        stepped = False
+        for i in itertools.count():
+            with contextlib.ExitStack() as iteration:
+                iteration.enter_context(fit_step_span(i))
+                with timed_host_span("fit", "next_batch", overhead):
+                    batch = next(batches, None)
+                if batch is None or (n_steps is not None and i >= n_steps):
+                    break
+                if (
+                    self.profile_dir is not None
+                    and i == self.profile_steps[0]
+                    and not self._profiled
+                    and self._profiler is None
+                ):
+                    # with the batch in hand, so that the capture's first
+                    # operation is the step's.  The iteration's annotation
+                    # opened before the capture began and is not in it: open
+                    # it again for the rest of the iteration.
+                    iteration.close()
+                    self._start_capture(state)
+                    iteration.enter_context(fit_step_span(i))
+                began = time.perf_counter()
+                # rebinding ``state`` drops the donated arrays of the state
+                # before: host work of every step, and counted here
+                state, in_step, stop = self._fit_step(state, batch, log_every)
+                overhead["loop"] += time.perf_counter() - began - in_step
+            stepped = True
+            if stop:
+                return state
             if self._profiler is not None and i == self.profile_steps[1] - 1:
-                jax.block_until_ready((state, losses))
-                self._profiler.stop()
-                self._profiler = None
-                logger.info("xprof trace captured to %s", self.profile_dir)
-            if self.watchdog:
-                self.watchdog.beat()
-            if self._session:
-                self._session.tick(n_samples)
-            step = self._state_step(state)
-            if self.autopilot is not None:
-                # the controller may remap the state (algorithm switch) —
-                # the loss sync here is what feeds its canary parity check
+                self._stop_capture(state, "captured")
+        if stepped:
+            jax.block_until_ready(self.last_losses)
+        if self._profiler is not None:
+            # epoch ended inside the capture window: close it here (one
+            # short trace kept) rather than recording every later epoch
+            self._stop_capture(state, "cut at epoch end")
+        if self._summary_due:
+            self._summarize_capture()
+        return state
+
+    def _fit_step(self, state, batch, log_every: int):
+        """One iteration of :meth:`fit` after its batch: ``(state, seconds
+        inside train_step, whether to stop)``."""
+        if self._session and not self._session.profiled:
+            # one-time measured execution-order profile for autotune
+            try:
+                self._session.profile_and_report(state, batch)
+            except Exception as e:
+                logger.warning("bucket-order profiling failed: %s", e)
+                self._session.profiled = True
+        n_samples = jax.tree.leaves(batch)[0].shape[0]
+        with host_span("fit/train_step"), self.timer.step(n_samples):
+            in_step = time.perf_counter()
+            state, losses = self.ddp.train_step(state, batch)
+            in_step = time.perf_counter() - in_step
+        self.last_losses = losses
+        if self.watchdog:
+            self.watchdog.beat()
+        if self._session:
+            self._session.tick(n_samples)
+        step = self._state_step(state)
+        if self.autopilot is not None:
+            # the controller may remap the state (algorithm switch) —
+            # the loss sync here is what feeds its canary parity check
+            with host_span("fit/autopilot"):
                 jax.block_until_ready(losses)
                 state = self.autopilot.tick(state, step, float(losses.mean()))
-            if self.snapshotter is not None:
+        if self.snapshotter is not None:
+            with host_span("fit/snapshot"):
                 self.snapshotter.maybe_snapshot(state, step)
-            if self.gang is not None:
-                # window-cadenced, best-effort; off-cadence calls return
-                # immediately and KV trouble degrades to a local-only view
+        if self.gang is not None:
+            # window-cadenced, best-effort; off-cadence calls return
+            # immediately and KV trouble degrades to a local-only view
+            with host_span("fit/gang"):
                 ho = self.ddp.host_overhead
                 denom = max(1, int(ho.get("steps", 1)))
                 self.gang.tick(
@@ -314,14 +375,16 @@ class Trainer:
                     phase_ms={k: 1e3 * v / denom for k, v in ho.items()
                               if k != "steps"},
                 )
-            if self.preemption is not None and self.preemption.should_stop():
-                self._drain_and_exit(state, step)
-                return state
-            if self.ckpt_dir and step % self.ckpt_interval == 0:
-                from bagua_tpu.checkpoint import save_checkpoint
+        if self.preemption is not None and self.preemption.should_stop():
+            self._drain_and_exit(state, step)
+            return state, in_step, True
+        if self.ckpt_dir and step % self.ckpt_interval == 0:
+            from bagua_tpu.checkpoint import save_checkpoint
 
+            with host_span("fit/checkpoint"):
                 save_checkpoint(step, self.ckpt_dir, state)
-            if log_every and step % log_every == 0:
+        if log_every and step % log_every == 0:
+            with host_span("fit/log_sync"):
                 jax.block_until_ready(losses)
                 logger.info(
                     "step %d loss %.5f (%.1f samples/s)",
@@ -329,20 +392,48 @@ class Trainer:
                     float(losses.mean()),
                     self.ddp.speed_meter.speed(30.0),
                 )
-            if self.telemetry is not None:
-                # about to pull the next batch — a hang here is the input
-                # pipeline's, not the device's
-                self.telemetry.enter_phase("data")
-        if losses is not None:
-            jax.block_until_ready(losses)
-        if self._profiler is not None:
-            # epoch ended inside the capture window: close it here (one
-            # short trace kept) rather than recording every later epoch
-            jax.block_until_ready(state)
-            self._profiler.stop()
-            self._profiler = None
-            logger.info("xprof trace (cut at epoch end) captured to %s", self.profile_dir)
-        return state
+        if self.telemetry is not None:
+            # about to pull the next batch — a hang here is the input
+            # pipeline's, not the device's
+            self.telemetry.enter_phase("data")
+        return state, in_step, False
+
+    def _start_capture(self, state) -> None:
+        from bagua_tpu.observability import ProfilerSession
+
+        jax.block_until_ready(state)  # clean capture window
+        self._profiler = ProfilerSession(self.profile_dir)
+        self._profiler.start()
+        self._profiled = True
+
+    def _stop_capture(self, state, how: str) -> None:
+        with host_span("fit/capture"):  # the drain before the stop
+            jax.block_until_ready((state, self.last_losses))
+        self._profiler.stop()
+        self._profiler = None
+        self._summary_due = True
+        logger.info("xprof trace (%s) written to %s", how, self.profile_dir)
+
+    def _summarize_capture(self) -> None:
+        """Reduces the capture this ``fit`` call held, once the loop has
+        drained, and leaves the compiled step's text beside it for
+        ``ci/analyze_trace.py``.  A capture that cannot be reduced costs the
+        summary, never the run."""
+        from bagua_tpu.observability import trace_analysis
+
+        self._summary_due = False
+        try:
+            hlo_text = self.ddp.step_texts.get(self.ddp.last_variant)
+            if hlo_text:
+                with open(os.path.join(self.profile_dir, trace_analysis.STEP_TEXT_FILE), "w") as f:
+                    f.write(hlo_text)
+            self.profile_summary = trace_analysis.summarize_capture(
+                self.profile_dir, hlo_text=hlo_text)
+        except Exception:
+            logger.exception("the capture under %s could not be reduced", self.profile_dir)
+            return
+        if self.profile_summary is not None:
+            logger.info("captured step: %s", trace_analysis.format_partition(self.profile_summary))
 
     def _state_step(self, state) -> int:
         """Completed-step count, readable on every process of the gang (the

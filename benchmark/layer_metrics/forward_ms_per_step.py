@@ -1,0 +1,9 @@
+"""Milliseconds per captured step that device 0 spent in operations traced
+under ``bagua_step/phase=fwd_bwd`` outside autodiff's ``transpose(`` frame: the
+forward pass, from the program's summary of the capture."""
+
+from benchmark.step_summary import partition_ms
+
+
+def read(context):
+    return partition_ms(context, "forward")

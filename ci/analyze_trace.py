@@ -1,28 +1,25 @@
 #!/usr/bin/env python3
-"""Measured overlap efficiency from an XLA profiler capture.
+"""The step's anatomy and the measured overlap from a profiler capture.
 
-CLI/driver face of :mod:`bagua_tpu.observability.trace_analysis`: point it
-at a profiler log dir (``jax.profiler.trace`` /
-``bagua_tpu.observability.ProfilerSession`` output) and it reports, per
-labeled ``(algo, bucket)``, how much of each collective span ran hidden
-under concurrent compute — the device's own verdict on the overlap
-relaxations that PERF_AUDIT only asserts structurally.
+CLI face of :mod:`bagua_tpu.observability.trace_analysis`: point it at a
+profiler log dir (``Trainer(profile_dir=...)`` /
+``bagua_tpu.observability.ProfilerSession`` output, an ``.xplane.pb``
+somewhere under it) and it prints what ``trainer.profile_summary`` held at
+the end of the capturing ``fit`` call (:func:`summarize_capture`: the
+device's busy time by step phase, the exchange operation by operation, host
+spans, idle gaps by host span, the lead of every step's dispatch), and with
+``--overlap`` the per-bucket hidden fraction (:func:`analyze_trace`).
 
-Bucket attribution needs the compiled HLO of the captured step (the join is
-instruction name → ``op_name`` metadata → bucket label); pass it with
-``--hlo``.  Without it only the aggregate ``measured_overlap_frac`` is
-reported and every span lands in ``unattributed``.
+Attribution needs the compiled text of the captured step (the join is
+instruction name → ``op_name`` metadata → scope label).  ``Trainer`` leaves
+it beside its capture as ``step.hlo.txt`` and it is picked up from there;
+pass another with ``--hlo``.  Without it every operation is ``unattributed``
+and only collectives are told (by opcode).
 
 Usage::
 
-    # from a Trainer(profile_dir=...) / ProfilerSession capture:
-    python ci/analyze_trace.py /tmp/bagua_trace --hlo step.hlo.txt
-
-    # aggregate only (no HLO at hand):
     python ci/analyze_trace.py /tmp/bagua_trace
-
-``ci/trace_vgg16.py`` drives :func:`analyze` in-process to record
-``measured_overlap_frac`` in ``TRACE_VGG16.json``.
+    python ci/analyze_trace.py /tmp/bagua_trace --hlo step.hlo.txt --overlap
 """
 
 import argparse
@@ -34,47 +31,63 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:  # runnable from any cwd without an editable install
     sys.path.insert(0, REPO)
 
-from bagua_tpu.observability.trace_analysis import analyze_trace
-
-
-def analyze(log_dir, hlo_text=None, module=None):
-    """In-process entry point (what ``ci/trace_vgg16.py`` calls)."""
-    return analyze_trace(log_dir, hlo_text=hlo_text, module=module)
+from bagua_tpu.observability.trace_analysis import (
+    STEP_TEXT_FILE,
+    analyze_trace,
+    format_partition,
+    summarize_capture,
+)
 
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("trace_dir", help="profiler log dir or .trace.json.gz path")
+    ap.add_argument("trace_dir", help="profiler log dir or .xplane.pb path")
     ap.add_argument(
         "--hlo", default=None,
-        help="compiled HLO text file of the captured step (enables per-bucket "
-        "attribution)",
+        help=f"compiled HLO text of the captured step (default: {STEP_TEXT_FILE} "
+        "in the log dir, where Trainer leaves it)",
+    )
+    ap.add_argument("--device", type=int, default=0, help="which device's steps")
+    ap.add_argument(
+        "--overlap", action="store_true",
+        help="print the per-bucket hidden fraction (analyze_trace) instead",
     )
     ap.add_argument(
         "--module", default=None,
-        help="restrict to events of this hlo_module (default: the module "
-        "named in --hlo, or all modules)",
+        help="with --overlap: restrict to events of this hlo_module (default: "
+        "the module named in the HLO text, or all modules)",
     )
     ap.add_argument("--out", default=None, help="also write the report as JSON")
     args = ap.parse_args()
 
+    hlo = args.hlo
+    if hlo is None and os.path.isfile(os.path.join(args.trace_dir, STEP_TEXT_FILE)):
+        hlo = os.path.join(args.trace_dir, STEP_TEXT_FILE)
     hlo_text = None
-    if args.hlo:
-        with open(args.hlo) as f:
+    if hlo:
+        with open(hlo) as f:
             hlo_text = f.read()
-    report = analyze(args.trace_dir, hlo_text=hlo_text, module=args.module)
+    if args.overlap:
+        report = analyze_trace(args.trace_dir, hlo_text=hlo_text, module=args.module)
+    else:
+        report = summarize_capture(args.trace_dir, hlo_text=hlo_text, device=args.device)
 
     if args.out:
         with open(args.out, "w") as f:
             json.dump(report, f, indent=1)
     print(json.dumps(report, indent=1))
-    print(
-        f"\nmeasured_overlap_frac = {report['measured_overlap_frac']} over "
-        f"{report['collective_spans']} collective spans "
-        f"({report['collective_ms']} ms on the wire, "
-        f"{report['hidden_ms']} ms hidden under compute)",
-        file=sys.stderr,
-    )
+    if report is None:
+        print(f"\nno operation of device {args.device} in the capture", file=sys.stderr)
+    elif args.overlap:
+        print(
+            f"\nmeasured_overlap_frac = {report['measured_overlap_frac']} over "
+            f"{report['collective_spans']} collective spans "
+            f"({report['collective_ms']} ms on the wire, "
+            f"{report['hidden_ms']} ms hidden under compute)",
+            file=sys.stderr,
+        )
+    else:
+        print("\n" + format_partition(report), file=sys.stderr)
 
 
 if __name__ == "__main__":

@@ -2191,7 +2191,7 @@ def axis_attribution_lane(out_prefix: str):
 def autotune_planner_lane(fixture_path=None):
     """Recorded-span planner gate (pure cost model, no compile — CPU-safe).
 
-    Replays the committed VGG16 span fixture (``ci/record_vgg16_spans.py``)
+    Replays the committed VGG16 span fixture (``ci/fixtures/vgg16_bucket_spans.json``)
     through the trace-driven bucket planner and asserts its DP partition
     predicts *strictly lower* exposed-communication time than the seed greedy
     byte-threshold plan evaluated under the same cost model — the planner's
@@ -3177,36 +3177,7 @@ EXPECTED = {
 }
 
 
-def load_trace_overlap():
-    """Scheduler-visible overlap evidence from ci/trace_vgg16.py's artifact:
-    the measured full-step times for both execution modes (absent until that
-    script has run on this checkout)."""
-    path = os.path.join(REPO, "TRACE_VGG16.json")
-    if not os.path.exists(path):
-        return None
-    try:
-        with open(path) as f:
-            tr = json.load(f)
-    except (OSError, json.JSONDecodeError):
-        return None
-    if "full_step_overlap_ms" not in tr:
-        return None
-    return {
-        "backend": tr.get("backend"),
-        "full_step_ms": tr.get("full_step_ms"),
-        "full_step_overlap_ms": tr.get("full_step_overlap_ms"),
-        "overlap_gain_ms": tr.get("derived", {}).get("overlap_gain_ms"),
-        # device-measured overlap efficiency (ci/analyze_trace.py join of the
-        # captured trace against the in-graph bucket labels; absent in older
-        # artifacts)
-        "measured_overlap_frac": tr.get("measured_overlap_frac"),
-        # per-algorithm monolithic/overlap full-step timings for the
-        # compressed + decentralized families (absent in older artifacts)
-        "algo_overlap_ms": tr.get("algo_overlap_ms"),
-    }
-
-
-def render_md(ddp_results, fsdp_result, n, trace=None, model="vgg16"):
+def render_md(ddp_results, fsdp_result, n, model="vgg16"):
     lines = [
         "# PERF_AUDIT — compiled wire-pattern audit",
         "",
@@ -3302,41 +3273,6 @@ def render_md(ddp_results, fsdp_result, n, trace=None, model="vgg16"):
         "leaf shapes.",
         "",
     ]
-    if trace:
-        lines += [
-            f"Scheduler-visible overlap (ci/trace_vgg16.py, "
-            f"{trace.get('backend')} backend): full step "
-            f"{trace.get('full_step_ms')} ms monolithic vs "
-            f"{trace.get('full_step_overlap_ms')} ms overlapped — gain "
-            f"{trace.get('overlap_gain_ms')} ms/step."
-            + (
-                f"  Measured overlap (device trace, hidden wire / total wire): "
-                f"{trace['measured_overlap_frac']}."
-                if trace.get("measured_overlap_frac") is not None
-                else ""
-            ),
-            "",
-        ]
-        for algo, t in (trace.get("algo_overlap_ms") or {}).items():
-            frac = t.get("measured_overlap_frac")
-            lines.append(
-                f"- `{algo}`: {t.get('full_step_ms')} ms monolithic vs "
-                f"{t.get('full_step_overlap_ms')} ms overlapped "
-                f"(gain {t.get('overlap_gain_ms')} ms/step"
-                + (f", measured overlap {frac}" if frac is not None else "")
-                + ")"
-            )
-        if trace.get("algo_overlap_ms"):
-            lines.append("")
-        if trace.get("backend") == "cpu" and trace.get("measured_overlap_frac") is not None:
-            lines += [
-                "(The measured fractions above come from the 1-device CPU "
-                "smoke, where collectives degenerate to no-ops — they are "
-                "meaningful only from a multi-device/chip capture.  The "
-                "8-device lane in `tests/test_telemetry.py` regression-tests "
-                "the analyzer's per-bucket attribution end-to-end.)",
-                "",
-            ]
     lines += [
         "## Roofline projection (v5e, VGG16 bs32/chip)",
         "",
@@ -3583,11 +3519,10 @@ def main():
         )
     fsdp_result = None if args.ddp_only else audit_fsdp()[0]
 
-    trace = load_trace_overlap()
     with open(args.out + ".json", "w") as f:
         json.dump(
             {"ddp": ddp_results, "fsdp": fsdp_result, "mesh": n,
-             "model": args.model, "trace_overlap": trace,
+             "model": args.model,
              "autotune_planner": planner_result,
              "wire": wire_result,
              "health": health_result,
@@ -3607,7 +3542,7 @@ def main():
             f, indent=1,
         )
     with open(args.out + ".md", "w") as f:
-        f.write(render_md(ddp_results, fsdp_result, n, trace=trace, model=args.model))
+        f.write(render_md(ddp_results, fsdp_result, n, model=args.model))
     print(f"wrote {args.out}.md and .json", file=sys.stderr)
 
 

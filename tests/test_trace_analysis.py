@@ -4,20 +4,24 @@ The overlap metric is only as trustworthy as ``_merge_intervals`` /
 ``_covered`` on the degenerate spans real traces contain — zero-length
 events, identical timestamps, fully-nested intervals — and as the loader's
 behavior on a capture the profiler never finished writing (job killed
-mid-profile): salvage the parsed prefix, never raise.
+mid-profile): salvage the planes written whole, never raise.
 """
 
-import gzip
-import json
+import os
+import sys
 
 import pytest
 
 from bagua_tpu.observability.trace_analysis import (
     _covered,
     _merge_intervals,
+    analyze_events,
     analyze_trace,
     load_trace_events,
 )
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "ci"))
+from trim_capture import xspace_bytes  # noqa: E402  (the PR's own writer of fixtures)
 
 
 # -- interval math ------------------------------------------------------------
@@ -86,53 +90,56 @@ def test_covered_identical_timestamps_not_double_counted():
 # -- corrupt/truncated captures -----------------------------------------------
 
 
-def trace_event(hlo_op, ts, dur, pid=1, tid=1, module="m"):
-    return {"ph": "X", "pid": pid, "tid": tid, "ts": ts, "dur": dur,
-            "name": hlo_op, "args": {"hlo_op": hlo_op, "hlo_module": module}}
+def trace_event(hlo_op, ts, dur, device=0, line="t1", module="m"):
+    """An operation as ``load_trace_events`` gives it (microseconds)."""
+    return {"hlo_op": hlo_op, "hlo_module": module, "lane": (device, line),
+            "ts": ts, "dur": dur}
 
 
-def write_trace(path, events):
-    with gzip.open(path, "wt") as f:
-        json.dump({"traceEvents": events}, f)
+def cpu_plane(events, line="tf_XLAPjRtCpuClient/1"):
+    """The ``/host:CPU`` plane of a CPU capture: one executor line whose
+    events carry the operation as statistics."""
+    return ("/host:CPU", [(line, [
+        (e["hlo_op"], 1e3 * e["ts"], 1e3 * e["dur"],
+         {"hlo_op": e["hlo_op"], "hlo_module": e["hlo_module"], "run_id": 1,
+          "device_ordinal": e["lane"][0]})
+        for e in events])])
 
 
-def test_analyze_synthetic_trace_overlap_math(tmp_path):
-    path = str(tmp_path / "t.trace.json.gz")
+def write_capture(path, planes):
+    with open(path, "wb") as f:
+        f.write(xspace_bytes(planes))
+
+
+def test_analyze_synthetic_trace_overlap_math():
     # compute on lane 1 covers [0,100]; the collective [50,150] on lane 2
     # is half hidden
-    write_trace(path, [
-        trace_event("fusion.1", ts=0.0, dur=100.0, tid=1),
-        trace_event("all-reduce.7", ts=50.0, dur=100.0, tid=2),
+    rep = analyze_events([
+        trace_event("fusion.1", ts=0.0, dur=100.0, line="t1"),
+        trace_event("all-reduce.7", ts=50.0, dur=100.0, line="t2"),
     ])
-    rep = analyze_trace(path)
     assert rep["collective_spans"] == 1
     assert rep["measured_overlap_frac"] == pytest.approx(0.5)
     assert rep["per_bucket"] == []  # no HLO text: spans are unattributed
     assert rep["unattributed"]["spans"] == 1
 
 
-def test_truncated_trace_degrades_to_salvaged_prefix(tmp_path, caplog, monkeypatch):
+def test_truncated_capture_degrades_to_salvaged_prefix(tmp_path, caplog):
     import logging
 
-    from bagua_tpu.observability import trace_analysis
-
-    # small read chunks so the decompression error lands mid-stream, the way
-    # it does on a multi-GB real capture (default chunk is 4 MiB)
-    orig = trace_analysis._iter_trace_events
-    monkeypatch.setattr(trace_analysis, "_iter_trace_events",
-                        lambda f: orig(f, chunk=1024))
-
-    path = str(tmp_path / "t.trace.json.gz")
-    events = [trace_event(f"fusion.{i}", ts=10.0 * i, dur=5.0) for i in range(500)]
-    events.append(trace_event("all-reduce.0", ts=0.0, dur=50.0, tid=2))
-    write_trace(path, events)
+    path = str(tmp_path / "t.xplane.pb")
+    first = [trace_event(f"fusion.{i}", ts=10.0 * i, dur=5.0) for i in range(300)]
+    second = [trace_event(f"fusion.{300 + i}", ts=10.0 * i, dur=5.0, device=1)
+              for i in range(200)]
+    second.append(trace_event("all-reduce.0", ts=0.0, dur=50.0, device=1))
+    write_capture(path, [cpu_plane(first), cpu_plane(second, line="tf_XLAPjRtCpuClient/2")])
     full = load_trace_events(path)
     assert len(full) == 501
 
-    # chop the gzip stream mid-file: the common killed-mid-profile capture
+    # chop the file inside its second plane: the common killed-mid-profile capture
     blob = open(path, "rb").read()
     with open(path, "wb") as f:
-        f.write(blob[: len(blob) // 2])
+        f.write(blob[: len(blob) * 4 // 5])
     with caplog.at_level(logging.WARNING,
                          logger="bagua_tpu.observability.trace_analysis"):
         salvaged = load_trace_events(path)
@@ -143,13 +150,13 @@ def test_truncated_trace_degrades_to_salvaged_prefix(tmp_path, caplog, monkeypat
     assert rep["num_xla_events"] == len(salvaged)
 
 
-def test_garbage_gzip_payload_degrades_empty(tmp_path):
-    path = str(tmp_path / "t.trace.json.gz")
+def test_garbage_payload_degrades_empty(tmp_path):
+    path = str(tmp_path / "t.xplane.pb")
     with open(path, "wb") as f:
-        f.write(b"\x1f\x8b\x08\x00garbage-not-a-gzip-body")
+        f.write(b"\x1f\x8b\x08\x00garbage-not-a-capture")
     assert load_trace_events(path) == []
 
 
-def test_missing_trace_still_raises(tmp_path):
+def test_missing_capture_still_raises(tmp_path):
     with pytest.raises(FileNotFoundError):
         load_trace_events(str(tmp_path / "empty_dir"))
