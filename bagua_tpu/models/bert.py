@@ -22,6 +22,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from bagua_tpu.models.losses import softmax_cross_entropy
 from bagua_tpu.parallel.ring_attention import ring_attention, _block_attention_local
 from bagua_tpu.parallel.tensor_parallel import (
     ColumnParallelDense,
@@ -166,7 +167,6 @@ def mlm_loss_fn(model: BertForPreTraining):
     def loss_fn(params, batch):
         input_ids, labels = batch
         logits = model.apply({"params": params}, input_ids)
-        logp = jax.nn.log_softmax(logits)
-        return -jnp.mean(jnp.take_along_axis(logp, labels[..., None], axis=-1))
+        return jnp.mean(softmax_cross_entropy(logits, labels))
 
     return loss_fn

@@ -6,9 +6,9 @@ import dataclasses
 from typing import Any, Tuple, Union
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
+from bagua_tpu.models.losses import softmax_cross_entropy
 from bagua_tpu.parallel.ring_attention import ring_attention, _block_attention_local
 from bagua_tpu.parallel.tensor_parallel import ColumnParallelDense, ParallelMLP, RowParallelDense
 
@@ -135,8 +135,7 @@ def lm_loss_fn(model: GPTModel):
     def loss_fn(params, batch):
         ids = batch
         logits = model.apply({"params": params}, ids)
-        logp = jax.nn.log_softmax(logits[:, :-1])
-        nll = -jnp.take_along_axis(logp, ids[:, 1:, None], axis=-1)[..., 0]
+        nll = softmax_cross_entropy(logits[:, :-1], ids[:, 1:])
         if _zigzag_active(cfg):
             t = ids.shape[1]
             if t < 4:

@@ -11,6 +11,8 @@ from typing import Dict, List, Sequence
 import jax
 import jax.numpy as jnp
 
+from bagua_tpu.models.losses import softmax_cross_entropy
+
 
 def init_mlp(key, sizes: Sequence[int]) -> Dict[str, Dict[str, jnp.ndarray]]:
     """He-initialized MLP: ``sizes = [in, h1, ..., out]``."""
@@ -44,5 +46,4 @@ def mse_loss(params, batch) -> jnp.ndarray:
 def softmax_loss(params, batch) -> jnp.ndarray:
     x, y = batch
     logits = mlp_apply(params, x)
-    logp = jax.nn.log_softmax(logits)
-    return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
+    return jnp.mean(softmax_cross_entropy(logits, y))

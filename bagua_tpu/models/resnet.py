@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from bagua_tpu.contrib.sync_batchnorm import SyncBatchNorm
+from bagua_tpu.models.losses import softmax_cross_entropy
 
 
 class BottleneckBlock(nn.Module):
@@ -99,7 +100,6 @@ def resnet_loss_fn(model: ResNet):
             {"params": params["params"], "batch_stats": params["batch_stats"]},
             x, mutable=["batch_stats"],
         )
-        logp = jax.nn.log_softmax(logits)
-        return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
+        return jnp.mean(softmax_cross_entropy(logits, y))
 
     return loss_fn

@@ -10,8 +10,9 @@ the loss in float32.
 from typing import Any, Sequence, Union
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
+
+from bagua_tpu.models.losses import softmax_cross_entropy
 
 # 'M' = 2x2 max pool; ints = conv output channels (VGG16 = config D)
 VGG16_CFG: Sequence[Union[str, int]] = (
@@ -61,7 +62,6 @@ def vgg_loss_fn(model: VGG):
     def loss_fn(params, batch):
         x, y = batch
         logits = model.apply({"params": params}, x)
-        logp = jax.nn.log_softmax(logits)
-        return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
+        return jnp.mean(softmax_cross_entropy(logits, y))
 
     return loss_fn
