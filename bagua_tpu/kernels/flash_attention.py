@@ -30,8 +30,13 @@ Padding: ``t_q``/``t_k`` pad to their (128-aligned) tile edges, ``d`` to
 128; padded keys are masked out, padded queries/channels sliced off after.
 
 Known limit: the mask is a dense ``(b, t_k, t_q)`` int8 array — the one
-remaining O(t²) HBM object on this path (256 MiB at t=16k; ~16 GiB at
-128k).  Compute and gradients are already tile-local, so the next step for
+remaining O(t²) HBM object on this path (64 MiB at t=8,192, where every
+query-key tile of every head reads its part of it again; 256 MiB at t=16k;
+~16 GiB at 128k).  On the v5e at 20 heads x 8,192 x 256, causal, these
+kernels (f32 tiles) took 37.5 ms forward + backward against 24.9 ms for the
+Pallas kernels that ship with JAX, which make the mask in the kernel and
+multiply in bf16 (``PERF.md`` section 6, PR 29): whole-sequence causal
+attention goes through ``kernels/causal_attention.py``.  Compute and gradients are already tile-local, so the next step for
 beyond-32k shards is in-kernel mask generation (causal offsets / segment
 ids via iota, splash-attention style) replacing the materialized array.
 """

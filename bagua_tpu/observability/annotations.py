@@ -14,6 +14,7 @@ host-side analog as OTel spans in ``bagua-opentelemetry``):
     bagua_ex/algo=gradient_allreduce/bucket=3/phase=overlap   (bucket exchanges)
     bagua_ex/axis=tp/phase=rs_ring                             (model-parallel)
     bagua_step/phase=optimizer                                 (step phases)
+    bagua_model/part=attn_core                                 (parts of a model)
     bagua_host/step/dispatch                                   (host spans)
 
 The second form labels *model-parallel* exchanges — the tensor-parallel
@@ -47,13 +48,16 @@ from bagua_tpu.observability.scope_grammar import (
     EXCHANGE_PREFIX,
     FIT_STEP,
     HOST_PREFIX,
+    MODEL_PREFIX,
     STEP_PREFIX,
     format_exchange_label,
     format_host_span,
+    format_model_label,
     format_mp_label,
     format_step_label,
     parse_exchange_label,
     parse_host_span,
+    parse_model_part,
     parse_mp_label,
     parse_step_phase,
 )
@@ -67,14 +71,17 @@ __all__ = [
     "EXCHANGE_PREFIX",
     "STEP_PREFIX",
     "HOST_PREFIX",
+    "MODEL_PREFIX",
     "bucket_scope",
     "step_scope",
     "mp_scope",
+    "model_scope",
     "host_span",
     "timed_host_span",
     "fit_step_span",
     "parse_exchange_label",
     "parse_host_span",
+    "parse_model_part",
     "parse_mp_label",
     "parse_step_phase",
 ]
@@ -107,6 +114,16 @@ def mp_scope(axis: str, phase: str):
     a context manager around the collective, exactly like
     :func:`bucket_scope`."""
     return jax.named_scope(format_mp_label(axis, phase))
+
+
+def model_scope(part: str):
+    """Named scope labeling one part of a model's forward pass
+    (``attn_proj``, ``attn_core``, ``moe_route``, ``moe_dispatch``,
+    ``moe_experts``, ``moe_combine``, ``moe_shared``, ``dense_mlp``,
+    ``head``).  Autodiff carries the frame into the backward pass's ops, so
+    the device trace gives each part's forward and backward time together
+    (``model_part_ms`` of ``trace_analysis.summarize_capture``)."""
+    return jax.named_scope(format_model_label(part))
 
 
 def host_span(name: str):

@@ -18,6 +18,7 @@ The three label forms::
     bagua_ex/algo=gradient_allreduce/bucket=3/phase=overlap   (bucket exchanges)
     bagua_ex/axis=tp/phase=rs_ring                            (model-parallel)
     bagua_step/phase=optimizer                                (engine step phases)
+    bagua_model/part=attn_core                                (parts of a model)
 
 and, on the host's side of the same capture, the spans the fit loop and the
 engine open around their own work (``jax.profiler.TraceAnnotation``, see
@@ -46,6 +47,7 @@ __all__ = [
     "EXCHANGE_PREFIX",
     "STEP_PREFIX",
     "STALE_PREFIX",
+    "MODEL_PREFIX",
     "HOST_PREFIX",
     "FIT_STEP",
     "EXCHANGE_RE",
@@ -54,10 +56,12 @@ __all__ = [
     "QR_RE",
     "OVERLAP_BWD_RE",
     "STALE_RE",
+    "MODEL_RE",
     "format_exchange_label",
     "format_mp_label",
     "format_step_label",
     "format_stale_scope",
+    "format_model_label",
     "format_host_span",
     "parse_exchange_label",
     "parse_mp_label",
@@ -65,6 +69,7 @@ __all__ = [
     "parse_qr_scope",
     "parse_overlap_bwd",
     "parse_stale_scope",
+    "parse_model_part",
     "parse_host_span",
     "hlo_op_labels",
 ]
@@ -73,6 +78,9 @@ __all__ = [
 EXCHANGE_PREFIX = "bagua_ex"
 STEP_PREFIX = "bagua_step"
 STALE_PREFIX = "bagua_stale"
+#: parts of a model's forward pass (and, through autodiff, of its backward
+#: pass): a model whose step is made of unlike parts names them
+MODEL_PREFIX = "bagua_model"
 #: host spans (profiler annotations, not HLO metadata)
 HOST_PREFIX = "bagua_host"
 #: the ``StepTraceAnnotation`` around one iteration of ``Trainer.fit``
@@ -92,6 +100,7 @@ OVERLAP_BWD_RE = re.compile(r"bagua_overlap_bwd/bucket=(?P<bucket>\d+)")
 #: the bounded-staleness sanction frame (τ = the staleness bound the
 #: algorithm was compiled at)
 STALE_RE = re.compile(STALE_PREFIX + r"/tau=(?P<tau>\d+)")
+MODEL_RE = re.compile(MODEL_PREFIX + r"/part=(?P<part>[^/\"]+)")
 
 
 # -- formatters (the single way a label string is ever built) -----------------
@@ -118,6 +127,10 @@ def format_stale_scope(tau) -> str:
     traced under — the marker :func:`parse_stale_scope` (and through it the
     static verifier's sanction) recovers from the jaxpr name stack."""
     return f"{STALE_PREFIX}/tau={int(tau)}"
+
+
+def format_model_label(part: str) -> str:
+    return f"{MODEL_PREFIX}/part={part}"
 
 
 def format_host_span(name: str) -> str:
@@ -181,6 +194,13 @@ def parse_stale_scope(op_name: str) -> Optional[int]:
     """The staleness bound τ of a ``bagua_stale`` frame, if present."""
     m = STALE_RE.search(op_name or "")
     return int(m.group("tau")) if m else None
+
+
+def parse_model_part(op_name: str) -> Optional[str]:
+    """The part of the model an op was traced under, if labeled: the
+    innermost ``bagua_model`` frame of its ``op_name``."""
+    parts = MODEL_RE.findall(op_name or "")
+    return parts[-1] if parts else None
 
 
 def parse_host_span(event_name: str) -> Optional[str]:

@@ -57,6 +57,7 @@ from bagua_tpu.observability.scope_grammar import (
     hlo_op_labels,
     parse_exchange_label,
     parse_host_span,
+    parse_model_part,
     parse_mp_label,
     parse_step_phase,
 )
@@ -589,6 +590,12 @@ def summarize_capture(log_dir: str, hlo_text: Optional[str] = None, device: int 
         ``busy_ms``; with ``idle_ms`` to ``window_ms``.  ``unattributed_top``
         names the ten operations with most unattributed time (those the
         compiler made and gave no metadata: layout copies, prefetches).
+    ``model_part_ms``
+        only for a model that names its parts (``bagua_model/part=...``):
+        the ``forward`` and ``backward`` time by part, both passes together
+        (autodiff carries the frame into the backward pass), and ``other``
+        for what runs under neither name (norms, residual adds, the
+        embedding).  Sums to ``forward`` + ``backward`` of ``partition_ms``.
     ``exchange``
         ``calls``, ``bytes``, ``collective_ms`` (union of the collectives'
         spans on both lines), ``exposed_ms`` (the part no other operation
@@ -639,6 +646,7 @@ def summarize_capture(log_dir: str, hlo_text: Optional[str] = None, device: int 
     partition: Dict[str, float] = {}
     other_modules: Dict[str, float] = {}
     unattributed: Dict[str, float] = {}
+    model_parts: Dict[str, float] = {}
     for e, owned in zip(ops, _owned(spans)):
         e["class"] = "exchange" if _is_collective(e) else phase_of(e.get("op_name"))
         if e["hlo_module"] != module:
@@ -648,6 +656,9 @@ def summarize_capture(log_dir: str, hlo_text: Optional[str] = None, device: int 
         partition[e["class"]] = partition.get(e["class"], 0.0) + owned
         if e["class"] == "unattributed":
             unattributed[e["hlo_op"]] = unattributed.get(e["hlo_op"], 0.0) + owned
+        elif e["class"] in ("forward", "backward"):
+            part = parse_model_part(e.get("op_name")) or "other"
+            model_parts[part] = model_parts.get(part, 0.0) + owned
 
     def per_step_ms(us: float) -> float:
         return us / 1e3 / steps
@@ -683,6 +694,8 @@ def summarize_capture(log_dir: str, hlo_text: Optional[str] = None, device: int 
         "idle_by_host_span_ms": per_step_dict(idle_us),
         "per_step": per_step,
     }
+    if set(model_parts) - {"other"}:
+        summary["model_part_ms"] = per_step_dict(model_parts)
     _LAST_SUMMARY = summary
     return summary
 

@@ -299,6 +299,71 @@ def test_kernels_compile_through_mosaic_without_a_chip():
     assert proc.stdout.count("MOSAIC-OK") == len(chip_smoke.kernel_cases(dry=False))
 
 
+_EXPERT_MODEL_AOT = """
+import sys
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+except Exception as e:  # no libtpu, or it cannot start compile-only here
+    print("NO-TOPOLOGY", type(e).__name__, e)
+    sys.exit(3)
+# the kernels' choice is by backend, and here the backend is the CPU: steer it, in the test
+jax.default_backend = lambda: "tpu"
+from bagua_tpu.kernels.causal_attention import causal_attention
+from bagua_tpu.parallel.moe.dropless import grouped_matmul
+device = SingleDeviceSharding(topo.devices[0])
+
+
+def shape(dims, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(dims, dtype, sharding=device)
+
+
+def both_passes(fn):
+    def run(out_grad, *args):
+        out, vjp = jax.vjp(fn, *args)
+        return (out,) + vjp(out_grad)
+    return run
+
+
+# glm-4.7-flash.dp1-s8192: 20 heads x 8,192 positions x 256; 8,192 tokens x top-4 rows, 8 held experts
+qkv = shape((1, 20, 8192, 256))
+text = jax.jit(both_passes(lambda q, k, v: causal_attention(q, k, v, 1 / 16.0))).lower(
+    qkv, qkv, qkv, qkv).compile().as_text()
+print("ATTENTION-KERNELS", text.count("tpu_custom_call"), flush=True)
+sizes = shape((8,), jnp.int32)
+for width_in, width_out in ((2048, 1536), (1536, 2048)):
+    text = jax.jit(lambda out_grad, rows, kernels, group_sizes: both_passes(
+        lambda r, k: grouped_matmul(r, k, group_sizes))(out_grad, rows, kernels)).lower(
+        shape((32768, width_out)), shape((32768, width_in)), shape((8, width_in, width_out)),
+        sizes).compile().as_text()
+    print("GROUPED-KERNELS", text.count("tpu_custom_call"), flush=True)
+"""
+
+
+def test_the_expert_models_kernels_compile_for_the_chip_at_the_cells_shapes():
+    """What ``glm-4.7-flash.dp1-s8192`` runs on the chip and the CPU tests
+    cannot: the Pallas flash kernels at the tile edges of
+    ``kernels/causal_attention.py`` and the grouped products at
+    ``dropless.GMM_TILING``, forward and backward, through Mosaic for a
+    described v5e.  Tiles of 1,024 everywhere were refused here for fast
+    memory before any chip call (PR 29)."""
+    proc = subprocess.run(
+        [sys.executable, "-c", _EXPERT_MODEL_AOT],
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO_ROOT),
+        capture_output=True, text=True, timeout=600,
+    )
+    if proc.returncode == 3:
+        pytest.skip(proc.stdout.strip()[-300:])
+    assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-3000:]
+    counts = dict(line.split()[:2] for line in proc.stdout.splitlines()
+                  if line.startswith(("ATTENTION-KERNELS", "GROUPED-KERNELS")))
+    # forward, dk/dv and dq kernels; a product, its input's and its kernels' gradients
+    assert int(counts["ATTENTION-KERNELS"]) == 3 and int(counts["GROUPED-KERNELS"]) == 3
+    assert proc.stdout.count("GROUPED-KERNELS") == 2
+
+
 _HEAD_CENSUS = """
 import re, sys
 import jax, jax.numpy as jnp

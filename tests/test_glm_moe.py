@@ -1,0 +1,308 @@
+"""GLM-4.7-Flash at toy sizes on the CPU: the program's model against the
+benchmark's plain reference on seeded weights, one chip's share against the
+whole layer, the dropless dispatch under a forced imbalance, the blocked
+attention against the quadratic one, and the scopes that name the model's
+parts in a device trace."""
+
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bagua_tpu.kernels.causal_attention import blocked_causal_attention, causal_attention
+from bagua_tpu.models.glm_moe import (
+    GlmMoeConfig,
+    GlmMoeModel,
+    SparseExperts,
+    glm_moe_loss_fn,
+    glm_moe_test_config,
+)
+from bagua_tpu.observability import trace_analysis as ta
+from bagua_tpu.observability.annotations import model_scope
+from bagua_tpu.observability.scope_grammar import format_model_label, parse_model_part
+from bagua_tpu.parallel.moe.dropless import dropless_experts, sigmoid_topk_route
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.join(REPO, "ci"))
+from benchmark import manifest  # noqa: E402
+from trim_capture import xspace_bytes  # noqa: E402
+
+PARTS = ("attn_proj", "attn_core", "moe_route", "moe_dispatch", "moe_experts",
+         "moe_combine", "moe_shared", "dense_mlp", "head")
+
+
+@pytest.fixture(scope="module")
+def adapter():
+    return manifest.load_module("benchmark/configs/glm-4.7-flash.py")
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return manifest.load_module("benchmark/reference/glm_moe.py")
+
+
+def toy_sizes(adapter, **overrides):
+    """The configuration's toy sizes through the adapter, as a dry run has
+    them: 2 held of 8 experts, top-2, one dense and two expert layers."""
+    config = manifest.load_json("benchmark", "configs", "glm-4.7-flash.json")
+    config = {**config, **config["toy"], **overrides}
+    return adapter.sizes(config, {"seq_len": 32})
+
+
+def rel_err(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30))
+
+
+# -- the model against the plain reference ------------------------------------
+
+
+@pytest.mark.parametrize("nextn", [0, 1], ids=["no_prediction_module", "prediction_module"])
+def test_loss_and_every_gradient_leaf_match_the_reference_in_float32(adapter, reference, nextn):
+    sz = toy_sizes(adapter, num_nextn_predict_layers=nextn)
+    ref_params = reference.init_params(jax.random.PRNGKey(3), sz)
+    ids = adapter.draw_batch(jax.random.PRNGKey(4), 2, sz)
+    model = GlmMoeModel(adapter.model_config(sz, compute_dtype=jnp.float32))
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.value_and_grad(glm_moe_loss_fn(model))(
+            adapter.to_program(ref_params, sz), ids)
+        ref_loss, ref_grads = jax.value_and_grad(reference.loss)(ref_params, ids, sz)
+    assert float(loss) == pytest.approx(float(ref_loss), abs=2e-6)
+    want = adapter.to_program(ref_grads, sz, cast=False)
+    assert jax.tree.structure(grads) == jax.tree.structure(want)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(want)):
+        name = jax.tree_util.keystr(path)
+        if "correction_bias" in name:  # steers the choice only
+            assert not np.any(np.asarray(g)) and not np.any(np.asarray(w)), name
+        else:
+            assert np.linalg.norm(w) > 0, name
+            assert rel_err(g, w) < 2e-4, (name, rel_err(g, w))
+    assert ("mtp_block" in grads) == bool(nextn)
+
+
+def test_to_program_maps_the_references_tree_onto_the_programs_leaf_for_leaf(adapter, reference):
+    sz = toy_sizes(adapter, num_nextn_predict_layers=1)
+    model = GlmMoeModel(adapter.model_config(sz))
+    ids = adapter.draw_batch(jax.random.PRNGKey(0), 1, sz)
+    made = jax.eval_shape(lambda k: model.init(k, ids)["params"], jax.random.PRNGKey(0))
+    ref = jax.eval_shape(lambda k: reference.init_params(k, sz), jax.random.PRNGKey(0))
+    # marked leaves: each of the reference's lands on exactly one of the program's
+    marked = jax.tree.unflatten(jax.tree.structure(ref), [
+        jnp.full(leaf.shape, float(n), leaf.dtype) for n, leaf in enumerate(jax.tree.leaves(ref))])
+    mapped = adapter.to_program(marked, sz)
+    assert jax.tree.structure(mapped) == jax.tree.structure(made)
+    assert jax.tree.map(lambda x: (x.shape, x.dtype), mapped) == jax.tree.map(
+        lambda x: (x.shape, x.dtype), made)
+    assert sorted(float(x.ravel()[0]) for x in jax.tree.leaves(mapped)) == [
+        float(n) for n in range(len(jax.tree.leaves(ref)))]
+    assert adapter.HEAD_LEAF in {
+        jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_leaves_with_path(made)}
+
+
+def test_the_config_is_built_from_the_published_keys():
+    published = manifest.load_json("benchmark", "configs", "glm-4.7-flash.json")
+    cfg = GlmMoeConfig.from_hf({**published, **published["published"]}, experts_held=(8, 8))
+    assert (cfg.n_routed_experts, cfg.num_hidden_layers, cfg.vocab_size) == (64, 47, 154880)
+    assert (cfg.q_lora_rank, cfg.kv_lora_rank, cfg.v_head_dim) == (768, 512, 256)
+    assert cfg.held == (8, 8) and GlmMoeConfig().held == (0, 64)
+    with pytest.raises(ValueError, match="is no range"):
+        GlmMoeConfig(experts_held=(60, 8))
+
+
+# -- one chip's share and the whole layer -------------------------------------
+
+
+def expert_layer_weights(reference, sz, key):
+    whole = {**sz, "experts_held": (0, sz["routed_experts_total"]), "num_hidden_layers": 2,
+             "num_nextn_predict_layers": 0}
+    return whole, reference.init_params(key, whole)["layers"][1]
+
+
+def test_the_eight_shares_add_up_to_the_uncut_references_layer(adapter, reference):
+    sz = toy_sizes(adapter)
+    total = sz["routed_experts_total"]
+    whole, w = expert_layer_weights(reference, sz, jax.random.PRNGKey(5))
+    h = jax.random.normal(jax.random.PRNGKey(6), (2, 16, sz["hidden_size"]), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = reference.expert_mlp(h, w, whole)
+        shared = reference.swiglu(h, w["s_gate"], w["s_up"], w["s_down"])
+        routed = jnp.zeros_like(h)
+        for share in range(total):  # each of the eight shares holds one expert
+            held = (share, 1)
+            cfg = adapter.model_config({**sz, "experts_held": held}, compute_dtype=jnp.float32)
+            mine = {k: v[share:share + 1] for k, v in w.items() if k.startswith("e_")}
+            params = adapter._block({**w, **mine, "attn_norm": 0, "w_dq": 0, "q_norm": 0, "w_uq": 0,
+                                     "w_dkv": 0, "kv_norm": 0, "w_ukv": 0, "w_o": 0,
+                                     "mlp_norm": 0})["moe"]
+            out = SparseExperts(cfg).apply({"params": params}, h)
+            # what every chip computes alike, the shared expert, counted once
+            routed = routed + (out - shared)
+    assert total == 8 and rel_err(routed + shared, want) < 1e-5
+    # and no share alone is the layer
+    assert rel_err(out, want) > 0.05
+
+
+def test_routing_drops_nothing_when_every_token_goes_to_one_held_expert():
+    tokens, hidden, width, experts = 96, 16, 8, 8
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    x = jax.random.normal(keys[0], (tokens, hidden), jnp.float32)
+    gate, up = (0.3 * jax.random.normal(k, (2, hidden, width)) for k in keys[1:3])
+    down = 0.3 * jax.random.normal(keys[3], (2, width, hidden))
+    # a router that says nothing and a bias that sends every token to experts 1 and 6
+    bias = jnp.zeros(experts).at[jnp.array([1, 6])].set(1.0)
+    chosen, weights = sigmoid_topk_route(x, jnp.zeros((hidden, experts)), bias, 2, 1.8)
+    assert set(np.unique(chosen)) == {1, 6}
+    np.testing.assert_allclose(weights, 0.9, rtol=1e-6)  # 0.5 / (0.5 + 0.5) * 1.8
+    got = dropless_experts(x, chosen, weights, gate, up, down, held=(0, 2), num_experts=experts)
+    # expert 1 is the second of the two held: all 96 rows are its, none is lost
+    want = 0.9 * (jax.nn.silu(x @ gate[1]) * (x @ up[1])) @ down[1]
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    assert np.all(np.linalg.norm(np.asarray(got), axis=-1) > 0)
+    # a share that holds neither expert adds nothing, and its gradient is finite
+    none, grad = jax.value_and_grad(lambda x: jnp.sum(dropless_experts(
+        x, chosen, weights, gate, up, down, held=(2, 2), num_experts=experts)))(x)
+    assert float(none) == 0.0 and np.all(np.isfinite(np.asarray(grad)))
+
+
+def test_dispatch_gradients_match_a_dense_evaluation():
+    tokens, hidden, width, experts, k = 40, 12, 6, 8, 3
+    keys = jax.random.split(jax.random.PRNGKey(8), 6)
+    x = jax.random.normal(keys[0], (tokens, hidden))
+    router = jax.random.normal(keys[1], (hidden, experts))
+    bias = 0.1 * jax.random.normal(keys[2], (experts,))
+    gate, up = (0.3 * jax.random.normal(kk, (3, hidden, width)) for kk in keys[3:5])
+    down = 0.3 * jax.random.normal(keys[5], (3, width, hidden))
+    held = (4, 3)
+
+    def sparse(x, router, gate, up, down):
+        chosen, weights = sigmoid_topk_route(x, router, bias, k, 1.8)
+        return jnp.sum(jnp.sin(dropless_experts(
+            x, chosen, weights, gate, up, down, held=held, num_experts=experts)))
+
+    def dense(x, router, gate, up, down):
+        chosen, weights = sigmoid_topk_route(x, router, bias, k, 1.8)
+        out = 0.0
+        for n in range(held[1]):
+            w = jnp.sum(jnp.where(chosen == held[0] + n, weights, 0.0), axis=-1, keepdims=True)
+            out = out + w * ((jax.nn.silu(x @ gate[n]) * (x @ up[n])) @ down[n])
+        return jnp.sum(jnp.sin(out))
+
+    args = (x, router, gate, up, down)
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(sparse, argnums=range(5))(*args)
+        want = jax.value_and_grad(dense, argnums=range(5))(*args)
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+    for g, w in zip(got[1], want[1]):
+        assert rel_err(g, w) < 1e-5
+
+
+# -- attention ----------------------------------------------------------------
+
+
+def quadratic_attention(q, k, v, scale):
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    t = q.shape[2]
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+
+
+@pytest.mark.parametrize("block_q", [8, 16, 64])
+def test_blocked_attention_equals_quadratic_attention_in_value_and_gradient(block_q):
+    # a head of 12 dimensions without position and 4 rotary ones, as the toy model's
+    b, h, t, nope, rope = 2, 3, 64, 12, 4
+    keys = jax.random.split(jax.random.PRNGKey(9), 5)
+    q_nope, k_nope = (jax.random.normal(kk, (b, h, t, nope)) for kk in keys[:2])
+    q_rope = jax.random.normal(keys[2], (b, h, t, rope))
+    k_rope = jnp.broadcast_to(jax.random.normal(keys[3], (b, 1, t, rope)), (b, h, t, rope))
+    v = jax.random.normal(keys[4], (b, h, t, nope + rope))
+    q, k = jnp.concatenate([q_nope, q_rope], -1), jnp.concatenate([k_nope, k_rope], -1)
+    scale = 1 / math.sqrt(nope + rope)
+
+    def through(attn):
+        return lambda q, k, v: jnp.sum(jnp.cos(attn(q, k, v)))
+
+    with jax.default_matmul_precision("highest"):
+        got = blocked_causal_attention(q, k, v, scale, block_q)
+        want = quadratic_attention(q, k, v, scale)
+        got_grads = jax.grad(through(lambda *a: blocked_causal_attention(*a, scale, block_q)),
+                             argnums=(0, 1, 2))(q, k, v)
+        want_grads = jax.grad(through(lambda *a: quadratic_attention(*a, scale)),
+                              argnums=(0, 1, 2))(q, k, v)
+    assert rel_err(got, want) < 1e-6
+    for g, w in zip(got_grads, want_grads):
+        assert rel_err(g, w) < 1e-5
+    # off the chip the one entry point is the composition
+    np.testing.assert_array_equal(causal_attention(q, k, v, scale), got if block_q == 64 else
+                                  blocked_causal_attention(q, k, v, scale, 64))
+    with pytest.raises(ValueError, match="do not divide"):
+        blocked_causal_attention(q, k, v, scale, 48)
+
+
+# -- the scopes ---------------------------------------------------------------
+
+
+def test_model_scope_grammar_round_trips_and_reaches_both_passes():
+    assert format_model_label("attn_core") == "bagua_model/part=attn_core"
+    fwd = "jit(step)/bagua_step/phase=fwd_bwd/jvp(GlmMoeModel)/layer_1/moe/bagua_model/part=moe_route/dot_general"
+    bwd = ("jit(step)/bagua_step/phase=fwd_bwd/transpose(jvp(GlmMoeModel))/layer_1/attn/"
+           "bagua_model/part=attn_core/pallas_call")
+    assert parse_model_part(fwd) == "moe_route" and parse_model_part(bwd) == "attn_core"
+    assert parse_model_part("jit(step)/bagua_step/phase=fwd_bwd/add") is None
+    assert parse_model_part(None) is None
+    # the innermost frame names the part
+    assert parse_model_part("bagua_model/part=moe_shared/x/bagua_model/part=head/dot") == "head"
+
+    cfg = glm_moe_test_config(num_nextn_predict_layers=0)
+    model = GlmMoeModel(cfg)
+    ids = jnp.zeros((1, 16), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    text = jax.jit(jax.grad(glm_moe_loss_fn(model))).lower(params, ids).as_text(debug_info=True)
+    for part in PARTS:
+        label = format_model_label(part)
+        assert label in text, part
+        assert any("transpose(" in line for line in text.splitlines() if label in line), part
+    with model_scope("head"):
+        pass
+
+
+def test_summary_gives_model_part_ms_beside_the_partition(tmp_path):
+    """Two steps of 100 µs: forward 10 under ``attn_core`` and 5 under no
+    part, backward 20 under ``attn_core``, 8 under ``moe_experts`` and 2
+    under no part, the update 5."""
+    fwd, bwd = "bagua_step/phase=fwd_bwd", "bagua_step/phase=fwd_bwd/transpose(jvp(m))"
+    ops, modules = [], []
+    for base in (0, 100):
+        def op(n, start, end, op_name):
+            ops.append((f"%fusion.{n} = f32[4] fusion()", 1000 * (1000 + base + start),
+                        1000 * (end - start), {"op_name": op_name}))
+
+        op(1, 0, 10, fwd + "/layer_0/bagua_model/part=attn_core/dot")
+        op(2, 10, 15, fwd + "/layer_0/add")
+        op(3, 15, 35, bwd + "/layer_0/bagua_model/part=attn_core/dot")
+        op(4, 35, 43, bwd + "/layer_1/bagua_model/part=moe_experts/gmm")
+        op(5, 43, 45, bwd + "/layer_0/mul")
+        op(6, 45, 50, "bagua_step/phase=optimizer")
+        modules.append(("jit_local_step(1)", 1000 * (1000 + base), 1000 * 50, {}))
+    path = str(tmp_path / "parts.xplane.pb")
+    with open(path, "wb") as f:
+        f.write(xspace_bytes([("/device:TPU:0", [(ta._MODULES, modules), (ta._OPS, ops)])]))
+    got = ta.summarize_capture(path)
+    ms = pytest.approx
+    assert got["partition_ms"] == {"forward": ms(0.015), "backward": ms(0.030), "optimizer": ms(0.005)}
+    assert got["model_part_ms"] == {"attn_core": ms(0.030), "moe_experts": ms(0.008), "other": ms(0.007)}
+    assert sum(got["model_part_ms"].values()) == ms(
+        got["partition_ms"]["forward"] + got["partition_ms"]["backward"])
+
+
+def test_a_model_without_part_scopes_has_no_model_part_ms(tmp_path):
+    ops = [("%fusion.1 = f32[4] fusion()", 1_000_000, 10_000, {"op_name": "bagua_step/phase=fwd_bwd/dot"})]
+    modules = [("jit_local_step(1)", 1_000_000, 10_000, {})]
+    path = str(tmp_path / "plain.xplane.pb")
+    with open(path, "wb") as f:
+        f.write(xspace_bytes([("/device:TPU:0", [(ta._MODULES, modules), (ta._OPS, ops)])]))
+    assert "model_part_ms" not in ta.summarize_capture(path)
