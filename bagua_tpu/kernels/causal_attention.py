@@ -12,12 +12,17 @@ Two implementations of the same function, one per backend:
   output and each row's log-sum-exp, and the hand-written backward builds each
   block's probabilities again from them.  It runs anywhere and is what every
   backend but the TPU runs.
-* on a TPU, the Pallas flash kernels that ship with JAX
-  (``jax.experimental.pallas.ops.tpu.flash_attention``: forward, dq and dk/dv
-  kernels, causal blocks skipped inside the kernel).  Chosen once, by the
-  step's time on the v5e at 20 heads x 8,192 x 256 (``PERF.md`` section 6,
-  PR 29: the composition and this repo's own ``kernels/flash_attention.py``
-  beside it); not an option.
+* on a TPU, the Pallas *splash* kernels that ship with JAX
+  (``jax.experimental.pallas.ops.tpu.splash_attention``) over a causal mask:
+  the blocks above the diagonal are taken out of the grid on the host, the
+  mask is applied only in the blocks the diagonal crosses, and one fused
+  backward kernel forms each block's scores and ``dP`` once and gives ``dQ``,
+  ``dK`` and ``dV`` (``dQ`` as one partial per ``block_kv_dkv`` keys, summed
+  after the kernel).  Chosen by the step's time on the v5e at 20 heads x
+  8,192 x 256 (``PERF.md`` section 6, PR 30: JAX's flash kernels, which form
+  every block three times and mask every one, and splash's two-kernel
+  backward beside it; PR 29: the composition and this repo's own
+  ``kernels/flash_attention.py``); not an option.
 """
 
 import functools
@@ -28,12 +33,18 @@ import jax.numpy as jnp
 #: query rows per block of the composition: 20 heads x 512 x 8,192 float32
 #: scores are 335 MB at the last block, 21 MB at the first
 BLOCK_Q = 512
-#: tile edges of the Pallas kernels on the chip, each kernel's measured at
-#: 20 heads x 8,192 x 256 on the v5e (PERF.md section 6, PR 29): 512 everywhere
-#: but the forward's 1,024 queries against 1,024 keys a major step, the dk/dv
-#: kernel's 1,024 keys a major step and the dq kernel's 1,024 queries
-FLASH_BLOCK = 512
-FLASH_BLOCK_MAJOR = 1024
+#: tile edges of the splash kernels, decided on the v5e at 20 heads x 8,192 x 256
+#: by the step's time among those that fit a kernel's 16 MB of fast memory *in
+#: the step* (PERF.md section 6, PR 30).  The forward takes ``block_q`` queries
+#: against ``block_kv`` keys a grid step, ``block_kv_compute`` at a time; the
+#: fused backward ``block_q_dkv`` against ``block_kv_dkv`` and writes one ``dQ``
+#: partial per ``block_kv_dkv`` keys: eight at 8,192 positions, which beat four
+#: (2,048 keys fit only with 512 queries)
+SPLASH_BLOCKS = dict(
+    block_q=1024, block_kv=1024, block_kv_compute=256,
+    block_q_dkv=1024, block_kv_dkv=1024, block_kv_dkv_compute=512)
+#: the positions must divide by every edge; the edges are powers of two
+SPLASH_BLOCK_MAJOR = max(SPLASH_BLOCKS.values())
 
 _NEG = -1e30  # finite: a masked score must not make ``exp(s - m)`` a NaN
 
@@ -41,18 +52,34 @@ _NEG = -1e30  # finite: a masked score must not make ``exp(s - m)`` a NaN
 def causal_attention(q, k, v, scale: float):
     """``softmax(q k^T * scale + causal mask) v``.  ``q``, ``k``, ``v`` are
     ``(batch, heads, positions, head size)`` with one head size; the result
-    has ``q``'s type."""
+    has ``q``'s type.  On a TPU the kernels take no scale, so what is computed
+    there is ``softmax((q * scale) k^T + causal mask) v`` with ``q * scale``
+    rounded to ``q``'s type: the same numbers where ``scale`` is a power of
+    two, one more rounding of ``q`` where it is not."""
     t = q.shape[2]
-    if jax.default_backend() == "tpu" and t % FLASH_BLOCK_MAJOR == 0:
-        from jax.experimental.pallas.ops.tpu import flash_attention as fa
-
-        n, major = FLASH_BLOCK, FLASH_BLOCK_MAJOR
-        blocks = fa.BlockSizes(
-            block_q=major, block_k_major=major, block_k=n, block_b=1,
-            block_q_major_dkv=n, block_k_major_dkv=major, block_k_dkv=n, block_q_dkv=n,
-            block_k_major_dq=n, block_k_dq=n, block_q_dq=major)
-        return fa.flash_attention(q, k, v, causal=True, sm_scale=scale, block_sizes=blocks)
+    if jax.default_backend() == "tpu" and t % SPLASH_BLOCK_MAJOR == 0:
+        return _splash_causal_attention(q, k, v, scale)
     return blocked_causal_attention(q, k, v, scale, min(BLOCK_Q, t))
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(heads: int, t: int, interpret: bool = False):
+    """The kernels of one ``(heads, t)``, built once: the causal mask's block
+    tables are made on the host in numpy, and every layer of a model shares
+    them."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash, splash_attention_mask as masks)
+
+    mask = masks.MultiHeadMask([masks.CausalMask((t, t))] * heads)
+    with jax.ensure_compile_time_eval():  # the tables are constants of whatever trace asks first
+        return splash.make_splash_mha(
+            mask, block_sizes=splash.BlockSizes(use_fused_bwd_kernel=True, **SPLASH_BLOCKS),
+            head_shards=1, q_seq_shards=1, interpret=interpret)
+
+
+def _splash_causal_attention(q, k, v, scale: float, interpret: bool = False):
+    kernel = _splash_kernel(q.shape[1], q.shape[2], interpret)
+    return jax.vmap(kernel)((q * scale).astype(q.dtype), k, v)
 
 
 def _blocks(t: int, block_q: int):

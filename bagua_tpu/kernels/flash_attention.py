@@ -34,11 +34,17 @@ remaining O(t²) HBM object on this path (64 MiB at t=8,192, where every
 query-key tile of every head reads its part of it again; 256 MiB at t=16k;
 ~16 GiB at 128k).  On the v5e at 20 heads x 8,192 x 256, causal, these
 kernels (f32 tiles) took 37.5 ms forward + backward against 24.9 ms for the
-Pallas kernels that ship with JAX, which make the mask in the kernel and
-multiply in bf16 (``PERF.md`` section 6, PR 29): whole-sequence causal
-attention goes through ``kernels/causal_attention.py``.  Compute and gradients are already tile-local, so the next step for
-beyond-32k shards is in-kernel mask generation (causal offsets / segment
-ids via iota, splash-attention style) replacing the materialized array.
+Pallas flash kernels that ship with JAX, which make the mask in the kernel
+and multiply in bf16 (``PERF.md`` section 6, PR 29; 23.1 ms a layer in the
+step after tile tuning), and those against 18.0 ms a layer in the step for
+JAX's splash kernels with a fused backward, which take the masked blocks out
+of the grid on the host and form each block of scores once (section 6,
+PR 30, where the tile edges were decided by the step's time).  Whole-sequence
+causal attention goes through ``kernels/causal_attention.py``, which runs
+the splash kernels on a TPU.  Compute and gradients are already tile-local,
+so the next step for beyond-32k shards is in-kernel mask generation (causal
+offsets / segment ids via iota, splash-attention style) replacing the
+materialized array.
 """
 
 import functools

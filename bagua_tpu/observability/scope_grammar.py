@@ -212,7 +212,11 @@ def parse_host_span(event_name: str) -> Optional[str]:
 
 # -- the HLO join table -------------------------------------------------------
 
-_HLO_INSTR = re.compile(r"%([A-Za-z0-9_.\-]+) = .*metadata=\{[^}]*op_name=\"([^\"]*)\"")
+# an instruction's start, or an ``op_name`` of the instruction begun last.  A
+# Pallas kernel's custom call prints its ``kernel_metadata`` over several lines
+# and its ``metadata={op_name=...}`` after them, so the two are not on one line
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%([A-Za-z0-9_.\-]+) = |metadata=\{[^}]*op_name=\"([^\"]*)\"", re.MULTILINE)
 _HLO_MODULE = re.compile(r"^HloModule ([^\s,]+)", re.MULTILINE)
 
 
@@ -221,4 +225,10 @@ def hlo_op_labels(hlo_text: str) -> Tuple[str, Dict[str, str]]:
     HLO text — the join table between trace events and named-scope labels."""
     m = _HLO_MODULE.search(hlo_text)
     module = m.group(1) if m else ""
-    return module, {name: op_name for name, op_name in _HLO_INSTR.findall(hlo_text)}
+    labels, instruction = {}, None
+    for name, op_name in _HLO_INSTR.findall(hlo_text):
+        if name:
+            instruction = name
+        elif instruction is not None:
+            labels[instruction] = op_name
+    return module, labels

@@ -339,16 +339,39 @@ for width_in, width_out in ((2048, 1536), (1536, 2048)):
         shape((32768, width_out)), shape((32768, width_in)), shape((8, width_in, width_out)),
         sizes).compile().as_text()
     print("GROUPED-KERNELS", text.count("tpu_custom_call"), flush=True)
+# the cell's whole step under the engine's compiler options: inside it the fused
+# backward kernel needs 0.3 to 0.4 MB more fast memory than compiled alone (PR 30)
+from bagua_tpu.ddp import STEP_COMPILER_OPTIONS
+from benchmark import manifest
+cell = manifest.load_cell("glm-4.7-flash.dp1-s8192")
+params = jax.eval_shape(lambda k: cell.adapter.to_program(cell.adapter.as_stored(
+    cell.reference.init_params(k, cell.sizes)), cell.sizes), jax.random.PRNGKey(0))
+params = jax.tree.map(lambda a: shape(a.shape, a.dtype), params)
+loss_fn = cell.adapter.build_loss(cell.sizes)
+
+
+def sgd_step(params, batch):
+    loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+    return jax.tree.map(lambda p, g: p - 0.01 * g.astype(p.dtype), params, grads), loss
+
+
+text = jax.jit(sgd_step, donate_argnums=(0,), compiler_options=STEP_COMPILER_OPTIONS["tpu"]).lower(
+    params, shape((1, 8192), jnp.int32)).compile().as_text()
+print("STEP-ATTENTION-KERNELS",
+      sum(1 for line in text.splitlines() if "%splash_mha_fwd" in line.split(" = ")[0]),
+      sum(1 for line in text.splitlines() if "%splash_mha_dkv" in line.split(" = ")[0]), flush=True)
 """
 
 
 def test_the_expert_models_kernels_compile_for_the_chip_at_the_cells_shapes():
     """What ``glm-4.7-flash.dp1-s8192`` runs on the chip and the CPU tests
-    cannot: the Pallas flash kernels at the tile edges of
-    ``kernels/causal_attention.py`` and the grouped products at
+    cannot: the Pallas splash kernels (``splash_mha_fwd`` and the fused
+    backward ``splash_mha_dkv``, which gives ``dQ``, ``dK`` and ``dV``) at the
+    tile edges of ``kernels/causal_attention.py`` and the grouped products at
     ``dropless.GMM_TILING``, forward and backward, through Mosaic for a
-    described v5e.  Tiles of 1,024 everywhere were refused here for fast
-    memory before any chip call (PR 29)."""
+    described v5e.  A kernel may use 16 MB of fast memory: the flash kernels'
+    tiles of 1,024 everywhere (PR 29) and the fused backward's 1,024 queries
+    against 2,048 keys (PR 30) were refused here before any chip call."""
     proc = subprocess.run(
         [sys.executable, "-c", _EXPERT_MODEL_AOT],
         env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO_ROOT),
@@ -359,9 +382,14 @@ def test_the_expert_models_kernels_compile_for_the_chip_at_the_cells_shapes():
     assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-3000:]
     counts = dict(line.split()[:2] for line in proc.stdout.splitlines()
                   if line.startswith(("ATTENTION-KERNELS", "GROUPED-KERNELS")))
-    # forward, dk/dv and dq kernels; a product, its input's and its kernels' gradients
-    assert int(counts["ATTENTION-KERNELS"]) == 3 and int(counts["GROUPED-KERNELS"]) == 3
+    # the forward kernel and the one fused backward kernel; a product, its
+    # input's and its kernels' gradients
+    assert int(counts["ATTENTION-KERNELS"]) == 2 and int(counts["GROUPED-KERNELS"]) == 3
     assert proc.stdout.count("GROUPED-KERNELS") == 2
+    # five layers' forward and fused backward kernels in the step the cell runs
+    step = next(line.split() for line in proc.stdout.splitlines()
+                if line.startswith("STEP-ATTENTION-KERNELS"))
+    assert step[1:] == ["5", "5"]
 
 
 _HEAD_CENSUS = """

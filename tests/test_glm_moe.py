@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from bagua_tpu.kernels import causal_attention as causal_attention_module
 from bagua_tpu.kernels.causal_attention import blocked_causal_attention, causal_attention
 from bagua_tpu.models.glm_moe import (
     GlmMoeConfig,
@@ -241,6 +242,62 @@ def test_blocked_attention_equals_quadratic_attention_in_value_and_gradient(bloc
                                   blocked_causal_attention(q, k, v, scale, 64))
     with pytest.raises(ValueError, match="do not divide"):
         blocked_causal_attention(q, k, v, scale, 48)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("scale", [0.125, 0.11], ids=["scale_2^-3", "scale_0.11"])
+@pytest.mark.parametrize("b,h,tiles,d", [(2, 2, 2, 128), (1, 1, 4, 256)],
+                         ids=["2x2_two_tiles_d128", "1x1_four_tiles_d256"])
+def test_the_chips_attention_kernels_equal_quadratic_attention_in_interpret_mode(
+        b, h, tiles, d, scale, dtype):
+    """The TPU branch of ``causal_attention`` through Pallas' interpreter, at
+    the committed tile edges: blocks above the diagonal skipped, blocks on it
+    masked, blocks below it whole, and more than one partial of ``dQ``.  What
+    it computes is ``softmax((q * scale) k^T) v`` with ``q * scale`` rounded
+    to ``q``'s type (the docstring says so): that function in float32 is the
+    oracle, and beside it the stated function, which a scale that is no power
+    of two meets one bf16 rounding of ``q`` further off."""
+    t = tiles * causal_attention_module.SPLASH_BLOCK_MAJOR
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    q, k, v, d_out = (jax.random.normal(kk, (b, h, t, d), dtype) for kk in keys)
+
+    def f32(x):
+        return x.astype(jnp.float32)
+
+    def both_passes(attn):
+        out, vjp = jax.vjp(attn, q, k, v)
+        return (out,) + vjp(d_out.astype(out.dtype))
+
+    with jax.default_matmul_precision("highest"):
+        got = both_passes(lambda q, k, v: causal_attention_module._splash_causal_attention(
+            q, k, v, scale, interpret=True))
+        computed = both_passes(lambda q, k, v: quadratic_attention(
+            f32((q * scale).astype(q.dtype)), f32(k), f32(v), 1.0))
+        stated = both_passes(lambda q, k, v: quadratic_attention(f32(q), f32(k), f32(v), scale))
+    assert all(g.dtype == dtype and g.shape == q.shape for g in got)
+    # bf16: the probabilities and dS are rounded to the operands' type before each product
+    near, one_more_rounding = (1e-5, 1e-5) if dtype == jnp.float32 else (6e-3, 1e-2)
+    for g, c, s in zip(got, computed, stated):
+        assert rel_err(g, c) < near
+        assert rel_err(g, s) < (near if scale == 0.125 else one_more_rounding)
+    if scale == 0.125:  # a power of two: the two functions are one
+        for c, s in zip(computed, stated):
+            np.testing.assert_array_equal(c, s)
+
+
+def test_the_chips_attention_kernels_are_built_once_per_heads_and_positions():
+    """Five layers of one model share one kernel object: the mask's block
+    tables are numpy work on the host at trace time."""
+    build = causal_attention_module._splash_kernel
+    major = causal_attention_module.SPLASH_BLOCK_MAJOR
+    assert build(2, 2 * major, True) is build(2, 2 * major, True)
+    assert build(2, 2 * major, True) is not build(1, 2 * major, True)
+    text = str(jax.make_jaxpr(jax.grad(lambda q: jnp.sum(
+        causal_attention_module._splash_causal_attention(q, q, q, 0.125, interpret=True).astype(
+            jnp.float32))))(jax.ShapeDtypeStruct((1, 2, 2 * major, 128), jnp.bfloat16)))
+    # one forward kernel and one backward kernel that gives dq, dk and dv
+    assert text.count("pallas_call") == 2
+    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text and "splash_mha_dq" not in text
 
 
 # -- the scopes ---------------------------------------------------------------

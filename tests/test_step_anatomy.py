@@ -24,6 +24,7 @@ from bagua_tpu.observability.scope_grammar import (
     format_host_span,
     hlo_op_labels,
     parse_host_span,
+    parse_model_part,
 )
 from bagua_tpu.trainer import Trainer
 
@@ -409,6 +410,36 @@ def test_a_trainer_without_profile_dir_keeps_no_text_and_no_summary(group):
         assert lowered == [] and trainer.profile_summary is None
     finally:
         trainer.close()
+
+
+def test_join_table_reads_an_instruction_printed_over_several_lines():
+    """A Pallas kernel's custom call prints its ``kernel_metadata`` over
+    several lines and its ``op_name`` after them (the splash attention
+    kernels on the chip, PR 30): the label belongs to the instruction begun
+    last, and its neighbours keep theirs."""
+    text = """HloModule jit_step, is_scheduled=true
+
+%fused_computation.1 (param_0.1: f32[8]) -> f32[8] {
+  %param_0.1 = f32[8]{0} parameter(0)
+  ROOT %multiply.3 = f32[8]{0} multiply(%param_0.1, %param_0.1), metadata={op_name="jit(step)/bagua_step/phase=optimizer/mul"}
+}
+
+ENTRY %main.9 (Arg_0.1: f32[8]) -> f32[8] {
+  %Arg_0.1 = f32[8]{0} parameter(0)
+  %splash_mha_fwd_residuals.5 = (f32[8]{0}, bf16[8]{0:T(8,128)(2,1)}) custom-call(%Arg_0.1), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q\\": 1024, \\"block_kv\\": 1024}"
+}}, metadata={op_name="jit(step)/bagua_step/phase=fwd_bwd/jvp(m)/bagua_model/part=attn_core/vmap(jit(_splash_attention))/splash_mha_fwd_residuals/pallas_call" stack_frame_id=8}, backend_config={}
+  %get-tuple-element.2 = f32[8]{0} get-tuple-element(%splash_mha_fwd_residuals.5), index=0
+  ROOT %fusion.1 = f32[8]{0} fusion(%get-tuple-element.2), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(step)/bagua_step/phase=optimizer/mul"}
+}
+"""
+    module, labels = hlo_op_labels(text)
+    assert module == "jit_step"
+    assert set(labels) == {"multiply.3", "splash_mha_fwd_residuals.5", "fusion.1"}
+    assert ta.phase_of(labels["splash_mha_fwd_residuals.5"]) == "forward"
+    assert parse_model_part(labels["splash_mha_fwd_residuals.5"]) == "attn_core"
+    assert ta.phase_of(labels["fusion.1"]) == "optimizer"
+    assert dict(ta._HLO_OPCODE.findall(text))["splash_mha_fwd_residuals.5"] == "custom-call"
 
 
 def test_compiled_step_of_four_devices_carries_every_phase_and_labels_every_collective(traced_fit):
