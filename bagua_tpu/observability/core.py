@@ -49,7 +49,7 @@ class SpanRecorder:
 
     def record_measured_order(self, plan, bucket_times) -> None:
         """Convert measured per-bucket readiness costs (seconds, aligned with
-        ``plan.specs`` — see ``DistributedDataParallel.profile_bucket_order``)
+        ``plan.specs`` — see ``service.autotune_session.profile_bucket_order``)
         into ``tensor_ready`` spans: a tensor's start time is its bucket's
         measured cost, with a sub-microsecond offset keeping slots within a
         bucket in a stable order.  The autotune service sorts by start time,

@@ -7,7 +7,7 @@ the loop analytically, T3-style (arXiv:2401.16677: schedule collectives
 against the measured compute timeline):
 
 * **Inputs** — per-tensor cotangent arrival times (seconds into the
-  backward pass, from ``DistributedDataParallel.profile_bucket_order``'s
+  backward pass, from ``service.autotune_session.profile_bucket_order``'s
   single-probe capture) and per-bucket measured wire timings / hidden
   fractions (from ``observability.trace_analysis`` rows, shipped as
   ``bucket_wire`` spans).
@@ -43,7 +43,8 @@ returned partition is optimal for this objective, not just greedy.
 
 ``holds_bucketized_state`` algorithms cannot re-bucket mid-training
 (``DistributedDataParallel.rebucket`` raises); callers gate on that before
-adopting a plan — the :class:`~bagua_tpu.ddp.AutotuneSession` already does.
+adopting a plan — the
+:class:`~bagua_tpu.service.autotune_session.AutotuneSession` already does.
 """
 
 import dataclasses

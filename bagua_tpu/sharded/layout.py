@@ -34,6 +34,7 @@ __all__ = [
     "ShardLayout",
     "reshard_bucket_rows",
     "reshard_group_flat",
+    "reshard_opt_groups",
     "assemble_full_flats",
 ]
 
@@ -251,3 +252,35 @@ def reshard_group_flat(
     if not new_rows:
         return np.zeros((new.n_shards, 0), dtype=flat.dtype)
     return np.concatenate(new_rows, axis=1)
+
+
+def reshard_opt_groups(sharded, old: ShardLayout, new: ShardLayout, n_new=None):
+    """Per-dtype-group sharded optimizer state (host-side, rank-stacked)
+    moved from ``old`` to ``new``: each group's flat vectors through
+    :func:`reshard_group_flat`, other leaves as they are.  With ``n_new`` (a
+    snapshot resumed at another world size) leaves that every rank holds
+    alike (e.g. adam's count) are re-broadcast to that many rows."""
+    import jax
+
+    out = []
+    for new_g in new.groups:
+        old_g = old.group_for(new_g.dtype)
+        if old_g is None:
+            raise ValueError(
+                f"cannot reshard: old layout lacks dtype group {new_g.dtype!r}"
+            )
+
+        def fix(l, old_g=old_g):
+            arr = np.asarray(l)
+            if (
+                arr.ndim >= 2
+                and arr.shape[0] == old.n_shards
+                and arr.shape[-1] == old_g.shard_total
+            ):
+                return reshard_group_flat(arr, old, new, old_g.dtype).astype(arr.dtype)
+            if n_new is not None and arr.ndim >= 1 and arr.shape[0] == old.n_shards:
+                return np.broadcast_to(arr[0][None], (n_new,) + arr[0].shape).copy()
+            return arr
+
+        out.append(jax.tree.map(fix, sharded[old.groups.index(old_g)]))
+    return tuple(out)

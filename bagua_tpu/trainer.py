@@ -14,9 +14,10 @@ from typing import Callable, Iterable, Optional, Tuple
 import jax
 
 from bagua_tpu.algorithms.base import Algorithm
-from bagua_tpu.ddp import AutotuneSession, DistributedDataParallel
+from bagua_tpu.ddp import DistributedDataParallel
 from bagua_tpu.observability import StepTimer, Watchdog
 from bagua_tpu.observability.annotations import fit_step_span, host_span, timed_host_span
+from bagua_tpu.service.autotune_session import AutotuneSession
 
 logger = logging.getLogger(__name__)
 
@@ -423,7 +424,7 @@ class Trainer:
 
         self._summary_due = False
         try:
-            hlo_text = self.ddp.step_texts.get(self.ddp.last_variant)
+            hlo_text = self.ddp.step_text()
             if hlo_text:
                 with open(os.path.join(self.profile_dir, trace_analysis.STEP_TEXT_FILE), "w") as f:
                     f.write(hlo_text)

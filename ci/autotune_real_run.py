@@ -52,11 +52,10 @@ def measure_overlap(ddp, state, batch, label):
     from bagua_tpu.observability.core import ProfilerSession
     from bagua_tpu.observability.trace_analysis import analyze_trace
 
-    variant = ddp.impl.step_variant(ddp._host_step or 0)
-    fn = ddp._step_fns.get(variant)
+    fn = ddp.compiled_step(ddp.impl.step_variant(ddp._host_step or 0))
     if fn is None:
         state, _ = ddp.train_step(state, batch)  # populate the jit cache
-        fn = ddp._step_fns[ddp.impl.step_variant(ddp._host_step - 1)]
+        fn = ddp.compiled_step()
     hlo = fn.lower(state, batch).compile().as_text()
     prof_dir = tempfile.mkdtemp(prefix=f"bagua_autotune_{label}_")
     state, _ = ProfilerSession(prof_dir).trace_steps(ddp.train_step, state, [batch])
@@ -67,10 +66,11 @@ def measure_overlap(ddp, state, batch, label):
 def main():
     import bagua_tpu
     from bagua_tpu.algorithms.gradient_allreduce import GradientAllReduceAlgorithm
-    from bagua_tpu.ddp import AutotuneSession, DistributedDataParallel
+    from bagua_tpu.ddp import DistributedDataParallel
     from bagua_tpu.models.mlp import init_mlp, mse_loss
     from bagua_tpu.observability import Telemetry
     from bagua_tpu.service.autotune_client import AutotuneClient
+    from bagua_tpu.service.autotune_session import AutotuneSession
     from bagua_tpu.service.autotune_service import AutotuneService, start_autotune_server
 
     group = bagua_tpu.init_process_group()

@@ -136,7 +136,7 @@ def main():
     n_compiles = len(counter.compiles)
     cold_compile_s = times[0]
     jax.clear_caches()
-    ddp._step_fns = {}
+    ddp.drop_step_variants()
     t0 = time.perf_counter()
     state, losses = ddp.train_step(state, batch)
     jax.block_until_ready(losses)

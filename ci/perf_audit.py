@@ -510,7 +510,7 @@ def hang_forensics_lane(out_prefix: str, steps: int = 8):
         digest = hashlib.sha256()
         for leaf in jax.tree.leaves((state.params, state.opt_state)):
             digest.update(np.asarray(leaf).tobytes())
-        program = next(iter(ddp._flight_programs.values()), ()) if flight else ()
+        program = (ddp.flight_program() or ()) if flight else ()
         ddp.shutdown()
         tel.close()
         return digest.hexdigest(), statistics.median(walls), list(program)

@@ -141,8 +141,8 @@ def live_certify(group, params, batch, name):
         state, losses = ddp.train_step(state, batch)
         jax.block_until_ready(losses)
         variant = ddp.impl.step_variant(0)
-        captured = ddp._flight_programs.get(variant)
-        predicted = ddp._predicted_programs.get(variant)
+        captured = ddp.flight_program(variant)
+        predicted = ddp.predicted_program(variant)
         if not captured or not predicted:
             return {
                 "algo": name,

@@ -14,6 +14,7 @@ import pytest
 from bagua_tpu.defs import BaguaHyperparameter, TensorDeclaration
 from bagua_tpu.service.autotune_client import AutotuneClient
 from bagua_tpu.service.autotune_service import AutotuneService, start_autotune_server
+from bagua_tpu.service.autotune_session import AutotuneSession, profile_bucket_order
 from bagua_tpu.service.bayesian_optimizer import BayesianOptimizer, BoolParam, IntParam
 
 
@@ -173,7 +174,7 @@ def test_autotune_session_rebuckets(group):
     import optax
 
     from bagua_tpu.algorithms.gradient_allreduce import GradientAllReduceAlgorithm
-    from bagua_tpu.ddp import AutotuneSession, DistributedDataParallel
+    from bagua_tpu.ddp import DistributedDataParallel
     from bagua_tpu.models.mlp import init_mlp, mse_loss
 
     service = AutotuneService(
@@ -235,8 +236,8 @@ def test_profile_bucket_order_measures_backward_depth(group):
         jnp.asarray(rng.randn(64, 64), np.float32),
         jnp.asarray(rng.randn(64, 8), np.float32),
     )
-    t1 = ddp.profile_bucket_order(state, batch)
-    t2 = ddp.profile_bucket_order(state, batch)
+    t1 = profile_bucket_order(ddp, state, batch)
+    t2 = profile_bucket_order(ddp, state, batch)
     times = [min(a, b) for a, b in zip(t1, t2)]  # noise floor
 
     def bucket_of(fragment):
@@ -274,8 +275,8 @@ def test_profile_single_probe_machinery(group):
         jnp.asarray(rng.randn(16, 16), np.float32),
         jnp.asarray(rng.randn(16, 4), np.float32),
     )
-    times, capture = ddp.profile_bucket_order(
-        state, batch, return_capture=True, method="single_probe"
+    times, capture = profile_bucket_order(
+        ddp, state, batch, return_capture=True, method="single_probe"
     )
     assert len(times) == ddp.plan.num_buckets
     assert all(t >= 0.0 for t in times)
@@ -283,7 +284,7 @@ def test_profile_single_probe_machinery(group):
     assert capture["labeled_buckets"] == ddp.plan.num_buckets
     assert "bagua_probe/bucket=0" in capture["hlo_text"]
     # auto on a host backend routes to the pruned probe
-    t2, cap2 = ddp.profile_bucket_order(state, batch, return_capture=True)
+    t2, cap2 = profile_bucket_order(ddp, state, batch, return_capture=True)
     assert cap2["method"] == "pruned_per_bucket" and len(t2) == len(times)
 
 
@@ -297,7 +298,7 @@ def test_session_profile_reports_measured_order(group):
     import optax
 
     from bagua_tpu.algorithms.gradient_allreduce import GradientAllReduceAlgorithm
-    from bagua_tpu.ddp import AutotuneSession, DistributedDataParallel
+    from bagua_tpu.ddp import DistributedDataParallel
     from bagua_tpu.models.mlp import init_mlp, mse_loss
 
     service = AutotuneService(world_size=1, autotune_level=1)
@@ -417,7 +418,7 @@ def test_untuned_service_preserves_user_wire_dtype(group):
     import optax
 
     from bagua_tpu.algorithms.gradient_allreduce import GradientAllReduceAlgorithm
-    from bagua_tpu.ddp import AutotuneSession, DistributedDataParallel
+    from bagua_tpu.ddp import DistributedDataParallel
     from bagua_tpu.models.mlp import init_mlp, mse_loss
 
     service = AutotuneService(
@@ -457,7 +458,7 @@ def test_autotune_session_applies_wire_dtype(group):
     import optax
 
     from bagua_tpu.algorithms.gradient_allreduce import GradientAllReduceAlgorithm
-    from bagua_tpu.ddp import AutotuneSession, DistributedDataParallel
+    from bagua_tpu.ddp import DistributedDataParallel
     from bagua_tpu.models.mlp import init_mlp, mse_loss
 
     service = AutotuneService(

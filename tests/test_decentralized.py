@@ -364,7 +364,7 @@ def test_flat_shift_one_hlo_has_no_all_gather(group):
         process_group=group,
     )
     state = ddp.init(params)
-    fn = ddp._step_fns.get("default") or ddp._build_step("default")
+    fn = ddp._build_step("default")
     batch = (jnp.zeros((8, 6), jnp.float32), jnp.zeros((8, 2), jnp.float32))
     hlo = jax.jit(fn).lower(state, batch).compile().as_text()
     assert "collective-permute" in hlo

@@ -358,7 +358,7 @@ def test_ddp_capture_replays_one_record_per_collective(group):
     fr = FlightRecorder(capacity=128, rank=0, world_size=1)
     ddp, _ = run_steps(group, "gradient_allreduce", fr, steps=3)
     assert ddp.plan.num_buckets > 1
-    (program,) = ddp._flight_programs.values()
+    program = ddp.flight_program()
     # the captured program: one overlap collective per plan bucket, in the
     # named-scope grammar, carrying plan bytes + version
     assert len(program) == ddp.plan.num_buckets
@@ -408,7 +408,7 @@ def test_quantized_ring_records_hops(group, monkeypatch):
     state, losses = ddp.train_step(state, make_batch())
     jax.block_until_ready(losses)
     ddp.shutdown()
-    (program,) = ddp._flight_programs.values()
+    program = ddp.flight_program()
     hops = [r for r in program if r["phase"] == "hop"]
     n = ddp.group.size
     assert hops, "quantized ring left no hop records"

@@ -285,7 +285,7 @@ def test_flight_records_carry_data_axes():
     state, losses = ddp.train_step(state, make_batch())
     jax.block_until_ready(losses)
     ddp.shutdown()
-    (program,) = ddp._flight_programs.values()
+    program = ddp.flight_program()
     exchange = [r for r in program if r["phase"] != "hop"]
     assert exchange, "no exchange records captured"
     for rec in exchange:
@@ -319,7 +319,7 @@ def test_axis_budget_partition_exact_over_traced_program(
     state, losses = ddp.train_step(state, make_batch())
     jax.block_until_ready(losses)
     ddp.shutdown()
-    (program,) = ddp._flight_programs.values()
+    program = ddp.flight_program()
 
     legs = {ax: AlphaBeta(0.0, 1e8 * (i + 1))
             for i, ax in enumerate(g.data_axes)}

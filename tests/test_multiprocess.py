@@ -187,7 +187,8 @@ AUTOTUNE_WORKER = textwrap.dedent(
     import optax
     import bagua_tpu
     from bagua_tpu.algorithms import Algorithm
-    from bagua_tpu.ddp import AutotuneSession, DistributedDataParallel
+    from bagua_tpu.ddp import DistributedDataParallel
+    from bagua_tpu.service.autotune_session import AutotuneSession
     from bagua_tpu.distributed import init_from_env
     from bagua_tpu.models.mlp import init_mlp, mse_loss
     from bagua_tpu.service.autotune_client import get_hyperparameters_service_client

@@ -169,14 +169,13 @@ def test_directive_flip_is_recompile_free(group):
     ddp = make_ddp(group, tau=TAU)
     state = ddp.init(params)
     state, _ = ddp.train_step(state, (jnp.asarray(xs[0]), jnp.asarray(ys[0])))
-    compiled_before = dict(ddp._step_fns)
-    assert compiled_before, "step did not compile"
+    compiled_before = ddp.compiled_step()
+    assert compiled_before is not None, "step did not compile"
     state = ddp.apply_degradation_directive(state, (STALE_RANK,))
     state, _ = ddp.train_step(state, (jnp.asarray(xs[1]), jnp.asarray(ys[1])))
     state = ddp.apply_degradation_directive(state, ())
     state, _ = ddp.train_step(state, (jnp.asarray(xs[2]), jnp.asarray(ys[2])))
-    for variant, fn in compiled_before.items():
-        assert ddp._step_fns[variant] is fn, "directive flip re-traced the step"
+    assert ddp.compiled_step() is compiled_before, "directive flip re-traced the step"
 
 
 def test_directive_validates_ranks_and_knob(group):
@@ -203,10 +202,10 @@ def test_apply_staleness_is_the_single_recompile_switch(group):
     ddp = make_ddp(group, tau=0)
     state = ddp.init(params)
     state, _ = ddp.train_step(state, (jnp.asarray(xs[0]), jnp.asarray(ys[0])))
-    assert ddp._step_fns
+    assert ddp.compiled_step() is not None
     assert ddp.apply_staleness(TAU, reason="planner") is True
     assert ddp.impl.staleness_tau == TAU
-    assert not ddp._step_fns, "τ switch must invalidate the compiled step"
+    assert ddp.compiled_step() is None, "τ switch must invalidate the compiled step"
     assert ddp.apply_staleness(TAU, reason="planner") is False  # no-op
     with pytest.raises(ValueError):
         ddp.apply_staleness(-1, reason="planner")

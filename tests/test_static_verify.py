@@ -419,11 +419,12 @@ def test_strict_gate_blocks_dispatch(group, monkeypatch):
         with pytest.raises(StaticVerifyError, match="wire_exactness"):
             ddp.train_step(state, make_batch())
         assert tel.flight.records() == [], "collectives dispatched anyway"
-        assert ddp._flight_programs == {}
+        variant = ddp.impl.step_variant(0)
+        assert ddp.flight_program(variant) is None
         # the rejected step must not linger in any cache: a caller that
         # catches the error and retries re-verifies instead of dispatching
-        assert ddp._step_fns == {}, "rejected step left in the jit cache"
-        assert ddp._predicted_programs == {}
+        assert ddp.compiled_step(variant) is None, "rejected step left in the jit cache"
+        assert ddp.predicted_program(variant) is None
         with pytest.raises(StaticVerifyError, match="wire_exactness"):
             ddp.train_step(state, make_batch())
         assert tel.flight.records() == []
@@ -446,8 +447,8 @@ def test_strict_gate_passes_real_engines(group, monkeypatch):
             state, losses = ddp.train_step(state, make_batch())
             jax.block_until_ready(losses)
             variant = ddp.impl.step_variant(0)
-            predicted = ddp._predicted_programs.get(variant)
-            captured = ddp._flight_programs.get(variant)
+            predicted = ddp.predicted_program(variant)
+            captured = ddp.flight_program(variant)
             assert predicted, f"{name}: gate stored no prediction"
             assert captured, f"{name}: no live flight program"
             assert canonical_records(predicted) == canonical_records(captured)
@@ -716,7 +717,7 @@ def test_warn_gate_survives_trace_failure(group, monkeypatch, caplog):
         state = ddp.init(init_mlp(jax.random.PRNGKey(0), LAYERS))
         with pytest.raises(TypeError, match="synthetic trace failure"):
             ddp.train_step(state, make_batch())
-        assert ddp._step_fns == {}
+        assert ddp.compiled_step(ddp.impl.step_variant(0)) is None
     finally:
         ddp.shutdown()
 

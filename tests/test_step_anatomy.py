@@ -378,7 +378,7 @@ def test_fit_reduces_its_capture_and_compiles_nothing_inside_it(traced_fit):
     # the step's text lies beside the capture for ci/analyze_trace.py, and gives the same
     with open(os.path.join(profile_dir, ta.STEP_TEXT_FILE)) as f:
         text = f.read()
-    assert text == trainer.ddp.step_texts[trainer.ddp.last_variant]
+    assert text == trainer.ddp.step_text()
     again = ta.summarize_capture(profile_dir, hlo_text=text)
     assert again["partition_ms"] == pytest.approx(summary["partition_ms"])
 
@@ -406,7 +406,7 @@ def test_a_trainer_without_profile_dir_keeps_no_text_and_no_summary(group):
         trainer.ddp._build_step = counting_build
         state = trainer.init_state(init_mlp(jax.random.PRNGKey(0), LAYERS))
         trainer.fit(state, batches(3), log_every=0)
-        assert not trainer.ddp.keep_step_text and trainer.ddp.step_texts == {}
+        assert not trainer.ddp.keep_step_text and trainer.ddp.step_text() is None
         assert lowered == [] and trainer.profile_summary is None
     finally:
         trainer.close()
@@ -444,7 +444,7 @@ ENTRY %main.9 (Arg_0.1: f32[8]) -> f32[8] {
 
 def test_compiled_step_of_four_devices_carries_every_phase_and_labels_every_collective(traced_fit):
     trainer = traced_fit[0]
-    text = trainer.ddp.step_texts[trainer.ddp.last_variant]
+    text = trainer.ddp.step_text()
     _, labels = hlo_op_labels(text)
     phases = {ta.phase_of(op_name) for op_name in labels.values()}
     assert {"forward", "backward", "optimizer"} <= phases

@@ -70,10 +70,8 @@ def make_ddp(group, overlap, telemetry=None, bucket_size=1 << 9):
 
 
 def compiled_hlo(ddp, state, batch):
-    """Compiled HLO text of the (single) cached step variant."""
-    assert len(ddp._step_fns) == 1, ddp._step_fns.keys()
-    (fn,) = ddp._step_fns.values()
-    return fn.lower(state, batch).compile().as_text()
+    """Compiled HLO text of the step variant last dispatched."""
+    return ddp.compiled_step().lower(state, batch).compile().as_text()
 
 
 def op_name_labels(hlo):
@@ -242,7 +240,7 @@ def test_ddp_telemetry_steady_state_then_forced_retrace(group, tmp_path):
     rep = tel.recompile.report()
     assert rep["steps"] == 5 and rep["retraces"] == 0 and rep["alerts"] == 0
 
-    ddp._step_fns = {}  # forced cache churn: the step variant must rebuild
+    ddp.drop_step_variants()  # forced cache churn: the step variant must rebuild
     state, _ = ddp.train_step(state, batch)
     rep = tel.recompile.report()
     assert rep["retraces"] == 1 and rep["alerts"] == 1
