@@ -55,7 +55,10 @@ def causal_attention(q, k, v, scale: float):
     has ``q``'s type.  On a TPU the kernels take no scale, so what is computed
     there is ``softmax((q * scale) k^T + causal mask) v`` with ``q * scale``
     rounded to ``q``'s type: the same numbers where ``scale`` is a power of
-    two, one more rounding of ``q`` where it is not."""
+    two, one more rounding of ``q`` where it is not.  A ``scale`` of 1 says
+    that the caller's ``q`` carries the scale already (``models/glm_moe.py``
+    multiplies in float32 in the pass that rounds ``q``): ``q`` then goes to
+    the kernels as it came, with no pass and no rounding of its own."""
     t = q.shape[2]
     if jax.default_backend() == "tpu" and t % SPLASH_BLOCK_MAJOR == 0:
         return _splash_causal_attention(q, k, v, scale)
@@ -79,7 +82,9 @@ def _splash_kernel(heads: int, t: int, interpret: bool = False):
 
 def _splash_causal_attention(q, k, v, scale: float, interpret: bool = False):
     kernel = _splash_kernel(q.shape[1], q.shape[2], interpret)
-    return jax.vmap(kernel)((q * scale).astype(q.dtype), k, v)
+    if scale != 1.0:
+        q = (q * scale).astype(q.dtype)
+    return jax.vmap(kernel)(q, k, v)
 
 
 def _blocks(t: int, block_q: int):

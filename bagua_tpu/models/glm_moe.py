@@ -24,9 +24,33 @@ anywhere):
   cross-entropy against ``t_{i+2}`` added with weight ``mtp_loss_weight``.
 
 Parameters are stored in float32; matrix products take ``compute_dtype``
-operands and accumulate in float32; norms, the router, the logits and the
-loss are float32.  Each part of the forward pass sits under a
+operands and accumulate in float32; norms, the rotation, the router, the
+logits and the loss are float32.  Each part of the forward pass sits under a
 ``bagua_model/part=...`` scope, so a device trace tells the parts apart.
+
+How the attention's operands are written (:func:`latent_qk`; ``PERF.md``
+section 6, PR 32).  The attention kernels read ``q``, ``k``, ``v`` as
+``(batch, heads, positions, head size)``, so the products contract onto that
+layout and ``W_o`` contracts the kernels' result over ``(heads, head size)``:
+no array with the positions in it is transposed, sliced by stride or
+concatenated from narrow pieces.  ``q k^T`` is a sum over a head's ``nope +
+rope`` score columns, the same whatever their order as long as ``q`` and
+``k`` share it, and the published rotary embedding (the interleaved
+convention: stored columns ``2 i`` and ``2 i + 1`` are a pair) only needs
+each pair's two numbers side by side.  So the layer takes a head's score
+columns in the order ``[nope a | first of every pair | nope b | second of
+every pair]``, half the plain columns in ``a`` and half in ``b``: the two of
+a pair lie half a head apart, and the rotation is ``first * cos - second *
+sin`` and ``second * cos + first * sin`` between the two halves of a head,
+with cos 1 and sin 0 on the plain columns and the scores' scale in both
+tables: one pass in float32 over the product's result, one rounding.  The
+order is made by slicing and reshaping the *weights* (``W_uq``'s columns,
+``W_dkv``'s rotary columns, ``W_ukv``'s ``k_nope`` and ``v`` columns apart);
+the stored parameters keep their shapes and column order, and their
+gradients arrive there by the same slices transposed.  ``k``'s product
+writes all ``nope + rope`` columns through zero columns of the weight where
+the rotary ones go, and the one rotary key all heads share is added to its
+result: each element is the dot product it was, plus an exact zero.
 """
 
 import dataclasses
@@ -38,7 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from bagua_tpu.kernels.causal_attention import causal_attention
-from bagua_tpu.models.llama import RMSNorm, apply_rope
+from bagua_tpu.models.llama import RMSNorm
 from bagua_tpu.models.losses import softmax_cross_entropy
 from bagua_tpu.observability.annotations import model_scope
 from bagua_tpu.parallel.moe.dropless import dropless_experts, sigmoid_topk_route
@@ -89,8 +113,10 @@ class GlmMoeConfig:
             raise ValueError(
                 f"experts_held {self.experts_held} is no range of the "
                 f"{self.n_routed_experts} routed experts")
-        if self.qk_rope_head_dim % 2:
-            raise ValueError(f"qk_rope_head_dim ({self.qk_rope_head_dim}) must be even")
+        if self.qk_rope_head_dim % 2 or self.qk_nope_head_dim % 2:
+            raise ValueError(
+                f"qk_rope_head_dim ({self.qk_rope_head_dim}) and qk_nope_head_dim "
+                f"({self.qk_nope_head_dim}) must be even")
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -130,39 +156,99 @@ class _Kernels(nn.Module):
         return self.param(name, nn.initializers.normal(0.02), shape, jnp.float32)
 
 
+def _product(pattern: str, x, kernel, dtype):
+    """``einsum(pattern, x, kernel)`` with ``dtype`` operands, float32
+    accumulation, ``dtype`` result: the contraction writes the layout its
+    reader takes."""
+    return jnp.einsum(pattern, x.astype(dtype), kernel.astype(dtype),
+                      preferred_element_type=jnp.float32).astype(dtype)
+
+
+#: ``(batch, positions, rank)`` times ``(rank, heads, size)`` as the attention
+#: kernels read it, ``(batch, heads, positions, size)``
+HEADS_MAJOR = "btr,rhd->bhtd"
+
+
+def pairs_apart(kernel):
+    """The stored (interleaved) rotary columns of a weight ``(..., rope)`` as
+    the first of every pair and the second of every pair, ``(..., rope / 2)``
+    each."""
+    pairs = kernel.reshape(kernel.shape[:-1] + (kernel.shape[-1] // 2, 2))
+    return pairs[..., 0], pairs[..., 1]
+
+
+def _rotate(first, second, rope: int, theta: float, scale: float = 1.0):
+    """The rotary embedding between two half heads ``(..., positions,
+    width)``, in float32: the last ``rope / 2`` columns of ``first`` and of
+    ``second`` are the first and the second of the rotary pairs and get the
+    two numbers :func:`~bagua_tpu.models.llama.apply_rope` gives the pair,
+    the columns before them have no position; all times ``scale``."""
+    t, width = first.shape[-2:]
+    plain = width - rope // 2
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, rope, 2, dtype=jnp.float32) / rope))
+    ang = jnp.arange(t).astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.concatenate([jnp.ones((t, plain), jnp.float32), jnp.cos(ang)], axis=-1) * scale
+    sin = jnp.concatenate([jnp.zeros((t, plain), jnp.float32), jnp.sin(ang)], axis=-1) * scale
+    first, second = first.astype(jnp.float32), second.astype(jnp.float32)
+    return first * cos - second * sin, second * cos + first * sin
+
+
+def latent_qk(c_q, c_kv, k_rope, q_up, k_up, rope: int, theta: float, dtype):
+    """The attention kernels' ``q`` (times ``1 / sqrt(head size)``) and ``k``,
+    both ``(batch, heads, positions, nope + rope)``, with a head's columns in
+    the order ``[nope a | pair firsts | nope b | pair seconds]`` (the module's
+    text says why).  ``c_q`` and ``c_kv`` are the normed latents, ``k_rope
+    (batch, positions, rope)`` the shared rotary key before rotation with its
+    columns :func:`pairs_apart`; ``q_up (rank, heads, nope + rope)`` and
+    ``k_up (rank, heads, nope)`` have the stored columns."""
+    nope = k_up.shape[-1]
+    a, half = nope // 2, (nope + rope) // 2
+    firsts, seconds = pairs_apart(q_up[..., nope:])
+    y = _product(HEADS_MAJOR, c_q, jnp.concatenate(
+        [q_up[..., :a], firsts, q_up[..., a:nope], seconds], axis=-1), dtype)
+    q = jnp.concatenate(_rotate(y[..., :half], y[..., half:], rope, theta,
+                                1.0 / math.sqrt(nope + rope)), axis=-1).astype(dtype)
+    # the product writes k whole: zero columns in the weight where the rotary
+    # columns go, and the one rotary key all heads share added to its result
+    gap = ((0, 0), (0, 0), (0, rope // 2))
+    k_up = jnp.concatenate([jnp.pad(k_up[..., :a], gap), jnp.pad(k_up[..., a:], gap)], axis=-1)
+    shared = jnp.concatenate([
+        jnp.pad(r.astype(dtype), ((0, 0), (0, 0), (a, 0)))
+        for r in _rotate(k_rope[..., :rope // 2], k_rope[..., rope // 2:], rope, theta)], axis=-1)
+    return q, _product(HEADS_MAJOR, c_kv, k_up, dtype) + shared[:, None]
+
+
 class LatentAttention(_Kernels):
     cfg: GlmMoeConfig
 
     @nn.compact
     def __call__(self, x):
         cfg, dt = self.cfg, self.cfg.compute_dtype
-        b, t, hidden = x.shape
+        hidden = x.shape[-1]
         heads, nope, rope, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                                  cfg.qk_rope_head_dim, cfg.v_head_dim)
+        rank = cfg.kv_lora_rank
         if nope + rope != dv:
             raise NotImplementedError(
                 f"one head size for scores and values: {nope} + {rope} != {dv}")
         with model_scope("attn_proj"):
-            c_q = RMSNorm(cfg.rms_norm_eps, name="q_norm")(
-                _matmul(x, self.kernel("q_down", hidden, cfg.q_lora_rank), dt))
-            q = _matmul(c_q, self.kernel("q_up", cfg.q_lora_rank, heads * (nope + rope)), dt)
-            q = q.reshape(b, t, heads, nope + rope)
-            down = _matmul(x, self.kernel("kv_down", hidden, cfg.kv_lora_rank + rope), dt)
-            c_kv = RMSNorm(cfg.rms_norm_eps, name="kv_norm")(down[..., :cfg.kv_lora_rank])
-            kv = _matmul(c_kv, self.kernel("kv_up", cfg.kv_lora_rank, heads * (nope + dv)), dt)
-            kv = kv.reshape(b, t, heads, nope + dv)
-            positions = jnp.arange(t)
-            q_rope = apply_rope(q[..., nope:], positions, cfg.rope_theta)
-            k_rope = apply_rope(down[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta)
-            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
-            k = jnp.concatenate(
-                [kv[..., :nope], jnp.broadcast_to(k_rope, (b, t, heads, rope))], axis=-1)
-            q, k, v = (y.transpose(0, 2, 1, 3) for y in (q, k, kv[..., nope:]))
+            q_down = self.kernel("q_down", hidden, cfg.q_lora_rank)
+            q_up = self.kernel("q_up", cfg.q_lora_rank, heads * (nope + rope)).reshape(
+                cfg.q_lora_rank, heads, nope + rope)
+            kv_down = self.kernel("kv_down", hidden, rank + rope)
+            kv_up = self.kernel("kv_up", rank, heads * (nope + dv)).reshape(rank, heads, nope + dv)
+            out = self.kernel("out", heads * dv, hidden).reshape(heads, dv, hidden)
+            c_q = RMSNorm(cfg.rms_norm_eps, name="q_norm")(_matmul(x, q_down, dt))
+            down = _matmul(x, jnp.concatenate(
+                (kv_down[:, :rank],) + pairs_apart(kv_down[:, rank:]), axis=1), dt)
+            c_kv = RMSNorm(cfg.rms_norm_eps, name="kv_norm")(down[..., :rank])
+            q, k = latent_qk(c_q, c_kv, down[..., rank:], q_up, kv_up[..., :nope],
+                             rope, cfg.rope_theta, dt)
+            v = _product(HEADS_MAJOR, c_kv, kv_up[..., nope:], dt)
         with model_scope("attn_core"):
-            ctx = causal_attention(q, k, v, 1.0 / math.sqrt(nope + rope))
+            ctx = causal_attention(q, k, v, 1.0)
         with model_scope("attn_proj"):
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, t, heads * dv)
-            return _matmul(ctx, self.kernel("out", heads * dv, hidden), dt)
+            return _product("bhtd,hdm->btm", ctx, out, dt)
 
 
 class SwiGLU(_Kernels):

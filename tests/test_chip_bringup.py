@@ -360,6 +360,60 @@ text = jax.jit(sgd_step, donate_argnums=(0,), compiler_options=STEP_COMPILER_OPT
 print("STEP-ATTENTION-KERNELS",
       sum(1 for line in text.splitlines() if "%splash_mha_fwd" in line.split(" = ")[0]),
       sum(1 for line in text.splitlines() if "%splash_mha_dkv" in line.split(" = ")[0]), flush=True)
+# a census of what layer_1's attention runs in that step beside its products and its two
+# kernels: each entry operation under part=attn_proj or part=attn_core with the bytes of its
+# operands and its result, from the shapes
+import collections, math, re
+computations, current = {}, None
+for line in text.splitlines():
+    head = re.match(r"(ENTRY )?%(\\S+) \\(.*\\{\\s*$", line)
+    instruction = re.match(r"\\s*(?:ROOT )?%(\\S+) = (.*?) ([a-z][\\w-]*)\\((.*)$", line)
+    if head:
+        current = computations.setdefault("ENTRY" if head.group(1) else head.group(2), [])
+    elif line.rstrip() == "}":
+        current = None
+    elif instruction and current is not None:
+        current.append(list(instruction.groups()))
+    elif current:  # a Pallas call prints its metadata over several lines
+        current[-1][3] += " " + line
+
+
+def nbytes(shape):
+    # the number in a type's name is an element's bits; a pred takes a byte
+    return sum((int(re.sub(r"\\D", "", kind) or 8) // 8) * math.prod(map(int, filter(None, dims.split(","))))
+               for kind, dims in re.findall(r"\\b([a-z]+\\d+|pred)\\[([\\d,]*)\\]", shape))
+
+
+def opcodes_inside(rest):
+    found = set()
+    for called in re.findall(r"calls=%([\\w.\\-]+)", rest):
+        for _, _, opcode, inner in computations[called]:
+            found |= {opcode} | opcodes_inside(inner)
+    return found
+
+
+entry = computations["ENTRY"]
+shape_of = {name: shape for name, shape, _, _ in entry}
+done = {rest.split(")")[0].strip("%"): shape for _, shape, opcode, rest in entry if opcode.endswith("-done")}
+moved = collections.Counter()
+for name, shape, opcode, rest in entry:
+    part = re.search(r'op_name="[^"]*/layer_1/attn/[^"]*part=(attn_proj|attn_core)/', rest)
+    if not part or opcode.endswith("-done") or opcode in (
+            "bitcast", "get-tuple-element", "tuple", "constant", "parameter", "iota"):
+        continue
+    inside = opcodes_inside(rest) | {opcode}
+    kind = ("kernel" if "tpu_custom_call" in rest else
+            "product" if inside & {"convolution", "dot"} else "other")
+    operands = re.findall(r"%([\\w.\\-]+)", rest.split(")")[0])
+    moved[part.group(1), kind] += nbytes(done.get(name, shape)) + sum(
+        nbytes(shape_of.get(operand, "")) for operand in operands)
+    plain = re.sub(r"\\{[^}]*\\}", "", shape).replace(" ", "")
+    if inside & {"gather", "scatter"}:
+        print("ATTENTION-GATHER-SCATTER", name, plain, flush=True)
+    if opcode == "copy" and nbytes(shape) // 2 >= 8192 * 20 * 192:
+        print("ATTENTION-LARGE-COPY", name, plain, flush=True)
+for (part, kind), total in sorted(moved.items()):
+    print("ATTENTION-MOVED", part, kind, total, flush=True)
 """
 
 
@@ -390,6 +444,21 @@ def test_the_expert_models_kernels_compile_for_the_chip_at_the_cells_shapes():
     step = next(line.split() for line in proc.stdout.splitlines()
                 if line.startswith("STEP-ATTENTION-KERNELS"))
     assert step[1:] == ["5", "5"]
+    # Guards the 16 ms a step (of 260, my chip runs, PR 32) that the operations
+    # around latent attention's five products took to re-tile, slice, gather
+    # and scatter sequence-sized arrays: one layer moved 3.00 GB under
+    # ``attn_proj`` and 1.75 GB under ``attn_core`` outside the products and
+    # the kernels (of which 0.76 are the sum of the eight ``dQ`` partials).
+    # The bounds are what ``models/glm_moe.py`` reads now (1.07 and 1.02 GB)
+    # plus a tenth.
+    lines = [line.split() for line in proc.stdout.splitlines()]
+    assert not [words for words in lines if words[0] == "ATTENTION-GATHER-SCATTER"], proc.stdout
+    assert not [words for words in lines if words[0] == "ATTENTION-LARGE-COPY"], proc.stdout
+    moved = {(words[1], words[2]): int(words[3]) for words in lines if words[0] == "ATTENTION-MOVED"}
+    assert set(moved) == {("attn_proj", "product"), ("attn_proj", "other"),
+                          ("attn_core", "kernel"), ("attn_core", "other")}, moved
+    assert moved["attn_core", "kernel"] > 1.6e9, moved  # q, k, v, o, do and eight dQ partials
+    assert moved["attn_proj", "other"] < 1.18e9 and moved["attn_core", "other"] < 1.12e9, moved
 
 
 _HEAD_CENSUS = """

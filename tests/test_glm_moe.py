@@ -21,7 +21,10 @@ from bagua_tpu.models.glm_moe import (
     SparseExperts,
     glm_moe_loss_fn,
     glm_moe_test_config,
+    latent_qk,
+    pairs_apart,
 )
+from bagua_tpu.models.llama import apply_rope
 from bagua_tpu.observability import trace_analysis as ta
 from bagua_tpu.observability.annotations import model_scope
 from bagua_tpu.observability.scope_grammar import format_model_label, parse_model_part
@@ -283,6 +286,62 @@ def test_the_chips_attention_kernels_equal_quadratic_attention_in_interpret_mode
     if scale == 0.125:  # a power of two: the two functions are one
         for c, s in zip(computed, stated):
             np.testing.assert_array_equal(c, s)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads", [1, 3], ids=["one_head", "three_heads"])
+@pytest.mark.parametrize("nope,rope", [(12, 4), (192, 64)], ids=["rope4", "rope64"])
+def test_scores_from_columns_set_apart_equal_scores_from_the_stored_interleaved_columns(
+        nope, rope, heads, dtype):
+    """The layer puts the two columns of each rotary pair half a head apart,
+    in ``q`` and in ``k`` alike, by reordering *weights*; the scores ``q k^T``
+    (the scale is in ``q``) and their gradients on the *stored* columns of
+    ``q_up`` and ``kv_down`` are those of ``apply_rope`` on the stored,
+    interleaved columns.  Both head sizes are powers of four, so the scale is
+    a power of two and bf16 rounds alike on both sides."""
+    b, t, hidden, q_rank, rank, theta = 2, 16, 20, 24, 12, 1e4
+    keys = jax.random.split(jax.random.PRNGKey(11), 7)
+    x, c_q, c_kv = (jax.random.normal(kk, (b, t, width), dtype)
+                    for kk, width in zip(keys, (hidden, q_rank, rank)))
+    q_up = 0.3 * jax.random.normal(keys[3], (q_rank, heads, nope + rope))
+    k_up = 0.3 * jax.random.normal(keys[4], (rank, heads, nope))
+    rope_down = 0.3 * jax.random.normal(keys[5], (hidden, rope))  # kv_down's rotary columns
+    readout = jax.random.normal(keys[6], (b, heads, t, t))
+    positions = jnp.arange(t)
+
+    def product(pattern, a, w):
+        return jnp.einsum(pattern, a, w.astype(dtype), preferred_element_type=jnp.float32).astype(dtype)
+
+    def set_apart(q_up, rope_down):
+        k_rope = product("bth,hr->btr", x, jnp.concatenate(pairs_apart(rope_down), axis=1))
+        q, k = latent_qk(c_q, c_kv, k_rope, q_up, k_up, rope, theta, dtype)
+        assert q.dtype == k.dtype == dtype and q.shape == k.shape == (b, heads, t, nope + rope)
+        return jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32)
+
+    def stored(q_up, rope_down):
+        q = product("btr,rhd->bthd", c_q, q_up)
+        q = jnp.concatenate([q[..., :nope], apply_rope(q[..., nope:], positions, theta)], axis=-1)
+        k_rope = apply_rope(product("bth,hr->btr", x, rope_down)[:, :, None], positions, theta)
+        k = jnp.concatenate([product("btr,rhd->bthd", c_kv, k_up),
+                             jnp.broadcast_to(k_rope, (b, t, heads, rope))], axis=-1)
+        return jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                          preferred_element_type=jnp.float32) / math.sqrt(nope + rope)
+
+    def read(scores):
+        def readout_and_scores(*weights):
+            s = scores(*weights)
+            return jnp.sum(readout * s), s
+        return jax.value_and_grad(readout_and_scores, argnums=(0, 1), has_aux=True)(q_up, rope_down)
+
+    with jax.default_matmul_precision("highest"):
+        (got, got_scores), got_grads = read(set_apart)
+        (want, want_scores), want_grads = read(stored)
+    near = 1e-5 if dtype == jnp.float32 else 2e-3  # bf16: a last bit of q or k by the order of a float32 sum
+    assert rel_err(got_scores, want_scores) < near
+    assert float(got) == pytest.approx(float(want), rel=near, abs=near)
+    for g, w in zip(got_grads, want_grads):
+        assert g.shape == w.shape and np.linalg.norm(w) > 0
+        assert rel_err(g, w) < near
 
 
 def test_the_chips_attention_kernels_are_built_once_per_heads_and_positions():
