@@ -23,6 +23,22 @@ Two implementations of the same function, one per backend:
   every block three times and mask every one, and splash's two-kernel
   backward beside it; PR 29: the composition and this repo's own
   ``kernels/flash_attention.py``); not an option.
+
+Grouped queries: ``k`` and ``v`` may have fewer heads than ``q``, a divisor
+of its count; key-value head ``n`` then serves the query heads ``n * g ..
+(n + 1) * g - 1``.  The composition lays a group's query heads along the
+rows of one block of scores against their key-value head.  On a TPU each
+key-value head and its group go to splash's multi-query kernels
+(``make_splash_mqa``), which read one ``k`` and one ``v`` for all of a
+group's query heads and add ``dK`` and ``dV`` up over the group inside the
+backward kernel: nothing is repeated on either backend.  By the step's time
+on the v5e at 32 query heads on 8 key-value heads x 8,192 x 64 (``PERF.md``
+section 6, PR 33): 157.98 ms against 160.59 for the multi-head kernels
+indexing the key-value head by ``query head // g`` and 160.25 for keys
+repeated four times; the tile edges decided at head size 256 measured the
+same as five others at head size 64 (160.54 to 161.33) and serve both.  With
+as many key-value heads as query heads (``models/glm_moe.py``) both backends
+run what they ran.
 """
 
 import functools
@@ -51,40 +67,52 @@ _NEG = -1e30  # finite: a masked score must not make ``exp(s - m)`` a NaN
 
 def causal_attention(q, k, v, scale: float):
     """``softmax(q k^T * scale + causal mask) v``.  ``q``, ``k``, ``v`` are
-    ``(batch, heads, positions, head size)`` with one head size; the result
-    has ``q``'s type.  On a TPU the kernels take no scale, so what is computed
-    there is ``softmax((q * scale) k^T + causal mask) v`` with ``q * scale``
+    ``(batch, heads, positions, head size)`` with one head size, ``k`` and
+    ``v`` with ``q``'s heads or a divisor of them (grouped queries: the
+    module's text); the result has ``q``'s shape and type.  On a TPU the
+    kernels take no scale, so what is computed there is ``softmax((q * scale) k^T + causal mask) v`` with ``q * scale``
     rounded to ``q``'s type: the same numbers where ``scale`` is a power of
     two, one more rounding of ``q`` where it is not.  A ``scale`` of 1 says
     that the caller's ``q`` carries the scale already (``models/glm_moe.py``
     multiplies in float32 in the pass that rounds ``q``): ``q`` then goes to
     the kernels as it came, with no pass and no rounding of its own."""
     t = q.shape[2]
+    if k.shape != v.shape or q.shape[1] % k.shape[1]:
+        raise ValueError(f"k {k.shape} and v {v.shape} must be one shape whose heads divide "
+                         f"q's {q.shape[1]}")
     if jax.default_backend() == "tpu" and t % SPLASH_BLOCK_MAJOR == 0:
         return _splash_causal_attention(q, k, v, scale)
     return blocked_causal_attention(q, k, v, scale, min(BLOCK_Q, t))
 
 
 @functools.lru_cache(maxsize=None)
-def _splash_kernel(heads: int, t: int, interpret: bool = False):
+def _splash_kernel(heads: int, t: int, interpret: bool = False, multi_query: bool = False):
     """The kernels of one ``(heads, t)``, built once: the causal mask's block
     tables are made on the host in numpy, and every layer of a model shares
-    them."""
+    them.  ``multi_query``: ``heads`` query heads on one key-value head."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as splash, splash_attention_mask as masks)
 
     mask = masks.MultiHeadMask([masks.CausalMask((t, t))] * heads)
+    make = splash.make_splash_mqa if multi_query else splash.make_splash_mha
     with jax.ensure_compile_time_eval():  # the tables are constants of whatever trace asks first
-        return splash.make_splash_mha(
+        return make(
             mask, block_sizes=splash.BlockSizes(use_fused_bwd_kernel=True, **SPLASH_BLOCKS),
             head_shards=1, q_seq_shards=1, interpret=interpret)
 
 
 def _splash_causal_attention(q, k, v, scale: float, interpret: bool = False):
-    kernel = _splash_kernel(q.shape[1], q.shape[2], interpret)
+    b, heads, t, size = q.shape
+    kv_heads = k.shape[1]
     if scale != 1.0:
         q = (q * scale).astype(q.dtype)
-    return jax.vmap(kernel)(q, k, v)
+    if kv_heads == heads:
+        return jax.vmap(_splash_kernel(heads, t, interpret))(q, k, v)
+    # each key-value head with its group of query heads: one k, one v a group
+    group = heads // kv_heads
+    kernel = _splash_kernel(group, t, interpret, multi_query=True)
+    out = jax.vmap(jax.vmap(kernel))(q.reshape(b, kv_heads, group, t, size), k, v)
+    return out.reshape(b, heads, t, size)
 
 
 def _blocks(t: int, block_q: int):
@@ -93,26 +121,37 @@ def _blocks(t: int, block_q: int):
     return [(i * block_q, (i + 1) * block_q) for i in range(t // block_q)]
 
 
-def _block_scores(q_blk, k_seen, start: int, scale: float):
-    """Float32 scores of query rows ``start ..`` against keys ``0 .. end``,
-    masked above the diagonal."""
+def _rows_by_group(x, kv_heads: int, start: int, end: int):
+    """Positions ``start .. end`` of ``x (batch, heads, positions, size)``
+    with each key-value head's group of query heads laid along the rows:
+    ``(batch, key-value heads, group x (end - start), size)``."""
+    b, heads, _, d = x.shape
+    return x[:, :, start:end].reshape(b, kv_heads, heads // kv_heads * (end - start), d)
+
+
+def _block_scores(q_blk, k_seen, start: int, block_q: int, scale: float):
+    """Float32 scores of a group's query rows at positions ``start .. start +
+    block_q`` against keys ``0 .. end``, masked above the diagonal."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q_blk, k_seen,
                    preferred_element_type=jnp.float32) * scale
-    rows = start + jnp.arange(q_blk.shape[2])[:, None]
+    rows = start + jnp.tile(jnp.arange(block_q), q_blk.shape[2] // block_q)[:, None]
     return jnp.where(jnp.arange(k_seen.shape[2])[None, :] <= rows, s, _NEG)
 
 
 def _forward(q, k, v, scale, block_q):
+    b, heads, t, d = q.shape
+    kv_heads = k.shape[1]
     outs, lses = [], []
-    for start, end in _blocks(q.shape[2], block_q):
-        s = _block_scores(q[:, :, start:end], k[:, :, :end], start, scale)
+    for start, end in _blocks(t, block_q):
+        s = _block_scores(_rows_by_group(q, kv_heads, start, end), k[:, :, :end],
+                          start, block_q, scale)
         m = jnp.max(s, axis=-1, keepdims=True)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=-1, keepdims=True)
         o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v[:, :, :end],
                        preferred_element_type=jnp.float32) / l
-        outs.append(o.astype(q.dtype))
-        lses.append((m + jnp.log(l))[..., 0])
+        outs.append(o.astype(q.dtype).reshape(b, heads, block_q, d))
+        lses.append((m + jnp.log(l))[..., 0].reshape(b, heads, block_q))
     return jnp.concatenate(outs, axis=2), jnp.concatenate(lses, axis=2)
 
 
@@ -129,20 +168,24 @@ def _fwd(q, k, v, scale, block_q):
 def _bwd(scale, block_q, res, d_out):
     q, k, v, out, lse = res
     f32 = jnp.float32
+    b, heads, t, d = q.shape
+    kv_heads = k.shape[1]
     # softmax's backward needs each row's sum of p * dp, which is do . o
     delta = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1, keepdims=True)
     dq = []
     dk, dv = jnp.zeros(k.shape, f32), jnp.zeros(v.shape, f32)
-    for start, end in _blocks(q.shape[2], block_q):
-        q_blk, do_blk = q[:, :, start:end], d_out[:, :, start:end]
-        s = _block_scores(q_blk, k[:, :, :end], start, scale)
-        p = jnp.exp(s - lse[:, :, start:end, None])
+    for start, end in _blocks(t, block_q):
+        q_blk, do_blk = (_rows_by_group(x, kv_heads, start, end) for x in (q, d_out))
+        s = _block_scores(q_blk, k[:, :, :end], start, block_q, scale)
+        p = jnp.exp(s - _rows_by_group(lse[..., None], kv_heads, start, end))
+        # the sums over the rows are sums over a group's query heads too
         dv = dv.at[:, :, :end].add(jnp.einsum(
             "bhqk,bhqd->bhkd", p.astype(v.dtype), do_blk, preferred_element_type=f32))
         dp = jnp.einsum("bhqd,bhkd->bhqk", do_blk, v[:, :, :end], preferred_element_type=f32)
-        ds = (p * (dp - delta[:, :, start:end]) * scale).astype(q.dtype)
+        ds = (p * (dp - _rows_by_group(delta, kv_heads, start, end)) * scale).astype(q.dtype)
         dq.append(jnp.einsum("bhqk,bhkd->bhqd", ds, k[:, :, :end],
-                             preferred_element_type=f32).astype(q.dtype))
+                             preferred_element_type=f32).astype(q.dtype).reshape(
+                                 b, heads, block_q, d))
         dk = dk.at[:, :, :end].add(jnp.einsum(
             "bhqk,bhqd->bhkd", ds, q_blk, preferred_element_type=f32))
     return jnp.concatenate(dq, axis=2), dk.astype(k.dtype), dv.astype(v.dtype)

@@ -120,7 +120,10 @@ def model_scope(part: str):
     """Named scope labeling one part of a model's forward pass
     (``attn_proj``, ``attn_core``, ``moe_route``, ``moe_dispatch``,
     ``moe_experts``, ``moe_combine``, ``moe_shared``, ``dense_mlp``,
-    ``head``).  Autodiff carries the frame into the backward pass's ops, so
+    ``head``; in ``models/lfm2_moe.py`` also ``conv_proj``, the two products
+    of a gated short convolution, and ``conv_core``, the gates and taps
+    between them).  Any name is a part: the summary keeps what it finds.
+    Autodiff carries the frame into the backward pass's ops, so
     the device trace gives each part's forward and backward time together
     (``model_part_ms`` of ``trace_analysis.summarize_capture``)."""
     return jax.named_scope(format_model_label(part))

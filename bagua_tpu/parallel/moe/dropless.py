@@ -20,7 +20,10 @@ no exchange of its own and no stand-in for one.
 
 The grouped product is ``megablox.gmm`` (Pallas, ships with JAX) on a TPU
 and ``jax.lax.ragged_dot`` elsewhere; rows past the held groups are left
-unwritten by the one and zero by the other, so every use masks them.
+unwritten by the one and zero by the other, so every use masks them.  The
+kernel's tile is a function of the product's shape (:func:`gmm_tiling`): the
+layer has two callers whose experts differ in width and in the rows a group
+gets (``models/glm_moe.py`` 1536, ``models/lfm2_moe.py`` 1792).
 """
 
 import functools
@@ -31,26 +34,50 @@ import jax.numpy as jnp
 
 from bagua_tpu.observability.annotations import model_scope
 
-#: (rows, contraction, columns) tile of the grouped product on the chip:
-#: measured on the v5e at 32,768 x 2048 x 1536 with 8 groups (PERF.md, PR 29)
-GMM_TILING = (512, 1024, 768)
+#: (rows, contraction, columns) tiles of the grouped product measured on the
+#: v5e by the step's time, by the experts' width; 8 groups in 32,768 rows; a
+#: contraction of 0 is the whole of it in one tile.  1536 (``glm-4.7-flash``:
+#: 512 expected rows a group; PERF.md, PR 29).  1792 (``lfm2-8b-a1b``: 1,024
+#: expected rows a group; 768 does not divide 1792 = 2 x 7 x 128; PERF.md
+#: section 6, PR 33, with the multi-query attention kernels: 128 rows against
+#: the whole contraction 151.93 ms, ``(256, 1024, 896)`` 156.73, ``(512,
+#: 1024, 896)`` 157.92, ``(128, 1024, 896)`` 159.87, ``(256, 512, 896)``
+#: 159.86; 256 rows against the whole contraction, 1,024 rows, or 1,792
+#: columns a tile are refused for the 16 MB of fast memory)
+GMM_TILES = {1536: (512, 1024, 768), 1792: (128, 0, 896)}
+
+
+def gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """The tile of one grouped product ``(m, k) x (groups, k, n)`` on the
+    chip; ``megablox`` asks it of the forward product and of the two products
+    of its backward pass, each with its own ``k`` and ``n``.  Of an expert's
+    two dimensions the narrower is its width and the other the model's hidden
+    size: a measured width takes its tile from :data:`GMM_TILES` whichever of
+    ``k`` and ``n`` it is; any other takes half the width across, or one tile
+    of 128 lanes where that is no multiple of them."""
+    width = min(k, n)
+    half = width // 2
+    rows, contraction, columns = GMM_TILES.get(
+        width, (512, 1024, half if half % 128 == 0 else 128))
+    return rows, contraction or k, columns
 
 
 def sigmoid_topk_route(h, router_kernel, correction_bias, k: int, scaling: float,
-                       normalize: bool = True) -> Tuple[jax.Array, jax.Array]:
+                       normalize: bool = True, eps: float = 1e-20) -> Tuple[jax.Array, jax.Array]:
     """``(chosen experts (tokens, k) int32, their weights (tokens, k) f32)``.
 
     In float32 at the highest matmul precision, whatever ``h`` came in:
     ``s = sigmoid(h W_r)``; the ``k`` experts of largest ``s + b`` (``b``
     steers the choice only and takes no gradient: the ``noaux_tc`` method);
-    ``w = s[chosen] / (sum s[chosen] + 1e-20) * scaling``."""
+    ``w = s[chosen] / (sum s[chosen] + eps) * scaling`` (``eps``: 1e-20 in
+    the ``glm4_moe_lite`` family, 1e-6 in ``lfm2_moe``)."""
     scores = jax.nn.sigmoid(jnp.dot(
         h.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(correction_bias), k)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     if normalize:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     return chosen.astype(jnp.int32), weights * scaling
 
 
@@ -82,7 +109,7 @@ def grouped_matmul(rows, kernels, group_sizes):
     if jax.default_backend() == "tpu":
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        return gmm(rows, kernels, group_sizes, rows.dtype, GMM_TILING)
+        return gmm(rows, kernels, group_sizes, rows.dtype, gmm_tiling)
     return jax.lax.ragged_dot(rows, kernels, group_sizes)
 
 

@@ -1,0 +1,13 @@
+"""Share of the chip's bf16 peak at which the grouped-query attention core
+ran: the operations of scores and mixing at the causal half of the square and
+at the published head size of 64 whatever the kernel pads it to, forward and
+backward, nothing recomputed (the adapter's
+``attention_core_flops_per_sample``), over the time under
+``bagua_model/part=attn_core``.  Compute bounds it."""
+
+from benchmark.model_parts import roofline_pct
+
+
+def read(context):
+    return roofline_pct(context, "gqa_attention_core_roofline_pct",
+                        "attention_core_flops_per_sample", "attn_core")

@@ -333,12 +333,25 @@ text = jax.jit(both_passes(lambda q, k, v: causal_attention(q, k, v, 1 / 16.0)))
     qkv, qkv, qkv, qkv).compile().as_text()
 print("ATTENTION-KERNELS", text.count("tpu_custom_call"), flush=True)
 sizes = shape((8,), jnp.int32)
-for width_in, width_out in ((2048, 1536), (1536, 2048)):
-    text = jax.jit(lambda out_grad, rows, kernels, group_sizes: both_passes(
-        lambda r, k: grouped_matmul(r, k, group_sizes))(out_grad, rows, kernels)).lower(
-        shape((32768, width_out)), shape((32768, width_in)), shape((8, width_in, width_out)),
-        sizes).compile().as_text()
-    print("GROUPED-KERNELS", text.count("tpu_custom_call"), flush=True)
+
+
+def grouped_kernels(label, width):
+    for width_in, width_out in ((2048, width), (width, 2048)):
+        text = jax.jit(lambda out_grad, rows, kernels, group_sizes: both_passes(
+            lambda r, k: grouped_matmul(r, k, group_sizes))(out_grad, rows, kernels)).lower(
+            shape((32768, width_out)), shape((32768, width_in)), shape((8, width_in, width_out)),
+            sizes).compile().as_text()
+        print(label, text.count("tpu_custom_call"), flush=True)
+
+
+grouped_kernels("GROUPED-KERNELS", 1536)
+# lfm2-8b-a1b.dp1-s8192: 32 query heads on 8 key-value heads x 8,192 x 64, no key repeated;
+# experts of width 1792, which the tile of width 1536 does not divide
+q, kv = shape((1, 32, 8192, 64)), shape((1, 8, 8192, 64))
+text = jax.jit(both_passes(lambda q, k, v: causal_attention(q, k, v, 1.0))).lower(
+    q, q, kv, kv).compile().as_text()
+print("GQA-KERNELS", text.count("tpu_custom_call"), flush=True)
+grouped_kernels("WIDTH-1792-KERNELS", 1792)
 # the cell's whole step under the engine's compiler options: inside it the fused
 # backward kernel needs 0.3 to 0.4 MB more fast memory than compiled alone (PR 30)
 from bagua_tpu.ddp import STEP_COMPILER_OPTIONS
@@ -440,6 +453,12 @@ def test_the_expert_models_kernels_compile_for_the_chip_at_the_cells_shapes():
     # input's and its kernels' gradients
     assert int(counts["ATTENTION-KERNELS"]) == 2 and int(counts["GROUPED-KERNELS"]) == 3
     assert proc.stdout.count("GROUPED-KERNELS") == 2
+    # lfm2-8b-a1b.dp1-s8192 (PR 33): the same two attention kernels at a head of 64 with four
+    # query heads a key-value head, and the grouped products at width 1792
+    lfm2 = [line.split() for line in proc.stdout.splitlines()
+            if line.startswith(("GQA-KERNELS", "WIDTH-1792-KERNELS"))]
+    assert lfm2 == [
+        ["GQA-KERNELS", "2"], ["WIDTH-1792-KERNELS", "3"], ["WIDTH-1792-KERNELS", "3"]], lfm2
     # five layers' forward and fused backward kernels in the step the cell runs
     step = next(line.split() for line in proc.stdout.splitlines()
                 if line.startswith("STEP-ATTENTION-KERNELS"))
