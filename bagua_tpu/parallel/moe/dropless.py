@@ -18,6 +18,29 @@ them it is this chip's share of an expert-parallel deployment, and the
 shares of all chips add up to the whole (``tests/test_glm_moe.py``).  It has
 no exchange of its own and no stand-in for one.
 
+The permutation out and the permutation back are two passes that are each
+other's transpose, and every use in the layer is one of them (PR 34):
+
+* :func:`spread`: row ``r`` of the buffer takes ``scale[r] * src[perm[r] //
+  k]`` where it is live and zero where it is not.  Dispatch forward is
+  ``spread`` of ``x`` with no scale (scope ``moe_dispatch``); combine's
+  backward is ``spread`` of the token's gradient with the row's weight as
+  scale, and the weights' gradient, a row-wise dot product with the saved
+  buffer, rides beside it (``moe_combine``).
+* :func:`collect`: token ``t`` takes ``sum_j w[t, j] * rows[inverse[t * k +
+  j]]`` over its live choices, accumulated in float32 and rounded once.
+  Combine forward is ``collect`` with the router's weights
+  (``moe_combine``); dispatch's backward is ``collect`` with weight 1 of the
+  sum of the two input gradients (``moe_dispatch``).
+
+Each is a ``jax.custom_vjp`` whose backward is the other, so autodiff builds
+neither a ``(tokens, k, hidden)`` array (on the chip a re-tiling to four
+sublanes), nor its float32 broadcast, nor a scatter-add.  A dead row holds
+whatever the buffer held: the passes *select* it away and never scale it.
+Nothing is sized to, or branched on, the live rows.  The 32,768 scalars a
+pass needs in the other order (the weight a row, the gradient a choice) are
+reordered by a sort, which costs a tenth of the gather.
+
 The grouped product is ``megablox.gmm`` (Pallas, ships with JAX) on a TPU
 and ``jax.lax.ragged_dot`` elsewhere; rows past the held groups are left
 unwritten by the one and zero by the other, so every use masks them.  The
@@ -75,31 +98,132 @@ def sigmoid_topk_route(h, router_kernel, correction_bias, k: int, scaling: float
         h.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(correction_bias), k)
-    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    # the chosen scores by a select over the experts: a gather of tokens x k
+    # scalars takes 0.33 ms a layer on the chip, this 0.01 (PERF.md, PR 34)
+    picked = chosen[..., None] == jnp.arange(scores.shape[-1])
+    weights = jnp.sum(jnp.where(picked, scores[:, None, :], 0), axis=-1)
     if normalize:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     return chosen.astype(jnp.int32), weights * scaling
 
 
+# -- the two passes ------------------------------------------------------------
+#
+# ``order = (perm, inverse, n_live)`` describes the row buffer: ``perm`` a
+# permutation of its ``tokens x k`` positions (row ``r`` belongs to token
+# ``perm[r] // k``), ``inverse`` its inverse (choice ``j`` of token ``t`` lies
+# in row ``inverse[t * k + j]``), and the rows from 0 to ``n_live - 1`` live.
+
+
+def _live(rows: int, n_live):
+    return jnp.arange(rows) < n_live
+
+
+def _reorder(values, index, index_inverse):
+    """``values[index]`` of one scalar a row, for a permutation ``index``
+    with inverse ``index_inverse``, as a sort by ``index_inverse``: 0.02 ms
+    for 32,768 scalars on the chip, where the gather takes 0.25."""
+    return jax.lax.sort((index_inverse, values), num_keys=1)[1]
+
+
+def _row_dots(buffer, src, order, fan: int):
+    """``dot(buffer[r], src[perm[r] // fan])`` of every live row in float32,
+    in the buffer's order: the gradient of a row's scale."""
+    perm, _, n_live = order
+    dots = jnp.sum(buffer.astype(jnp.float32) * src[perm // fan].astype(jnp.float32), axis=-1)
+    return jnp.where(_live(perm.shape[0], n_live), dots, 0)
+
+
+#: rows of zeros put under ``spread``'s source for the dead rows to take: a
+#: tile of bf16
+PAD_ROWS = 16
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_rows(x, perm, inverse, fan: int):
-    """``x[perm // fan]``, where ``perm`` is a permutation of
-    ``rows(x) * fan`` positions with inverse ``inverse``.  Its transpose is a
-    gather by ``inverse`` and a sum over ``fan``: autodiff's scatter-add of
-    32,768 rows never appears."""
-    return x[perm // fan]
+def spread(src, scale, order, fan: int):
+    """Row ``r`` of the ``rows(src) * fan`` buffer takes ``scale[r] *
+    src[perm[r] // fan]``, rounded once (``src[perm[r] // fan]`` where
+    ``scale`` is ``None``), where ``r < n_live``, and zero where not.  One
+    gather from the ``rows(src)`` rows; the transpose of :func:`collect`.
+
+    Without a scale the select is in the index: a dead row takes a row of
+    zeros put under ``src``, and the gather is the whole pass.  With one, the
+    product and the select fuse into the pass that reads the buffer next
+    (by the step's time on the chip, PERF.md section 6, PR 34)."""
+    perm, _, n_live = order
+    live = _live(perm.shape[0], n_live)
+    if scale is None:
+        padded = jnp.pad(src, ((0, PAD_ROWS), (0, 0)))
+        return padded[jnp.where(live, perm // fan, src.shape[0])]
+    return jnp.where(live[:, None], (scale[:, None] * src[perm // fan]).astype(src.dtype), 0)
 
 
-def _take_rows_fwd(x, perm, inverse, fan):
-    return x[perm // fan], (inverse, x.shape[0])
+def _spread_fwd(src, scale, order, fan):
+    return spread(src, scale, order, fan), (None if scale is None else src, scale, order)
 
 
-def _take_rows_bwd(fan, res, g):
-    inverse, rows = res
-    return g[inverse].reshape(rows, fan, g.shape[-1]).sum(axis=1), None, None
+def _spread_bwd(fan, res, grad):
+    src, scale, order = res
+    if scale is None:
+        return collect(grad, None, order, fan), None, None
+    perm, inverse, _ = order
+    weight = _reorder(scale, inverse, perm).reshape(-1, fan)
+    return collect(grad, weight, order, fan), _row_dots(grad, src, order, fan), None
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+spread.defvjp(_spread_fwd, _spread_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def collect(buffer, weight, order, fan: int):
+    """Token ``t`` takes ``sum_j weight[t, j] * buffer[inverse[t * fan + j]]``
+    (weight 1 where ``weight`` is ``None``) over the ``j`` whose row is live
+    (``inverse[t * fan + j] < n_live``), accumulated in float32 and rounded
+    once.  A dead row is selected away, never scaled: it holds whatever the
+    buffer held.  The transpose of :func:`spread`.
+
+    ``fan`` gathers of ``rows(buffer) / fan`` rows and their sum, never an
+    array whose second-minor dimension is ``fan``.  The result is written
+    here (the barrier): left to fuse into its readers, the sum is formed
+    once in the forward and once in the backward pass, and the ``fan``
+    gathered arrays of every layer stay live between the two."""
+    _, inverse, n_live = order
+    index = inverse.reshape(-1, fan)
+    total = 0.0
+    for j in range(fan):
+        taken = buffer[index[:, j]].astype(jnp.float32)
+        if weight is not None:
+            taken = weight[:, j, None] * taken
+        total = total + jnp.where((index[:, j] < n_live)[:, None], taken, 0)
+    return jax.lax.optimization_barrier(total.astype(buffer.dtype))
+
+
+def _collect_fwd(buffer, weight, order, fan):
+    return collect(buffer, weight, order, fan), (None if weight is None else buffer, weight, order)
+
+
+def _collect_bwd(fan, res, grad):
+    buffer, weight, order = res
+    if weight is None:
+        return spread(grad, None, order, fan), None, None
+    perm, inverse, _ = order
+    scale = _reorder(weight.reshape(-1), perm, inverse)
+    dots = _reorder(_row_dots(buffer, grad, order, fan), inverse, perm)
+    return spread(grad, scale, order, fan), dots.reshape(weight.shape).astype(weight.dtype), None
+
+
+collect.defvjp(_collect_fwd, _collect_bwd)
+
+
+@jax.custom_vjp
+def _for_two_readers(buffer):
+    """``buffer`` twice: its two readers' gradients come back apart and are
+    added here, under the caller's scope and not wherever autodiff would."""
+    return buffer, buffer
+
+
+_for_two_readers.defvjp(lambda buffer: ((buffer, buffer), None),
+                        lambda _, grads: (grads[0] + grads[1],))
 
 
 def grouped_matmul(rows, kernels, group_sizes):
@@ -128,19 +252,18 @@ def dropless_experts(x, chosen, weights, gate, up, down, held: Tuple[int, int],
         key = ((chosen - first) % num_experts).reshape(-1)
         perm = jnp.argsort(key, stable=True)
         inverse = jnp.argsort(perm)
-        sizes = jnp.bincount(key, length=num_experts)[:count].astype(jnp.int32)
-        live = (jnp.arange(tokens * k) < jnp.sum(sizes))[:, None]
-        rows = jnp.where(live, _take_rows(x, perm, inverse, k), 0)
+        sizes = jnp.sum(key[:, None] == jnp.arange(count), axis=0, dtype=jnp.int32)
+        n_live = jnp.sum(sizes)
+        order = (perm, inverse, n_live)
+        live = _live(tokens * k, n_live)[:, None]
+        for_gate, for_up = _for_two_readers(spread(x, None, order, k))
     with model_scope("moe_experts"):
         # each product's result is masked before anything reads it: on the
         # chip the rows past the held groups are whatever the buffer held
         def product(lhs, kernels):
             return jnp.where(live, grouped_matmul(lhs, kernels.astype(x.dtype), sizes), 0)
 
-        hidden = jax.nn.silu(product(rows, gate)) * product(rows, up)
+        hidden = jax.nn.silu(product(for_gate, gate)) * product(for_up, up)
         out = product(hidden, down)
     with model_scope("moe_combine"):
-        mine = ((chosen >= first) & (chosen < first + count))[..., None]
-        back = _take_rows(out, inverse, perm, 1).reshape(tokens, k, -1)
-        share = jnp.where(mine, weights[..., None], 0.0)
-        return jnp.sum(jnp.where(mine, back, 0) * share, axis=1).astype(x.dtype)
+        return collect(out, weights, order, k)

@@ -377,18 +377,25 @@ print("STEP-ATTENTION-KERNELS",
 # kernels: each entry operation under part=attn_proj or part=attn_core with the bytes of its
 # operands and its result, from the shapes
 import collections, math, re
-computations, current = {}, None
-for line in text.splitlines():
-    head = re.match(r"(ENTRY )?%(\\S+) \\(.*\\{\\s*$", line)
-    instruction = re.match(r"\\s*(?:ROOT )?%(\\S+) = (.*?) ([a-z][\\w-]*)\\((.*)$", line)
-    if head:
-        current = computations.setdefault("ENTRY" if head.group(1) else head.group(2), [])
-    elif line.rstrip() == "}":
-        current = None
-    elif instruction and current is not None:
-        current.append(list(instruction.groups()))
-    elif current:  # a Pallas call prints its metadata over several lines
-        current[-1][3] += " " + line
+
+
+def computations_of(text):
+    computations, current = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%(\\S+) \\(.*\\{\\s*$", line)
+        instruction = re.match(r"\\s*(?:ROOT )?%(\\S+) = (.*?) ([a-z][\\w-]*)\\((.*)$", line)
+        if head:
+            current = computations.setdefault("ENTRY" if head.group(1) else head.group(2), [])
+        elif line.rstrip() == "}":
+            current = None
+        elif instruction and current is not None:
+            current.append(list(instruction.groups()))
+        elif current:  # a Pallas call prints its metadata over several lines
+            current[-1][3] += " " + line
+    return computations
+
+
+computations = computations_of(text)
 
 
 def nbytes(shape):
@@ -427,6 +434,49 @@ for name, shape, opcode, rest in entry:
         print("ATTENTION-LARGE-COPY", name, plain, flush=True)
 for (part, kind), total in sorted(moved.items()):
     print("ATTENTION-MOVED", part, kind, total, flush=True)
+# one expert layer of each cell, forward and backward: the bytes its routing machinery moves
+# (scopes moe_dispatch and moe_combine), counted as above; a fusion that gathers reads the rows
+# it takes, so there an operand counts for no more than the result
+from bagua_tpu.observability.annotations import model_scope
+from bagua_tpu.parallel.moe.dropless import dropless_experts, sigmoid_topk_route
+
+
+def expert_layer(experts, scaling, eps):
+    def layer(x, router, bias, gate, up, down):
+        with model_scope("moe_route"):
+            chosen, weights = sigmoid_topk_route(x, router, bias, 4, scaling, True, eps)
+        return dropless_experts(x, chosen, weights, gate, up, down, (0, 8), experts)
+    return layer
+
+
+def spec(dims, dtype=jnp.bfloat16):  # the census above took the name ``shape``
+    return jax.ShapeDtypeStruct(dims, dtype, sharding=device)
+
+
+for label, width, experts, scaling, eps in (
+        ("glm-4.7-flash", 1536, 64, 1.8, 1e-20), ("lfm2-8b-a1b", 1792, 32, 1.0, 1e-6)):
+    f32 = jnp.float32
+    text = jax.jit(both_passes(expert_layer(experts, scaling, eps))).lower(
+        spec((8192, 2048)), spec((8192, 2048)), spec((2048, experts), f32), spec((experts,), f32),
+        spec((8, 2048, width), f32), spec((8, 2048, width), f32),
+        spec((8, width, 2048), f32)).compile().as_text()
+    computations = computations_of(text)
+    entry = computations["ENTRY"]
+    shape_of = {name: result for name, result, _, _ in entry}
+    moved = 0
+    for name, result, opcode, rest in entry:
+        if (not re.search(r'op_name="[^"]*part=(moe_dispatch|moe_combine)\\b', rest) or opcode in (
+                "bitcast", "get-tuple-element", "tuple", "constant", "parameter", "iota")):
+            continue
+        plain = re.sub(r"\\{[^}]*\\}", "", result).replace(" ", "")
+        if "[8192,4,2048]" in plain or "f32[32768,2048]" in plain:
+            print("MACHINERY-WIDE-INTERMEDIATE", label, name, plain, flush=True)
+        if "tpu_custom_call" in rest:
+            print("MACHINERY-KERNEL", label, name, flush=True)
+        cap = nbytes(result) if "gather" in opcodes_inside(rest) else math.inf
+        moved += nbytes(result) + sum(min(nbytes(shape_of.get(operand, "")), cap)
+                                      for operand in re.findall(r"%([\\w.\\-]+)", rest.split(")")[0]))
+    print("MACHINERY-MOVED", label, moved, flush=True)
 """
 
 
@@ -478,6 +528,21 @@ def test_the_expert_models_kernels_compile_for_the_chip_at_the_cells_shapes():
                           ("attn_core", "kernel"), ("attn_core", "other")}, moved
     assert moved["attn_core", "kernel"] > 1.6e9, moved  # q, k, v, o, do and eight dQ partials
     assert moved["attn_proj", "other"] < 1.18e9 and moved["attn_core", "other"] < 1.12e9, moved
+    # Guards the 20 ms a step (of 246 and of 156, my chip runs, PR 34) that the expert layer's
+    # permutation out and back took over what it takes now: one layer moved 4.07 GB under
+    # ``moe_dispatch`` and ``moe_combine`` (gathers by ``inverse`` re-tiled to
+    # ``[8192,4,2048]``, a float32 broadcast of the token's gradient re-tiled to
+    # ``f32[32768,2048]``, selects with a pass of their own) and moves 2.09 GB as ``spread``
+    # and ``collect``.  The bound is that plus a tenth.  ISSUE 34 asked for 1.5: XLA's gather
+    # on the chip is a fusion of its own that takes no second gather, no producer and no
+    # reduction into it, so a sum of ``k`` gathers is ``k`` arrays written and read again, and
+    # Mosaic refuses the one-row slice a Pallas pass would move by DMA (PERF.md section 6,
+    # PR 34).  The passes are plain ``jax.numpy``: no kernel of their own.
+    machinery = {words[1]: int(words[2]) for words in lines if words[0] == "MACHINERY-MOVED"}
+    assert set(machinery) == {"glm-4.7-flash", "lfm2-8b-a1b"}, proc.stdout
+    assert all(0.5e9 < total < 2.3e9 for total in machinery.values()), machinery
+    assert not [words for words in lines if words[0] == "MACHINERY-WIDE-INTERMEDIATE"], proc.stdout
+    assert not [words for words in lines if words[0] == "MACHINERY-KERNEL"], proc.stdout
 
 
 _HEAD_CENSUS = """

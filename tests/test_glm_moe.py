@@ -28,7 +28,7 @@ from bagua_tpu.models.llama import apply_rope
 from bagua_tpu.observability import trace_analysis as ta
 from bagua_tpu.observability.annotations import model_scope
 from bagua_tpu.observability.scope_grammar import format_model_label, parse_model_part
-from bagua_tpu.parallel.moe.dropless import dropless_experts, sigmoid_topk_route
+from bagua_tpu.parallel.moe.dropless import collect, dropless_experts, sigmoid_topk_route, spread
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -203,6 +203,111 @@ def test_dispatch_gradients_match_a_dense_evaluation():
     assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
     for g, w in zip(got[1], want[1]):
         assert rel_err(g, w) < 1e-5
+
+
+def _row_order(seed, tokens, k, experts, count):
+    """``(perm, inverse, n_live)`` as the layer builds it, from random choices."""
+    key = jax.random.randint(jax.random.PRNGKey(seed), (tokens * k,), 0, experts)
+    perm = jnp.argsort(key, stable=True)
+    return perm, jnp.argsort(perm), jnp.sum(key < count)
+
+
+@pytest.mark.parametrize("scaled", [True, False], ids=["scaled", "unscaled"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+def test_spread_and_collect_are_each_others_transpose(dtype, scaled):
+    tokens, k, hidden = 24, 3, 16
+    order = perm, inverse, n_live = _row_order(11, tokens, k, experts=8, count=3)
+    assert 0 < int(n_live) < tokens * k
+    keys = jax.random.split(jax.random.PRNGKey(12), 4)
+    src = jax.random.normal(keys[0], (tokens, hidden)).astype(dtype)
+    buffer = jax.random.normal(keys[1], (tokens * k, hidden)).astype(dtype)
+    scale = jax.random.uniform(keys[2], (tokens * k,), minval=0.5) if scaled else None
+    weight = scale[inverse].reshape(tokens, k) if scaled else None
+    live = (jnp.arange(tokens * k) < n_live)[:, None]
+    # the passes against their definitions, written plainly
+    plain_scale = scale[:, None] if scaled else 1.0
+    want_rows = jnp.where(live, (plain_scale * src[perm // k]).astype(dtype), 0)
+    np.testing.assert_array_equal(spread(src, scale, order, k), want_rows)
+    taken = jnp.where(live, buffer, 0).astype(jnp.float32) * plain_scale
+    want_tokens = jnp.sum(taken[inverse].reshape(tokens, k, hidden), axis=1).astype(dtype)
+    tol = dict(rtol=1e-6, atol=1e-6) if dtype == jnp.float32 else dict(rtol=1e-2, atol=1e-2)
+    np.testing.assert_allclose(np.asarray(collect(buffer, weight, order, k), np.float32),
+                               np.asarray(want_tokens, np.float32), **tol)
+    # a dead row of the buffer is whatever it held: NaN here, and never read
+    poisoned = jnp.where(live, buffer, jnp.nan)
+    np.testing.assert_array_equal(collect(poisoned, weight, order, k), collect(buffer, weight, order, k))
+    # the transpose of each is the other, to the last bit
+    got = jax.vjp(lambda s: spread(s, scale, order, k), src)[1](buffer)[0]
+    np.testing.assert_array_equal(got, collect(buffer, weight, order, k))
+    got = jax.vjp(lambda b: collect(b, weight, order, k), buffer)[1](src)[0]
+    np.testing.assert_array_equal(got, spread(src, scale, order, k))
+    # and what autodiff makes of the plain definitions (a scatter-add)
+    plain = jax.vjp(lambda s: jnp.where(live, plain_scale * s.astype(jnp.float32)[perm // k], 0),
+                    src)[1](buffer.astype(jnp.float32))[0]
+    np.testing.assert_allclose(
+        np.asarray(collect(buffer, weight, order, k), np.float32), plain, **tol)
+    if scaled:  # the row-wise dot products: the gradients of the scale and of the weight
+        d_scale = jax.vjp(lambda c: spread(src, c, order, k), scale)[1](buffer)[0]
+        want = jnp.where(live[:, 0], jnp.sum(
+            buffer.astype(jnp.float32) * src.astype(jnp.float32)[perm // k], axis=-1), 0)
+        np.testing.assert_allclose(d_scale, want, **tol)
+        d_weight = jax.vjp(lambda w: collect(poisoned, w, order, k), weight)[1](src)[0]
+        np.testing.assert_allclose(d_weight, want[inverse].reshape(tokens, k), **tol)
+
+
+LOADS = {
+    # experts, held, bias that steers the choice
+    "an_eighth_held": (16, (6, 2), None),
+    "a_quarter_held": (8, (4, 2), None),
+    "every_expert_held": (4, (0, 4), None),
+    "every_token_on_one_held_expert": (8, (5, 2), (6, 1)),
+}
+
+
+@pytest.mark.parametrize("load", list(LOADS))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+def test_layer_and_every_gradient_match_a_dense_evaluation_at_every_load(dtype, load):
+    experts, held, favoured = LOADS[load]
+    tokens, hidden, width, k = 48, 16, 8, 2
+    keys = jax.random.split(jax.random.PRNGKey(21), 7)
+    x = jax.random.normal(keys[0], (tokens, hidden)).astype(dtype)
+    router = 0.5 * jax.random.normal(keys[1], (hidden, experts))
+    bias = 0.1 * jax.random.normal(keys[2], (experts,))
+    if favoured:  # the k experts every token goes to: one of them is held
+        bias = bias.at[jnp.array(favoured)].add(8.0)
+    gate, up = (0.3 * jax.random.normal(kk, (held[1], hidden, width)) for kk in keys[3:5])
+    down = 0.3 * jax.random.normal(keys[5], (held[1], width, hidden))
+    probe = jax.random.normal(keys[6], (tokens, hidden))
+
+    def sparse(x, router, gate, up, down):
+        chosen, weights = sigmoid_topk_route(x, router, bias, k, 1.8)
+        out = dropless_experts(x, chosen, weights, gate, up, down, held=held, num_experts=experts)
+        return jnp.sum(probe * out.astype(jnp.float32)), (out, chosen)
+
+    def dense(x, router, gate, up, down):
+        chosen, weights = sigmoid_topk_route(x, router, bias, k, 1.8)
+        x = x.astype(jnp.float32)
+        out = 0.0
+        for n in range(held[1]):
+            w = jnp.sum(jnp.where(chosen == held[0] + n, weights, 0.0), axis=-1, keepdims=True)
+            out = out + w * ((jax.nn.silu(x @ gate[n]) * (x @ up[n])) @ down[n])
+        return jnp.sum(probe * out), (out, chosen)
+
+    args = (x, router, gate, up, down)
+    with jax.default_matmul_precision("highest"):
+        (_, (got_out, chosen)), got = jax.value_and_grad(sparse, argnums=range(5), has_aux=True)(*args)
+        (_, (want_out, _)), want = jax.value_and_grad(dense, argnums=range(5), has_aux=True)(*args)
+    mine = (chosen >= held[0]) & (chosen < held[0] + held[1])
+    if favoured:
+        assert set(np.unique(chosen)) == set(favoured) and np.all(np.sum(mine, axis=-1) == 1)
+    elif held[1] == experts:
+        assert np.all(mine)
+    else:
+        assert 0 < int(jnp.sum(mine)) < tokens * k
+    bound = 1e-5 if dtype == jnp.float32 else 2e-2
+    assert rel_err(got_out, want_out) < bound
+    for name, g, w in zip(("x", "router", "gate", "up", "down"), got, want):
+        assert g.dtype == w.dtype and rel_err(g, w) < bound, (name, rel_err(g, w))
 
 
 # -- attention ----------------------------------------------------------------

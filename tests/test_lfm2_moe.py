@@ -31,6 +31,7 @@ from bagua_tpu.models.lfm2_moe import (
     rotate_half,
 )
 from bagua_tpu.observability.scope_grammar import format_model_label
+from bagua_tpu.parallel.moe import dropless
 from bagua_tpu.parallel.moe.dropless import gmm_tiling, sigmoid_topk_route
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -184,6 +185,62 @@ def test_the_routers_eps_is_an_argument_whose_default_is_glms():
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     np.testing.assert_allclose(lfm2, picked / (picked.sum(-1, keepdims=True) + 1e-6), rtol=1e-6)
     assert float(jnp.max(jnp.abs(lfm2.sum(-1) - 1.0))) < 1e-5 and np.all(lfm2 <= tiny)
+
+
+def _unwritten_rows_are_nan(grouped_matmul):
+    """``grouped_matmul`` as the chip runs it: ``megablox.gmm`` leaves the rows
+    past the held groups unwritten, in the product and in its input's
+    gradient.  Here they come back NaN."""
+    def poison(rows, group_sizes):
+        live = (jnp.arange(rows.shape[0]) < jnp.sum(group_sizes))[:, None]
+        return jnp.where(live, rows, jnp.nan)
+
+    @jax.custom_vjp
+    def poisoned(rows, kernels, group_sizes):
+        return poison(grouped_matmul(rows, kernels, group_sizes), group_sizes)
+
+    def fwd(rows, kernels, group_sizes):
+        out, vjp = jax.vjp(lambda r, k: grouped_matmul(r, k, group_sizes), rows, kernels)
+        return poison(out, group_sizes), (vjp, group_sizes)
+
+    def bwd(res, grad):
+        vjp, group_sizes = res
+        live = (jnp.arange(grad.shape[0]) < jnp.sum(group_sizes))[:, None]
+        d_rows, d_kernels = vjp(jnp.where(live, grad, 0))  # the kernel reads the groups' rows alone
+        return poison(d_rows, group_sizes), d_kernels, None
+
+    poisoned.defvjp(fwd, bwd)
+    return poisoned
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+def test_rows_the_grouped_product_leaves_unwritten_reach_no_value_and_no_gradient(dtype, monkeypatch):
+    tokens, hidden, width, experts, k, held = 64, 16, 8, 32, 4, (8, 8)
+    keys = jax.random.split(jax.random.PRNGKey(5), 7)
+    x = jax.random.normal(keys[0], (tokens, hidden)).astype(dtype)
+    router = 0.5 * jax.random.normal(keys[1], (hidden, experts))
+    bias = 0.002 * jax.random.normal(keys[2], (experts,))
+    gate, up = (0.3 * jax.random.normal(kk, (held[1], hidden, width)) for kk in keys[3:5])
+    down = 0.3 * jax.random.normal(keys[5], (held[1], width, hidden))
+    probe = jax.random.normal(keys[6], (tokens, hidden))
+
+    def layer(x, router, gate, up, down):
+        chosen, weights = sigmoid_topk_route(x, router, bias, k, 1.0, True, 1e-6)
+        out = dropless.dropless_experts(x, chosen, weights, gate, up, down, held=held,
+                                        num_experts=experts)
+        return jnp.sum(probe * out.astype(jnp.float32)), (out, chosen)
+
+    run = jax.value_and_grad(layer, argnums=range(5), has_aux=True)
+    (_, (want_out, chosen)), want = run(x, router, gate, up, down)
+    dead = tokens * k - int(jnp.sum((chosen >= held[0]) & (chosen < held[0] + held[1])))
+    assert 0 < dead < tokens * k  # a quarter held: most of the buffer is dead rows
+    monkeypatch.setattr(dropless, "grouped_matmul", _unwritten_rows_are_nan(dropless.grouped_matmul))
+    (_, (got_out, _)), got = run(x, router, gate, up, down)
+    np.testing.assert_array_equal(got_out, want_out)
+    assert np.all(np.isfinite(np.asarray(got_out, np.float32)))
+    for name, g, w in zip(("x", "router", "gate", "up", "down"), got, want):
+        assert np.all(np.isfinite(np.asarray(g, np.float32))), name
+        np.testing.assert_array_equal(g, w, err_msg=name)
 
 
 # -- the gated short convolution ----------------------------------------------
