@@ -1,5 +1,6 @@
-"""Causal self-attention that never holds more than a block of scores, in the
-forward and in the backward pass.
+"""Causal self-attention, over all earlier keys or over a window of them,
+that never holds more than a block of scores, in the forward and in the
+backward pass.
 
 ``softmax(q k^T * scale + causal) v`` over a whole sequence at 8,192
 positions and 20 heads is 5.4 GB of float32 scores a layer if written down
@@ -39,6 +40,20 @@ repeated four times; the tile edges decided at head size 256 measured the
 same as five others at head size 64 (160.54 to 161.33) and serve both.  With
 as many key-value heads as query heads (``models/glm_moe.py``) both backends
 run what they ran.
+
+One mask, two shapes.  With ``window`` position ``i`` sees the keys ``j`` with
+``i - window < j <= i`` (the window counts the current position), and each
+backend leaves the blocks behind the window out as it leaves the blocks
+above the diagonal out: the composition takes a block's keys from ``start -
+window + 1`` and bounds the scores from below as from above; on a TPU the
+mask is splash's ``LocalMask`` with ``window - 1`` keys to the left and none
+to the right, whose blocks behind the window leave the grid on the host and
+whose two edges are applied only in the blocks they cross.  At 8,192
+positions and a window of 4,096 that is 30 of a layer's 36 tiles of 1,024 x
+1,024 (``models/smallthinker_moe.py``: windowed and global layers in one
+stack, 7 query heads a key-value head).  A window that holds the whole
+sequence *is* the causal mask and runs as ``window=None`` does; and
+``window=None`` builds and runs what it did before there was a window.
 """
 
 import functools
@@ -65,8 +80,10 @@ SPLASH_BLOCK_MAJOR = max(SPLASH_BLOCKS.values())
 _NEG = -1e30  # finite: a masked score must not make ``exp(s - m)`` a NaN
 
 
-def causal_attention(q, k, v, scale: float):
-    """``softmax(q k^T * scale + causal mask) v``.  ``q``, ``k``, ``v`` are
+def causal_attention(q, k, v, scale: float, window=None):
+    """``softmax(q k^T * scale + causal mask) v``; with ``window``, the mask
+    also hides key ``j`` from position ``i`` where ``i - j >= window``.
+    ``q``, ``k``, ``v`` are
     ``(batch, heads, positions, head size)`` with one head size, ``k`` and
     ``v`` with ``q``'s heads or a divisor of them (grouped queries: the
     module's text); the result has ``q``'s shape and type.  On a TPU the
@@ -80,20 +97,30 @@ def causal_attention(q, k, v, scale: float):
     if k.shape != v.shape or q.shape[1] % k.shape[1]:
         raise ValueError(f"k {k.shape} and v {v.shape} must be one shape whose heads divide "
                          f"q's {q.shape[1]}")
+    if window is not None:
+        if window < 1:
+            raise ValueError(f"a window of {window} keys holds not even the current position")
+        if window >= t:  # every earlier key is inside it: the causal mask
+            window = None
     if jax.default_backend() == "tpu" and t % SPLASH_BLOCK_MAJOR == 0:
-        return _splash_causal_attention(q, k, v, scale)
-    return blocked_causal_attention(q, k, v, scale, min(BLOCK_Q, t))
+        return _splash_causal_attention(q, k, v, scale, window=window)
+    return blocked_causal_attention(q, k, v, scale, min(BLOCK_Q, t), window)
 
 
 @functools.lru_cache(maxsize=None)
-def _splash_kernel(heads: int, t: int, interpret: bool = False, multi_query: bool = False):
-    """The kernels of one ``(heads, t)``, built once: the causal mask's block
-    tables are made on the host in numpy, and every layer of a model shares
-    them.  ``multi_query``: ``heads`` query heads on one key-value head."""
+def _splash_kernel(heads: int, t: int, interpret: bool = False, multi_query: bool = False,
+                   window=None):
+    """The kernels of one ``(heads, t, window)``, built once: the mask's block
+    tables are made on the host in numpy, and every layer of a model with
+    that mask shares them.  ``multi_query``: ``heads`` query heads on one
+    key-value head.  ``window``: ``window - 1`` keys to the left of the
+    diagonal and none to its right."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as splash, splash_attention_mask as masks)
 
-    mask = masks.MultiHeadMask([masks.CausalMask((t, t))] * heads)
+    one = (masks.CausalMask((t, t)) if window is None
+           else masks.LocalMask((t, t), window_size=(window - 1, 0), offset=0))
+    mask = masks.MultiHeadMask([one] * heads)
     make = splash.make_splash_mqa if multi_query else splash.make_splash_mha
     with jax.ensure_compile_time_eval():  # the tables are constants of whatever trace asks first
         return make(
@@ -101,16 +128,16 @@ def _splash_kernel(heads: int, t: int, interpret: bool = False, multi_query: boo
             head_shards=1, q_seq_shards=1, interpret=interpret)
 
 
-def _splash_causal_attention(q, k, v, scale: float, interpret: bool = False):
+def _splash_causal_attention(q, k, v, scale: float, interpret: bool = False, window=None):
     b, heads, t, size = q.shape
     kv_heads = k.shape[1]
     if scale != 1.0:
         q = (q * scale).astype(q.dtype)
     if kv_heads == heads:
-        return jax.vmap(_splash_kernel(heads, t, interpret))(q, k, v)
+        return jax.vmap(_splash_kernel(heads, t, interpret, window=window))(q, k, v)
     # each key-value head with its group of query heads: one k, one v a group
     group = heads // kv_heads
-    kernel = _splash_kernel(group, t, interpret, multi_query=True)
+    kernel = _splash_kernel(group, t, interpret, multi_query=True, window=window)
     out = jax.vmap(jax.vmap(kernel))(q.reshape(b, kv_heads, group, t, size), k, v)
     return out.reshape(b, heads, t, size)
 
@@ -129,43 +156,55 @@ def _rows_by_group(x, kv_heads: int, start: int, end: int):
     return x[:, :, start:end].reshape(b, kv_heads, heads // kv_heads * (end - start), d)
 
 
-def _block_scores(q_blk, k_seen, start: int, block_q: int, scale: float):
+def _first_key(start: int, window) -> int:
+    """The first key any query of the block that begins at ``start`` sees."""
+    return 0 if window is None else max(0, start - window + 1)
+
+
+def _block_scores(q_blk, k_seen, start: int, block_q: int, scale: float, first: int = 0,
+                  window=None):
     """Float32 scores of a group's query rows at positions ``start .. start +
-    block_q`` against keys ``0 .. end``, masked above the diagonal."""
+    block_q`` against keys ``first .. end``, masked above the diagonal and,
+    with ``window``, behind the window."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q_blk, k_seen,
                    preferred_element_type=jnp.float32) * scale
     rows = start + jnp.tile(jnp.arange(block_q), q_blk.shape[2] // block_q)[:, None]
-    return jnp.where(jnp.arange(k_seen.shape[2])[None, :] <= rows, s, _NEG)
+    keys = jnp.arange(k_seen.shape[2])[None, :]
+    if window is None:
+        return jnp.where(keys <= rows, s, _NEG)
+    keys = keys + first
+    return jnp.where((keys <= rows) & (rows - keys < window), s, _NEG)
 
 
-def _forward(q, k, v, scale, block_q):
+def _forward(q, k, v, scale, block_q, window=None):
     b, heads, t, d = q.shape
     kv_heads = k.shape[1]
     outs, lses = [], []
     for start, end in _blocks(t, block_q):
-        s = _block_scores(_rows_by_group(q, kv_heads, start, end), k[:, :, :end],
-                          start, block_q, scale)
+        first = _first_key(start, window)
+        s = _block_scores(_rows_by_group(q, kv_heads, start, end), k[:, :, first:end],
+                          start, block_q, scale, first, window)
         m = jnp.max(s, axis=-1, keepdims=True)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=-1, keepdims=True)
-        o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v[:, :, :end],
+        o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v[:, :, first:end],
                        preferred_element_type=jnp.float32) / l
         outs.append(o.astype(q.dtype).reshape(b, heads, block_q, d))
         lses.append((m + jnp.log(l))[..., 0].reshape(b, heads, block_q))
     return jnp.concatenate(outs, axis=2), jnp.concatenate(lses, axis=2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def blocked_causal_attention(q, k, v, scale: float, block_q: int):
-    return _forward(q, k, v, scale, block_q)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def blocked_causal_attention(q, k, v, scale: float, block_q: int, window=None):
+    return _forward(q, k, v, scale, block_q, window)[0]
 
 
-def _fwd(q, k, v, scale, block_q):
-    out, lse = _forward(q, k, v, scale, block_q)
+def _fwd(q, k, v, scale, block_q, window):
+    out, lse = _forward(q, k, v, scale, block_q, window)
     return out, (q, k, v, out, lse)
 
 
-def _bwd(scale, block_q, res, d_out):
+def _bwd(scale, block_q, window, res, d_out):
     q, k, v, out, lse = res
     f32 = jnp.float32
     b, heads, t, d = q.shape
@@ -176,17 +215,18 @@ def _bwd(scale, block_q, res, d_out):
     dk, dv = jnp.zeros(k.shape, f32), jnp.zeros(v.shape, f32)
     for start, end in _blocks(t, block_q):
         q_blk, do_blk = (_rows_by_group(x, kv_heads, start, end) for x in (q, d_out))
-        s = _block_scores(q_blk, k[:, :, :end], start, block_q, scale)
+        first = _first_key(start, window)
+        s = _block_scores(q_blk, k[:, :, first:end], start, block_q, scale, first, window)
         p = jnp.exp(s - _rows_by_group(lse[..., None], kv_heads, start, end))
         # the sums over the rows are sums over a group's query heads too
-        dv = dv.at[:, :, :end].add(jnp.einsum(
+        dv = dv.at[:, :, first:end].add(jnp.einsum(
             "bhqk,bhqd->bhkd", p.astype(v.dtype), do_blk, preferred_element_type=f32))
-        dp = jnp.einsum("bhqd,bhkd->bhqk", do_blk, v[:, :, :end], preferred_element_type=f32)
+        dp = jnp.einsum("bhqd,bhkd->bhqk", do_blk, v[:, :, first:end], preferred_element_type=f32)
         ds = (p * (dp - _rows_by_group(delta, kv_heads, start, end)) * scale).astype(q.dtype)
-        dq.append(jnp.einsum("bhqk,bhkd->bhqd", ds, k[:, :, :end],
+        dq.append(jnp.einsum("bhqk,bhkd->bhqd", ds, k[:, :, first:end],
                              preferred_element_type=f32).astype(q.dtype).reshape(
                                  b, heads, block_q, d))
-        dk = dk.at[:, :, :end].add(jnp.einsum(
+        dk = dk.at[:, :, first:end].add(jnp.einsum(
             "bhqk,bhqd->bhkd", ds, q_blk, preferred_element_type=f32))
     return jnp.concatenate(dq, axis=2), dk.astype(k.dtype), dv.astype(v.dtype)
 

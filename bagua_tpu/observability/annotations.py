@@ -122,7 +122,10 @@ def model_scope(part: str):
     ``moe_experts``, ``moe_combine``, ``moe_shared``, ``dense_mlp``,
     ``head``; in ``models/lfm2_moe.py`` also ``conv_proj``, the two products
     of a gated short convolution, and ``conv_core``, the gates and taps
-    between them).  Any name is a part: the summary keeps what it finds.
+    between them; in ``models/smallthinker_moe.py`` ``attn_window_core``, the
+    core of a layer whose mask has a window, beside ``attn_core`` for the
+    layers whose mask has none).  Any name is a part: the summary keeps what
+    it finds.
     Autodiff carries the frame into the backward pass's ops, so
     the device trace gives each part's forward and backward time together
     (``model_part_ms`` of ``trace_analysis.summarize_capture``)."""

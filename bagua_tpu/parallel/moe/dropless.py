@@ -1,5 +1,8 @@
-"""Dropless expert layer: a sigmoid top-k router over all the experts of the
-model, and the part of the result that the experts *held here* give.
+"""Dropless expert layer: a top-k router over all the experts of the model
+(sigmoid scores with a selection bias, :func:`sigmoid_topk_route`, or a
+softmax over the chosen logits, :func:`softmax_topk_route`), and the part of
+the result that the experts *held here* give, each a gated unit whose gate is
+the caller's (SiLU by default, ReLU in ``models/smallthinker_moe.py``).
 
 The GShard layer beside it (:mod:`bagua_tpu.parallel.moe.layer`) sends every
 token through a dense ``(tokens, experts, capacity)`` mask and drops what
@@ -45,8 +48,11 @@ The grouped product is ``megablox.gmm`` (Pallas, ships with JAX) on a TPU
 and ``jax.lax.ragged_dot`` elsewhere; rows past the held groups are left
 unwritten by the one and zero by the other, so every use masks them.  The
 kernel's tile is a function of the product's shape (:func:`gmm_tiling`): the
-layer has two callers whose experts differ in width and in the rows a group
-gets (``models/glm_moe.py`` 1536, ``models/lfm2_moe.py`` 1792).
+layer has three callers whose experts differ in width, in the model's hidden
+size, in the choices a token makes and in the rows a group gets
+(``models/glm_moe.py`` 1536 of 2048, 4 choices; ``models/lfm2_moe.py`` 1792 of
+2048, 4; ``models/smallthinker_moe.py`` 768 of 2560, 6: a buffer of 49,152
+rows).
 """
 
 import functools
@@ -58,16 +64,27 @@ import jax.numpy as jnp
 from bagua_tpu.observability.annotations import model_scope
 
 #: (rows, contraction, columns) tiles of the grouped product measured on the
-#: v5e by the step's time, by the experts' width; 8 groups in 32,768 rows; a
-#: contraction of 0 is the whole of it in one tile.  1536 (``glm-4.7-flash``:
-#: 512 expected rows a group; PERF.md, PR 29).  1792 (``lfm2-8b-a1b``: 1,024
-#: expected rows a group; 768 does not divide 1792 = 2 x 7 x 128; PERF.md
-#: section 6, PR 33, with the multi-query attention kernels: 128 rows against
-#: the whole contraction 151.93 ms, ``(256, 1024, 896)`` 156.73, ``(512,
-#: 1024, 896)`` 157.92, ``(128, 1024, 896)`` 159.87, ``(256, 512, 896)``
-#: 159.86; 256 rows against the whole contraction, 1,024 rows, or 1,792
-#: columns a tile are refused for the 16 MB of fast memory)
-GMM_TILES = {1536: (512, 1024, 768), 1792: (128, 0, 896)}
+#: v5e by the step's time, by the experts' width, or by the product's own
+#: ``(contraction, columns)`` where the two orientations want two tiles; 8
+#: groups; a contraction of 0 is the whole of it in one tile.  1536
+#: (``glm-4.7-flash``: 512 expected rows a group of 32,768; PERF.md, PR 29).
+#: 1792 (``lfm2-8b-a1b``: 1,024 expected rows a group; 768 does not divide
+#: 1792 = 2 x 7 x 128; PERF.md section 6, PR 33, with the multi-query
+#: attention kernels: 128 rows against the whole contraction 151.93 ms,
+#: ``(256, 1024, 896)`` 156.73, ``(512, 1024, 896)`` 157.92, ``(128, 1024,
+#: 896)`` 159.87, ``(256, 512, 896)`` 159.86; 256 rows against the whole
+#: contraction, 1,024 rows, or 1,792 columns a tile are refused for the 16 MB
+#: of fast memory).  768 of 2,560 (``smallthinker-21ba3b``: 768 expected rows
+#: a group of 49,152; PERF.md section 6, PR 36, fourteen tiles by a plain SGD
+#: step's time: these two 165.52 ms; the whole contraction against 384
+#: columns, and 640 or 384 the other way, 165.88 to 167.13; ``(512, 1280,
+#: 768)`` with 1,280 or 768 columns the other way 166.10 and 166.78; a
+#: contraction of 512, which the default below gives, 167.78 to 170.35; 768
+#: columns against the whole contraction are refused for fast memory).  Every
+#: tile of it divides its dimension: a contraction tile that hangs over is
+#: masked in float32 at every step of the kernel's grid.
+GMM_TILES = {1536: (512, 1024, 768), 1792: (128, 0, 896),
+             (2560, 768): (256, 1280, 768), (768, 2560): (256, 0, 1280)}
 
 
 def gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
@@ -75,14 +92,27 @@ def gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
     chip; ``megablox`` asks it of the forward product and of the two products
     of its backward pass, each with its own ``k`` and ``n``.  Of an expert's
     two dimensions the narrower is its width and the other the model's hidden
-    size: a measured width takes its tile from :data:`GMM_TILES` whichever of
-    ``k`` and ``n`` it is; any other takes half the width across, or one tile
-    of 128 lanes where that is no multiple of them."""
+    size: a measured shape or width takes its tile from :data:`GMM_TILES`
+    (a width whichever of ``k`` and ``n`` it is); any other takes half the
+    width across, or one tile of 128 lanes where that is no multiple of them,
+    against the most of the contraction, up to 1,024, that divides it in
+    whole tiles of lanes (the whole of it where nothing does)."""
     width = min(k, n)
+    measured = GMM_TILES.get((k, n)) or GMM_TILES.get(width)
+    if measured:
+        rows, contraction, columns = measured
+        return rows, contraction or k, columns
     half = width // 2
-    rows, contraction, columns = GMM_TILES.get(
-        width, (512, 1024, half if half % 128 == 0 else 128))
-    return rows, contraction or k, columns
+    contraction = next((c for c in (1024, 512, 256, 128) if k % c == 0), k)
+    return 512, contraction, half if half % 128 == 0 else 128
+
+
+def _of_chosen(values, chosen):
+    """``values[t, chosen[t, j]]`` by a select over the experts: a gather of
+    tokens x k scalars takes 0.33 ms a layer on the chip, this 0.01 (PERF.md,
+    PR 34)."""
+    picked = chosen[..., None] == jnp.arange(values.shape[-1])
+    return jnp.sum(jnp.where(picked, values[:, None, :], 0), axis=-1)
 
 
 def sigmoid_topk_route(h, router_kernel, correction_bias, k: int, scaling: float,
@@ -98,13 +128,24 @@ def sigmoid_topk_route(h, router_kernel, correction_bias, k: int, scaling: float
         h.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(correction_bias), k)
-    # the chosen scores by a select over the experts: a gather of tokens x k
-    # scalars takes 0.33 ms a layer on the chip, this 0.01 (PERF.md, PR 34)
-    picked = chosen[..., None] == jnp.arange(scores.shape[-1])
-    weights = jnp.sum(jnp.where(picked, scores[:, None, :], 0), axis=-1)
+    weights = _of_chosen(scores, chosen)
     if normalize:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     return chosen.astype(jnp.int32), weights * scaling
+
+
+def softmax_topk_route(h, router_kernel, k: int) -> Tuple[jax.Array, jax.Array]:
+    """``(chosen experts (tokens, k) int32, their weights (tokens, k) f32)``.
+
+    In float32 at the highest matmul precision, whatever ``h`` came in: ``l
+    = h W_r``; the ``k`` experts of largest ``l`` (among equals the lower
+    index, as ``lax.top_k`` has it); ``w = softmax(l[chosen])`` over those
+    ``k`` alone, so the weights sum to one with nothing more to divide by.
+    No selection bias and no scaling factor."""
+    logits = jnp.dot(h.astype(jnp.float32), router_kernel.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    _, chosen = jax.lax.top_k(logits, k)
+    return chosen.astype(jnp.int32), jax.nn.softmax(_of_chosen(logits, chosen), axis=-1)
 
 
 # -- the two passes ------------------------------------------------------------
@@ -238,13 +279,15 @@ def grouped_matmul(rows, kernels, group_sizes):
 
 
 def dropless_experts(x, chosen, weights, gate, up, down, held: Tuple[int, int],
-                     num_experts: int):
-    """The held experts' part of ``sum_j weights[:, j] * E_chosen[:, j](x)``.
+                     num_experts: int, activation=jax.nn.silu):
+    """The held experts' part of ``sum_j weights[:, j] * E_chosen[:, j](x)``,
+    ``E(u) = down(activation(gate u) * up u)``.
 
-    ``x`` (tokens, hidden); ``chosen``/``weights`` from
-    :func:`sigmoid_topk_route`; ``gate``, ``up`` (held, hidden, width) and
-    ``down`` (held, width, hidden) the SwiGLU kernels of experts
-    ``held[0] .. held[0] + held[1] - 1`` of ``num_experts``."""
+    ``x`` (tokens, hidden); ``chosen``/``weights`` from one of the routers
+    above; ``gate``, ``up`` (held, hidden, width) and ``down`` (held, width,
+    hidden) the kernels of experts ``held[0] .. held[0] + held[1] - 1`` of
+    ``num_experts``; ``activation`` the gate's, SiLU (SwiGLU) unless the
+    caller's model has another."""
     tokens, k = chosen.shape
     first, count = held
     with model_scope("moe_dispatch"):
@@ -263,7 +306,7 @@ def dropless_experts(x, chosen, weights, gate, up, down, held: Tuple[int, int],
         def product(lhs, kernels):
             return jnp.where(live, grouped_matmul(lhs, kernels.astype(x.dtype), sizes), 0)
 
-        hidden = jax.nn.silu(product(for_gate, gate)) * product(for_up, up)
+        hidden = activation(product(for_gate, gate)) * product(for_up, up)
         out = product(hidden, down)
     with model_scope("moe_combine"):
         return collect(out, weights, order, k)

@@ -1,0 +1,10 @@
+"""Milliseconds per captured step that device 0 spent in the held experts'
+grouped products of ``smallthinker-21ba3b``, their masks and the ReLU gate
+between them, forward and backward (``bagua_model/part=moe_experts``), from
+the program's summary of the capture."""
+
+from benchmark.model_parts import part_ms
+
+
+def read(context):
+    return part_ms(context, "moe_experts")

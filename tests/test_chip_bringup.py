@@ -352,6 +352,20 @@ text = jax.jit(both_passes(lambda q, k, v: causal_attention(q, k, v, 1.0))).lowe
     q, q, kv, kv).compile().as_text()
 print("GQA-KERNELS", text.count("tpu_custom_call"), flush=True)
 grouped_kernels("WIDTH-1792-KERNELS", 1792)
+# smallthinker-21ba3b.dp1-s8192: 28 query heads on 4 key-value heads x 8,192 x 128 under a
+# window of 4,096 keys and under the causal mask; experts of width 768 of a hidden size of
+# 2,560 (which 1,024 does not divide) in a buffer of six rows a token
+q, kv = shape((1, 28, 8192, 128)), shape((1, 4, 8192, 128))
+for label, window in (("WINDOW-KERNELS", 4096), ("GROUP-OF-7-KERNELS", None)):
+    text = jax.jit(both_passes(lambda q, k, v: causal_attention(q, k, v, 1.0, window=window))).lower(
+        q, q, kv, kv).compile().as_text()
+    print(label, text.count("tpu_custom_call"), flush=True)
+for width_in, width_out in ((2560, 768), (768, 2560)):
+    text = jax.jit(lambda out_grad, rows, kernels, group_sizes: both_passes(
+        lambda r, k: grouped_matmul(r, k, group_sizes))(out_grad, rows, kernels)).lower(
+        shape((49152, width_out)), shape((49152, width_in)), shape((8, width_in, width_out)),
+        sizes).compile().as_text()
+    print("WIDTH-768-KERNELS", text.count("tpu_custom_call"), flush=True)
 # the cell's whole step under the engine's compiler options: inside it the fused
 # backward kernel needs 0.3 to 0.4 MB more fast memory than compiled alone (PR 30)
 from bagua_tpu.ddp import STEP_COMPILER_OPTIONS
@@ -509,6 +523,13 @@ def test_the_expert_models_kernels_compile_for_the_chip_at_the_cells_shapes():
             if line.startswith(("GQA-KERNELS", "WIDTH-1792-KERNELS"))]
     assert lfm2 == [
         ["GQA-KERNELS", "2"], ["WIDTH-1792-KERNELS", "3"], ["WIDTH-1792-KERNELS", "3"]], lfm2
+    # smallthinker-21ba3b.dp1-s8192 (PR 36): the same two kernels seven query heads a key-value
+    # head under the window's mask and under the causal one, and the grouped products at the
+    # tiles measured for 768 of 2,560
+    st = [line.split() for line in proc.stdout.splitlines()
+          if line.startswith(("WINDOW-KERNELS", "GROUP-OF-7-KERNELS", "WIDTH-768-KERNELS"))]
+    assert st == [["WINDOW-KERNELS", "2"], ["GROUP-OF-7-KERNELS", "2"],
+                  ["WIDTH-768-KERNELS", "3"], ["WIDTH-768-KERNELS", "3"]], st
     # five layers' forward and fused backward kernels in the step the cell runs
     step = next(line.split() for line in proc.stdout.splitlines()
                 if line.startswith("STEP-ATTENTION-KERNELS"))

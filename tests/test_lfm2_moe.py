@@ -475,7 +475,8 @@ def test_the_grouped_products_tile_is_chosen_by_shape(width, tile):
     for k, n in ((hidden, width), (width, hidden)):  # a contraction of 0: all of it in one tile
         assert gmm_tiling(rows, k, n) == (tile[0], tile[1] or k, tile[2])
     assert width % tile[2] == 0 and tile[2] % 128 == 0
-    assert gmm_tiling(64, 48, 24) == (512, 1024, 128)  # a toy width: one tile of lanes
+    # a toy width: one tile of lanes against the whole contraction (1,024 does not divide it)
+    assert gmm_tiling(64, 48, 24) == (512, 48, 128)
 
 
 # -- the scopes ---------------------------------------------------------------
