@@ -3,10 +3,27 @@
 A loss that takes ``log_softmax(logits)`` and then gathers the label's
 entry writes a second vocabulary-sized array to read one value a row of it:
 on BERT-Large's head (4096 x 30522 f32) that was 500 MB and 1.51 ms a step
-(PR 28, ``PERF.md`` section 6).  Here the only vocabulary-sized array the
-forward pass holds is the logits; autodiff's gradient of this form is
-``softmax - onehot`` scaled by the cotangent, which the compiler builds
-inside the operands of the two gradient matmuls from the logits alone.
+(PR 28, ``PERF.md`` section 6).  Here the only vocabulary-sized array either
+pass holds is the logits.  The gradient is ``softmax - onehot`` scaled by the
+cotangent, and how the label's logit is picked decides what the compiler
+makes of the ``onehot`` half (the compiled steps, ``PERF.md`` section 6,
+PR 37):
+
+* picked by ``take_along_axis``, a gather whose gradient is a scatter.  At
+  ``[32, 128, V]`` (BERT) the compiler turns that scatter into a select
+  inside the operands of the two gradient matmuls.  At ``[1, 8192, V]`` (the
+  three expert cells) it keeps it a scatter: ``softmax * g`` is written as
+  tokens x vocabulary float32 (537 to 634 MB), re-tiled flat, scattered
+  into, and converted to bf16 before ``dX`` and ``dW`` read it, 5.1 to 5.9 ms
+  a step with no name (``copy.187`` and ``fusion.439`` of
+  ``lfm2-8b-a1b.dp1-s8192``, 1.61 and 1.60 ms; ledger, PR 36);
+* picked by a one-hot select and a row sum, as here.  The gradient is
+  ``where(hit, g, 0)``, element-wise, and at both shapes ``dX`` and ``dW``
+  take the float32 logits, the row's log-sum-exp and the compare as operands
+  of their own fusions: no ``dlogits`` array, no scatter.  The products
+  take on 0.2 to 0.9 ms of it, and the expert cells' steps are 4.4 to 7.0 ms
+  shorter (of 130, 172 and 223).  At BERT's shape the row sum joins the bias
+  gradient's pass over the logits (+0.1%).
 """
 
 import jax
@@ -15,8 +32,11 @@ import jax.numpy as jnp
 
 def softmax_cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
     """Per-example ``-log_softmax(logits)[..., label]`` over the last axis, in
-    the logits' dtype: the row's log-sum-exp (max-shifted) minus the picked
-    logit.  ``labels`` has the logits' shape without the last axis; callers
-    take their own mean."""
-    picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    the logits' dtype: the row's log-sum-exp (max-shifted) minus the label's
+    logit, which is a sum of that logit and zeros.  ``labels`` has the logits'
+    shape without the last axis and lies in ``[0, V)``: a label outside picks
+    nothing and the row reads its log-sum-exp, where a gather would clamp.
+    Callers take their own mean."""
+    hit = labels[..., None] == jnp.arange(logits.shape[-1])
+    picked = jnp.sum(jnp.where(hit, logits, 0), axis=-1)
     return jax.nn.logsumexp(logits, axis=-1) - picked

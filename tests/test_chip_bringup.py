@@ -618,14 +618,55 @@ for name, shape, op_name in entry_of(
     if layer:
         kernel = re.match(r"(bf16|f32)\\[(1024|3072|4096),(1024|3072|4096)\\]", shape)
         print("BACKWARD", "W" if kernel else "X", layer.group(1), flush=True)
+
+# the end of the three expert models at their cells' shapes, forward and backward: what has
+# tokens x vocabulary elements, and whether anything scatters
+import math
+from bagua_tpu.models.llama import RMSNorm
+from bagua_tpu.models.losses import softmax_cross_entropy
+TOKENS = 8192
+
+
+def on_chip(dims, dtype):
+    return jax.ShapeDtypeStruct(dims, dtype, sharding=device)
+
+
+def head_loss(product):  # final norm, head, the mean over positions - 1 targets
+    def loss_fn(x, scale, matrix, ids):
+        h = RMSNorm(1e-6).apply({"params": {"scale": scale}}, x)
+        logits = jnp.einsum(product, h, matrix.astype(x.dtype), preferred_element_type=jnp.float32)
+        return jnp.mean(softmax_cross_entropy(logits, jnp.roll(ids, -1, axis=1))[:, :-1])
+    return loss_fn
+
+
+for label, hidden, vocab, product in (
+        ("lfm2-8b-a1b", 2048, 16384, "btm,vm->btv"),  # the embedding's transpose
+        ("smallthinker-21ba3b", 2560, 18992, "btm,mv->btv"),
+        ("glm-4.7-flash", 2048, 19360, "btm,mv->btv")):
+    matrix = (vocab, hidden) if product == "btm,vm->btv" else (hidden, vocab)
+    text = jax.jit(jax.value_and_grad(head_loss(product), argnums=(0, 1, 2))).lower(
+        on_chip((1, TOKENS, hidden), jnp.bfloat16), on_chip((hidden,), jnp.float32),
+        on_chip(matrix, jnp.float32), on_chip((1, TOKENS), jnp.int32)).compile().as_text()
+    print("HEAD-SCATTERS", label, len(re.findall(r" scatter\\(", text)), flush=True)
+    for line in text[text.index("\\nENTRY "):].splitlines():
+        m = re.match(r"\\s*(?:ROOT )?%(\\S+) = (.*?) ([a-z][\\w-]*)\\(", line)
+        if not m or m.group(3) in ("get-tuple-element", "bitcast", "tuple"):
+            continue
+        result = re.sub(r"\\{[^}]*\\}", "", m.group(2)).replace(" ", "")
+        if any(math.prod(map(int, dims.split(","))) >= TOKENS * vocab
+               for dims in re.findall(r"\\[([\\d,]+)\\]", result)):
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            print("HEAD-VOCAB-SIZED", label, m.group(1), result, m.group(3),
+                  op_name.group(1) if op_name else "-", flush=True)
 """
 
 
 @pytest.fixture(scope="module")
 def head_census():
-    """Two programs compiled for a described v5e in one process of their own
-    (one loader of libtpu at a time): BERT's head at full width, and a plain
-    SGD step under the options the engine compiles its step with."""
+    """Programs compiled for a described v5e in one process of their own (one
+    loader of libtpu at a time): BERT's head at full width, a plain SGD step
+    under the options the engine compiles its step with, and the final norm,
+    head and loss of the three expert cells at their shapes."""
     proc = subprocess.run(
         [sys.executable, "-c", _HEAD_CENSUS],
         env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO_ROOT),
@@ -647,6 +688,25 @@ def test_bert_head_writes_one_vocabulary_sized_array(head_census):
     writers = [words[1:] for words in head_census if words[0] == "VOCAB-WRITER"]
     assert len(writers) == 1, writers
     assert writers[0][1].endswith("mlm_decoder/dot_general"), writers
+
+
+@pytest.mark.parametrize("model", ["lfm2-8b-a1b", "smallthinker-21ba3b", "glm-4.7-flash"])
+def test_expert_head_holds_the_forward_logits_and_no_other_array_of_their_size(
+        head_census, model):
+    """Guards the 4.4 to 7.0 ms a step (of 130, 172 and 223; my chip runs, PR
+    37) that the head's gradient took on its way to the two products while the
+    loss picked the label's logit with a gather: at one sequence of 8,192
+    rows the compiler kept the gather's gradient a scatter, so ``softmax *
+    g`` was written as 8,192 x vocabulary float32 (537 to 634 MB), re-tiled
+    flat, scattered into and converted to bf16 before ``dX`` and ``dW`` read
+    it.  With the label picked by a one-hot select (``models/losses.py``, PR
+    37) the only instruction of that size is the product that makes the
+    logits, and ``dX`` and ``dW`` form their operand from them."""
+    scatters = [words for words in head_census if words[:2] == ["HEAD-SCATTERS", model]]
+    assert scatters == [["HEAD-SCATTERS", model, "0"]], scatters
+    sized = [words[2:] for words in head_census if words[:2] == ["HEAD-VOCAB-SIZED", model]]
+    assert len(sized) == 1, sized
+    assert sized[0][2] == "fusion" and sized[0][3].endswith("dot_general"), sized
 
 
 def test_step_options_keep_weight_gradients_inside_the_backward_pass(head_census):

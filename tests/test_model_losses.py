@@ -1,7 +1,7 @@
 """``models.losses.softmax_cross_entropy``: the cross-entropy written from the
-logits (log-sum-exp minus the picked logit) against the form it replaced in
-the five model files, ``log_softmax`` then ``take_along_axis``, which is kept
-here as the reference."""
+logits (log-sum-exp minus the logit a one-hot select picks) against the form
+it replaced in the five model files, ``log_softmax`` then ``take_along_axis``,
+which is kept here as the reference."""
 
 import dataclasses
 
@@ -42,7 +42,7 @@ def assert_close(got, want, tol):
 
 
 @DTYPES
-@pytest.mark.parametrize("shape", [(4, 10), (2, 7, 33), (8, 16, 30522)], ids=str)
+@pytest.mark.parametrize("shape", [(4, 10), (2, 7, 33), (8, 16, 30522), (1, 64, 1031)], ids=str)
 def test_value_and_gradient_equal_the_log_softmax_form(shape, dtype):
     logits, labels = draw(shape, dtype)
     weights = jnp.asarray(np.random.RandomState(1).rand(*shape[:-1]).astype(np.float32))
@@ -94,6 +94,60 @@ def test_labels_at_both_ends_of_the_vocabulary(label, dtype):
     # the picked entry is that column and no neighbour's
     want = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1) - logits[:, label].astype(jnp.float32)
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), **VALUE_TOL[dtype])
+
+
+def one_sequence(dtype, positions=48, vocab=131):
+    """A ``[1, T, V]`` batch as the expert models' losses see it: the labels
+    are the ids rolled by one, both ends of the vocabulary are among them, and
+    one label stands three times running."""
+    logits, ids = draw((1, positions, vocab), dtype, seed=5)
+    ids = ids.at[0, 3].set(0).at[0, 9].set(vocab - 1).at[0, 20:23].set(77)
+    return logits, jnp.roll(ids, -1, axis=1)
+
+
+@DTYPES
+def test_next_token_mean_over_one_sequence_equals_the_log_softmax_form(dtype):
+    """The mean the expert models take: every row against the token after it,
+    the last row (whose label is the roll's wrap-around) left out."""
+    logits, labels = one_sequence(dtype)
+    assert {0, logits.shape[-1] - 1} <= set(np.asarray(labels[0, :-1]).tolist())
+    assert np.asarray(labels)[0, 19:22].tolist() == [77, 77, 77]
+
+    def mean_of(fn):
+        return lambda lg: jnp.mean(fn(lg, labels)[:, :-1].astype(jnp.float32))
+
+    got, got_grad = jax.value_and_grad(mean_of(softmax_cross_entropy))(logits)
+    want, want_grad = jax.value_and_grad(mean_of(reference_cross_entropy))(logits)
+    # a mean of 47 values, each within VALUE_TOL
+    assert_close(got, want, VALUE_TOL[dtype])
+    assert_close(got_grad, want_grad, GRAD_TOL[dtype])
+    assert np.all(np.asarray(got_grad, np.float32)[0, -1] == 0.0)  # the row left out
+
+
+@pytest.mark.parametrize("reduce", ["sum", "next_token_mean"])
+def test_gradient_is_softmax_minus_onehot_in_f32(reduce):
+    """The select's gradient is ``where(hit, g, 0)``: it takes ``g`` from the
+    label's entry of the log-sum-exp's own gradient, ``g * softmax``, and
+    touches no other, bit for bit."""
+    logits, labels = one_sequence(jnp.float32)
+    positions, vocab = logits.shape[1:]
+    kept = np.ones((1, positions, 1), np.float32)
+    g = np.float32(1.0)
+    if reduce == "next_token_mean":
+        kept[:, -1] = 0.0
+        g = np.float32(1.0 / (positions - 1))
+
+    def total(fn):
+        return lambda lg: jnp.sum(fn(lg) * kept[..., 0]) * g
+
+    grad = np.asarray(jax.grad(total(lambda lg: softmax_cross_entropy(lg, labels)))(logits))
+    lse_grad = np.asarray(jax.grad(total(lambda lg: jax.nn.logsumexp(lg, axis=-1)))(logits))
+    onehot = kept * np.asarray(jax.nn.one_hot(labels, vocab, dtype=jnp.float32))
+    np.testing.assert_array_equal(grad, lse_grad - g * onehot)
+    softmax = np.asarray(jax.nn.softmax(logits, axis=-1))
+    np.testing.assert_allclose(grad, g * (kept * softmax - onehot), rtol=1e-5, atol=1e-9)
+    # each kept row's gradient sums to zero: one whole softmax less one
+    np.testing.assert_allclose(grad.sum(axis=-1), 0.0, atol=1e-6)
 
 
 # -- the five model loss functions give what they gave --------------------------
