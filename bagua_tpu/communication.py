@@ -45,6 +45,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from bagua_tpu.defs import ReduceOp
 from bagua_tpu.mesh import MeshSpec
+from bagua_tpu.observability.cold_start import cold_event
 
 INTER_AXIS = "inter"
 INTRA_AXIS = "intra"
@@ -163,6 +164,7 @@ class BaguaProcessGroup:
         )
 
 
+@cold_event("setup", "group")
 def init_process_group(
     devices: Optional[Sequence] = None,
     intra_size: Optional[int] = None,

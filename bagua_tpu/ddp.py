@@ -24,6 +24,7 @@ Re-bucketing (autotune proposing a new bucket assignment) swaps the
 """
 
 import dataclasses
+import functools
 import logging
 import time
 from typing import Any, Callable, NamedTuple, Optional
@@ -44,6 +45,7 @@ from bagua_tpu.communication import (
 )
 from bagua_tpu.env import get_default_bucket_size, get_static_verify_mode
 from bagua_tpu.observability.annotations import step_scope, timed_host_span
+from bagua_tpu.observability.cold_start import cold_host_span, step_compile_seconds
 from bagua_tpu.observability.core import StepTimer
 from bagua_tpu.observability.metrics import (
     switch_reason_family,
@@ -243,6 +245,11 @@ class DistributedDataParallel:
                               "post": 0.0, "build": 0.0, "telemetry": 0.0,
                               "health": 0.0, "next_batch": 0.0, "loop": 0.0,
                               "steps": 0}
+        #: the ``perf_counter`` instant of the last
+        #: ``host_overhead_snapshot(reset=True)``: since when the counters
+        #: above count, and where the set-up that the process's cold record
+        #: holds (``cold_start.setup_snapshot(until=...)``) ends
+        self._overhead_since: Optional[float] = None
         #: set by a ``Trainer`` that will capture a profile: keep each step
         #: variant's compiled text, the join table from a captured operation
         #: to its scope labels (``trace_analysis.summarize_capture``)
@@ -1375,13 +1382,19 @@ class DistributedDataParallel:
             tel.on_step_start(self._host_step, variant=variant)
         rec = self._variants.get(variant)
         missed = rec is None
+        host = dispatch_host = self._host
         if missed:
             # A jit-cache miss IS the compile event the recompile detector
             # counts — report it before building so a hang inside tracing
             # still shows the miss in the telemetry snapshot.
             if tel is not None:
                 tel.on_compile(variant, self._host_step)
-            with self._host("build"):
+            # ... and a cold event: the build and this step's dispatch go on
+            # the process's record under the variant's name, with whatever
+            # JAX traces, lowers and compiles while each is open
+            dispatch_host = cold = functools.partial(
+                cold_host_span, "step", totals=self.host_overhead, detail=variant)
+            with cold("build") as build:
                 fn = self._build_step(variant)
                 # Pre-dispatch gate: prove the new program gang-consistent
                 # BEFORE the first dispatch compiles/runs it (no-op when
@@ -1394,13 +1407,15 @@ class DistributedDataParallel:
                     fn, self._maybe_static_verify(variant, state, batch)
                 )
                 if self.keep_step_text:
-                    # here and nowhere later: a capture must hold no compile
-                    rec.text = fn.lower(state, batch).compile().as_text()
+                    # here and nowhere later: a capture must hold no compile.
+                    # A span of its own: an untraced run has no such lowering
+                    with cold_host_span("step", "text", detail=variant):
+                        rec.text = fn.lower(state, batch).compile().as_text()
             self._variants[variant] = rec
         self.last_variant = variant
         self._host_step += 1
         t0 = time.perf_counter()
-        with self._host("pre") as pre:
+        with host("pre") as pre:
             if self._pending_reshard is not None:
                 state = self._apply_pending_reshard(state)
             state = self.impl.host_pre_dispatch(state)
@@ -1413,14 +1428,14 @@ class DistributedDataParallel:
         # enqueue (see async_model_average.py module docstring).
         lock = self.impl.host_dispatch_lock
         if lock is not None:
-            with self._host("lock_wait") as lock_wait:
+            with host("lock_wait") as lock_wait:
                 lock.acquire()
             step_ov["lock_wait"] = lock_wait.elapsed
         try:
-            with self._host("dispatch") as dispatch:
+            with dispatch_host("dispatch") as dispatch:
                 out = self._flight_dispatch(rec, state, batch, variant, flight, missed)
             new_state, losses = out[0], out[1]
-            with self._host("post") as post:
+            with host("post") as post:
                 self.impl.host_post_dispatch(new_state, self._host_step)
         finally:
             if lock is not None:
@@ -1430,18 +1445,19 @@ class DistributedDataParallel:
         wall = time.perf_counter() - t0
         self.step_timer.tick(wall)
         if missed and tel is not None:
-            # jit compiles synchronously inside the first dispatch, so on a
-            # cache-miss step the dispatch duration IS the compile wall —
+            # what the variant cost to make: the tracing, lowering and
+            # backend compile (or cache load) that JAX reported while its
+            # build and this dispatch were open, not the dispatch's wall —
             # the compile_ms histogram + the goodput ledger's compile bucket
             tel.on_compile_done(
                 variant, self._host_step - 1,
-                wall_ms=step_ov.get("dispatch", 0.0) * 1e3,
+                wall_ms=step_compile_seconds(variant, since=build.began) * 1e3,
             )
         if tel is not None:
-            with self._host("telemetry"):
+            with host("telemetry"):
                 self._telemetry_on_step(tel, batch, variant, wall, step_ov)
         if self.health_monitor is not None and len(out) == 3:
-            with self._host("health"):
+            with host("health"):
                 loss_mean, gn_max, nonfinite = self._read_health(out[2])
                 self.health_monitor.observe(
                     step=self._host_step - 1, loss=loss_mean, grad_norm=gn_max,
@@ -1629,7 +1645,11 @@ class DistributedDataParallel:
         return jax.jit(fn)(state)
 
     def host_overhead_snapshot(self, reset: bool = False) -> dict:
-        """Per-step host-side milliseconds by phase (see ``host_overhead``)."""
+        """Per-step host-side milliseconds by phase (see ``host_overhead``),
+        and ``since``: the ``perf_counter`` instant of the last ``reset=True``
+        (None before any), from which they count.  The reset leaves the
+        process's cold record whole: what it holds before ``since`` is the
+        set-up of the stretch this snapshot describes."""
         ov = dict(self.host_overhead)
         n = max(1, ov.pop("steps"))
         out = {f"{k}_ms_per_step": round(v * 1e3 / n, 3) for k, v in ov.items()}
@@ -1637,9 +1657,11 @@ class DistributedDataParallel:
         out["step_wall_ms"] = {
             k: round(v * 1e3, 3) for k, v in self.step_timer.percentiles().items()
         }
+        out["since"] = self._overhead_since
         if reset:
             for k in self.host_overhead:
                 self.host_overhead[k] = 0.0 if k != "steps" else 0
+            self._overhead_since = time.perf_counter()
         return out
 
     def shutdown(self):

@@ -141,11 +141,13 @@ def host_span(name: str):
 
 class timed_host_span:
     """``host_span(f"{where}/{key}")`` that also adds the ``perf_counter``
-    time it was open to ``totals[key]`` and leaves it in ``elapsed``: the
-    span in the capture and the counter of the same name are one
-    measurement and cannot disagree."""
+    time it was open to ``totals[key]`` and leaves it in ``elapsed`` (and the
+    reading it opened at in ``began``): the span in the capture and the
+    counter of the same name are one measurement and cannot disagree.
+    :class:`~bagua_tpu.observability.cold_start.cold_host_span` is the same
+    span around what happens once or rarely."""
 
-    __slots__ = ("_span", "_totals", "_key", "_t0", "elapsed")
+    __slots__ = ("_span", "_totals", "_key", "began", "elapsed")
 
     def __init__(self, where: str, key: str, totals: dict):
         self._span = host_span(f"{where}/{key}")
@@ -154,11 +156,11 @@ class timed_host_span:
 
     def __enter__(self):
         self._span.__enter__()
-        self._t0 = time.perf_counter()
+        self.began = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self.elapsed = time.perf_counter() - self._t0
+        self.elapsed = time.perf_counter() - self.began
         self._totals[self._key] += self.elapsed
         self._span.__exit__(*exc)
         return False

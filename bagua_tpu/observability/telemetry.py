@@ -336,8 +336,10 @@ class Telemetry:
 
     def on_compile_done(self, variant: str, step: int, wall_ms: float) -> None:
         """The compile announced by :meth:`on_compile` finished; ``wall_ms``
-        is its measured wall time (the engine reads it off the first
-        dispatch, which jit compiles synchronously).  Feeds the
+        is what it took: the tracing, lowering and backend compile (or cache
+        load) that JAX reported while the variant's build and first dispatch
+        were open (``cold_start.step_compile_seconds``), not that dispatch's
+        wall.  Feeds the
         ``compile_ms`` histogram, the detector's per-variant wall ledger,
         and the goodput ledger's compile bucket."""
         self.recompile.record_compile_wall(variant, wall_ms)

@@ -13,11 +13,16 @@ from typing import Callable, Iterable, Optional, Tuple
 
 import jax
 
-from bagua_tpu.algorithms.base import Algorithm
-from bagua_tpu.ddp import DistributedDataParallel
-from bagua_tpu.observability import StepTimer, Watchdog
-from bagua_tpu.observability.annotations import fit_step_span, host_span, timed_host_span
-from bagua_tpu.service.autotune_session import AutotuneSession
+from bagua_tpu.observability import cold_start
+
+# the module's body is a cold event, counted from here: the package's own
+# import has ended by now and is a span of its own
+with cold_start.cold_host_span("setup", "import", detail=__name__):
+    from bagua_tpu.algorithms.base import Algorithm
+    from bagua_tpu.ddp import DistributedDataParallel
+    from bagua_tpu.observability import StepTimer, Watchdog
+    from bagua_tpu.observability.annotations import fit_step_span, host_span, timed_host_span
+    from bagua_tpu.service.autotune_session import AutotuneSession
 
 logger = logging.getLogger(__name__)
 
@@ -81,6 +86,7 @@ class Trainer:
             the loop's) — every move statically verified before dispatch.
     """
 
+    @cold_start.cold_event("setup", "trainer")
     def __init__(
         self,
         loss_fn: Callable,
@@ -153,6 +159,7 @@ class Trainer:
         self._profiler = None
         self._profiled = False  # one capture per Trainer, across fit() calls
         self._summary_due = False  # a capture has stopped and is not reduced yet
+        self._startup = None  # the set-up's partition, kept when the first ``fit`` call ends
         #: ``trace_analysis.summarize_capture`` of the capture, made at the
         #: end of the ``fit`` call that held it (None before, and without
         #: ``profile_dir``)
@@ -205,6 +212,7 @@ class Trainer:
                 logger.warning("not on the main thread: preemption watcher "
                                "responds to trigger() only, not SIGTERM")
 
+    @cold_start.cold_event("setup", "init_state")
     def init_state(self, params=None, stacked_params=None):
         state = self.ddp.init(params, stacked_params=stacked_params)
         resumed = False
@@ -333,7 +341,19 @@ class Trainer:
             self._stop_capture(state, "cut at epoch end")
         if self._summary_due:
             self._summarize_capture()
+        if self._startup is None:
+            self._startup = cold_start.setup_snapshot()
+            logger.info("%s", cold_start.format_setup(self._startup))
         return state
+
+    def startup_report(self) -> dict:
+        """Where the time before the first steps went, in seconds by class
+        (:func:`~bagua_tpu.observability.cold_start.setup_snapshot`: import,
+        init, the step's tracing and its compile or cache load, every other
+        program compiled, the cache's hits and misses), from the process's
+        record of cold events: up to the end of the first ``fit`` call, which
+        logs it once, or up to now before that."""
+        return self._startup or cold_start.setup_snapshot()
 
     def _fit_step(self, state, batch, log_every: int):
         """One iteration of :meth:`fit` after its batch: ``(state, seconds
