@@ -4,6 +4,10 @@ sigmoid top-k router over all routed experts, a shared expert, and a
 multi-token-prediction module.  Built from the keys of the published
 ``config.json`` (:meth:`GlmMoeConfig.from_hf`).
 
+The residual stream starts as the tokens' rows of the embedding table in
+``compute_dtype`` (:func:`~bagua_tpu.models.embedding.embed`, the one lookup
+of the three expert models, which also says what an id outside the vocabulary
+does; the prediction module's lookup of the next token is the same function).
 Per layer, on the residual stream ``x`` (RMSNorm with a learned scale, no bias
 anywhere):
 
@@ -62,6 +66,7 @@ import jax
 import jax.numpy as jnp
 
 from bagua_tpu.kernels.causal_attention import causal_attention
+from bagua_tpu.models.embedding import embed
 from bagua_tpu.models.llama import RMSNorm
 from bagua_tpu.models.losses import softmax_cross_entropy
 from bagua_tpu.observability.annotations import model_scope
@@ -327,7 +332,7 @@ class GlmMoeModel(_Kernels):
                 h = RMSNorm(cfg.rms_norm_eps, name=norm_name)(h)
                 return jnp.dot(h.astype(dt), head.astype(dt), preferred_element_type=jnp.float32)
 
-        x = embedding[ids].astype(dt)
+        x = embed(embedding, ids, dt)
         for n in range(cfg.num_hidden_layers):
             x = GlmMoeBlock(cfg, dense=n < cfg.first_k_dense_replace, name=f"layer_{n}")(x)
         logits = logits_of(x, "final_norm")
@@ -337,7 +342,7 @@ class GlmMoeModel(_Kernels):
             raise NotImplementedError("multi-token prediction of depth 1 only")
         # the token after each position; the last position has none and is
         # no target's input (causal attention keeps it to itself)
-        after = embedding[jnp.roll(ids, -1, axis=1)].astype(dt)
+        after = embed(embedding, jnp.roll(ids, -1, axis=1), dt)
         joined = jnp.concatenate([
             RMSNorm(cfg.rms_norm_eps, name="mtp_embed_norm")(after),
             RMSNorm(cfg.rms_norm_eps, name="mtp_hidden_norm")(x)], axis=-1)

@@ -5,8 +5,11 @@ leading layers and a sigmoid top-k router over all routed experts with no
 shared expert in the rest.  Built from the keys of the published
 ``config.json`` (:meth:`Lfm2MoeConfig.from_hf`).
 
-Per layer, on the residual stream ``x`` (RMSNorm with a learned scale, no bias
-anywhere): ``x += Mixer(RMSNorm(x))``, ``x += FFN(RMSNorm(x))``.
+The residual stream starts as the tokens' rows of the embedding table in
+``compute_dtype`` (:func:`~bagua_tpu.models.embedding.embed`, the one lookup
+of the three expert models, which also says what an id outside the vocabulary
+does).  Per layer, on the residual stream ``x`` (RMSNorm with a learned scale,
+no bias anywhere): ``x += Mixer(RMSNorm(x))``, ``x += FFN(RMSNorm(x))``.
 
 * ``layer_types[n] == "conv"``: ``[B | C | u] = h W_in``; ``z = B * u``;
   ``c_t = sum_j taps[j] * z_{t-j}`` per channel over ``conv_L_cache`` taps
@@ -28,7 +31,8 @@ anywhere): ``x += Mixer(RMSNorm(x))``, ``x += FFN(RMSNorm(x))``.
   :func:`~bagua_tpu.parallel.moe.dropless.dropless_experts`), each a SwiGLU of
   ``moe_intermediate_size``.
 * head: ``RMSNorm(x) Emb^T``: the output matrix is the embedding, one leaf
-  with two gradients.
+  with two gradients (the lookup's, a float32 array of the table's shape
+  whatever form it was built in, and the product's).
 
 Parameters are stored in float32; matrix products take ``compute_dtype``
 operands and accumulate in float32; norms, the rotation, the gates and taps,
@@ -57,6 +61,7 @@ import jax
 import jax.numpy as jnp
 
 from bagua_tpu.kernels.causal_attention import causal_attention
+from bagua_tpu.models.embedding import embed
 from bagua_tpu.models.glm_moe import HEADS_MAJOR, SwiGLU, _Kernels, _matmul, _product
 from bagua_tpu.models.llama import RMSNorm
 from bagua_tpu.models.losses import softmax_cross_entropy
@@ -330,7 +335,7 @@ class Lfm2MoeModel(_Kernels):
     def __call__(self, ids):
         cfg, dt = self.cfg, self.cfg.compute_dtype
         embedding = self.kernel("embedding", cfg.vocab_size, cfg.hidden_size)
-        x = embedding[ids].astype(dt)
+        x = embed(embedding, ids, dt)
         for n, mixer in enumerate(cfg.layer_types):
             x = Lfm2MoeBlock(cfg, mixer, dense=n < cfg.num_dense_layers, name=f"layer_{n}")(x)
         with model_scope("head"):

@@ -3,8 +3,11 @@ arXiv:2507.20984): a causal decoder whose attention layers are of two kinds in
 one stack, and whose router is fed before attention.  Built from the keys of
 the published ``config.json`` (:meth:`SmallThinkerConfig.from_hf`).
 
-Per layer ``n``, on the residual stream ``x`` (RMSNorm with a learned scale,
-no bias anywhere, no norm on heads):
+The residual stream starts as the tokens' rows of the embedding table in
+``compute_dtype`` (:func:`~bagua_tpu.models.embedding.embed`, the one lookup
+of the three expert models, which also says what an id outside the vocabulary
+does).  Per layer ``n``, on the residual stream ``x`` (RMSNorm with a learned
+scale, no bias anywhere, no norm on heads):
 
 * ``h = RMSNorm(x)``.
 * the router, **before attention**, from ``h``:
@@ -49,6 +52,7 @@ import jax
 import jax.numpy as jnp
 
 from bagua_tpu.kernels.causal_attention import causal_attention
+from bagua_tpu.models.embedding import embed
 from bagua_tpu.models.glm_moe import HEADS_MAJOR, _Kernels, _product
 from bagua_tpu.models.lfm2_moe import lfm2_moe_loss_fn, rotate_half
 from bagua_tpu.models.llama import RMSNorm
@@ -212,7 +216,7 @@ class SmallThinkerModel(_Kernels):
     @nn.compact
     def __call__(self, ids):
         cfg, dt = self.cfg, self.cfg.compute_dtype
-        x = self.kernel("embedding", cfg.vocab_size, cfg.hidden_size)[ids].astype(dt)
+        x = embed(self.kernel("embedding", cfg.vocab_size, cfg.hidden_size), ids, dt)
         for n, (windowed, rotary) in enumerate(zip(cfg.sliding_window_layout, cfg.rope_layout)):
             x = SmallThinkerBlock(cfg, bool(windowed), bool(rotary), name=f"layer_{n}")(x)
         with model_scope("head"):

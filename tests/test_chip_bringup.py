@@ -631,6 +631,18 @@ def on_chip(dims, dtype):
     return jax.ShapeDtypeStruct(dims, dtype, sharding=device)
 
 
+def entry_results(text):  # (line, name, result without layouts, opcode) of what the entry computes
+    for line in text[text.index("\\nENTRY "):].splitlines():
+        m = re.match(r"\\s*(?:ROOT )?%(\\S+) = (.*?) ([a-z][\\w-]*)\\(", line)
+        if m and m.group(3) not in ("get-tuple-element", "bitcast", "tuple", "parameter"):
+            yield line, m.group(1), re.sub(r"\\{[^}]*\\}", "", m.group(2)).replace(" ", ""), m.group(3)
+
+
+def holds(result, elements, dtype=""):  # an array of so many elements or more among the results
+    return any(math.prod(map(int, dims.split(","))) >= elements
+               for dims in re.findall(dtype + r"\\[([\\d,]+)\\]", result))
+
+
 def head_loss(product):  # final norm, head, the mean over positions - 1 targets
     def loss_fn(x, scale, matrix, ids):
         h = RMSNorm(1e-6).apply({"params": {"scale": scale}}, x)
@@ -648,16 +660,44 @@ for label, hidden, vocab, product in (
         on_chip((1, TOKENS, hidden), jnp.bfloat16), on_chip((hidden,), jnp.float32),
         on_chip(matrix, jnp.float32), on_chip((1, TOKENS), jnp.int32)).compile().as_text()
     print("HEAD-SCATTERS", label, len(re.findall(r" scatter\\(", text)), flush=True)
-    for line in text[text.index("\\nENTRY "):].splitlines():
-        m = re.match(r"\\s*(?:ROOT )?%(\\S+) = (.*?) ([a-z][\\w-]*)\\(", line)
-        if not m or m.group(3) in ("get-tuple-element", "bitcast", "tuple"):
-            continue
-        result = re.sub(r"\\{[^}]*\\}", "", m.group(2)).replace(" ", "")
-        if any(math.prod(map(int, dims.split(","))) >= TOKENS * vocab
-               for dims in re.findall(r"\\[([\\d,]+)\\]", result)):
+    for line, name, result, opcode in entry_results(text):
+        if holds(result, TOKENS * vocab):
             op_name = re.search(r'op_name="([^"]*)"', line)
-            print("HEAD-VOCAB-SIZED", label, m.group(1), result, m.group(3),
+            print("HEAD-VOCAB-SIZED", label, name, result, opcode,
                   op_name.group(1) if op_name else "-", flush=True)
+
+# the embedding's lookup at the three cells' shapes, backward and SGD update: what scatters,
+# what the kernels are, and what has vocabulary x hidden float32 elements.  The gradient's
+# form is chosen by backend, and here the backend is the CPU: steer it, in the test
+jax.default_backend = lambda: "tpu"
+from bagua_tpu.models.embedding import embed
+
+
+def lookup_update(tied):
+    def update(table, ids, out_grad, head_grad):
+        _, vjp = jax.vjp(lambda t: embed(t, ids, jnp.bfloat16), table)
+        grad = vjp(out_grad)[0]
+        return table - 0.01 * (grad + head_grad if tied else grad)
+    return update
+
+
+for label, hidden, vocab, product in (
+        ("lfm2-8b-a1b", 2048, 16384, "tied"),  # the table is the output matrix too
+        ("smallthinker-21ba3b", 2560, 18992, ""),
+        ("glm-4.7-flash", 2048, 19360, "")):
+    text = jax.jit(lookup_update(bool(product)), donate_argnums=(0,),
+                   compiler_options=STEP_COMPILER_OPTIONS["tpu"]).lower(
+        on_chip((vocab, hidden), jnp.float32), on_chip((1, TOKENS), jnp.int32),
+        on_chip((1, TOKENS, hidden), jnp.bfloat16),
+        on_chip((vocab, hidden), jnp.float32)).compile().as_text()
+    for dims in re.findall(r" = \\w+\\[([\\d,]*)\\]\\S* scatter\\(", text):
+        print("EMBED-SCATTER", label, math.prod(map(int, dims.split(","))) if dims else 1, flush=True)
+    for line, name, result, opcode in entry_results(text):
+        if "tpu_custom_call" in line:
+            print("EMBED-KERNEL", label, name, result, flush=True)
+        elif holds(result, vocab * hidden, "f32"):
+            print("EMBED-TABLE-SIZED", label, name, result, opcode, flush=True)
+    print("EMBED-DONE", label, vocab * hidden, flush=True)
 """
 
 
@@ -707,6 +747,32 @@ def test_expert_head_holds_the_forward_logits_and_no_other_array_of_their_size(
     sized = [words[2:] for words in head_census if words[:2] == ["HEAD-VOCAB-SIZED", model]]
     assert len(sized) == 1, sized
     assert sized[0][2] == "fusion" and sized[0][3].endswith("dot_general"), sized
+
+
+@pytest.mark.parametrize("model", ["lfm2-8b-a1b", "smallthinker-21ba3b", "glm-4.7-flash"])
+def test_embeddings_gradient_is_one_grouped_product_and_no_row_scatter(head_census, model):
+    """Guards the 8.41 ms a step (of 164.6, ``smallthinker-21ba3b.dp1-s8192``;
+    ledger, PR 39) that ``fusion.237 f32[18992,2560]`` took, and the 1.5 ms
+    of the other two cells: the row scatter-add autodiff makes of
+    ``embedding[ids]``, one serial read-modify-write a row.  The lookup's
+    backward pass and SGD update at the three cells' shapes (LFM2's with the
+    head's gradient added, as its tied table has it): nothing scatters but
+    the kernel's group metadata, a few dozen numbers; one ``tgmm`` call;
+    and beside its result the only array of vocabulary x hidden float32
+    elements is the new table (``models/embedding.py``, PR 41).  The parent's
+    ``embedding[ids].astype(dt)`` gives a scatter of the table's size, its
+    cotangent beside it, and no call."""
+    words = [w[2:] for w in head_census if w[0].startswith("EMBED-") and w[1] == model]
+    kinds = [w[0] for w in head_census if w[0].startswith("EMBED-") and w[1] == model]
+    (table_elements,) = [int(w[0]) for w, kind in zip(words, kinds) if kind == "EMBED-DONE"]
+    scattered = [int(w[0]) for w, kind in zip(words, kinds) if kind == "EMBED-SCATTER"]
+    assert scattered and max(scattered) < 1024, scattered
+    kernels = [w for w, kind in zip(words, kinds) if kind == "EMBED-KERNEL"]
+    assert len(kernels) == 1 and kernels[0][0].startswith("tgmm"), kernels
+    blocks, block, hidden = map(int, re.findall(r"\d+", kernels[0][1].split("f32", 1)[1]))
+    assert table_elements <= blocks * block * hidden < table_elements + block * hidden, kernels
+    sized = [w for w, kind in zip(words, kinds) if kind == "EMBED-TABLE-SIZED"]
+    assert len(sized) == 1 and sized[0][2] == "fusion", sized  # the update, which is the root
 
 
 def test_step_options_keep_weight_gradients_inside_the_backward_pass(head_census):
