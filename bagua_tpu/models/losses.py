@@ -24,6 +24,17 @@ PR 37):
   take on 0.2 to 0.9 ms of it, and the expert cells' steps are 4.4 to 7.0 ms
   shorter (of 130, 172 and 223).  At BERT's shape the row sum joins the bias
   gradient's pass over the logits (+0.1%).
+
+Since PR 42 one caller takes it several times a step and weights its result a
+position: ``models/ouro.py`` calls it once an exit, four times on float32
+logits of ``[1, 8192, 49152]`` (1.61 GB each, 2.5 times the columns of any
+shape above), each call under ``jax.checkpoint`` so that one array of logits
+is alive at a time, and multiplies the per-position results by an exit
+distribution the model computes: the cotangent ``g`` is then a different
+number in every row, not the mean's one constant.  At that shape the compiled
+step still holds no ``dlogits`` array and no scatter: the rebuilt logits, the
+row statistics, the compare and ``g`` are operands of the two gradient
+products' fusions (``PERF.md`` section 6, PR 42).
 """
 
 import jax

@@ -15,6 +15,7 @@ host-side analog as OTel spans in ``bagua-opentelemetry``):
     bagua_ex/axis=tp/phase=rs_ring                             (model-parallel)
     bagua_step/phase=optimizer                                 (step phases)
     bagua_model/part=attn_core                                 (parts of a model)
+    bagua_model/pass=2                                         (passes of a looped stack)
     bagua_host/step/dispatch                                   (host spans)
 
 The second form labels *model-parallel* exchanges — the tensor-parallel
@@ -54,10 +55,12 @@ from bagua_tpu.observability.scope_grammar import (
     format_host_span,
     format_model_label,
     format_mp_label,
+    format_pass_label,
     format_step_label,
     parse_exchange_label,
     parse_host_span,
     parse_model_part,
+    parse_model_pass,
     parse_mp_label,
     parse_step_phase,
 )
@@ -76,12 +79,14 @@ __all__ = [
     "step_scope",
     "mp_scope",
     "model_scope",
+    "pass_scope",
     "host_span",
     "timed_host_span",
     "fit_step_span",
     "parse_exchange_label",
     "parse_host_span",
     "parse_model_part",
+    "parse_model_pass",
     "parse_mp_label",
     "parse_step_phase",
 ]
@@ -120,16 +125,28 @@ def model_scope(part: str):
     """Named scope labeling one part of a model's forward pass
     (``attn_proj``, ``attn_core``, ``moe_route``, ``moe_dispatch``,
     ``moe_experts``, ``moe_combine``, ``moe_shared``, ``dense_mlp``,
-    ``head``; in ``models/lfm2_moe.py`` also ``conv_proj``, the two products
+    ``head``, ``embed``; in ``models/lfm2_moe.py`` also ``conv_proj``, the two products
     of a gated short convolution, and ``conv_core``, the gates and taps
     between them; in ``models/smallthinker_moe.py`` ``attn_window_core``, the
     core of a layer whose mask has a window, beside ``attn_core`` for the
-    layers whose mask has none).  Any name is a part: the summary keeps what
-    it finds.
-    Autodiff carries the frame into the backward pass's ops, so
-    the device trace gives each part's forward and backward time together
+    layers whose mask has none; in ``models/ouro.py`` ``exit_gate``, the gate's
+    product after every pass, the exit distribution, the weighted sum of the
+    exits' losses and the entropy).  Any name is a part: the summary keeps
+    what it finds.
+    Autodiff carries the frame into the backward pass's ops, and
+    ``jax.checkpoint`` into those it runs again there, so the device trace
+    gives each part's forward, backward and recomputed time together
     (``model_part_ms`` of ``trace_analysis.summarize_capture``)."""
     return jax.named_scope(format_model_label(part))
+
+
+def pass_scope(index: int):
+    """Named scope ``bagua_model/pass=<index>`` around one run of a stack
+    whose layers run several times with the same weights, and around that
+    run's exit (``models/ouro.py``; passes count from 1).  The parts' scopes
+    nest inside it, so an operation has a part and a pass, and the summary
+    gives ``model_pass_ms`` beside ``model_part_ms``."""
+    return jax.named_scope(format_pass_label(index))
 
 
 def host_span(name: str):

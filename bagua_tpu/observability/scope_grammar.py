@@ -19,6 +19,7 @@ The three label forms::
     bagua_ex/axis=tp/phase=rs_ring                            (model-parallel)
     bagua_step/phase=optimizer                                (engine step phases)
     bagua_model/part=attn_core                                (parts of a model)
+    bagua_model/pass=2                                        (passes of a looped stack)
 
 and, on the host's side of the same capture, the spans the fit loop and the
 engine open around their own work (``jax.profiler.TraceAnnotation``, see
@@ -57,11 +58,13 @@ __all__ = [
     "OVERLAP_BWD_RE",
     "STALE_RE",
     "MODEL_RE",
+    "PASS_RE",
     "format_exchange_label",
     "format_mp_label",
     "format_step_label",
     "format_stale_scope",
     "format_model_label",
+    "format_pass_label",
     "format_host_span",
     "parse_exchange_label",
     "parse_mp_label",
@@ -70,6 +73,7 @@ __all__ = [
     "parse_overlap_bwd",
     "parse_stale_scope",
     "parse_model_part",
+    "parse_model_pass",
     "parse_host_span",
     "hlo_op_labels",
 ]
@@ -100,7 +104,10 @@ OVERLAP_BWD_RE = re.compile(r"bagua_overlap_bwd/bucket=(?P<bucket>\d+)")
 #: the bounded-staleness sanction frame (τ = the staleness bound the
 #: algorithm was compiled at)
 STALE_RE = re.compile(STALE_PREFIX + r"/tau=(?P<tau>\d+)")
-MODEL_RE = re.compile(MODEL_PREFIX + r"/part=(?P<part>[^/\"]+)")
+# (a scope open where autodiff begins is written inside its frame: ``jvp(bagua_model/part=x)``)
+MODEL_RE = re.compile(MODEL_PREFIX + r"/part=(?P<part>[^/\")]+)")
+#: which run of a stack whose layers run several times with shared weights
+PASS_RE = re.compile(MODEL_PREFIX + r"/pass=(?P<pass>\d+)")
 
 
 # -- formatters (the single way a label string is ever built) -----------------
@@ -131,6 +138,10 @@ def format_stale_scope(tau) -> str:
 
 def format_model_label(part: str) -> str:
     return f"{MODEL_PREFIX}/part={part}"
+
+
+def format_pass_label(index) -> str:
+    return f"{MODEL_PREFIX}/pass={int(index)}"
 
 
 def format_host_span(name: str) -> str:
@@ -201,6 +212,13 @@ def parse_model_part(op_name: str) -> Optional[str]:
     innermost ``bagua_model`` frame of its ``op_name``."""
     parts = MODEL_RE.findall(op_name or "")
     return parts[-1] if parts else None
+
+
+def parse_model_pass(op_name: str) -> Optional[int]:
+    """The pass of a looped stack an op was traced under, if labeled: the
+    innermost ``bagua_model/pass=`` frame of its ``op_name``."""
+    passes = PASS_RE.findall(op_name or "")
+    return int(passes[-1]) if passes else None
 
 
 def parse_host_span(event_name: str) -> Optional[str]:

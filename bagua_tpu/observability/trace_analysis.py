@@ -21,7 +21,9 @@ Attribution: the capture's events carry the instruction's name but not its
 a v5e capture, jax 0.9.0: the statistics of an ``XLA Ops`` event are its
 device offset and duration).  The join runs through the compiled module's
 text (``compiled.as_text()``): instruction name → ``op_name`` →
-``bagua_step`` / ``bagua_ex`` / ``bagua_overlap_bwd`` frames.  An event that
+``bagua_step`` / ``bagua_ex`` / ``bagua_overlap_bwd`` frames, a model's own
+``bagua_model/part=`` (``attn_core`` … ``exit_gate``) and ``bagua_model/pass=``
+frames, and ``jax.checkpoint``'s ``rematted_computation``.  An event that
 does carry an ``op_name`` statistic is read there.  Without the text every
 operation is ``unattributed`` but collectives are still told, by opcode.
 
@@ -35,7 +37,9 @@ Two reductions share the loader and the interval arithmetic:
 
 :func:`summarize_capture`
     one device's captured steps: the partition of its busy time by step
-    phase, the exchange operation by operation (where each collective sits
+    phase (``recompute``, what ``jax.checkpoint`` runs again inside the
+    backward pass, is a class of its own), a model's time by part and, where
+    its stack runs several times, by pass, the exchange operation by operation (where each collective sits
     relative to the backward pass), host spans, each idle gap put down to the
     host span open when it began, and for every step how long its request
     waited in the device's queue.
@@ -52,12 +56,15 @@ from typing import Dict, Iterable, List, Optional, Tuple
 from bagua_tpu.observability.scope_grammar import (
     EXCHANGE_RE,
     FIT_STEP,
+    MODEL_PREFIX,
     MP_RE,
     OVERLAP_BWD_RE,
+    PASS_RE,
     hlo_op_labels,
     parse_exchange_label,
     parse_host_span,
     parse_model_part,
+    parse_model_pass,
     parse_mp_label,
     parse_step_phase,
 )
@@ -100,6 +107,16 @@ _BITS = re.compile(r"[0-9]+")
 #: the step phases the partition renames: what runs under ``fwd_bwd`` is
 #: split by autodiff's ``transpose(`` frame, the two update paths are one
 _OPTIMIZER_PHASES = ("optimizer", "sharded_update")
+#: the frame ``jax.checkpoint`` writes around the operations of the forward
+#: pass that it runs again inside the backward pass
+_RECOMPUTE_FRAME = "rematted_computation"
+#: the classes of the partition that are the model's own work
+_MODEL_CLASSES = ("forward", "backward", "recompute")
+#: a layer's scope right under a pass's, where a part's scope is nested inside
+#: it (``bagua_model/pass=2/layer_1/attn/bagua_model/part=attn_core``)
+_LAYER_UNDER_PASS = re.compile(
+    PASS_RE.pattern + r"/(?!" + MODEL_PREFIX + r"/)(?P<layer>[^/]+)/(?:[^\"]*/)?"
+    + MODEL_PREFIX + "/part=")
 
 
 # -- the capture --------------------------------------------------------------
@@ -361,13 +378,17 @@ def _is_collective(event: Dict) -> bool:
 def phase_of(op_name: Optional[str]) -> str:
     """The partition's class of a non-collective operation from its
     ``op_name`` metadata: ``forward`` / ``backward`` (``fwd_bwd`` without /
-    with autodiff's ``transpose(`` frame), ``optimizer`` (``optimizer`` and
-    ``sharded_update``), any other ``bagua_step`` phase by its own name,
-    ``unattributed`` without one."""
+    with autodiff's ``transpose(`` frame), ``recompute`` (``fwd_bwd`` inside
+    ``jax.checkpoint``'s ``rematted_computation`` frame: operations of the
+    forward pass run again inside the backward pass), ``optimizer``
+    (``optimizer`` and ``sharded_update``), any other ``bagua_step`` phase by
+    its own name, ``unattributed`` without one."""
     phase = parse_step_phase(op_name)
     if phase is None:
         return "unattributed"
     if phase == "fwd_bwd":
+        if _RECOMPUTE_FRAME in op_name:
+            return "recompute"
         return "backward" if "transpose(" in op_name else "forward"
     return "optimizer" if phase in _OPTIMIZER_PHASES else phase
 
@@ -584,18 +605,32 @@ def summarize_capture(log_dir: str, hlo_text: Optional[str] = None, device: int 
         the device's busy time inside the step module, every operation of
         the operations' line in exactly one class: ``exchange`` (a
         collective, told by opcode), else :func:`phase_of` its label
-        (``forward``, ``backward``, ``optimizer``, ``restack``, ``algo_start``
-        … ``unattributed``).  Sums to ``step_busy_ms``; with
+        (``forward``, ``backward``, ``recompute``, ``optimizer``, ``restack``,
+        ``algo_start`` … ``unattributed``; a class nothing ran in is left
+        out, so a model that rebuilds nothing in its backward pass has no
+        ``recompute``).  Sums to ``step_busy_ms``; with
         ``other_modules_ms`` (by module: a feed's batch maker) to
         ``busy_ms``; with ``idle_ms`` to ``window_ms``.  ``unattributed_top``
         names the ten operations with most unattributed time (those the
         compiler made and gave no metadata: layout copies, prefetches).
     ``model_part_ms``
         only for a model that names its parts (``bagua_model/part=...``):
-        the ``forward`` and ``backward`` time by part, both passes together
-        (autodiff carries the frame into the backward pass), and ``other``
-        for what runs under neither name (norms, residual adds, the
-        embedding).  Sums to ``forward`` + ``backward`` of ``partition_ms``.
+        the ``forward``, ``backward`` and ``recompute`` time by part, all
+        together (autodiff carries the frame into the backward pass, and
+        ``jax.checkpoint`` into what it runs again there), and ``other`` for
+        what runs under no part's name (norms, residual adds).  Sums to
+        ``forward`` + ``backward`` + ``recompute`` of ``partition_ms``.
+    ``model_pass_ms``, ``layer_applications_per_step``
+        only for a model whose stack runs several times and names its passes
+        (``bagua_model/pass=<t>``, ``models/ouro.py``): the same three
+        classes' time by pass, keyed ``"1"``, ``"2"`` …, each operation under
+        its own label (the passes are written out one after the other, so
+        nothing is split by order); what runs under no pass (the lookup, the
+        loss that joins the exits) is the rest of the three classes' sum.
+        And how often a layer ran in one forward pass: the distinct ``(pass,
+        layer)`` pairs among the step's ``forward`` operations, a layer being
+        the scope right under a pass's with a part's scope nested inside it
+        (a flax module's name).
     ``exchange``
         ``calls``, ``bytes``, ``collective_ms`` (union of the collectives'
         spans on both lines), ``exposed_ms`` (the part no other operation
@@ -647,6 +682,8 @@ def summarize_capture(log_dir: str, hlo_text: Optional[str] = None, device: int 
     other_modules: Dict[str, float] = {}
     unattributed: Dict[str, float] = {}
     model_parts: Dict[str, float] = {}
+    model_passes: Dict[str, float] = {}
+    layers_run = set()
     for e, owned in zip(ops, _owned(spans)):
         e["class"] = "exchange" if _is_collective(e) else phase_of(e.get("op_name"))
         if e["hlo_module"] != module:
@@ -656,9 +693,15 @@ def summarize_capture(log_dir: str, hlo_text: Optional[str] = None, device: int 
         partition[e["class"]] = partition.get(e["class"], 0.0) + owned
         if e["class"] == "unattributed":
             unattributed[e["hlo_op"]] = unattributed.get(e["hlo_op"], 0.0) + owned
-        elif e["class"] in ("forward", "backward"):
+        elif e["class"] in _MODEL_CLASSES:
             part = parse_model_part(e.get("op_name")) or "other"
             model_parts[part] = model_parts.get(part, 0.0) + owned
+            run = parse_model_pass(e.get("op_name"))
+            if run is not None:
+                model_passes[str(run)] = model_passes.get(str(run), 0.0) + owned
+                layer = _LAYER_UNDER_PASS.search(e["op_name"]) if e["class"] == "forward" else None
+                if layer:
+                    layers_run.add((run, layer.group("layer")))
 
     def per_step_ms(us: float) -> float:
         return us / 1e3 / steps
@@ -696,6 +739,9 @@ def summarize_capture(log_dir: str, hlo_text: Optional[str] = None, device: int 
     }
     if set(model_parts) - {"other"}:
         summary["model_part_ms"] = per_step_dict(model_parts)
+    if model_passes:
+        summary["model_pass_ms"] = per_step_dict(model_passes)
+        summary["layer_applications_per_step"] = len(layers_run)
     _LAST_SUMMARY = summary
     return summary
 
