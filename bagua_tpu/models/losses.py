@@ -25,16 +25,20 @@ PR 37):
   shorter (of 130, 172 and 223).  At BERT's shape the row sum joins the bias
   gradient's pass over the logits (+0.1%).
 
-Since PR 42 one caller takes it several times a step and weights its result a
-position: ``models/ouro.py`` calls it once an exit, four times on float32
-logits of ``[1, 8192, 49152]`` (1.61 GB each, 2.5 times the columns of any
-shape above), each call under ``jax.checkpoint`` so that one array of logits
-is alive at a time, and multiplies the per-position results by an exit
-distribution the model computes: the cotangent ``g`` is then a different
-number in every row, not the mean's one constant.  At that shape the compiled
-step still holds no ``dlogits`` array and no scatter: the rebuilt logits, the
-row statistics, the compare and ``g`` are operands of the two gradient
-products' fusions (``PERF.md`` section 6, PR 42).
+One caller takes it several times a step and weights its result a position:
+``models/ouro.py`` calls it once an exit, four times on float32 logits of
+``[1, 8192, 49152]`` (1.61 GB each, 2.5 times the columns of any shape above),
+and multiplies the per-position results by an exit distribution the model
+computes, so the cotangent ``g`` is a different number in every row, not the
+mean's one constant.  Since PR 43 that caller differentiates it *inside the
+forward pass* (``ouro._exit_sum``: ``jax.vjp`` of the head's product and this
+function, pulled back along the rows' weights where the logits have just been
+written), so nothing of it runs in the backward pass and nothing is rebuilt
+(PR 42 rebuilt each call under ``jax.checkpoint``); an exit keeps the two
+products' results and the per-position values, 0.23 GB.  At that shape the
+compiled step holds no ``dlogits`` array and no scatter either way: the
+logits, the row statistics, the compare and ``g`` are operands of the two
+gradient products' fusions (``PERF.md`` section 6, PR 42 and PR 43).
 """
 
 import jax

@@ -2,17 +2,20 @@
 benchmark's plain reference on seeded weights at one, two and four passes; the
 loop tied to a plain stack (``L`` layers run ``R`` times equal ``R x L`` layers
 that hold copies, and a shared leaf's gradient is the sum of its copies'); the
-exit distribution against a four-line oracle; one pass as the plain cross
-entropy; what the model rebuilds in its backward pass against a version that
-keeps everything; the engine on four devices: one exchange a bucket of the
-summed gradient, with the exchange inside the backward pass and after it; and
-the scopes that name the model's parts and passes, with the summary's
+exit distribution against a four-line oracle, and taken pass by pass; one pass
+as the plain cross entropy; the exit that takes the head's gradient products in
+the forward pass against autodiff of the plain composition, and the model with
+it against a version that keeps everything and against the exit that rebuilds
+its logits; the engine on four devices: one exchange a bucket of the summed
+gradient, with the exchange inside the backward pass and after it; and the
+scopes that name the model's parts and passes, with the summary's
 ``recompute`` class and ``model_pass_ms``."""
 
 import os
 import re
 import sys
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,6 +35,8 @@ from bagua_tpu.models.ouro import (
     OuroModel,
     distribution_entropy,
     exit_distribution,
+    exit_share,
+    mean_weights,
     ouro_loss_fn,
     ouro_test_config,
 )
@@ -52,6 +57,10 @@ from test_lfm2_moe import rel_err  # noqa: E402
 from trim_capture import xspace_bytes  # noqa: E402
 
 PARTS = ("embed", "attn_proj", "attn_core", "dense_mlp", "head", "exit_gate")
+#: two programs of the same operations on the same operands, by compute dtype: rounding of another
+#: order at most (a normed state's cotangents from its gate, its head and the later passes are
+#: added in the compute dtype, in the order each program writes them)
+BOUNDS = {jnp.float32: 2e-6, jnp.bfloat16: 2e-2}
 
 
 @pytest.fixture(scope="module")
@@ -91,7 +100,8 @@ def test_loss_and_every_gradient_leaf_match_the_reference_in_float32(
         loss, grads = jax.value_and_grad(ouro_loss_fn(model))(
             adapter.to_program(ref_params, sz), ids)
         ref_loss, ref_grads = jax.value_and_grad(reference.loss)(ref_params, ids, sz)
-    assert float(loss) == pytest.approx(float(ref_loss), abs=2e-6)
+    # the exits add rows weighted 1 / N where the reference divides a sum by N: another order
+    assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
     want = adapter.to_program(ref_grads, sz, cast=False)
     assert jax.tree.structure(grads) == jax.tree.structure(want)
     for name, g in leaves_by_name(grads).items():
@@ -261,48 +271,186 @@ def test_one_pass_is_the_plain_cross_entropy():
     assert float(ouro_loss_fn(model)(params, ids)) == pytest.approx(float(plain), abs=1e-6)
 
 
-def test_given_targets_the_model_returns_each_exits_cross_entropy():
+def test_given_targets_the_model_returns_what_each_exit_adds_to_the_loss():
     cfg = ouro_test_config()
     model = OuroModel(cfg)
     ids = jax.random.randint(jax.random.PRNGKey(3), (2, 16), 0, cfg.vocab_size)
     targets = jnp.roll(ids, -1, axis=1)
     params = model.init(jax.random.PRNGKey(4), ids)["params"]
+    params["exit_gate_bias"] = jnp.float32(0.3)
     logits, gates = model.apply({"params": params}, ids)
-    entropies, gates_again = model.apply({"params": params}, ids, targets)
-    assert entropies.shape == gates.shape == (3, 2, 16)
+    sums, gates_again = model.apply({"params": params}, ids, targets)
+    assert sums.shape == (3,) and sums.dtype == jnp.float32 and gates.shape == (3, 2, 16)
     np.testing.assert_allclose(np.asarray(gates_again), np.asarray(gates), atol=1e-6)
+    weighted = exit_distribution(gates) * softmax_cross_entropy(logits, targets[None])
     np.testing.assert_allclose(
-        np.asarray(entropies), np.asarray(softmax_cross_entropy(logits, targets[None])), atol=1e-5)
+        np.asarray(sums), np.asarray(jnp.mean(weighted[:, :, :-1], axis=(1, 2))), rtol=2e-6)
+    # the mean's weights: one place, the last position of every sequence out
+    weights = np.asarray(mean_weights((2, 16)))
+    assert weights.shape == (2, 16) and not weights[:, -1].any()
+    np.testing.assert_allclose(weights[:, :-1], 1.0 / 30, rtol=1e-7)
+
+
+@pytest.mark.parametrize("passes", [1, 2, 4])
+def test_the_shares_taken_pass_by_pass_are_the_exit_distribution(passes):
+    gate_logits = 3.0 * jax.random.normal(jax.random.PRNGKey(passes), (passes, 2, 16), jnp.float32)
+    left, shares = jnp.ones((2, 16), jnp.float32), []
+    for t in range(passes):
+        share, left = exit_share(gate_logits[t], left, last=t == passes - 1)
+        shares.append(share)
+    np.testing.assert_allclose(
+        np.asarray(jnp.stack(shares)), np.asarray(exit_distribution(gate_logits)), atol=1e-6)
+    assert not np.asarray(left).any()  # the last pass leaves nothing
+    # the first pass's share moves with its own gate, unless it is the last and takes everything
+    moved = exit_share(gate_logits[0] + 1.0, jnp.ones((2, 16)), last=passes == 1)[0]
+    assert (passes == 1) == bool(np.all(np.asarray(moved) == np.asarray(shares[0])))
 
 
 # -- the memory plan ----------------------------------------------------------
 
 
+def plain_exit_sum(h, head, targets, weights):
+    """What :func:`ouro._exit_sum` computes, as plain operations that autodiff
+    differentiates as written: every exit's logits kept for the backward pass."""
+    return h, jnp.sum(weights * softmax_cross_entropy(ouro._logits(h, head, h.dtype), targets))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-def test_what_the_backward_pass_rebuilds_changes_neither_loss_nor_gradient(monkeypatch, dtype):
-    cfg = ouro_test_config(total_ut_steps=4, compute_dtype=dtype)
+def test_the_exits_three_gradients_are_autodiffs_of_the_plain_composition(dtype):
+    """The normed state's, the output matrix's and the row weights', under a
+    cotangent other than one (a scaled loss) with another on the state that
+    goes on, rows of weight zero and a label outside the vocabulary."""
+    rows, hidden, vocab = (2, 12), 32, 96
+    keys = jax.random.split(jax.random.PRNGKey(17), 5)
+    h = jax.random.normal(keys[0], rows + (hidden,), jnp.float32).astype(dtype)
+    head = 0.3 * jax.random.normal(keys[1], (hidden, vocab), jnp.float32)
+    targets = jax.random.randint(keys[2], rows, 0, vocab).at[0, 3].set(vocab + 5).at[1, 0].set(-1)
+    weights = jax.random.uniform(keys[3], rows, jnp.float32).at[:, -1].set(0.0).at[0, 5].set(0.0)
+    onward = jax.random.normal(keys[4], rows + (hidden,), jnp.float32)
+
+    def scaled(exit_fn):
+        def loss(h, head, weights):
+            h_on, total = exit_fn(h, head, targets, weights)
+            return 0.37 * total + jnp.sum(onward * h_on.astype(jnp.float32))
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(h, head, weights)
+
+    (value, grads), (want_value, want) = scaled(ouro._exit_sum), scaled(plain_exit_sum)
+    assert float(value) == pytest.approx(float(want_value), rel=1e-6)
+    assert [g.dtype for g in grads] == [dtype, jnp.float32, jnp.float32]
+    for name, g, w in zip(("state", "output matrix", "row weights"), grads, want):
+        assert g.shape == w.shape and rel_err(g, w) < BOUNDS[dtype], (name, rel_err(g, w))
+    # the exit's own part of the state's gradient, without what came back from later passes
+    own = np.asarray(grads[0], np.float32) - np.asarray(onward.astype(dtype), np.float32)
+    assert not own[:, -1].any() and not own[0, 5].any() and own[0, 3].any()
+    # a label outside picks nothing: the row reads its log-sum-exp, and so does its weight's gradient
+    with jax.default_matmul_precision("highest"):
+        lse = jax.nn.logsumexp(ouro._logits(h, head, dtype), axis=-1)
+    np.testing.assert_allclose(np.asarray(grads[2])[[0, 1], [3, 0]],
+                               0.37 * np.asarray(lse)[[0, 1], [3, 0]], rtol=1e-5)
+    # differentiated or not, the same value
+    assert float(ouro._exit_sum(h, head, targets, weights)[1]) == pytest.approx(
+        float(plain_exit_sum(h, head, targets, weights)[1]), rel=1e-6)
+
+
+def per_position_model(cfg, exit_fn):
+    """The looped model in the form ``ouro_loss_fn`` also takes: given targets
+    it returns every exit's cross entropies a position, from ``exit_fn(h, head,
+    targets) -> (h, entropies)``, and the loss function weights them."""
+
+    class PerPosition(OuroModel):
+        @nn.compact
+        def __call__(self, ids, targets):
+            cfg = self.cfg
+            x = ouro.embed(self.kernel("embedding", cfg.vocab_size, cfg.hidden_size), ids,
+                           cfg.compute_dtype)
+            layers = [OuroBlock(cfg, name=f"layer_{n}") for n in range(cfg.num_hidden_layers)]
+            final_norm = RMSNorm(cfg.rms_norm_eps, name="final_norm")
+            head = self.kernel("lm_head", cfg.hidden_size, cfg.vocab_size)
+            w_exit = self.kernel("exit_gate", cfg.hidden_size)
+            b_exit = self.param("exit_gate_bias", nn.initializers.zeros, (), jnp.float32)
+            exits, gates = [], []
+            for _ in range(cfg.total_ut_steps):
+                for layer in layers:
+                    x = layer(x)
+                x, entropies = exit_fn(final_norm(x), head, targets)
+                exits.append(entropies)
+                gates.append(jnp.einsum("btm,m->bt", x.astype(jnp.float32), w_exit,
+                                        precision=jax.lax.Precision.HIGHEST) + b_exit)
+            return jnp.stack(exits), jnp.stack(gates)
+
+    return PerPosition(cfg)
+
+
+def logits_products(text, shape):
+    """Products of the lowered ``text`` whose result is one exit's logits."""
+    result = "-> tensor<" + "x".join(map(str, shape)) + "xf32> loc("
+    return sum("dot_general" in line and result in line for line in text.splitlines())
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_the_gradient_products_taken_at_the_exit_change_neither_loss_nor_gradient(
+        monkeypatch, dtype):
+    passes = 4
+    cfg = ouro_test_config(total_ut_steps=passes, compute_dtype=dtype)
     model = OuroModel(cfg)
     ids = jax.random.randint(jax.random.PRNGKey(7), (2, 24), 0, cfg.vocab_size)
     params = model.init(jax.random.PRNGKey(8), ids)["params"]
     params["embedding"] = 50.0 * params["embedding"]
+    params["exit_gate_bias"] = jnp.float32(-0.4)
+    logits = (2, 24, cfg.vocab_size)
 
-    def both():
+    def both(model):
         with jax.default_matmul_precision("highest"):
             return jax.jit(jax.value_and_grad(ouro_loss_fn(model)))(params, ids)
 
-    loss, grads = both()
-    text = jax.jit(jax.grad(ouro_loss_fn(model))).lower(params, ids).as_text(debug_info=True)
-    assert "rematted_computation" in text and "optimization_barrier" in text
+    def lowered(model):
+        return jax.jit(jax.grad(ouro_loss_fn(model))).lower(params, ids).as_text(debug_info=True)
+
+    loss, grads = both(model)
+    text = lowered(model)
+    # nothing is rebuilt: the head's product runs once an exit, forward and backward together
+    assert "rematted_computation" not in text and "optimization_barrier" in text
+    assert logits_products(text, logits) == passes
     # a version that keeps everything: the exit as plain operations, differentiated as written
-    monkeypatch.setattr(ouro, "_exit", lambda h, head, targets: (
-        h, softmax_cross_entropy(ouro._logits(h, head, h.dtype), targets)))
-    kept_text = jax.jit(jax.grad(ouro_loss_fn(model))).lower(params, ids).as_text(debug_info=True)
+    monkeypatch.setattr(ouro, "_exit_sum", plain_exit_sum)
+    kept_text = lowered(model)
     assert "rematted_computation" not in kept_text and "optimization_barrier" not in kept_text
-    kept_loss, kept_grads = both()
-    assert float(loss) == pytest.approx(float(kept_loss), abs=1e-6)
-    for name, g in leaves_by_name(grads).items():
-        # the same operations on the same operands: float32 rounding of another order at most
-        assert rel_err(g, leaves_by_name(kept_grads)[name]) < 2e-6, name
+    assert logits_products(kept_text, logits) == passes
+    kept_loss, kept_grads = both(model)
+    # and the exit of three arguments, which builds every exit's logits a second time
+    rebuilding = per_position_model(cfg, ouro._exit)
+    rebuilt_text = lowered(rebuilding)
+    assert any("rematted_computation" in line and "dot_general" in line
+               for line in rebuilt_text.splitlines())
+    assert logits_products(rebuilt_text, logits) == 2 * passes
+    rebuilt_loss, rebuilt_grads = both(rebuilding)
+    for other_loss, other_grads in ((kept_loss, kept_grads), (rebuilt_loss, rebuilt_grads)):
+        assert float(loss) == pytest.approx(float(other_loss), rel=1e-6)
+        for name, g in leaves_by_name(grads).items():
+            # the same operations on the same operands: rounding of another order at most
+            assert rel_err(g, leaves_by_name(other_grads)[name]) < BOUNDS[dtype], name
+
+
+def test_a_model_of_per_position_entropies_and_the_exit_of_three_arguments_still_train():
+    """What the benchmark's own tests build: a model whose ``__call__(ids,
+    targets)`` returns ``(entropies (passes, batch, positions), gate logits)``
+    from ``ouro._exit`` trains through ``ouro_loss_fn`` to the loss
+    ``OuroModel`` trains to."""
+    cfg = ouro_test_config()
+    ids = jax.random.randint(jax.random.PRNGKey(9), (2, 16), 0, cfg.vocab_size)
+    params = OuroModel(cfg).init(jax.random.PRNGKey(10), ids)["params"]
+    model = per_position_model(cfg, ouro._exit)
+    entropies, gates = model.apply({"params": params}, ids, jnp.roll(ids, -1, axis=1))
+    assert entropies.shape == gates.shape == (3, 2, 16)
+    want = float(ouro_loss_fn(OuroModel(cfg))(params, ids))
+    assert float(ouro_loss_fn(model)(params, ids)) == pytest.approx(want, rel=1e-6)
+    step = jax.jit(lambda p: jax.tree.map(
+        lambda x, g: x - 0.5 * g, p, jax.grad(ouro_loss_fn(model))(p, ids)))
+    for _ in range(3):
+        params = step(params)
+    after = float(ouro_loss_fn(OuroModel(cfg))(params, ids))
+    assert np.isfinite(after) and after < want - 0.05
 
 
 # -- the engine on four devices -----------------------------------------------
@@ -387,7 +535,8 @@ def test_every_part_and_every_pass_is_named_in_both_passes_of_autodiff():
     order = [s for s, before in zip(seen[1:], seen) if s != before and s[1]]
     layer = ["attn_proj", "attn_core", "attn_proj", "dense_mlp"]
     assert order == [(None, "embed")] + [
-        (run, part) for run in (1, 2, 3) for part in layer * 2 + ["head", "exit_gate"]
+        # the gate before the head: a pass's share of the mass is an input of its exit
+        (run, part) for run in (1, 2, 3) for part in layer * 2 + ["exit_gate", "head"]
     ] + [(None, "exit_gate")]
 
 
