@@ -61,13 +61,19 @@ def main(argv=None) -> int:
         run.setup()
         run.watcher.close()
         run.free_program(close=False)
+        # a process's peak never falls: where the reference leaves it as the program's steps
+        # left it, the check lies under the program
+        peak_of_program = harness.device_section(devices)["memory_peak_bytes"]
         t1 = time.perf_counter()
         ref = run.reference()
         t2 = time.perf_counter()
         line = {"workload": cell.name, "seed": seed, "sound": run.numbers(ref),
                 "worst_leaves": run.worst_leaves,
                 "losses": run.warmup_losses, "reference_losses": ref[0],
-                "setup_s": t1 - t0, "reference_s": t2 - t1}
+                "setup_s": t1 - t0, "reference_s": t2 - t1,
+                "memory_peak_bytes": {
+                    "after_the_program": peak_of_program,
+                    "after_the_reference": harness.device_section(devices)["memory_peak_bytes"]}}
         sound.append(line["sound"])
         if n < control_seeds:
             low = run.reference(control=True)

@@ -24,6 +24,7 @@ records.
 """
 
 import contextlib
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -67,23 +68,50 @@ def lower_precision(loss_fn):
     return wrapped
 
 
-def reference_steps(loss_fn, params, batches, optimizer, micro: int, highest: bool = True,
-                    place=lambda part: part):
-    """One optimizer step of ``loss_fn(params, batch)`` from ``params`` for
-    each of ``batches``, each batch evaluated in micro-batches of ``micro``
-    rows, each put where ``place`` puts it.  Returns the per-step losses, the
-    first gradient and the parameters' total change.  ``highest`` multiplies
-    in full float32."""
+def _leaf_by_leaf(fn, tree, *rest):
+    """``jax.tree.map(fn, tree, *rest)`` one leaf at a time, each source leaf
+    deleted from the device once its result stands there: the trees are given
+    up, and no more than one leaf of them stands twice.  Waiting for each
+    result is what bounds it: a buffer is released only when the operation
+    that reads it has run, and the host would otherwise allocate every
+    result first."""
+    leaves, treedef = jax.tree.flatten(tree)
+    columns = [leaves] + [treedef.flatten_up_to(other) for other in rest]
+    out = []
+    for sources in zip(*columns):
+        out.append(jax.block_until_ready(fn(*sources)))
+        for source in sources:
+            source.delete()
+    return treedef.unflatten(out)
+
+
+def reference_steps(loss_fn, make_params, batches, optimizer, micro: int, highest: bool = True,
+                    place=lambda part: part, keep=lambda grad: grad):
+    """One optimizer step of ``loss_fn(params, batch)`` from ``make_params()``
+    for each of ``batches``, each batch evaluated in micro-batches of
+    ``micro`` rows, each put where ``place`` puts it.  Returns the per-step
+    losses, what ``keep`` makes of the first gradient (it is called before
+    the second step, and the harness takes the gradient to the host there)
+    and the parameters' total change.  ``highest`` multiplies in full float32.
+
+    On the device stand the parameters, the optimizer's state, the gradient
+    being made and at most one more tree of their size: three copies with
+    plain SGD, four with momentum, beside ``loss_fn``'s own step.  The start
+    does not live through the steps: ``make_params`` is called a second time
+    after the last of them, and the change is taken leaf by leaf."""
     value_and_grad = jax.jit(jax.value_and_grad(loss_fn))
     accumulate = jax.jit(
         lambda acc, part: jax.tree.map(jnp.add, acc, part), donate_argnums=0)
 
-    @jax.jit
+    # the gradient is not donated: with two results and three arguments one
+    # donation could not be used, and the default ``keep`` keeps the first
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
     def apply(p, opt_state, g):
         updates, opt_state = optimizer.update(g, opt_state, p)
         return optax.apply_updates(p, updates), opt_state
 
-    start, opt_state = params, optimizer.init(params)
+    params = make_params()
+    opt_state = optimizer.init(params)
     losses, first_grad = [], None
     precision = jax.default_matmul_precision("highest") if highest else contextlib.nullcontext()
     with precision:
@@ -96,13 +124,18 @@ def reference_steps(loss_fn, params, batches, optimizer, micro: int, highest: bo
             for k in range(parts):
                 part = place(jax.tree.map(lambda x: x[k * micro:(k + 1) * micro], batch))
                 out = value_and_grad(params, part)
-                total = out if total is None else accumulate(total, out)
-            loss, grad = jax.tree.map(lambda x: x / parts, total)
+                # waited for and dropped, or the next micro-batch's gradient is made beside it
+                total = out if total is None else jax.block_until_ready(accumulate(total, out))
+                del out
+            loss, grad = _leaf_by_leaf(lambda x: x / parts, total)
+            del total
             losses.append(float(loss))
-            if first_grad is None:
-                first_grad = grad
+            if len(losses) == 1:
+                first_grad = keep(grad)
             params, opt_state = apply(params, opt_state, grad)
-    delta = jax.tree.map(jnp.subtract, params, start)
+            del grad
+    del opt_state
+    delta = _leaf_by_leaf(jnp.subtract, params, make_params())
     return losses, first_grad, delta
 
 

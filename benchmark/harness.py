@@ -294,9 +294,19 @@ class Run:
         # compiler splits each micro-batch and adds the gradients up
         mesh = jax.sharding.Mesh(np.array(self.devices), ("rows",))
         whole = NamedSharding(mesh, P())
-        start = jax.device_put(cell.adapter.as_stored(
-            jax.jit(lambda k: cell.reference.init_params(k, sizes))(self.params_key)), whole)
         by_rows = NamedSharding(mesh, P("rows"))
+        init = jax.jit(lambda k: cell.reference.init_params(k, sizes))
+
+        def make_params():
+            return jax.device_put(cell.adapter.as_stored(init(self.params_key)), whole)
+
+        stored = jax.eval_shape(lambda: cell.adapter.to_program(make_params(), sizes))
+
+        def on_the_host(tree):
+            """The checked leaves of a tree in the reference's layout, off
+            the device: the first gradient leaves it before the second step."""
+            return jax.device_get(check.checked_leaves(
+                cell.adapter.to_program(tree, sizes, cast=False), stored))
 
         def loss_fn(params, batch):
             return cell.reference.loss(params, batch, sizes)
@@ -304,13 +314,10 @@ class Run:
         micro = min(REFERENCE_MICRO * len(self.devices), cell.global_batch)
         losses, grad, delta = check.reference_steps(
             check.lower_precision(loss_fn) if control else loss_fn,
-            start, self.feed_batches, check.make_optimizer(self.optimizer_spec),
-            micro, highest=not control, place=lambda part: jax.device_put(part, by_rows))
-        stored = jax.eval_shape(lambda t: cell.adapter.to_program(t, sizes), start)
-        del start
-        grad = check.checked_leaves(cell.adapter.to_program(grad, sizes, cast=False), stored)
-        delta = check.checked_leaves(cell.adapter.to_program(delta, sizes, cast=False), stored)
-        return losses, jax.device_get(grad), check.leaf_norms(jax.device_get(delta))
+            make_params, self.feed_batches, check.make_optimizer(self.optimizer_spec),
+            micro, highest=not control, place=lambda part: jax.device_put(part, by_rows),
+            keep=on_the_host)
+        return losses, grad, check.leaf_norms(on_the_host(delta))
 
     def numbers(self, ref) -> dict:
         """The program's checked steps against the reference's."""

@@ -152,6 +152,9 @@ def main(argv=None) -> int:
     if args.dry_run:
         result["dry_run"] = True
     print(json.dumps(result), flush=True)
+    # and each number compared beside its limit as the last lines on standard error, where the
+    # driver's record of a run that is not correct keeps them
+    print("\n".join(lines + [f"check window_losses_finite={finite}"]), file=sys.stderr, flush=True)
     return 0
 
 

@@ -1,14 +1,21 @@
 """``correct`` has been shown to fail: the control (the reference in the
 next lower precision, put in the program's place), the program itself with
-8-bit weights, and a run whose timed path is broken underneath.  At the
-configurations' toy sizes on the CPU, against the toy limits in their JSON;
-the chip's readings at the cells' own sizes are in PERF.md."""
+8-bit weights, and a run whose timed path is broken underneath.  And what the
+check itself holds on the device while it follows the steps: three trees of
+the parameters' size with plain SGD, four with momentum, and the numbers of
+the six-copy form it replaced, bit for bit.  At the configurations' toy sizes
+on the CPU, against the toy limits in their JSON; the chip's readings at the
+cells' own sizes are in PERF.md."""
 
+import contextlib
+import gc
 import json
 import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+import optax
 import pytest
 
 from benchmark import check, harness, manifest
@@ -59,6 +66,175 @@ def test_the_program_with_8_bit_weights_does_not_pass(name):
     cell, run = toy_run(name, SEEDS[0], patch_adapter=patch)
     passed, lines = check.verdict(run.numbers(run.reference()), cell.tolerances)
     assert not passed, lines
+
+
+# -- what the check holds, and that its numbers did not move -------------------
+
+
+def six_copy_reference_steps(loss_fn, params, batches, optimizer, micro, highest=True,
+                             place=lambda part: part):
+    """``check.reference_steps`` as it stood until PR 44, the plain oracle of
+    the two tests below: the start, the parameters, the first gradient, the
+    summed gradient, its mean and the step's result all on the device at once."""
+    value_and_grad = jax.jit(jax.value_and_grad(loss_fn))
+    accumulate = jax.jit(
+        lambda acc, part: jax.tree.map(jnp.add, acc, part), donate_argnums=0)
+
+    @jax.jit
+    def apply(p, opt_state, g):
+        updates, opt_state = optimizer.update(g, opt_state, p)
+        return optax.apply_updates(p, updates), opt_state
+
+    start, opt_state = params, optimizer.init(params)
+    losses, first_grad = [], None
+    precision = jax.default_matmul_precision("highest") if highest else contextlib.nullcontext()
+    with precision:
+        for batch in batches:
+            parts = jax.tree.leaves(batch)[0].shape[0] // micro
+            total = None
+            for k in range(parts):
+                part = place(jax.tree.map(lambda x: x[k * micro:(k + 1) * micro], batch))
+                out = value_and_grad(params, part)
+                total = out if total is None else accumulate(total, out)
+            loss, grad = jax.tree.map(lambda x: x / parts, total)
+            losses.append(float(loss))
+            if first_grad is None:
+                first_grad = grad
+            params, opt_state = apply(params, opt_state, grad)
+    delta = jax.tree.map(jnp.subtract, params, start)
+    return losses, first_grad, delta
+
+
+#: ``(the cell whose toy reference is the loss, under its own optimizer; as the control)``
+STEPPED = {
+    "sgd": ("bert-large.dp1", False),
+    "momentum": ("vgg16.dp1", False),
+    "control": ("bert-large.dp1", True),
+}
+
+
+MOMENTUM = {"name": "sgd", "learning_rate": 0.01, "momentum": 0.9}
+
+
+def toy_steps(kind, rows, seed=SEEDS[0], optimizer=None):
+    """``(loss_fn, make_params, batches, optimizer, highest)`` of a toy cell's
+    plain reference over ``check.CHECKED_STEPS`` batches of ``rows`` rows,
+    under the cell's own optimizer or the one given."""
+    name, control = STEPPED[kind]
+    cell = manifest.load_cell(name, dry=True)
+    sizes = cell.sizes
+    params_key, data_key = jax.random.split(jax.random.PRNGKey(seed))
+    init = jax.jit(lambda k: cell.adapter.as_stored(cell.reference.init_params(k, sizes)))
+    batches = [cell.adapter.draw_batch(jax.random.fold_in(data_key, k), rows, sizes)
+               for k in range(check.CHECKED_STEPS)]
+
+    def loss_fn(params, batch):
+        return cell.reference.loss(params, batch, sizes)
+
+    return (check.lower_precision(loss_fn) if control else loss_fn, lambda: init(params_key),
+            batches, check.make_optimizer(optimizer or cell.config["optimizer"]), not control)
+
+
+@pytest.mark.parametrize("micro", [6, 2], ids=["one_micro_batch", "three_micro_batches"])
+@pytest.mark.parametrize("kind", sorted(STEPPED))
+def test_the_three_copy_steps_give_the_six_copy_steps_numbers_bit_for_bit(kind, micro):
+    loss_fn, make_params, batches, optimizer, highest = toy_steps(kind, rows=6)
+    want = six_copy_reference_steps(loss_fn, make_params(), batches, optimizer, micro, highest)
+    got = check.reference_steps(loss_fn, make_params, batches, optimizer, micro, highest)
+    assert got[0] == want[0] and len(got[0]) == check.CHECKED_STEPS
+    for found, expected in zip(got[1:], want[1:]):
+        found, expected = jax.tree.leaves(found), jax.tree.leaves(expected)
+        assert len(found) == len(expected) > 0
+        assert all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in zip(found, expected))
+    # the steps moved the parameters, and the second batch is not the first
+    assert got[0][0] != got[0][1] and any(np.any(np.asarray(x)) for x in jax.tree.leaves(got[2]))
+
+
+def live_bytes():
+    return sum(x.nbytes for x in jax.live_arrays() if not x.is_deleted())
+
+
+def trees_held(steps, optimizer, micro):
+    """``({where: the most held there}, allowance)``: what the device holds
+    while ``steps`` follows a toy cell's two steps, in trees of the
+    parameters' size and beyond what it held before.  Read where a
+    micro-batch is placed (``place``: what stands as its gradient is about
+    to be made), from inside the loss (``loss``: a callback, so at every
+    evaluation, the gradient being made counted or not as the runtime has
+    it), where the first gradient is handed over (``keep``) and where the
+    start is made again (``params``).  The allowance is the micro-batches
+    themselves: BERT's token ids, a hundredth of a tree at toy sizes."""
+    loss_fn, make_params, batches, optimizer, highest = toy_steps("sgd", 6, optimizer=optimizer)
+    tree_bytes = sum(x.nbytes for x in jax.tree.leaves(make_params()))
+    seen = []
+
+    def census(where):
+        seen.append((where, (live_bytes() - before) / tree_bytes))
+
+    def watched_loss(params, batch):
+        jax.debug.callback(lambda: census("loss"))
+        return loss_fn(params, batch)
+
+    def watched_params():
+        census("params")
+        return make_params()
+
+    def watched_place(part):
+        census("place")
+        return part
+
+    def to_host(grad):
+        census("keep")
+        return jax.device_get(grad)
+
+    gc.collect()
+    before = live_bytes()
+    if steps is check.reference_steps:
+        out = steps(watched_loss, watched_params, batches, optimizer, micro, highest,
+                    place=watched_place, keep=to_host)
+    else:
+        out = steps(watched_loss, watched_params(), batches, optimizer, micro, highest,
+                    place=watched_place)
+    jax.effects_barrier()
+    del out
+    evaluations = check.CHECKED_STEPS * (6 // micro)
+    assert [where for where, _ in seen].count("loss") == evaluations
+    assert [where for where, _ in seen].count("place") == evaluations
+    assert min(held for where, held in seen if where != "params") > 0.99  # the parameters at least
+    most = {where: max(held for at, held in seen if at == where) for where, _ in seen}
+    return most, sum(x.nbytes for x in jax.tree.leaves(batches[0])) / tree_bytes + 0.01
+
+
+@pytest.mark.parametrize("micro", [6, 2], ids=["one_micro_batch", "three_micro_batches"])
+@pytest.mark.parametrize("optimizer,limit", [(None, 3), (MOMENTUM, 4)], ids=["sgd", "momentum"])
+def test_the_check_holds_three_trees_with_sgd_and_four_with_momentum(optimizer, limit, micro):
+    """Parameters, the optimizer's state, the gradient being made and at
+    most one more tree."""
+    most, allowance = trees_held(check.reference_steps, optimizer, micro)
+    assert allowance < 0.02
+    assert most["place"] + 1 <= limit + allowance  # and the gradient about to be made
+    assert max(most.values()) <= limit + allowance
+    assert most["params"] <= 1 + allowance  # the start is made again beside the result alone
+    # the census has been seen to count more: the form it replaced holds the start, the
+    # first gradient and the last step's sum beside them
+    six_copy, _ = trees_held(six_copy_reference_steps, optimizer, micro)
+    assert six_copy["place"] + 1 >= limit + 2
+
+
+def test_the_steps_give_up_the_trees_they_consume():
+    """The parameters the steps made are not on the device afterwards: what
+    is left is the change and what ``keep`` kept."""
+    loss_fn, make_params, batches, optimizer, highest = toy_steps("momentum", rows=6)
+    made = []
+
+    def remembered():
+        made.append(make_params())
+        return made[-1]
+
+    _, grad, delta = check.reference_steps(loss_fn, remembered, batches, optimizer, 2, highest)
+    assert len(made) == 2  # the start, and the start again for the change
+    assert all(x.is_deleted() for tree in made for x in jax.tree.leaves(tree))
+    assert not any(x.is_deleted() for x in jax.tree.leaves((grad, delta)))
 
 
 def drive(capsys, name):

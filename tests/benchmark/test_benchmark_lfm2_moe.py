@@ -10,7 +10,7 @@ import pytest
 
 from benchmark import check, manifest
 from test_benchmark_correct import toy_run
-from test_benchmark_run import run_cell
+from test_benchmark_run import later_pr, run_cell
 
 CELL = "lfm2-8b-a1b.dp1-s8192"
 GLM_CELL = "glm-4.7-flash.dp1-s8192"
@@ -152,26 +152,32 @@ def test_the_recorded_parts_cover_the_forward_and_backward_pass(recorded):
     assert summary["partition_ms"]["unattributed"] < 0.08 * summary["step_busy_ms"]
 
 
-def test_the_six_entries_follow_glms_five_and_list_this_cell_alone():
-    names = [m["name"] for m in BENCH["per_layer"]]
-    entries = {m["name"]: m for m in BENCH["per_layer"]}
+@pytest.mark.parametrize("bench", [BENCH, later_pr(BENCH)], ids=["as_it_stands", "after_a_later_pr"])
+def test_the_six_entries_follow_glms_five_and_list_this_cell_alone(bench):
+    names = [m["name"] for m in bench["per_layer"]]
+    entries = {m["name"]: m for m in bench["per_layer"]}
     for name in READERS:
         entry = entries[name]
         assert entry["workloads"] == [CELL] and entry["moves"] == "samples_per_s_per_chip"
         assert entry["source"] == "program_span"
         assert (entry["unit"] == "%") == name.endswith("_roofline_pct")
-    # the six were appended: they stand together, in this order, right after
-    # GLM's five, which keep their order and each its own cell alone (found by
-    # name, so a later PR may append after them)
-    at = names.index(next(iter(READERS)))
-    assert names[at:at + 6] == list(READERS) and names[at - 5:at] == GLM_READERS
+    # the six keep their order among themselves and come after GLM's five, which keep theirs
+    # and each its own cell alone: all found by name, so a later PR may append after them
+    assert [name for name in names if name in READERS] == list(READERS)
+    assert [name for name in names if name in GLM_READERS] == GLM_READERS
+    assert max(map(names.index, GLM_READERS)) < min(map(names.index, READERS))
     assert all(entries[name]["workloads"] == [GLM_CELL] for name in GLM_READERS)
-    layers = {m["layer"] for m in BENCH["per_layer"]}
+    layers = {m["layer"] for m in bench["per_layer"]}
     assert {entries[name]["layer"] for name in READERS} == {
         "short convolution", "attention", "expert layer"} <= layers
-    # and the cell and its configuration are the last of their lists
-    assert BENCH["workloads"][-1]["name"] == CELL and BENCH["configs"][-1]["name"] == "lfm2-8b-a1b"
-    assert BENCH["workloads"][-1]["traffic"] == "dp1-b1-s8192" and BENCH["workloads"][-1]["chips"] == 1
+    # the cell and its configuration stand once, after GLM's, and say what they said
+    cells = [w["name"] for w in bench["workloads"]]
+    configs = [c["name"] for c in bench["configs"]]
+    assert cells.count(CELL) == 1 and cells.index(GLM_CELL) < cells.index(CELL)
+    assert configs.count("lfm2-8b-a1b") == 1
+    assert configs.index("glm-4.7-flash") < configs.index("lfm2-8b-a1b")
+    cell = bench["workloads"][cells.index(CELL)]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == ("lfm2-8b-a1b", "dp1-b1-s8192", 1)
 
 
 def test_the_cell_reports_every_metric_without_a_list_and_its_own_six():

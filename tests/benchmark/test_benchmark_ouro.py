@@ -1,7 +1,7 @@
 """The ``ouro-2.6b`` configuration and its cell: the stated precision against
 the control at the toy limits, runs whose timed path is broken underneath (the
 state kept, half the batch, three passes of four, the entropy term left out,
-the next pass fed the un-normed state, 8-bit weights), the six readers on the
+the next pass fed the un-normed state, 8-bit weights), the five readers on the
 summary of a traced run on the chip, the adapter's operation counts worked out
 on paper, and what the configuration's file states of the cut."""
 
@@ -15,14 +15,13 @@ import pytest
 
 from benchmark import check, manifest
 from test_benchmark_correct import drive, toy_run
-from test_benchmark_run import run_cell
+from test_benchmark_run import later_pr, run_cell
 
 CELL = "ouro-2.6b.dp1-s8192"
 CONFIG = "ouro-2.6b"
 BENCH = manifest.benchmark_json()
 READERS = ["ouro_layer_products_ms_per_step", "ouro_layer_products_roofline_pct",
-           "ouro_attention_core_roofline_pct", "ouro_exits_ms_per_step", "ouro_head_roofline_pct",
-           "ouro_recompute_ms_per_step"]
+           "ouro_attention_core_roofline_pct", "ouro_exits_ms_per_step", "ouro_head_roofline_pct"]
 SOURCE = "https://huggingface.co/ByteDance/Ouro-2.6B/blob/main/config.json"
 
 
@@ -212,7 +211,6 @@ def test_the_readers_add_up_the_parts_they_name(recorded):
     assert read["ouro_layer_products_ms_per_step"] == pytest.approx(
         parts["attn_proj"] + parts["dense_mlp"])
     assert read["ouro_exits_ms_per_step"] == pytest.approx(parts["head"] + parts["exit_gate"])
-    assert read["ouro_recompute_ms_per_step"] == pytest.approx(summary["partition_ms"]["recompute"])
     cell = manifest.load_cell(CELL)
     peak = recorded["peaks"]["bf16_flops_per_s"]
     for name, count, ms in (
@@ -224,9 +222,6 @@ def test_the_readers_add_up_the_parts_they_name(recorded):
         assert read[name] == pytest.approx(
             100 * getattr(cell.adapter, count)(cell.sizes) / (ms / 1e3) / peak)
         assert 0 < read[name] < 100, name  # a share of a peak is a share
-    # what is rebuilt is the exits' logits, and a small part of the step
-    assert 0 < read["ouro_recompute_ms_per_step"] < 0.15 * summary["step_busy_ms"]
-    assert read["ouro_recompute_ms_per_step"] < parts["head"]
 
 
 @pytest.mark.parametrize("name", READERS)
@@ -234,11 +229,10 @@ def test_reader_gives_none_without_a_trace_or_without_what_it_reads(name, record
     read = manifest.layer_metric_reader(name)
     assert read(recorded) > 0
     assert read({**recorded, "trace": None}) is None
-    # a program whose model names no part and whose summary has no such class (the parent's)
+    # a program whose model names no part (the parent's)
     from bagua_tpu.observability import trace_analysis
 
     plain = {k: v for k, v in trace_analysis.last_summary().items() if k != "model_part_ms"}
-    plain["partition_ms"] = {k: v for k, v in plain["partition_ms"].items() if k != "recompute"}
     monkeypatch.setattr(trace_analysis, "_LAST_SUMMARY", plain)
     assert read(recorded) is None
     # and one without the reducer at all
@@ -271,33 +265,37 @@ def test_the_recorded_parts_and_passes_cover_the_three_classes(recorded):
     assert classes["unattributed"] < 0.1 * summary["step_busy_ms"]
 
 
-def test_the_six_entries_and_the_cell_are_appended_and_list_this_cell_alone():
-    names = [m["name"] for m in BENCH["per_layer"]]
-    entries = {m["name"]: m for m in BENCH["per_layer"]}
+@pytest.mark.parametrize("bench", [BENCH, later_pr(BENCH)], ids=["as_it_stands", "after_a_later_pr"])
+def test_the_five_entries_and_the_cell_list_this_cell_alone_and_keep_their_order(bench):
+    names = [m["name"] for m in bench["per_layer"]]
+    entries = {m["name"]: m for m in bench["per_layer"]}
     for name in READERS:
         entry = entries[name]
         assert entry["workloads"] == [CELL] and entry["moves"] == "samples_per_s_per_chip"
         assert entry["source"] == "program_span"
         assert (entry["unit"] == "%") == name.endswith("_roofline_pct")
         assert (entry["better"] == "higher") == name.endswith("_roofline_pct")
-    # appended together, in the issue's order, after the seven of set-up (found by name, so a
-    # later PR may append after these)
-    at = names.index(READERS[0])
-    assert names[at:at + 6] == READERS and names[at - 1] == "setup_named_pct"
+    # in the issue's order among themselves, after the seven of set-up: found by name, so a
+    # later PR may append after these
+    assert [name for name in names if name in READERS] == READERS
+    assert names.index("setup_named_pct") < names.index(READERS[0])
+    # every entry that lists this cell alone is one of the five: none reads a class the step
+    # no longer has
+    assert [m["name"] for m in bench["per_layer"] if m.get("workloads") == [CELL]] == READERS
     assert [entries[name]["layer"] for name in READERS] == [
-        "looped stack", "looped stack", "attention", "exits", "exits", "model step"]
-    cells = [w["name"] for w in BENCH["workloads"]]
-    configs = [c["name"] for c in BENCH["configs"]]
-    assert cells[cells.index(CELL) - 1] == "smallthinker-21ba3b.dp1-s8192"
-    assert configs[configs.index(CONFIG) - 1] == "smallthinker-21ba3b"
-    entry = BENCH["workloads"][cells.index(CELL)]
+        "looped stack", "looped stack", "attention", "exits", "exits"]
+    cells = [w["name"] for w in bench["workloads"]]
+    configs = [c["name"] for c in bench["configs"]]
+    assert cells.index("smallthinker-21ba3b.dp1-s8192") < cells.index(CELL)
+    assert configs.index("smallthinker-21ba3b") < configs.index(CONFIG)
+    entry = bench["workloads"][cells.index(CELL)]
     assert (entry["config"], entry["traffic"], entry["chips"]) == (CONFIG, "dp1-b1-s8192", 1)
     assert len(entry["why"]) <= 200
-    # one four-chip cell of seven, as before
-    assert [w["name"] for w in BENCH["workloads"] if w["chips"] == 4] == ["bert-large.dp4"]
+    # one four-chip cell, as before
+    assert [w["name"] for w in bench["workloads"] if w["chips"] == 4] == ["bert-large.dp4"]
 
 
-def test_the_cell_reports_every_metric_without_a_list_and_its_own_six():
+def test_the_cell_reports_every_metric_without_a_list_and_its_own_five():
     cell = manifest.load_cell(CELL)
     reported = {m["name"] for m in cell.per_layer}
     unlisted = {m["name"] for m in BENCH["per_layer"] if "workloads" not in m}

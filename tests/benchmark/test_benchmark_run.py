@@ -2,6 +2,7 @@
 last line's keys for every cell in ``BENCHMARK.json``, and the refusal to
 run without a chip."""
 
+import copy
 import json
 import os
 import subprocess
@@ -21,6 +22,22 @@ def run_cell(*args, timeout=900):
         capture_output=True, text=True, timeout=timeout, cwd=manifest.ROOT,
         env=dict(os.environ, BENCH_RUN="7"),
     )
+
+
+def later_pr(bench):
+    """``bench`` as it will stand once a later PR has appended a metric, a
+    cell and a configuration of its own: where the driver puts every new entry."""
+    later = copy.deepcopy(bench)
+    later["per_layer"].append({
+        "name": "a_later_prs_ms_per_step", "unit": "ms", "better": "lower",
+        "source": "program_span", "layer": "model step", "moves": "samples_per_s_per_chip",
+        "workloads": ["a-later-model.dp1"]})
+    later["workloads"].append({"name": "a-later-model.dp1", "config": "a-later-model",
+                               "traffic": "dp1-b1-s8192", "chips": 1, "why": "a later PR's"})
+    later["configs"].append({"name": "a-later-model", "source": "a later PR's",
+                             "file": "benchmark/configs/a-later-model.json", "reduced": [],
+                             "why": "a later PR's"})
+    return later
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -49,10 +66,13 @@ def test_dry_run_prints_the_contracts_last_line(name, trace):
     assert result["device"]["platform"] == "cpu" and result["device"]["count"] == cell.chips
     assert "memory_peak_bytes" in result["device"] and "kind" in result["device"]
     assert result["attempted"] > 10 and result["failed"] == 0
-    # every number compared is printed beside its limit, in every run
-    for number in result["checks"]:
-        assert any(line.startswith(f"DRY RUN check {number}=") and "limit=" in line
-                   for line in lines)
+    # every number compared is printed beside its limit, in every run, and those lines are
+    # the last on standard error
+    last = proc.stderr.strip().splitlines()[-len(result["checks"]) - 1:]
+    assert last[-1] == "check window_losses_finite=True"
+    for number, line in zip(result["checks"], last):
+        assert line.startswith(f"check {number}=") and "limit=" in line and line.endswith(" ok")
+        assert "DRY RUN " + line in lines
     assert result["correct"] is True, "\n".join(lines[-12:])
 
 

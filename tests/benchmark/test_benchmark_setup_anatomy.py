@@ -14,6 +14,7 @@ import pytest
 from bagua_tpu.observability import cold_start
 from bagua_tpu.observability.cold_start import ColdEvent
 from benchmark import manifest, setup_anatomy
+from test_benchmark_run import BENCH, later_pr
 
 GROUP, TRAINER, INIT_STATE = cold_start.INIT_SPANS
 BUILD, DISPATCH, TEXT = cold_start.STEP_SPANS
@@ -103,23 +104,25 @@ def test_the_named_share_is_the_classes_over_the_runs_own_setup_s(recorded):
     assert manifest.layer_metric_reader("setup_named_pct")(context()) <= 100.0
 
 
-def test_every_new_metric_has_its_file_and_its_entry_after_the_accepted_ones():
-    entries = manifest.benchmark_json()["per_layer"]
+@pytest.mark.parametrize("bench", [BENCH, later_pr(BENCH)], ids=["as_it_stands", "after_a_later_pr"])
+def test_every_new_metric_has_its_file_and_its_entry_after_the_accepted_ones(bench):
+    entries = bench["per_layer"]
     names = [m["name"] for m in entries]
-    last_accepted = names.index("st_moe_experts_roofline_pct")
-    assert names[last_accepted + 1:] == [
-        "setup_import_s", "setup_init_s", "setup_step_trace_s", "setup_step_compile_s",
-        "setup_other_programs_s", "setup_cache_misses", "setup_named_pct"]
-    assert set(names[last_accepted + 1:]) == set(EXPECTED)
-    end_to_end = {m["name"] for m in manifest.benchmark_json()["end_to_end"]}
-    for entry in entries[last_accepted + 1:]:
+    # found by name: the seven keep the order their PR gave them (``EXPECTED``'s) and stand
+    # after the entries accepted before them; what later PRs append comes after and is theirs
+    assert [name for name in names if name in EXPECTED] == list(EXPECTED)
+    assert names.index("st_moe_experts_roofline_pct") < names.index("setup_import_s")
+    end_to_end = {m["name"] for m in bench["end_to_end"]}
+    for entry in entries:
+        if entry["name"] not in EXPECTED:
+            continue
         assert entry["moves"] == "setup_s" and entry["moves"] in end_to_end
         assert "workloads" not in entry and entry["layer"] in ("entry", "engine")
         assert entry["source"] in ("program_span", "program_counter")
         assert os.path.exists(os.path.join(
             manifest.ROOT, "benchmark", "layer_metrics", entry["name"] + ".py"))
     # ... and every cell reports them: each reports setup_s
-    for workload in manifest.benchmark_json()["workloads"]:
+    for workload in BENCH["workloads"]:
         assert set(EXPECTED) <= {m["name"] for m in manifest.load_cell(workload["name"]).per_layer}
 
 
