@@ -131,8 +131,12 @@ def model_scope(part: str):
     core of a layer whose mask has a window, beside ``attn_core`` for the
     layers whose mask has none; in ``models/ouro.py`` ``exit_gate``, the gate's
     product after every pass, the exit distribution, the weighted sum of the
-    exits' losses and the entropy).  Any name is a part: the summary keeps
-    what it finds.
+    exits' losses and the entropy; in ``models/nemotron_h.py`` ``ssm_proj``, a
+    Mamba-2 mixer's two products, ``ssm_conv``, its depthwise causal
+    convolution with the bias and SiLU, ``ssm_core``, the chunked scan, the
+    ``D`` term, the gate and the group norm, and ``moe_latent``, the two
+    projections between the hidden and the experts' latent width).  Any name
+    is a part: the summary keeps what it finds.
     Autodiff carries the frame into the backward pass's ops, and
     ``jax.checkpoint`` into those it runs again there, so the device trace
     gives each part's forward, backward and recomputed time together

@@ -2,7 +2,9 @@
 (sigmoid scores with a selection bias, :func:`sigmoid_topk_route`, or a
 softmax over the chosen logits, :func:`softmax_topk_route`), and the part of
 the result that the experts *held here* give, each a gated unit whose gate is
-the caller's (SiLU by default, ReLU in ``models/smallthinker_moe.py``).
+the caller's (SiLU by default, ReLU in ``models/smallthinker_moe.py``) or, with
+no gate, two products around the caller's activation (``models/nemotron_h.py``:
+``down(relu(up u)^2)``).
 
 The GShard layer beside it (:mod:`bagua_tpu.parallel.moe.layer`) sends every
 token through a dense ``(tokens, experts, capacity)`` mask and drops what
@@ -10,8 +12,15 @@ exceeds the capacity.  This one sorts: the ``tokens x k`` assignments are
 ordered by expert, the rows of the held experts come first, one grouped
 matrix product per projection runs over exactly those rows, and each token
 takes its rows back by the inverse permutation.  Nothing is dropped at any
-load: the row buffer has ``tokens x k`` rows, which no routing can exceed,
-and the grouped product skips what lies past the held groups.
+load: a token chooses ``k`` *distinct* experts, so at most ``min(k, held)`` of
+its choices are held here, and the row buffer has ``tokens x min(k, held)``
+rows, which no routing can exceed; the grouped product skips what lies past
+the held groups.  Where a token makes no more choices than the chip holds
+experts (the first three callers below) that is ``tokens x k``, every
+assignment's own row; where it makes more (22 of 512 with 8 held:
+``tokens x 8`` and not ``tokens x 22``) the sort still runs over all ``tokens
+x k`` keys, the buffer is the first rows of that order, and an assignment
+whose place lies past it is dead like any other that is not held.
 
 The layer is *told* which experts it holds (``held = (first, count)`` of
 ``num_experts``).  It routes over all of them, normalises the ``k`` weights
@@ -40,7 +49,16 @@ Each is a ``jax.custom_vjp`` whose backward is the other, so autodiff builds
 neither a ``(tokens, k, hidden)`` array (on the chip a re-tiling to four
 sublanes), nor its float32 broadcast, nor a scatter-add.  A dead row holds
 whatever the buffer held: the passes *select* it away and never scale it.
-Nothing is sized to, or branched on, the live rows.  The 32,768 scalars a
+Arrays are sized to the most rows that can be live, never to those that are;
+one thing is branched on them: where a token makes more choices than experts
+are held, ``collect`` first brings each token's live choices to the front of
+its ``k`` (a sort of ``k`` keys a token) and gathers ``min(k, held)`` times,
+not ``k`` times, and it takes the rows from the buffer's first ``tokens`` rows
+alone when no more are live, which holds whenever the load is near its
+expectation: 16 MB, a source that fits fast memory, where the whole buffer is
+134 MB (by a plain SGD step's time of one such layer on the chip, 67.81 ms
+with 22 gathers, 61.56 with 8, 59.09 with 8 from the first rows; ``PERF.md``
+section 6, PR 45).  The scalars a
 pass needs in the other order (the weight a row, the gradient a choice) are
 reordered by a sort, which costs a tenth of the gather.
 
@@ -48,11 +66,12 @@ The grouped product is ``megablox.gmm`` (Pallas, ships with JAX) on a TPU
 and ``jax.lax.ragged_dot`` elsewhere; rows past the held groups are left
 unwritten by the one and zero by the other, so every use masks them.  The
 kernel's tile is a function of the product's shape (:func:`gmm_tiling`): the
-layer has three callers whose experts differ in width, in the model's hidden
+layer has four callers whose experts differ in width, in the model's hidden
 size, in the choices a token makes and in the rows a group gets
 (``models/glm_moe.py`` 1536 of 2048, 4 choices; ``models/lfm2_moe.py`` 1792 of
 2048, 4; ``models/smallthinker_moe.py`` 768 of 2560, 6: a buffer of 49,152
-rows).
+rows; ``models/nemotron_h.py`` 2688 of a latent 1024, no gate, 22 choices of
+512 experts with 8 held: a buffer of 65,536 rows for 2,816 expected).
 """
 
 import functools
@@ -80,11 +99,18 @@ from bagua_tpu.observability.annotations import model_scope
 #: columns, and 640 or 384 the other way, 165.88 to 167.13; ``(512, 1280,
 #: 768)`` with 1,280 or 768 columns the other way 166.10 and 166.78; a
 #: contraction of 512, which the default below gives, 167.78 to 170.35; 768
-#: columns against the whole contraction are refused for fast memory).  Every
-#: tile of it divides its dimension: a contraction tile that hangs over is
-#: masked in float32 at every step of the kernel's grid.
+#: columns against the whole contraction are refused for fast memory).
+#: 2,688 of a latent 1,024 (``nemotron-3-super``: 352 expected rows a group of
+#: 65,536; PERF.md section 6, PR 45, ten tiles by a plain SGD step's time of
+#: one expert layer: at these rows the tile moves nothing that can be read,
+#: 61.53 to 61.58 ms over all ten, these two 61.53 and the default below, whose
+#: contraction of 2,688 falls to tiles of 128, 61.58; none refused, 2,688
+#: columns against the whole contraction among them).  Every tile of it
+#: divides its dimension: a contraction tile that hangs over is masked in
+#: float32 at every step of the kernel's grid.
 GMM_TILES = {1536: (512, 1024, 768), 1792: (128, 0, 896),
-             (2560, 768): (256, 1280, 768), (768, 2560): (256, 0, 1280)}
+             (2560, 768): (256, 1280, 768), (768, 2560): (256, 0, 1280),
+             (1024, 2688): (128, 0, 896), (2688, 1024): (128, 896, 1024)}
 
 
 def gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
@@ -151,9 +177,12 @@ def softmax_topk_route(h, router_kernel, k: int) -> Tuple[jax.Array, jax.Array]:
 # -- the two passes ------------------------------------------------------------
 #
 # ``order = (perm, inverse, n_live)`` describes the row buffer: ``perm`` a
-# permutation of its ``tokens x k`` positions (row ``r`` belongs to token
+# permutation of the ``tokens x k`` assignments (row ``r`` belongs to token
 # ``perm[r] // k``), ``inverse`` its inverse (choice ``j`` of token ``t`` lies
 # in row ``inverse[t * k + j]``), and the rows from 0 to ``n_live - 1`` live.
+# A buffer *bounded* below ``tokens x k`` rows has the first ``rows`` entries
+# of ``perm`` and the whole of ``inverse``: a choice whose place lies past the
+# buffer is past ``n_live`` too, and dead.
 
 
 def _live(rows: int, n_live):
@@ -165,6 +194,28 @@ def _reorder(values, index, index_inverse):
     with inverse ``index_inverse``, as a sort by ``index_inverse``: 0.02 ms
     for 32,768 scalars on the chip, where the gather takes 0.25."""
     return jax.lax.sort((index_inverse, values), num_keys=1)[1]
+
+
+def _bounded(order) -> bool:
+    return order[0].shape[0] < order[1].shape[0]
+
+
+def _to_rows(by_choice, order):
+    """One scalar a choice as one a row of the buffer."""
+    perm, inverse, _ = order
+    by_row = _reorder(by_choice, perm, inverse)
+    return by_row[:perm.shape[0]] if _bounded(order) else by_row
+
+
+def _to_choices(by_row, order):
+    """One scalar a row of the buffer as one a choice; a choice whose place
+    lies past a bounded buffer takes zero."""
+    perm, inverse, _ = order
+    if _bounded(order):
+        # the whole permutation again, from its inverse
+        by_row = jnp.pad(by_row, (0, inverse.shape[0] - perm.shape[0]))
+        perm = _reorder(jnp.arange(inverse.shape[0], dtype=inverse.dtype), perm, inverse)
+    return _reorder(by_row, inverse, perm)
 
 
 def _row_dots(buffer, src, order, fan: int):
@@ -182,10 +233,11 @@ PAD_ROWS = 16
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def spread(src, scale, order, fan: int):
-    """Row ``r`` of the ``rows(src) * fan`` buffer takes ``scale[r] *
-    src[perm[r] // fan]``, rounded once (``src[perm[r] // fan]`` where
-    ``scale`` is ``None``), where ``r < n_live``, and zero where not.  One
-    gather from the ``rows(src)`` rows; the transpose of :func:`collect`.
+    """Row ``r`` of the buffer (``rows(src) * fan`` rows, or as many as it
+    is bounded to) takes ``scale[r] * src[perm[r] // fan]``, rounded once
+    (``src[perm[r] // fan]`` where ``scale`` is ``None``), where ``r <
+    n_live``, and zero where not.  One gather from the ``rows(src)`` rows;
+    the transpose of :func:`collect`.
 
     Without a scale the select is in the index: a dead row takes a row of
     zeros put under ``src``, and the gather is the whole pass.  With one, the
@@ -207,8 +259,7 @@ def _spread_bwd(fan, res, grad):
     src, scale, order = res
     if scale is None:
         return collect(grad, None, order, fan), None, None
-    perm, inverse, _ = order
-    weight = _reorder(scale, inverse, perm).reshape(-1, fan)
+    weight = _to_choices(scale, order).reshape(-1, fan)
     return collect(grad, weight, order, fan), _row_dots(grad, src, order, fan), None
 
 
@@ -230,13 +281,40 @@ def collect(buffer, weight, order, fan: int):
     gathered arrays of every layer stay live between the two."""
     _, inverse, n_live = order
     index = inverse.reshape(-1, fan)
-    total = 0.0
-    for j in range(fan):
-        taken = buffer[index[:, j]].astype(jnp.float32)
-        if weight is not None:
-            taken = weight[:, j, None] * taken
-        total = total + jnp.where((index[:, j] < n_live)[:, None], taken, 0)
-    return jax.lax.optimization_barrier(total.astype(buffer.dtype))
+    if _bounded(order):
+        # a token has at most rows(buffer) / tokens live choices: they come to
+        # the front of its ``fan``, a dead one's key past every row
+        fan = buffer.shape[0] // index.shape[0]
+        keyed = jnp.where(index < n_live, index, inverse.shape[0])
+        if weight is None:
+            index = jax.lax.sort(keyed, dimension=1)[:, :fan]
+        else:
+            index, weight = (
+                a[:, :fan] for a in jax.lax.sort((keyed, weight), dimension=1, num_keys=1))
+
+    def gathered(rows, at):
+        total = 0.0
+        for j in range(fan):
+            taken = rows[at[:, j]].astype(jnp.float32)
+            if weight is not None:
+                taken = weight[:, j, None] * taken
+            total = total + jnp.where((index[:, j] < n_live)[:, None], taken, 0)
+        return total.astype(buffer.dtype)
+
+    if not _bounded(order):
+        total = gathered(buffer, index)
+    else:
+        # what points past the rows read is clamped and selected away.  Live
+        # rows lie first: where no more than ``tokens`` are live, which holds
+        # whenever the load is near its expectation, the gathers read the
+        # buffer's first ``tokens`` rows alone, a source that fits fast memory
+        def from_first(count):
+            return lambda: gathered(buffer[:count], jnp.minimum(index, count - 1))
+
+        tokens, rows = index.shape[0], buffer.shape[0]
+        total = from_first(rows)() if rows <= tokens else jax.lax.cond(
+            n_live <= tokens, from_first(tokens), from_first(rows))
+    return jax.lax.optimization_barrier(total)
 
 
 def _collect_fwd(buffer, weight, order, fan):
@@ -247,9 +325,8 @@ def _collect_bwd(fan, res, grad):
     buffer, weight, order = res
     if weight is None:
         return spread(grad, None, order, fan), None, None
-    perm, inverse, _ = order
-    scale = _reorder(weight.reshape(-1), perm, inverse)
-    dots = _reorder(_row_dots(buffer, grad, order, fan), inverse, perm)
+    scale = _to_rows(weight.reshape(-1), order)
+    dots = _to_choices(_row_dots(buffer, grad, order, fan), order)
     return spread(grad, scale, order, fan), dots.reshape(weight.shape).astype(weight.dtype), None
 
 
@@ -281,32 +358,42 @@ def grouped_matmul(rows, kernels, group_sizes):
 def dropless_experts(x, chosen, weights, gate, up, down, held: Tuple[int, int],
                      num_experts: int, activation=jax.nn.silu):
     """The held experts' part of ``sum_j weights[:, j] * E_chosen[:, j](x)``,
-    ``E(u) = down(activation(gate u) * up u)``.
+    ``E(u) = down(activation(gate u) * up u)``, or ``down(activation(up u))``
+    where ``gate`` is ``None``.
 
     ``x`` (tokens, hidden); ``chosen``/``weights`` from one of the routers
-    above; ``gate``, ``up`` (held, hidden, width) and ``down`` (held, width,
-    hidden) the kernels of experts ``held[0] .. held[0] + held[1] - 1`` of
-    ``num_experts``; ``activation`` the gate's, SiLU (SwiGLU) unless the
-    caller's model has another."""
+    above (a token's ``k`` choices distinct); ``gate``, ``up`` (held, hidden,
+    width) and ``down`` (held, width, hidden) the kernels of experts
+    ``held[0] .. held[0] + held[1] - 1`` of ``num_experts``; ``activation``
+    the gate's, SiLU (SwiGLU) unless the caller's model has another, or the
+    ungated unit's own.  The buffer has ``tokens x min(k, held[1])`` rows."""
     tokens, k = chosen.shape
     first, count = held
+    rows = tokens * min(k, count)
     with model_scope("moe_dispatch"):
         # held experts get keys 0 .. count-1, so their rows sort to the front
         key = ((chosen - first) % num_experts).reshape(-1)
         perm = jnp.argsort(key, stable=True)
         inverse = jnp.argsort(perm)
+        if rows < tokens * k:
+            perm = perm[:rows]
         sizes = jnp.sum(key[:, None] == jnp.arange(count), axis=0, dtype=jnp.int32)
         n_live = jnp.sum(sizes)
         order = (perm, inverse, n_live)
-        live = _live(tokens * k, n_live)[:, None]
-        for_gate, for_up = _for_two_readers(spread(x, None, order, k))
+        live = _live(rows, n_live)[:, None]
+        buffer = spread(x, None, order, k)
+        if gate is not None:
+            for_gate, for_up = _for_two_readers(buffer)
     with model_scope("moe_experts"):
         # each product's result is masked before anything reads it: on the
         # chip the rows past the held groups are whatever the buffer held
         def product(lhs, kernels):
             return jnp.where(live, grouped_matmul(lhs, kernels.astype(x.dtype), sizes), 0)
 
-        hidden = activation(product(for_gate, gate)) * product(for_up, up)
+        if gate is None:
+            hidden = activation(product(buffer, up))
+        else:
+            hidden = activation(product(for_gate, gate)) * product(for_up, up)
         out = product(hidden, down)
     with model_scope("moe_combine"):
         return collect(out, weights, order, k)

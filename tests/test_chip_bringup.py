@@ -366,6 +366,19 @@ for width_in, width_out in ((2560, 768), (768, 2560)):
         shape((49152, width_out)), shape((49152, width_in)), shape((8, width_in, width_out)),
         sizes).compile().as_text()
     print("WIDTH-768-KERNELS", text.count("tpu_custom_call"), flush=True)
+# nemotron-3-super.dp1-s8192 (PR 45): 4 query heads on 1 key-value head x 8,192 x 128, no
+# positions; ungated experts of width 2688 in a latent width of 1,024, a buffer of eight rows
+# a token (22 chosen of 512, 8 held) at the tiles measured for the two shapes
+q, kv = shape((1, 4, 8192, 128)), shape((1, 1, 8192, 128))
+text = jax.jit(both_passes(lambda q, k, v: causal_attention(q, k, v, 1.0))).lower(
+    q, q, kv, kv).compile().as_text()
+print("GROUP-OF-4-AT-128-KERNELS", text.count("tpu_custom_call"), flush=True)
+for width_in, width_out in ((1024, 2688), (2688, 1024)):
+    text = jax.jit(lambda out_grad, rows, kernels, group_sizes: both_passes(
+        lambda r, k: grouped_matmul(r, k, group_sizes))(out_grad, rows, kernels)).lower(
+        shape((65536, width_out)), shape((65536, width_in)), shape((8, width_in, width_out)),
+        sizes).compile().as_text()
+    print("LATENT-WIDTH-KERNELS", text.count("tpu_custom_call"), flush=True)
 # the cell's whole step under the engine's compiler options: inside it the fused
 # backward kernel needs 0.3 to 0.4 MB more fast memory than compiled alone (PR 30)
 from bagua_tpu.ddp import STEP_COMPILER_OPTIONS
@@ -530,6 +543,12 @@ def test_the_expert_models_kernels_compile_for_the_chip_at_the_cells_shapes():
           if line.startswith(("WINDOW-KERNELS", "GROUP-OF-7-KERNELS", "WIDTH-768-KERNELS"))]
     assert st == [["WINDOW-KERNELS", "2"], ["GROUP-OF-7-KERNELS", "2"],
                   ["WIDTH-768-KERNELS", "3"], ["WIDTH-768-KERNELS", "3"]], st
+    # nemotron-3-super.dp1-s8192 (PR 45): the multi-query kernels at four queries a key of 128,
+    # and the grouped products between the latent 1,024 and the experts' 2,688
+    latent = [line.split() for line in proc.stdout.splitlines()
+              if line.startswith(("GROUP-OF-4-AT-128-KERNELS", "LATENT-WIDTH-KERNELS"))]
+    assert latent == [["GROUP-OF-4-AT-128-KERNELS", "2"], ["LATENT-WIDTH-KERNELS", "3"],
+                      ["LATENT-WIDTH-KERNELS", "3"]], latent
     # five layers' forward and fused backward kernels in the step the cell runs
     step = next(line.split() for line in proc.stdout.splitlines()
                 if line.startswith("STEP-ATTENTION-KERNELS"))
