@@ -68,17 +68,19 @@ projections), ``moe_route`` / ``moe_dispatch`` / ``moe_experts`` /
 **What is kept for the backward pass and what is built again** (8,192
 positions, the benchmark's share; each decided by a plain SGD step's time of
 one such layer on the chip beside the bytes the step compiled for the v5e
-holds: ``PERF.md`` section 6, PR 45).  Built again: the convolution's float32
-taps and SiLU from ``xBC`` (its hand-written backward, as
-``lfm2_moe.gated_short_conv`` has one), and the ``D`` term, gate and group norm
+holds: ``PERF.md`` section 6, PR 45 and PR 46).  Built again: the
+convolution's float32 taps and SiLU from ``xBC`` (its hand-written backward,
+as ``lfm2_moe.gated_short_conv`` has one), the ``D`` term, gate and group norm
 from the scan's result, ``x`` and ``z`` (:func:`_skip_gate_norm`: kept they
 cost 157 MB a layer *and* 0.69 ms, 37.22 ms a mixer layer's step against
-36.53).  Kept: the scan's chunk arrays (160 MB a layer over rebuilding them,
-0.49 ms faster, ``kernels/ssd_scan.py``), the routed experts' squared ReLU
-beside the up product's result (232 MB a layer at the buffer's 65,536 rows of
-2,688 columns, 1.95 ms faster than building it again: 59.60 ms an expert
-layer's step against 61.56), and every product's operands.  The cell's step
-holds 11.5 GiB of the chip's 15.75 with all of that.
+36.53), and, on a TPU, a chunk's decays and scores inside the scan's backward
+kernel, in fast memory (``kernels/ssd_scan.py``: the forward kernel keeps the
+state at each chunk's start, 33.6 MB a layer, and nothing else of its own;
+elsewhere the plain form keeps what autodiff keeps).  Kept: the routed
+experts' squared ReLU beside the up product's result (232 MB a layer at the
+buffer's 65,536 rows of 2,688 columns, 1.95 ms faster than building it again:
+59.60 ms an expert layer's step against 61.56), and every product's operands.
+The cell's step holds 10.95 GiB of the chip's 15.75 with all of that.
 """
 
 import dataclasses
@@ -286,11 +288,16 @@ causal_conv_silu.defvjp(_causal_conv_silu_fwd, _causal_conv_silu_bwd)
 @jax.checkpoint
 def _skip_gate_norm(y, x, z, skip, scale, eps):
     """``RMSNorm_group((y + D x) * silu(z)) * w`` over each group's columns,
-    ``(batch, positions, groups, heads a group, head size)`` each, in float32
-    and rounded once; built again from its inputs in the backward pass."""
+    ``(batch, positions, groups, heads a group x head size)`` each with
+    ``skip`` and ``scale`` a column's, in float32 and rounded once; built again
+    from its inputs in the backward pass.  A group's columns are the last
+    dimension, whole: with a head's 64 last the compiler lays the positions
+    along the lanes, and every array between this and the scan's kernels,
+    which take the columns there, is copied across (``PERF.md`` section 6,
+    PR 46)."""
     f32 = jnp.float32
-    gated = (y.astype(f32) + skip[..., None] * x.astype(f32)) * jax.nn.silu(z.astype(f32))
-    mean = jnp.mean(jnp.square(gated), axis=(-2, -1), keepdims=True)
+    gated = (y.astype(f32) + skip * x.astype(f32)) * jax.nn.silu(z.astype(f32))
+    mean = jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
     return (gated * jax.lax.rsqrt(mean + eps) * scale).astype(y.dtype)
 
 
@@ -341,10 +348,11 @@ class Mamba2Mixer(_Kernels):
             b, c = (xbc[..., inner + n * groups * state:inner + (n + 1) * groups * state].reshape(
                 batch, t, groups, state) for n in (0, 1))
             y = ssd_scan(x, jax.nn.softplus(dt + dt_bias), -jnp.exp(a_log), b, c, cfg.chunk_size)
-            by_group = (batch, t, groups, per, size)
+            by_group = (batch, t, groups, per * size)
             y = _skip_gate_norm(
                 y.reshape(by_group), x.reshape(by_group), z_xbc[..., :inner].reshape(by_group),
-                skip.reshape(groups, per), scale.reshape(groups, per, size), cfg.layer_norm_epsilon)
+                jnp.repeat(skip, size).reshape(groups, per * size),
+                scale.reshape(groups, per * size), cfg.layer_norm_epsilon)
         with model_scope("ssm_proj"):
             return _matmul(y.reshape(batch, t, inner), self.kernel("out_proj", inner, hidden), dtype)
 

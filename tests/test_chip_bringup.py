@@ -585,6 +585,87 @@ def test_the_expert_models_kernels_compile_for_the_chip_at_the_cells_shapes():
     assert not [words for words in lines if words[0] == "MACHINERY-KERNEL"], proc.stdout
 
 
+
+_SCAN_CENSUS = """
+import re, sys
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+except Exception as e:  # no libtpu, or it cannot start compile-only here
+    print("NO-TOPOLOGY", type(e).__name__, e)
+    sys.exit(3)
+# the scan's choice is by backend, and here the backend is the CPU: steer it, in the test
+jax.default_backend = lambda: "tpu"
+from bagua_tpu.ddp import STEP_COMPILER_OPTIONS
+from benchmark import manifest
+device = SingleDeviceSharding(topo.devices[0])
+cell = manifest.load_cell("nemotron-3-super.dp1-s8192")
+cell.config["hybrid_override_pattern"], cell.config["num_hidden_layers"] = "M", 1
+sizes = cell.sizes
+on_chip = lambda tree: jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=device), tree)
+params = on_chip(jax.eval_shape(lambda k: cell.adapter.to_program(cell.adapter.as_stored(
+    cell.reference.init_params(k, sizes)), sizes), jax.random.PRNGKey(0)))
+batch = on_chip(jax.eval_shape(lambda k: cell.adapter.draw_batch(k, 1, sizes), jax.random.PRNGKey(0)))
+loss_fn = cell.adapter.build_loss(sizes)
+
+
+def sgd_step(params, batch):
+    loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+    return jax.tree.map(lambda p, g: p - 0.01 * g.astype(p.dtype), params, grads), loss
+
+
+text = jax.jit(sgd_step, donate_argnums=(0,), compiler_options=STEP_COMPILER_OPTIONS["tpu"]).lower(
+    params, batch).compile().as_text()
+# every instruction, those inside the fusions too: the compiler fuses the plain form's decays
+# into the products that read them, so they are no result of the entry computation there either
+for line in text.splitlines():
+    m = re.match(r"\\s*(?:ROOT )?%(\\S+) = (.*?) ([a-z][\\w-]*)\\(", line)
+    op_name = re.search(r'op_name="([^"]*)"', line)
+    if not m or not op_name or "part=ssm_core" not in op_name.group(1):
+        continue
+    op_name = op_name.group(1)
+    if "tpu_custom_call" in line:
+        print("SCAN-KERNEL", m.group(1), "backward" if "transpose(" in op_name else "forward",
+              "ssd_scan" in op_name, flush=True)
+    for dims in re.findall(r"f32\\[([\\d,]+)\\]", m.group(2)):
+        count = 1
+        for d in dims.split(","):
+            count *= int(d)
+        if count >= 64 * 128 * 128 * 16:
+            print("SCAN-DECAY-SIZED", m.group(1), dims, op_name[-60:].replace(" ", "_"), flush=True)
+"""
+
+
+def test_the_mixers_step_holds_the_scans_two_kernels_and_no_array_of_decays():
+    """The engagement check of ``kernels/ssd_scan.py`` on a TPU (PR 46): a
+    plain SGD step of one Mamba-2 mixer between the slice's embedding and head,
+    at the shapes of ``nemotron-3-super.dp1-s8192`` (8,192 positions, 16 heads
+    of 64, one group, state 128, chunks of 128), compiled for a described v5e
+    with the backend steered to the TPU.  It holds the scan's forward and
+    backward kernels as Mosaic calls, both under ``bagua_model/part=ssm_core``
+    (the backward under autodiff's ``transpose(`` frame, where the trace's
+    reduction looks for it), and no float32 array of ``chunks x chunk x chunk
+    x heads`` = 16.8 M elements (67.1 MB) under that scope, inside a fusion or
+    out of it: the plain form's text has thirteen instructions with such a
+    result, and they were 9.5 ms of the cell's 219 ms step.
+    The chunk-start states the backward pass keeps are half that (33.6 MB)."""
+    proc = subprocess.run(
+        [sys.executable, "-c", _SCAN_CENSUS],
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO_ROOT),
+        capture_output=True, text=True, timeout=600,
+    )
+    if proc.returncode == 3:
+        pytest.skip(proc.stdout.strip()[-300:])
+    assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-3000:]
+    lines = [line.split() for line in proc.stdout.splitlines()]
+    kernels = sorted(words[2:] for words in lines if words[0] == "SCAN-KERNEL")
+    assert kernels == [["backward", "True"], ["forward", "True"]], proc.stdout
+    assert not [words for words in lines if words[0] == "SCAN-DECAY-SIZED"], proc.stdout
+
+
 _HEAD_CENSUS = """
 import re, sys
 import jax, jax.numpy as jnp
