@@ -60,12 +60,14 @@ IMPORT_SPAN = format_host_span("setup/import")
 #: ``init_process_group``, ``Trainer.__init__``, ``Trainer.init_state``
 INIT_SPANS = tuple(format_host_span(f"setup/{key}") for key in ("group", "trainer", "init_state"))
 #: what a ``Trainer`` with a ``profile_dir`` does inside the build for the
-#: step's text: ``lower().compile().as_text()``.  JAX keeps the jaxpr, the
-#: module and the executable it makes there, and the dispatch that follows
-#: traces, lowers and compiles nothing: the programs under this span are the
-#: step's, and what the span holds besides them is what a traced run adds
+#: step's text: ``as_text()`` of the executable the build compiled (since PR
+#: 47; it lowered and compiled the step itself before, and a record that
+#: holds programs under this span still has them counted as the step's)
 TEXT_SPAN = format_host_span("step/text")
-#: a missed step variant: building it, and the dispatch that compiles it
+#: a missed step variant: building it, which traces, lowers and compiles it,
+#: and the dispatch after it, whose one cold event of its own is the state's
+#: move into the layouts the step takes it in (``bagua_host/step/layout``,
+#: ``detail``: the variant, the leaves moved and their bytes a device)
 STEP_SPANS = tuple(format_host_span(f"step/{key}") for key in ("build", "dispatch")) + (TEXT_SPAN,)
 
 #: the classes of :func:`setup_snapshot`, which share no instant
@@ -211,9 +213,8 @@ def setup_snapshot(until: Optional[float] = None) -> dict:
     * ``step_trace`` / ``step_compile``: tracing and lowering / the backend's
       compile or cache load, inside the build or the first dispatch of a step
       variant that was missed;
-    * ``step_text``: what making the step's text (traced runs only) took
-      beyond the step's own tracing, lowering and compile, which happen
-      inside it;
+    * ``step_text``: what printing the step's text (traced runs only) took,
+      less any program made inside it;
     * ``other_programs``: tracing, lowering and backend compile of every
       other program, with ``other_programs_count`` (backend compiles) and
       ``other_programs_longest``, the five largest ``(name, seconds,

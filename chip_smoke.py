@@ -278,9 +278,8 @@ def check_after_steps(run: Run, n: int) -> str:
 
 
 def compiled_step_text(run: Run) -> str:
-    ddp = run.trainer.ddp
-    fn = ddp._build_step(ddp.impl.step_variant(ddp._host_step))
-    return fn.lower(run.state, run.batch).compile().as_text()
+    """The text of the step the run trained with: of the executable itself."""
+    return run.trainer.ddp.compiled_step().as_text()
 
 
 def spanning_census(text: str, n: int) -> dict:

@@ -56,7 +56,7 @@ def measure_overlap(ddp, state, batch, label):
     if fn is None:
         state, _ = ddp.train_step(state, batch)  # populate the jit cache
         fn = ddp.compiled_step()
-    hlo = fn.lower(state, batch).compile().as_text()
+    hlo = fn.as_text()
     prof_dir = tempfile.mkdtemp(prefix=f"bagua_autotune_{label}_")
     state, _ = ProfilerSession(prof_dir).trace_steps(ddp.train_step, state, [batch])
     analysis = analyze_trace(prof_dir, hlo_text=hlo)

@@ -215,6 +215,24 @@ def test_change_drops_every_record_and_the_next_step_captures_anew(group, name):
         ddp.shutdown()
 
 
+def test_a_kept_step_text_leaves_the_recorder_its_program(group):
+    """The step is traced once, in the build and under the recorder's
+    capture, and its text is read off the executable that was compiled
+    there.  Until PR 47 ``keep_step_text`` lowered the step before its first
+    dispatch, the dispatch then traced nothing, and a recorder beside a
+    ``profile_dir`` replayed an empty program (ROADMAP D5)."""
+    flight = FlightRecorder(capacity=256, rank=0, world_size=1)
+    ddp, state = _engine(group, ENTRIES["rebucket"], Telemetry(flight=flight))
+    ddp.keep_step_text = True
+    try:
+        state, _ = ddp.train_step(state, _batch())
+        assert ddp.step_text() == ddp.compiled_step().as_text()
+        assert ddp.flight_program()
+        _assert_program_of(ddp, ddp.flight_program())
+    finally:
+        ddp.shutdown()
+
+
 @pytest.mark.parametrize("name", ENTRIES)
 def test_rejected_change_leaves_the_old_configuration(group, name, monkeypatch):
     entry = ENTRIES[name]

@@ -319,18 +319,21 @@ def test_a_start_leaves_its_cold_spans_in_order(started):
 
 
 @pytest.mark.parametrize("event", [TRACE_EVENT, LOWERING_EVENT, BACKEND_COMPILE_EVENT])
-def test_the_cold_dispatch_holds_the_steps_trace_lowering_and_compile(started, event):
-    dispatch = named(started["events"], DISPATCH, "default")[0]
-    inside = [e for e in charged_to(started["events"], DISPATCH, "default")
+def test_the_cold_build_holds_the_steps_trace_lowering_and_compile(started, event):
+    build = named(started["events"], BUILD, "default")[0]
+    inside = [e for e in charged_to(started["events"], BUILD, "default")
               if e.name in cold_start.PROGRAM_EVENTS]
     mine = [e for e in inside if e.name == event]
     assert mine and sum(e.end - e.start for e in mine) > 0
     assert all("local_step" in e.detail for e in mine)
-    # the three kinds follow one another inside the dispatch's interval
-    assert sum(e.end - e.start for e in inside) <= dispatch.end - dispatch.start
-    assert all(dispatch.start <= e.start and e.end <= dispatch.end for e in inside)
+    # the three kinds follow one another inside the build's interval
+    assert sum(e.end - e.start for e in inside) <= build.end - build.start
+    assert all(build.start <= e.start and e.end <= build.end for e in inside)
     # every jit inside the step is traced inside the step's trace, and is the step's
     assert len([e for e in inside if e.name == TRACE_EVENT]) == 1
+    # ... and the dispatch that follows runs what the build compiled
+    assert not [e for e in charged_to(started["events"], DISPATCH, "default")
+                if e.name in cold_start.PROGRAM_EVENTS]
 
 
 def test_only_the_outermost_stretch_of_a_thread_goes_on_the_record(record):
@@ -350,12 +353,12 @@ def test_only_the_outermost_stretch_of_a_thread_goes_on_the_record(record):
     assert cold_start._open.depth == 0 and record[-1].name == TRACE_EVENT
 
 
-def test_the_hub_takes_the_measured_compile_and_not_the_dispatchs_wall(started):
-    dispatch = named(started["events"], DISPATCH, "default")[0]
+def test_the_hub_takes_the_measured_compile_and_not_the_builds_wall(started):
+    build = named(started["events"], BUILD, "default")[0]
     report = started["trainer"].telemetry.recompile.report()
     measured = 1e3 * cold_start.step_compile_seconds("default", since=started["begun"])
     assert report["compile_ms_by_variant"] == {"default": pytest.approx(measured, abs=1e-3)}
-    assert 0 < report["compile_ms_total"] < 1e3 * (dispatch.end - dispatch.start)
+    assert 0 < report["compile_ms_total"] < 1e3 * (build.end - build.start)
 
 
 def test_fit_logs_the_partition_once_and_the_report_stays(started):
@@ -414,16 +417,16 @@ def test_a_build_inside_the_window_is_named_with_its_variant(started, how, monke
     assert [(e.name, e.detail) for e in late if e.name.startswith("bagua_host/")] == [
         (BUILD, variant), (DISPATCH, variant)]
     compiled = [e for e in late if e.name == BACKEND_COMPILE_EVENT]
-    assert [(e.detail, e.under) for e in compiled] == [("jit(local_step)", (DISPATCH, variant))]
+    assert [(e.detail, e.under) for e in compiled] == [("jit(local_step)", (BUILD, variant))]
     assert compiled[0].end - compiled[0].start > 0
     # ... and in no class of the set-up that ended at ``since``
     assert cold_start.setup_snapshot(until=since) == before
     assert ddp.host_overhead_snapshot()["build_ms_per_step"] > 0
 
 
-def test_a_profiling_trainer_makes_the_step_inside_the_text_span(record, tmp_path):
-    """``lower().compile()`` for the step's text is where JAX traces, lowers
-    and compiles the step; the dispatch after it finds all three made."""
+def test_a_profiling_trainer_reads_the_text_of_the_step_the_build_made(record, tmp_path):
+    """The step's text is the text of the executable the build compiled and
+    the dispatch runs: nothing is traced, lowered or compiled for it."""
     metadata = jax.config.jax_compilation_cache_include_metadata_in_key
     group = bagua_tpu.init_process_group(devices=jax.devices()[:2])
     trainer = Trainer(mse_loss, optax.sgd(0.1), GradientAllReduceAlgorithm(), process_group=group,
@@ -436,10 +439,11 @@ def test_a_profiling_trainer_makes_the_step_inside_the_text_span(record, tmp_pat
         jax.config.update("jax_compilation_cache_include_metadata_in_key", metadata)
     text = named(record, TEXT, "default")
     assert len(text) == 1 and text[0].under == (BUILD, "default")
-    kinds = {e.name for e in charged_to(record, TEXT, "default")}
-    assert {TRACE_EVENT, LOWERING_EVENT, BACKEND_COMPILE_EVENT} <= kinds
-    assert not [e for e in charged_to(record, DISPATCH, "default")
-                if e.name in (LOWERING_EVENT, BACKEND_COMPILE_EVENT)]
+    assert not [e for e in charged_to(record, TEXT, "default") if e.name in cold_start.PROGRAM_EVENTS]
+    assert [e.name for e in charged_to(record, BUILD, "default")
+            if e.name in cold_start.PROGRAM_EVENTS] == [
+        TRACE_EVENT, LOWERING_EVENT, BACKEND_COMPILE_EVENT]
+    assert trainer.ddp.step_text() == trainer.ddp.compiled_step().as_text()
     found = trainer.startup_report()
     assert found["step_trace"] > 0 and found["step_compile"] > 0
-    assert 0 <= found["step_text"] < text[0].end - text[0].start
+    assert found["step_text"] == pytest.approx(text[0].end - text[0].start)

@@ -9,6 +9,7 @@ reduction of the same file, and on a CPU capture of ``Trainer.fit``."""
 import json
 import os
 import sys
+import time
 
 import jax
 import optax
@@ -17,6 +18,7 @@ import pytest
 import bagua_tpu
 from bagua_tpu.algorithms.gradient_allreduce import GradientAllReduceAlgorithm
 from bagua_tpu.models.mlp import init_mlp, mse_loss
+from bagua_tpu.observability import cold_start
 from bagua_tpu.observability import trace_analysis as ta
 from bagua_tpu.observability.annotations import host_span, timed_host_span
 from bagua_tpu.observability.scope_grammar import (
@@ -387,27 +389,14 @@ def test_a_trainer_without_profile_dir_keeps_no_text_and_no_summary(group):
     trainer = Trainer(mse_loss, optax.sgd(0.1), GradientAllReduceAlgorithm(),
                       process_group=group, watchdog_timeout_s=0)
     try:
-        lowered = []
-        build = trainer.ddp._build_step
-
-        def counting_build(variant):
-            fn = build(variant)
-
-            class Counted:
-                def __call__(self, *args):
-                    return fn(*args)
-
-                def lower(self, *args):
-                    lowered.append(variant)
-                    return fn.lower(*args)
-
-            return Counted()
-
-        trainer.ddp._build_step = counting_build
+        began = time.perf_counter()
         state = trainer.init_state(init_mlp(jax.random.PRNGKey(0), LAYERS))
         trainer.fit(state, batches(3), log_every=0)
         assert not trainer.ddp.keep_step_text and trainer.ddp.step_text() is None
-        assert lowered == [] and trainer.profile_summary is None
+        # ... and none was made: no text span on the process's cold record
+        assert not [e for e in cold_start.cold_events()
+                    if e.name == cold_start.TEXT_SPAN and e.start >= began]
+        assert trainer.profile_summary is None
     finally:
         trainer.close()
 

@@ -71,7 +71,7 @@ def make_ddp(group, overlap, telemetry=None, bucket_size=1 << 9):
 
 def compiled_hlo(ddp, state, batch):
     """Compiled HLO text of the step variant last dispatched."""
-    return ddp.compiled_step().lower(state, batch).compile().as_text()
+    return ddp.compiled_step().as_text()
 
 
 def op_name_labels(hlo):
