@@ -32,6 +32,9 @@ operands and accumulate in float32; norms, the rotation, the router, the
 logits and the loss are float32.  Each part of the forward pass sits under a
 ``bagua_model/part=...`` scope, so a device trace tells the parts apart.
 
+The parts shared with the other decoder models are ``models/decoder.py``'s
+(``RMSNorm``, ``Kernels``, ``matmul``, ``product``, ``HEADS_MAJOR``, ``SwiGLU``).
+
 How the attention's operands are written (:func:`latent_qk`; ``PERF.md``
 section 6, PR 32).  The attention kernels read ``q``, ``k``, ``v`` as
 ``(batch, heads, positions, head size)``, so the products contract onto that
@@ -62,12 +65,11 @@ import math
 from typing import Any, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from bagua_tpu.kernels.causal_attention import causal_attention
+from bagua_tpu.models.decoder import HEADS_MAJOR, Kernels, RMSNorm, SwiGLU, matmul, product
 from bagua_tpu.models.embedding import embed
-from bagua_tpu.models.llama import RMSNorm
 from bagua_tpu.models.losses import softmax_cross_entropy
 from bagua_tpu.observability.annotations import model_scope
 from bagua_tpu.parallel.moe.dropless import dropless_experts, sigmoid_topk_route
@@ -147,33 +149,6 @@ def glm_moe_test_config(**overrides) -> GlmMoeConfig:
     return GlmMoeConfig(**kwargs)
 
 
-def _matmul(x, kernel, dtype):
-    """``x @ kernel`` with ``dtype`` operands, float32 accumulation, ``dtype``
-    result."""
-    return jnp.dot(x.astype(dtype), kernel.astype(dtype),
-                   preferred_element_type=jnp.float32).astype(dtype)
-
-
-class _Kernels(nn.Module):
-    """Float32 kernels, normal(0, 0.02), declared by shape."""
-
-    def kernel(self, name: str, *shape: int):
-        return self.param(name, nn.initializers.normal(0.02), shape, jnp.float32)
-
-
-def _product(pattern: str, x, kernel, dtype):
-    """``einsum(pattern, x, kernel)`` with ``dtype`` operands, float32
-    accumulation, ``dtype`` result: the contraction writes the layout its
-    reader takes."""
-    return jnp.einsum(pattern, x.astype(dtype), kernel.astype(dtype),
-                      preferred_element_type=jnp.float32).astype(dtype)
-
-
-#: ``(batch, positions, rank)`` times ``(rank, heads, size)`` as the attention
-#: kernels read it, ``(batch, heads, positions, size)``
-HEADS_MAJOR = "btr,rhd->bhtd"
-
-
 def pairs_apart(kernel):
     """The stored (interleaved) rotary columns of a weight ``(..., rope)`` as
     the first of every pair and the second of every pair, ``(..., rope / 2)``
@@ -186,7 +161,7 @@ def _rotate(first, second, rope: int, theta: float, scale: float = 1.0):
     """The rotary embedding between two half heads ``(..., positions,
     width)``, in float32: the last ``rope / 2`` columns of ``first`` and of
     ``second`` are the first and the second of the rotary pairs and get the
-    two numbers :func:`~bagua_tpu.models.llama.apply_rope` gives the pair,
+    two numbers the interleaved rotary embedding gives the pair,
     the columns before them have no position; all times ``scale``."""
     t, width = first.shape[-2:]
     plain = width - rope // 2
@@ -209,7 +184,7 @@ def latent_qk(c_q, c_kv, k_rope, q_up, k_up, rope: int, theta: float, dtype):
     nope = k_up.shape[-1]
     a, half = nope // 2, (nope + rope) // 2
     firsts, seconds = pairs_apart(q_up[..., nope:])
-    y = _product(HEADS_MAJOR, c_q, jnp.concatenate(
+    y = product(HEADS_MAJOR, c_q, jnp.concatenate(
         [q_up[..., :a], firsts, q_up[..., a:nope], seconds], axis=-1), dtype)
     q = jnp.concatenate(_rotate(y[..., :half], y[..., half:], rope, theta,
                                 1.0 / math.sqrt(nope + rope)), axis=-1).astype(dtype)
@@ -220,10 +195,10 @@ def latent_qk(c_q, c_kv, k_rope, q_up, k_up, rope: int, theta: float, dtype):
     shared = jnp.concatenate([
         jnp.pad(r.astype(dtype), ((0, 0), (0, 0), (a, 0)))
         for r in _rotate(k_rope[..., :rope // 2], k_rope[..., rope // 2:], rope, theta)], axis=-1)
-    return q, _product(HEADS_MAJOR, c_kv, k_up, dtype) + shared[:, None]
+    return q, product(HEADS_MAJOR, c_kv, k_up, dtype) + shared[:, None]
 
 
-class LatentAttention(_Kernels):
+class LatentAttention(Kernels):
     cfg: GlmMoeConfig
 
     @nn.compact
@@ -243,32 +218,20 @@ class LatentAttention(_Kernels):
             kv_down = self.kernel("kv_down", hidden, rank + rope)
             kv_up = self.kernel("kv_up", rank, heads * (nope + dv)).reshape(rank, heads, nope + dv)
             out = self.kernel("out", heads * dv, hidden).reshape(heads, dv, hidden)
-            c_q = RMSNorm(cfg.rms_norm_eps, name="q_norm")(_matmul(x, q_down, dt))
-            down = _matmul(x, jnp.concatenate(
+            c_q = RMSNorm(cfg.rms_norm_eps, name="q_norm")(matmul(x, q_down, dt))
+            down = matmul(x, jnp.concatenate(
                 (kv_down[:, :rank],) + pairs_apart(kv_down[:, rank:]), axis=1), dt)
             c_kv = RMSNorm(cfg.rms_norm_eps, name="kv_norm")(down[..., :rank])
             q, k = latent_qk(c_q, c_kv, down[..., rank:], q_up, kv_up[..., :nope],
                              rope, cfg.rope_theta, dt)
-            v = _product(HEADS_MAJOR, c_kv, kv_up[..., nope:], dt)
+            v = product(HEADS_MAJOR, c_kv, kv_up[..., nope:], dt)
         with model_scope("attn_core"):
             ctx = causal_attention(q, k, v, 1.0)
         with model_scope("attn_proj"):
-            return _product("bhtd,hdm->btm", ctx, out, dt)
+            return product("bhtd,hdm->btm", ctx, out, dt)
 
 
-class SwiGLU(_Kernels):
-    width: int
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, x):
-        hidden = x.shape[-1]
-        h = jax.nn.silu(_matmul(x, self.kernel("gate", hidden, self.width), self.dtype))
-        h = h * _matmul(x, self.kernel("up", hidden, self.width), self.dtype)
-        return _matmul(h, self.kernel("down", self.width, hidden), self.dtype)
-
-
-class SparseExperts(_Kernels):
+class SparseExperts(Kernels):
     """The router over all routed experts, the held experts' part of the
     routed result, and the shared expert."""
 
@@ -313,7 +276,7 @@ class GlmMoeBlock(nn.Module):
         return x + SparseExperts(cfg, name="moe")(h)
 
 
-class GlmMoeModel(_Kernels):
+class GlmMoeModel(Kernels):
     """``ids (batch, positions)`` to ``(logits, multi-token-prediction logits
     or None)``, both float32 ``(batch, positions, vocab)``.  Position ``i`` of
     the second predicts token ``i + 2``; its last two positions have no
@@ -346,7 +309,7 @@ class GlmMoeModel(_Kernels):
         joined = jnp.concatenate([
             RMSNorm(cfg.rms_norm_eps, name="mtp_embed_norm")(after),
             RMSNorm(cfg.rms_norm_eps, name="mtp_hidden_norm")(x)], axis=-1)
-        h = _matmul(joined, self.kernel("mtp_proj", 2 * cfg.hidden_size, cfg.hidden_size), dt)
+        h = matmul(joined, self.kernel("mtp_proj", 2 * cfg.hidden_size, cfg.hidden_size), dt)
         h = GlmMoeBlock(cfg, dense=False, name="mtp_block")(h)
         return logits, logits_of(h, "mtp_final_norm")
 

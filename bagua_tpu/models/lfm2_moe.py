@@ -22,7 +22,7 @@ no bias anywhere): ``x += Mixer(RMSNorm(x))``, ``x += FFN(RMSNorm(x))``.
   rotary embedding on all columns in the rotate-half pairing (column ``i``
   with ``i + size / 2``); each key-value head serves ``heads / kv heads``
   query heads (:func:`~bagua_tpu.kernels.causal_attention.causal_attention`);
-  ``W_o``.
+  ``W_o`` (``decoder.GroupedQueryAttention`` with ``norm_eps`` and ``rope_theta``).
 * the first ``num_dense_layers``: SwiGLU of ``intermediate_size``.
 * the rest: :func:`~bagua_tpu.parallel.moe.dropless.sigmoid_topk_route` over
   all ``num_experts`` (``use_expert_bias``: a bias that steers the choice and
@@ -40,12 +40,12 @@ the router, the logits and the loss are float32.  Each part of the forward
 pass sits under a ``bagua_model/part=...`` scope (``conv_proj``: the
 mixer's two products; ``conv_core``: gates and taps).
 
-How the operands are written (``PERF.md`` section 6, PR 32 and 33).  The
-attention kernels read ``(batch, heads, positions, head size)``, so the three
-products contract onto that layout in float32, one pass norms, rotates,
-scales (``q`` carries ``1 / sqrt(head size)``) and rounds each of ``q`` and
-``k``, and ``W_o`` contracts the kernels' result over ``(heads, head size)``.
-The short convolution is one pass forward (it reads ``[B | C | u]`` as the
+The parts shared with the other decoder models are ``models/decoder.py``'s
+(``RMSNorm``, ``Kernels``, ``matmul``, ``SwiGLU``, ``shift``, the attention
+layer and how its operands are written, the next-token loss).
+
+How the operands are written (``PERF.md`` section 6, PR 33).  The short
+convolution is one pass forward (it reads ``[B | C | u]`` as the
 product wrote it and writes ``C * c`` where the next product reads it) and
 one pass backward that builds ``z`` and ``c`` again from ``[B | C | u]`` and
 writes ``[dB | dC | du]`` as one array, where the product's two gradients
@@ -53,18 +53,15 @@ read it: nothing but the product's result is kept for the backward pass.
 """
 
 import dataclasses
-import math
 from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from bagua_tpu.kernels.causal_attention import causal_attention
+from bagua_tpu.models.decoder import (
+    GroupedQueryAttention, Kernels, RMSNorm, SwiGLU, matmul, next_token_loss_fn, shift)
 from bagua_tpu.models.embedding import embed
-from bagua_tpu.models.glm_moe import HEADS_MAJOR, SwiGLU, _Kernels, _matmul, _product
-from bagua_tpu.models.llama import RMSNorm
-from bagua_tpu.models.losses import softmax_cross_entropy
 from bagua_tpu.observability.annotations import model_scope
 from bagua_tpu.parallel.moe.dropless import dropless_experts, sigmoid_topk_route
 
@@ -161,22 +158,11 @@ def lfm2_moe_test_config(**overrides) -> Lfm2MoeConfig:
 # -- the gated short convolution ----------------------------------------------
 
 
-def _shift(x, by: int, axis: int = 1):
-    """``x`` moved ``by`` positions later along ``axis`` (earlier if
-    negative), zeros moving in."""
-    if by == 0:
-        return x
-    t = x.shape[axis]
-    pad = [(0, 0)] * x.ndim
-    pad[axis] = (max(by, 0), max(-by, 0))
-    return jax.lax.slice_in_dim(jnp.pad(x, pad), max(-by, 0), max(-by, 0) + t, axis=axis)
-
-
 def _gates_and_taps(bcu, taps):
     f32 = jnp.float32
     gate_b, gate_c, u = (part.astype(f32) for part in jnp.split(bcu, 3, axis=-1))
     z = gate_b * u
-    c = sum(taps[j].astype(f32) * _shift(z, j) for j in range(taps.shape[0]))
+    c = sum(taps[j].astype(f32) * shift(z, j) for j in range(taps.shape[0]))
     return gate_b, gate_c, u, z, c
 
 
@@ -201,8 +187,8 @@ def _gated_short_conv_bwd(res, dy):
     dy = dy.astype(jnp.float32)
     dc = dy * gate_c
     # z_t feeds c_{t+j} through tap j: the taps run against time
-    dz = sum(taps[j].astype(jnp.float32) * _shift(dc, -j) for j in range(taps.shape[0]))
-    d_taps = jnp.stack([jnp.sum(dc * _shift(z, j), axis=(0, 1)) for j in range(taps.shape[0])])
+    dz = sum(taps[j].astype(jnp.float32) * shift(dc, -j) for j in range(taps.shape[0]))
+    d_taps = jnp.stack([jnp.sum(dc * shift(z, j), axis=(0, 1)) for j in range(taps.shape[0])])
     d_bcu = jnp.concatenate([dz * u, dy * c, dz * gate_b], axis=-1).astype(bcu.dtype)
     return d_bcu, d_taps.astype(taps.dtype)
 
@@ -210,7 +196,7 @@ def _gated_short_conv_bwd(res, dy):
 gated_short_conv.defvjp(_gated_short_conv_fwd, _gated_short_conv_bwd)
 
 
-class ShortConv(_Kernels):
+class ShortConv(Kernels):
     cfg: Lfm2MoeConfig
 
     @nn.compact
@@ -218,66 +204,20 @@ class ShortConv(_Kernels):
         cfg, dt = self.cfg, self.cfg.compute_dtype
         hidden = x.shape[-1]
         with model_scope("conv_proj"):
-            bcu = _matmul(x, self.kernel("in_proj", hidden, 3 * hidden), dt)
+            bcu = matmul(x, self.kernel("in_proj", hidden, 3 * hidden), dt)
         with model_scope("conv_core"):
             # the taps at a scale that keeps the mixer's output near its input's
             taps = self.param("taps", nn.initializers.normal(cfg.conv_L_cache ** -0.5),
                               (cfg.conv_L_cache, hidden), jnp.float32)
             y = gated_short_conv(bcu, taps)
         with model_scope("conv_proj"):
-            return _matmul(y, self.kernel("out_proj", hidden, hidden), dt)
-
-
-# -- grouped-query attention with normed heads --------------------------------
-
-
-def rotate_half(x, theta: float, scale: float = 1.0):
-    """The rotary embedding on all columns of ``x (..., positions, size)`` in
-    the rotate-half pairing (column ``i`` with ``i + size / 2``), in float32,
-    times ``scale``."""
-    t, size = x.shape[-2:]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, size, 2, dtype=jnp.float32) / size))
-    ang = jnp.arange(t).astype(jnp.float32)[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
-    first, second = x[..., :size // 2].astype(jnp.float32), x[..., size // 2:].astype(jnp.float32)
-    return jnp.concatenate([first * cos - second * sin, second * cos + first * sin], axis=-1)
-
-
-class GroupedQueryAttention(_Kernels):
-    cfg: Lfm2MoeConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg, dt = self.cfg, self.cfg.compute_dtype
-        hidden, size = x.shape[-1], cfg.head_size
-        heads, kv_heads = cfg.num_attention_heads, cfg.num_key_value_heads
-
-        def heads_of(name, count, scale=None):
-            """One projection as the kernels read it; with ``scale`` normed
-            per head, rotated and scaled in float32, rounded once."""
-            kernel = self.kernel(name + "_proj", hidden, count * size).reshape(hidden, count, size)
-            if scale is None:
-                return _product(HEADS_MAJOR, x, kernel, dt)
-            y = jnp.einsum(HEADS_MAJOR, x.astype(dt), kernel.astype(dt),
-                           preferred_element_type=jnp.float32)
-            y = RMSNorm(cfg.norm_eps, name=name + "_norm")(y)
-            return rotate_half(y, cfg.rope_theta, scale).astype(dt)
-
-        with model_scope("attn_proj"):
-            q = heads_of("q", heads, 1.0 / math.sqrt(size))
-            k = heads_of("k", kv_heads, 1.0)
-            v = heads_of("v", kv_heads)
-            out = self.kernel("out_proj", heads * size, hidden).reshape(heads, size, hidden)
-        with model_scope("attn_core"):
-            ctx = causal_attention(q, k, v, 1.0)
-        with model_scope("attn_proj"):
-            return _product("bhtd,hdm->btm", ctx, out, dt)
+            return matmul(y, self.kernel("out_proj", hidden, hidden), dt)
 
 
 # -- the expert layer ---------------------------------------------------------
 
 
-class RoutedExperts(_Kernels):
+class RoutedExperts(Kernels):
     """The router over all routed experts and the held experts' part of the
     routed result; no shared expert."""
 
@@ -317,7 +257,9 @@ class Lfm2MoeBlock(nn.Module):
         if self.mixer == "conv":
             x = x + ShortConv(cfg, name="conv")(h)
         else:
-            x = x + GroupedQueryAttention(cfg, name="attn")(h)
+            x = x + GroupedQueryAttention(
+                cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_size, cfg.compute_dtype,
+                norm_eps=cfg.norm_eps, rope_theta=cfg.rope_theta, name="attn")(h)
         h = RMSNorm(cfg.norm_eps, name="ffn_norm")(x)
         if self.dense:
             with model_scope("dense_mlp"):
@@ -325,7 +267,7 @@ class Lfm2MoeBlock(nn.Module):
         return x + RoutedExperts(cfg, name="moe")(h)
 
 
-class Lfm2MoeModel(_Kernels):
+class Lfm2MoeModel(Kernels):
     """``ids (batch, positions)`` to float32 logits ``(batch, positions,
     vocab)`` through the embedding's transpose."""
 
@@ -344,14 +286,4 @@ class Lfm2MoeModel(_Kernels):
                               preferred_element_type=jnp.float32)
 
 
-def lfm2_moe_loss_fn(model: Lfm2MoeModel):
-    """Next-token cross entropy, mean over each sequence's ``positions - 1``
-    targets.  ``batch`` is the ids alone."""
-
-    def loss_fn(params, batch):
-        logits = model.apply({"params": params}, batch)
-        # every row against the token that follows it, the rows without one
-        # left out of the mean: a slice of the logits would be a copy of them
-        return jnp.mean(softmax_cross_entropy(logits, jnp.roll(batch, -1, axis=1))[:, :-1])
-
-    return loss_fn
+lfm2_moe_loss_fn = next_token_loss_fn

@@ -18,6 +18,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from bagua_tpu.models.decoder import RMSNorm
 from bagua_tpu.models.gpt import _sp_positions, lm_loss_fn  # noqa: F401  (re-exported)
 from bagua_tpu.parallel.ring_attention import _block_attention_local, ring_attention
 from bagua_tpu.parallel.tensor_parallel import ColumnParallelDense, RowParallelDense
@@ -78,18 +79,6 @@ def llama_test_config(**overrides) -> LlamaConfig:
     )
     kwargs.update(overrides)
     return LlamaConfig(**kwargs)
-
-
-class RMSNorm(nn.Module):
-    eps: float = 1e-5
-
-    @nn.compact
-    def __call__(self, x):
-        dtype = x.dtype
-        x = x.astype(jnp.float32)
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
-        y = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + self.eps)
-        return (y * scale).astype(dtype)
 
 
 def apply_rope(x, positions, theta: float):

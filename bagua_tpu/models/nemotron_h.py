@@ -42,6 +42,12 @@ a final norm and an output matrix of its own.  On ``a = RMSNorm(x)``:
 * ``-``, a dense MLP of ``intermediate_size``, ``W_d relu(W_u a)^2`` (in the
   family; not in this model's pattern).
 
+The parts shared with the other decoder models are ``models/decoder.py``'s.
+:class:`Attention` is this file's own, the one copy beside
+``decoder.GroupedQueryAttention``: it *divides* ``q`` by ``sqrt(head size)``
+where that multiplies by the reciprocal (another program by an ulp), and reads
+its head counts from the ranges this chip holds.
+
 **What this chip holds.**  Three ranges, ``(first, count)`` each, ``None`` for
 all: ``experts_held`` of the routed experts (the router keeps its width, its
 choices and its normalisation; the terms of the experts held elsewhere are
@@ -93,10 +99,9 @@ import jax.numpy as jnp
 
 from bagua_tpu.kernels.causal_attention import causal_attention
 from bagua_tpu.kernels.ssd_scan import ssd_scan
+from bagua_tpu.models.decoder import (
+    HEADS_MAJOR, Kernels, RMSNorm, matmul, next_token_loss_fn, product, shift)
 from bagua_tpu.models.embedding import embed
-from bagua_tpu.models.glm_moe import HEADS_MAJOR, _Kernels, _matmul, _product
-from bagua_tpu.models.lfm2_moe import _shift, lfm2_moe_loss_fn
-from bagua_tpu.models.llama import RMSNorm
 from bagua_tpu.observability.annotations import model_scope
 from bagua_tpu.parallel.moe.dropless import dropless_experts, sigmoid_topk_route
 
@@ -249,7 +254,7 @@ def _taps_and_bias(xbc, taps, bias):
     last = taps.shape[0] - 1
     x = xbc.astype(jnp.float32)
     return bias.astype(jnp.float32) + sum(
-        taps[i].astype(jnp.float32) * _shift(x, last - i) for i in range(last + 1))
+        taps[i].astype(jnp.float32) * shift(x, last - i) for i in range(last + 1))
 
 
 @jax.custom_vjp
@@ -273,8 +278,8 @@ def _causal_conv_silu_bwd(res, dy):
     d_pre = dy.astype(jnp.float32) * gate * (1.0 + pre * (1.0 - gate))
     x = xbc.astype(jnp.float32)
     # x_t feeds position t + (L - 1) - i through tap i: the taps run against time
-    d_x = sum(taps[i].astype(jnp.float32) * _shift(d_pre, i - last) for i in range(last + 1))
-    d_taps = jnp.stack([jnp.sum(d_pre * _shift(x, last - i), axis=(0, 1)) for i in range(last + 1)])
+    d_x = sum(taps[i].astype(jnp.float32) * shift(d_pre, i - last) for i in range(last + 1))
+    d_taps = jnp.stack([jnp.sum(d_pre * shift(x, last - i), axis=(0, 1)) for i in range(last + 1)])
     return (d_x.astype(xbc.dtype), d_taps.astype(taps.dtype),
             jnp.sum(d_pre, axis=(0, 1)).astype(bias.dtype))
 
@@ -315,7 +320,7 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
 
 
-class Mamba2Mixer(_Kernels):
+class Mamba2Mixer(Kernels):
     cfg: NemotronHConfig
 
     @nn.compact
@@ -328,7 +333,7 @@ class Mamba2Mixer(_Kernels):
         inner, channels = heads * size, heads * size + 2 * groups * state
         with model_scope("ssm_proj"):
             w_in = self.kernel("in_proj", hidden, inner + channels + heads)
-            z_xbc = _matmul(a, w_in[:, :inner + channels], dtype)
+            z_xbc = matmul(a, w_in[:, :inner + channels], dtype)
             # the time steps stay float32 from the product on
             dt = jnp.dot(a.astype(dtype), w_in[:, inner + channels:].astype(dtype),
                          preferred_element_type=jnp.float32)
@@ -354,13 +359,13 @@ class Mamba2Mixer(_Kernels):
                 jnp.repeat(skip, size).reshape(groups, per * size),
                 scale.reshape(groups, per * size), cfg.layer_norm_epsilon)
         with model_scope("ssm_proj"):
-            return _matmul(y.reshape(batch, t, inner), self.kernel("out_proj", inner, hidden), dtype)
+            return matmul(y.reshape(batch, t, inner), self.kernel("out_proj", inner, hidden), dtype)
 
 
 # -- attention without positions ----------------------------------------------
 
 
-class Attention(_Kernels):
+class Attention(Kernels):
     cfg: NemotronHConfig
 
     @nn.compact
@@ -375,19 +380,19 @@ class Attention(_Kernels):
             # q carries 1 / sqrt(head size) from the pass that rounds it
             q = (jnp.einsum(HEADS_MAJOR, a.astype(dtype), heads_of("q", heads).astype(dtype),
                             preferred_element_type=jnp.float32) / math.sqrt(size)).astype(dtype)
-            k = _product(HEADS_MAJOR, a, heads_of("k", kv_heads), dtype)
-            v = _product(HEADS_MAJOR, a, heads_of("v", kv_heads), dtype)
+            k = product(HEADS_MAJOR, a, heads_of("k", kv_heads), dtype)
+            v = product(HEADS_MAJOR, a, heads_of("v", kv_heads), dtype)
             out = self.kernel("out_proj", heads * size, hidden).reshape(heads, size, hidden)
         with model_scope("attn_core"):
             ctx = causal_attention(q, k, v, 1.0)
         with model_scope("attn_proj"):
-            return _product("bhtd,hdm->btm", ctx, out, dtype)
+            return product("bhtd,hdm->btm", ctx, out, dtype)
 
 
 # -- the expert layer and the dense MLP ----------------------------------------
 
 
-class SquaredReluMLP(_Kernels):
+class SquaredReluMLP(Kernels):
     """``W_down relu(W_up a)^2``: two products, no gate."""
 
     width: int
@@ -396,11 +401,11 @@ class SquaredReluMLP(_Kernels):
     @nn.compact
     def __call__(self, a):
         hidden = a.shape[-1]
-        raised = relu2(_matmul(a, self.kernel("up", hidden, self.width), self.dtype))
-        return _matmul(raised, self.kernel("down", self.width, hidden), self.dtype)
+        raised = relu2(matmul(a, self.kernel("up", hidden, self.width), self.dtype))
+        return matmul(raised, self.kernel("down", self.width, hidden), self.dtype)
 
 
-class LatentExperts(_Kernels):
+class LatentExperts(Kernels):
     """The router over all routed experts on the hidden state, the held
     experts' part of the routed result in the latent width between its two
     projections, and the shared expert at the hidden width."""
@@ -420,14 +425,14 @@ class LatentExperts(_Kernels):
                 self.kernel("correction_bias", experts), cfg.num_experts_per_tok,
                 cfg.routed_scaling_factor, cfg.norm_topk_prob, cfg.router_eps)
         with model_scope("moe_latent"):
-            lowered = _matmul(tokens, self.kernel("latent_in", hidden, latent), dtype)
+            lowered = matmul(tokens, self.kernel("latent_in", hidden, latent), dtype)
         routed = dropless_experts(
             lowered, chosen, weights, None,
             self.kernel("experts_up", count, latent, width),
             self.kernel("experts_down", count, width, latent),
             held=(first, count), num_experts=experts, activation=relu2)
         with model_scope("moe_latent"):
-            routed = _matmul(routed, self.kernel("latent_out", latent, hidden), dtype)
+            routed = matmul(routed, self.kernel("latent_out", latent, hidden), dtype)
         with model_scope("moe_shared"):
             shared = SquaredReluMLP(cfg.moe_shared_expert_intermediate_size, dtype, name="shared")(a)
         return shared + routed.reshape(batch, t, hidden)
@@ -453,7 +458,7 @@ class NemotronHBlock(nn.Module):
             return x + SquaredReluMLP(cfg.intermediate_size, cfg.compute_dtype, name="mlp")(a)
 
 
-class NemotronHModel(_Kernels):
+class NemotronHModel(Kernels):
     """``ids (batch, positions)`` to float32 logits ``(batch, positions,
     vocab)`` through the output matrix."""
 
@@ -472,6 +477,4 @@ class NemotronHModel(_Kernels):
                               preferred_element_type=jnp.float32)
 
 
-#: next-token cross entropy over the ids alone, mean over each sequence's
-#: ``positions - 1`` targets: the loss of any model of ids to logits
-nemotron_h_loss_fn = lfm2_moe_loss_fn
+nemotron_h_loss_fn = next_token_loss_fn

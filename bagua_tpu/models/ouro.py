@@ -72,6 +72,9 @@ three arrays of 5,632 columns, all bf16): sixteen applications are 10 GB beside
 ``mfu_pct`` counts nothing recomputed; the summary's ``recompute`` class is
 the time of what is, and this model has none.
 
+The parts shared with the other decoder models are ``models/decoder.py``'s
+(``RMSNorm``, ``Kernels``, ``SwiGLU``, the attention layer with ``rope_theta``).
+
 Parameters are stored in float32; matrix products take ``compute_dtype``
 operands and accumulate in float32; norms, the rotation, the gate, the exit
 distribution, the logits and the loss are float32.  Each part of the forward
@@ -81,18 +84,14 @@ products, its cross entropies and their weighted sum).
 """
 
 import dataclasses
-import math
 from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from bagua_tpu.kernels.causal_attention import causal_attention
+from bagua_tpu.models.decoder import GroupedQueryAttention, Kernels, RMSNorm, SwiGLU
 from bagua_tpu.models.embedding import embed
-from bagua_tpu.models.glm_moe import HEADS_MAJOR, SwiGLU, _Kernels, _product
-from bagua_tpu.models.lfm2_moe import rotate_half
-from bagua_tpu.models.llama import RMSNorm
 from bagua_tpu.models.losses import softmax_cross_entropy
 from bagua_tpu.observability.annotations import model_scope, pass_scope
 
@@ -163,36 +162,6 @@ def ouro_test_config(**overrides) -> OuroConfig:
     return OuroConfig(**kwargs)
 
 
-class OuroAttention(_Kernels):
-    cfg: OuroConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg, dt = self.cfg, self.cfg.compute_dtype
-        hidden, size = x.shape[-1], cfg.head_dim
-        heads, kv_heads = cfg.num_attention_heads, cfg.num_key_value_heads
-
-        def heads_of(name, count, scale=None):
-            """One projection as the kernels read it; with ``scale`` rotated
-            and scaled in float32 and rounded once."""
-            kernel = self.kernel(name + "_proj", hidden, count * size).reshape(hidden, count, size)
-            if scale is None:
-                return _product(HEADS_MAJOR, x, kernel, dt)
-            y = jnp.einsum(HEADS_MAJOR, x.astype(dt), kernel.astype(dt),
-                           preferred_element_type=jnp.float32)
-            return rotate_half(y, cfg.rope_theta, scale).astype(dt)
-
-        with model_scope("attn_proj"):
-            q = heads_of("q", heads, 1.0 / math.sqrt(size))
-            k = heads_of("k", kv_heads, 1.0)
-            v = heads_of("v", kv_heads)
-            out = self.kernel("out_proj", heads * size, hidden).reshape(heads, size, hidden)
-        with model_scope("attn_core"):
-            ctx = causal_attention(q, k, v, 1.0)
-        with model_scope("attn_proj"):
-            return _product("bhtd,hdm->btm", ctx, out, dt)
-
-
 class OuroBlock(nn.Module):
     cfg: OuroConfig
 
@@ -200,7 +169,9 @@ class OuroBlock(nn.Module):
     def __call__(self, x):
         cfg = self.cfg
         h = RMSNorm(cfg.rms_norm_eps, name="input_norm")(x)
-        x = x + RMSNorm(cfg.rms_norm_eps, name="input_norm_2")(OuroAttention(cfg, name="attn")(h))
+        x = x + RMSNorm(cfg.rms_norm_eps, name="input_norm_2")(GroupedQueryAttention(
+            cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim, cfg.compute_dtype,
+            rope_theta=cfg.rope_theta, name="attn")(h))
         h = RMSNorm(cfg.rms_norm_eps, name="post_attention_norm")(x)
         with model_scope("dense_mlp"):
             h = SwiGLU(cfg.intermediate_size, cfg.compute_dtype, name="mlp")(h)
@@ -294,7 +265,7 @@ def _exit_sum_bwd(kept, cotangents):
 _exit_sum.defvjp(_exit_sum_fwd, _exit_sum_bwd)
 
 
-class OuroModel(_Kernels):
+class OuroModel(Kernels):
     """``ids (batch, positions)`` to the passes' exits, stacked over the
     passes: ``(logits (passes, batch, positions, vocab), gate logits (passes,
     batch, positions))``, float32; given ``targets (batch, positions)`` the

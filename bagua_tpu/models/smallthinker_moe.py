@@ -37,14 +37,14 @@ operands and accumulate in float32; norms, the rotation, the router, the
 logits and the loss are float32.  Each part of the forward pass sits under a
 ``bagua_model/part=...`` scope; the core of a windowed layer under
 ``attn_window_core`` and of a global one under ``attn_core``, so that the two
-masks are read apart.  The operands are written as ``models/lfm2_moe.py``
-writes them: the three products contract onto the kernels' ``(batch, heads,
-positions, head size)``, ``q`` carries ``1 / sqrt(head size)`` from the pass
-that rounds it (the rotation's, or the product's own where there is none).
+masks are read apart.
+
+The parts shared with the other decoder models are ``models/decoder.py``'s
+(``RMSNorm``, ``Kernels``, the next-token loss, and ``GroupedQueryAttention``
+with ``rope_theta`` or none and ``window`` or none by the layer's two keys).
 """
 
 import dataclasses
-import math
 from typing import Any, Tuple
 
 import flax.linen as nn
@@ -52,10 +52,8 @@ import jax
 import jax.numpy as jnp
 
 from bagua_tpu.kernels.causal_attention import causal_attention
+from bagua_tpu.models.decoder import GroupedQueryAttention, Kernels, RMSNorm, next_token_loss_fn
 from bagua_tpu.models.embedding import embed
-from bagua_tpu.models.glm_moe import HEADS_MAJOR, _Kernels, _product
-from bagua_tpu.models.lfm2_moe import lfm2_moe_loss_fn, rotate_half
-from bagua_tpu.models.llama import RMSNorm
 from bagua_tpu.observability.annotations import model_scope
 from bagua_tpu.parallel.moe.dropless import dropless_experts, softmax_topk_route
 
@@ -143,43 +141,17 @@ def smallthinker_test_config(**overrides) -> SmallThinkerConfig:
     return SmallThinkerConfig(**kwargs)
 
 
-class WindowOrGlobalAttention(_Kernels):
-    """Grouped-query attention of one layer: with or without the rotary
-    embedding, over all earlier keys or over the window."""
+class WindowOrGlobalAttention(GroupedQueryAttention):
+    """The shared layer with the kernel looked up under this module's name for
+    it: ``tests/benchmark`` puts a kernel that drops the window there."""
 
-    cfg: SmallThinkerConfig
-    windowed: bool
-    rotary: bool
-
-    @nn.compact
-    def __call__(self, x):
-        cfg, dt = self.cfg, self.cfg.compute_dtype
-        hidden, size = x.shape[-1], cfg.head_dim
-        heads, kv_heads = cfg.num_attention_heads, cfg.num_key_value_heads
-
-        def heads_of(name, count, scale=1.0, rotate=False):
-            """One projection as the kernels read it; rotated or scaled, it
-            is so in float32 and rounded once."""
-            kernel = self.kernel(name + "_proj", hidden, count * size).reshape(hidden, count, size)
-            if not rotate and scale == 1.0:
-                return _product(HEADS_MAJOR, x, kernel, dt)
-            y = jnp.einsum(HEADS_MAJOR, x.astype(dt), kernel.astype(dt),
-                           preferred_element_type=jnp.float32)
-            return (rotate_half(y, cfg.rope_theta, scale) if rotate else y * scale).astype(dt)
-
-        with model_scope("attn_proj"):
-            q = heads_of("q", heads, 1.0 / math.sqrt(size), self.rotary)
-            k = heads_of("k", kv_heads, rotate=self.rotary)
-            v = heads_of("v", kv_heads)
-            out = self.kernel("out_proj", heads * size, hidden).reshape(heads, size, hidden)
-        with model_scope("attn_window_core" if self.windowed else "attn_core"):
-            ctx = causal_attention(
-                q, k, v, 1.0, window=cfg.sliding_window_size if self.windowed else None)
-        with model_scope("attn_proj"):
-            return _product("bhtd,hdm->btm", ctx, out, dt)
+    @nn.nowrap
+    def core(self, q, k, v):
+        with model_scope("attn_core" if self.window is None else "attn_window_core"):
+            return causal_attention(q, k, v, 1.0, window=self.window)
 
 
-class SmallThinkerBlock(_Kernels):
+class SmallThinkerBlock(Kernels):
     cfg: SmallThinkerConfig
     windowed: bool
     rotary: bool
@@ -196,7 +168,10 @@ class SmallThinkerBlock(_Kernels):
             chosen, weights = softmax_topk_route(
                 h.reshape(b * t, hidden), self.kernel("router", hidden, experts),
                 cfg.moe_num_active_primary_experts)
-        x = x + WindowOrGlobalAttention(cfg, self.windowed, self.rotary, name="attn")(h)
+        x = x + WindowOrGlobalAttention(
+            cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim, cfg.compute_dtype,
+            rope_theta=cfg.rope_theta if self.rotary else None,
+            window=cfg.sliding_window_size if self.windowed else None, name="attn")(h)
         h = RMSNorm(cfg.rms_norm_eps, name="post_attention_norm")(x)
         routed = dropless_experts(
             h.reshape(b * t, hidden), chosen, weights,
@@ -207,7 +182,7 @@ class SmallThinkerBlock(_Kernels):
         return x + routed.reshape(b, t, hidden)
 
 
-class SmallThinkerModel(_Kernels):
+class SmallThinkerModel(Kernels):
     """``ids (batch, positions)`` to float32 logits ``(batch, positions,
     vocab)`` through the output matrix."""
 
@@ -226,6 +201,4 @@ class SmallThinkerModel(_Kernels):
                               preferred_element_type=jnp.float32)
 
 
-#: next-token cross entropy over the ids alone, mean over each sequence's
-#: ``positions - 1`` targets: the loss of any model of ids to logits
-smallthinker_loss_fn = lfm2_moe_loss_fn
+smallthinker_loss_fn = next_token_loss_fn

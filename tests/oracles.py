@@ -1,6 +1,8 @@
 """Shared numpy oracles for MinMaxUInt8 compression (reference semantics:
 ``tests/internal/compressor.py:4-33`` / ``bagua_kernels.cu:404-480``)."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 EPS = 1e-7
@@ -61,3 +63,31 @@ def oracle_compressed_allreduce(per_rank: np.ndarray, average: bool = True):
         q, mm = oracle_compress(reduced[r][None])
         out.append(oracle_decompress(q, mm)[0])
     return np.concatenate(out)
+
+
+# -- oracles of the decoder models' shared parts ------------------------------
+# (``tests/test_causal_attention.py``, ``tests/test_decoder.py`` and the
+# model files' tests)
+
+
+def rel_err(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30))
+
+
+def quadratic_attention(q, k, v, scale, window=None):
+    """Every score written down under the explicit mask (causal, and a window
+    of so many keys that counts the current position), each key-value head
+    repeated for its group."""
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    i, j = jnp.arange(q.shape[2])[:, None], jnp.arange(q.shape[2])[None, :]
+    seen = (i >= j) if window is None else (i >= j) & (i - j < window)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1), v)
+
+
+def both_passes(attn, q, k, v, d_out):
+    """``(out, dq, dk, dv)`` of ``attn(q, k, v)`` under the cotangent ``d_out``."""
+    out, vjp = jax.vjp(attn, q, k, v)
+    return (out,) + vjp(d_out.astype(out.dtype))

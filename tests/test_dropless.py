@@ -1,6 +1,7 @@
 """The dropless expert layer by its four callers' ``(k, held)``: the rows of
 its buffer, that no term is lost at the worst load, the expert with no gate,
-the tiles the two new shapes take, and the two passes over a bounded buffer."""
+the tiles the two new shapes take, and the two passes over a bounded buffer.
+Both sides of every comparison run compiled (``helpers.compiled``)."""
 
 import jax
 import jax.numpy as jnp
@@ -11,6 +12,7 @@ from bagua_tpu.models.nemotron_h import relu2
 from bagua_tpu.parallel.moe import dropless
 from bagua_tpu.parallel.moe.dropless import (
     GMM_TILES, collect, dropless_experts, gmm_tiling, sigmoid_topk_route, spread)
+from helpers import compiled
 
 #: ``(choices a token, experts of the model, experts held)`` of the four callers:
 #: ``glm_moe``, ``lfm2_moe``, ``smallthinker_moe``, ``nemotron_h``
@@ -100,7 +102,7 @@ def test_no_term_is_lost_when_every_token_chooses_every_held_expert(caller):
     def of(layer):
         def scalar(x, weights, up, down):
             return jnp.sum(probe * layer(x, chosen, weights, gate, up, down))
-        return jax.value_and_grad(scalar, argnums=(0, 1, 2, 3))(x, weights, up, down)
+        return compiled(jax.value_and_grad(scalar, argnums=(0, 1, 2, 3)), x, weights, up, down)
 
     got = of(lambda *a: dropless_experts(*a, held=(first, held), num_experts=experts,
                                          activation=activation))
@@ -122,7 +124,7 @@ def test_an_expert_with_no_gate_is_two_products_around_the_callers_activation(fi
         def scalar(x, router, up, down):
             chosen, weights = sigmoid_topk_route(x, router, bias, k, 5.0)
             return jnp.sum(jnp.sin(experts_fn(x, chosen, weights, None, up, down)))
-        return jax.value_and_grad(scalar, argnums=(0, 1, 2, 3))(x, router, up, down)
+        return compiled(jax.value_and_grad(scalar, argnums=(0, 1, 2, 3)), x, router, up, down)
 
     got = of(lambda *a: dropless_experts(*a, held=(first, held), num_experts=experts,
                                          activation=relu2))
@@ -131,7 +133,8 @@ def test_an_expert_with_no_gate_is_two_products_around_the_callers_activation(fi
     for g, w, name in zip(got[1], want[1], ("x", "router", "up", "down")):
         np.testing.assert_allclose(g, w, rtol=5e-4, atol=2e-5, err_msg=name)
     # one reader of the buffer and two products: a third of the gated unit's grouped products
-    chosen, weights = sigmoid_topk_route(x, router, bias, k, 5.0)
+    chosen, weights = compiled(lambda x, router: sigmoid_topk_route(x, router, bias, k, 5.0),
+                               x, router)
     products = _buffer_rows(lambda x: dropless_experts(
         x, chosen, weights, None, up, down, held=(first, held), num_experts=experts,
         activation=relu2), x)
@@ -188,22 +191,32 @@ def test_the_two_passes_over_a_bounded_buffer_are_each_others_transpose(weighted
             return scale[:, None] * out if weighted else out
 
         w, s = (weight, scale) if weighted else (None, None)
-        np.testing.assert_allclose(collect(buffer, w, order, fan), dense_collect(buffer, weight),
-                                   rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(spread(src, s, order, fan), dense_spread(src, scale),
-                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(
+            compiled(lambda b, w, order: collect(b, w, order, fan), buffer, w, order),
+            compiled(dense_collect, buffer, weight), rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(
+            compiled(lambda a, c, order: spread(a, c, order, fan), src, s, order),
+            compiled(dense_spread, src, scale), rtol=1e-5, atol=1e-6)
         probe_t = jnp.asarray(rng.normal(size=(tokens, width)), jnp.float32)
         probe_r = jnp.asarray(rng.normal(size=(rows, width)), jnp.float32)
         if weighted:
-            got = jax.grad(lambda b, w: jnp.sum(probe_t * collect(b, w, order, fan)), (0, 1))(buffer, weight)
-            want = jax.grad(lambda b, w: jnp.sum(probe_t * dense_collect(b, w)), (0, 1))(buffer, weight)
-            got += jax.grad(lambda a, c: jnp.sum(probe_r * spread(a, c, order, fan)), (0, 1))(src, scale)
-            want += jax.grad(lambda a, c: jnp.sum(probe_r * dense_spread(a, c)), (0, 1))(src, scale)
+            got = compiled(jax.grad(
+                lambda b, w: jnp.sum(probe_t * collect(b, w, order, fan)), (0, 1)), buffer, weight)
+            want = compiled(jax.grad(
+                lambda b, w: jnp.sum(probe_t * dense_collect(b, w)), (0, 1)), buffer, weight)
+            got += compiled(jax.grad(
+                lambda a, c: jnp.sum(probe_r * spread(a, c, order, fan)), (0, 1)), src, scale)
+            want += compiled(jax.grad(
+                lambda a, c: jnp.sum(probe_r * dense_spread(a, c)), (0, 1)), src, scale)
         else:
-            got = (jax.grad(lambda b: jnp.sum(probe_t * collect(b, None, order, fan)))(buffer),
-                   jax.grad(lambda a: jnp.sum(probe_r * spread(a, None, order, fan)))(src))
-            want = (jax.grad(lambda b: jnp.sum(probe_t * dense_collect(b, weight)))(buffer),
-                    jax.grad(lambda a: jnp.sum(probe_r * dense_spread(a, scale)))(src))
+            got = (compiled(jax.grad(
+                       lambda b: jnp.sum(probe_t * collect(b, None, order, fan))), buffer),
+                   compiled(jax.grad(
+                       lambda a: jnp.sum(probe_r * spread(a, None, order, fan))), src))
+            want = (compiled(jax.grad(
+                        lambda b: jnp.sum(probe_t * dense_collect(b, weight))), buffer),
+                    compiled(jax.grad(
+                        lambda a: jnp.sum(probe_r * dense_spread(a, scale))), src))
         for g, wnt in zip(got, want):
             np.testing.assert_allclose(g, wnt, rtol=1e-5, atol=1e-6)
 
@@ -225,7 +238,9 @@ def test_a_bounded_buffers_dead_rows_reach_no_value_and_no_gradient(monkeypatch)
                                num_experts=experts, activation=relu2)
         return jnp.sum(jnp.sin(out)), out
 
-    run = jax.value_and_grad(layer, argnums=range(4), has_aux=True)
+    def run(*args):  # traced anew each time: the second run meets the patched product
+        return compiled(jax.value_and_grad(layer, argnums=range(4), has_aux=True), *args)
+
     (_, want_out), want = run(x, router, up, down)
     monkeypatch.setattr(dropless, "grouped_matmul", _unwritten_rows_are_nan(dropless.grouped_matmul))
     (_, got_out), got = run(x, router, up, down)

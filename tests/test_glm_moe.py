@@ -1,8 +1,10 @@
 """GLM-4.7-Flash at toy sizes on the CPU: the program's model against the
 benchmark's plain reference on seeded weights, one chip's share against the
-whole layer, the dropless dispatch under a forced imbalance, the blocked
-attention against the quadratic one, and the scopes that name the model's
-parts in a device trace."""
+whole layer, the dropless dispatch under a forced imbalance, the latent
+attention's column order against the stored one, and the scopes that name the
+model's parts in a device trace.  The attention kernel's own tests are in
+``test_causal_attention.py``, the shared parts' in ``test_decoder.py``; every
+comparison here runs both sides compiled (``helpers.compiled``)."""
 
 import math
 import os
@@ -13,8 +15,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bagua_tpu.kernels import causal_attention as causal_attention_module
-from bagua_tpu.kernels.causal_attention import blocked_causal_attention, causal_attention
 from bagua_tpu.models.glm_moe import (
     GlmMoeConfig,
     GlmMoeModel,
@@ -34,6 +34,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "ci"))
 from benchmark import manifest  # noqa: E402
+from helpers import compiled  # noqa: E402
+from oracles import rel_err  # noqa: E402
 from trim_capture import xspace_bytes  # noqa: E402
 
 PARTS = ("attn_proj", "attn_core", "moe_route", "moe_dispatch", "moe_experts",
@@ -58,11 +60,6 @@ def toy_sizes(adapter, **overrides):
     return adapter.sizes(config, {"seq_len": 32})
 
 
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30))
-
-
 # -- the model against the plain reference ------------------------------------
 
 
@@ -73,9 +70,10 @@ def test_loss_and_every_gradient_leaf_match_the_reference_in_float32(adapter, re
     ids = adapter.draw_batch(jax.random.PRNGKey(4), 2, sz)
     model = GlmMoeModel(adapter.model_config(sz, compute_dtype=jnp.float32))
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(glm_moe_loss_fn(model))(
-            adapter.to_program(ref_params, sz), ids)
-        ref_loss, ref_grads = jax.value_and_grad(reference.loss)(ref_params, ids, sz)
+        loss, grads = compiled(jax.value_and_grad(glm_moe_loss_fn(model)),
+                               adapter.to_program(ref_params, sz), ids)
+        ref_loss, ref_grads = compiled(
+            jax.value_and_grad(lambda p, ids: reference.loss(p, ids, sz)), ref_params, ids)
     assert float(loss) == pytest.approx(float(ref_loss), abs=2e-6)
     want = adapter.to_program(ref_grads, sz, cast=False)
     assert jax.tree.structure(grads) == jax.tree.structure(want)
@@ -133,8 +131,8 @@ def test_the_eight_shares_add_up_to_the_uncut_references_layer(adapter, referenc
     whole, w = expert_layer_weights(reference, sz, jax.random.PRNGKey(5))
     h = jax.random.normal(jax.random.PRNGKey(6), (2, 16, sz["hidden_size"]), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        want = reference.expert_mlp(h, w, whole)
-        shared = reference.swiglu(h, w["s_gate"], w["s_up"], w["s_down"])
+        want = compiled(lambda h, w: reference.expert_mlp(h, w, whole), h, w)
+        shared = compiled(lambda h, w: reference.swiglu(h, w["s_gate"], w["s_up"], w["s_down"]), h, w)
         routed = jnp.zeros_like(h)
         for share in range(total):  # each of the eight shares holds one expert
             held = (share, 1)
@@ -143,7 +141,8 @@ def test_the_eight_shares_add_up_to_the_uncut_references_layer(adapter, referenc
             params = adapter._block({**w, **mine, "attn_norm": 0, "w_dq": 0, "q_norm": 0, "w_uq": 0,
                                      "w_dkv": 0, "kv_norm": 0, "w_ukv": 0, "w_o": 0,
                                      "mlp_norm": 0})["moe"]
-            out = SparseExperts(cfg).apply({"params": params}, h)
+            out = compiled(lambda params, h: SparseExperts(cfg).apply({"params": params}, h),
+                           params, h)
             # what every chip computes alike, the shared expert, counted once
             routed = routed + (out - shared)
     assert total == 8 and rel_err(routed + shared, want) < 1e-5
@@ -159,17 +158,20 @@ def test_routing_drops_nothing_when_every_token_goes_to_one_held_expert():
     down = 0.3 * jax.random.normal(keys[3], (2, width, hidden))
     # a router that says nothing and a bias that sends every token to experts 1 and 6
     bias = jnp.zeros(experts).at[jnp.array([1, 6])].set(1.0)
-    chosen, weights = sigmoid_topk_route(x, jnp.zeros((hidden, experts)), bias, 2, 1.8)
+    chosen, weights = compiled(
+        lambda x, bias: sigmoid_topk_route(x, jnp.zeros((hidden, experts)), bias, 2, 1.8), x, bias)
     assert set(np.unique(chosen)) == {1, 6}
     np.testing.assert_allclose(weights, 0.9, rtol=1e-6)  # 0.5 / (0.5 + 0.5) * 1.8
-    got = dropless_experts(x, chosen, weights, gate, up, down, held=(0, 2), num_experts=experts)
+    got = compiled(lambda *a: dropless_experts(*a, held=(0, 2), num_experts=experts),
+                   x, chosen, weights, gate, up, down)
     # expert 1 is the second of the two held: all 96 rows are its, none is lost
-    want = 0.9 * (jax.nn.silu(x @ gate[1]) * (x @ up[1])) @ down[1]
+    want = compiled(lambda x, gate, up, down: 0.9 * (
+        jax.nn.silu(x @ gate[1]) * (x @ up[1])) @ down[1], x, gate, up, down)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
     assert np.all(np.linalg.norm(np.asarray(got), axis=-1) > 0)
     # a share that holds neither expert adds nothing, and its gradient is finite
-    none, grad = jax.value_and_grad(lambda x: jnp.sum(dropless_experts(
-        x, chosen, weights, gate, up, down, held=(2, 2), num_experts=experts)))(x)
+    none, grad = compiled(jax.value_and_grad(lambda x: jnp.sum(dropless_experts(
+        x, chosen, weights, gate, up, down, held=(2, 2), num_experts=experts))), x)
     assert float(none) == 0.0 and np.all(np.isfinite(np.asarray(grad)))
 
 
@@ -198,8 +200,8 @@ def test_dispatch_gradients_match_a_dense_evaluation():
 
     args = (x, router, gate, up, down)
     with jax.default_matmul_precision("highest"):
-        got = jax.value_and_grad(sparse, argnums=range(5))(*args)
-        want = jax.value_and_grad(dense, argnums=range(5))(*args)
+        got = compiled(jax.value_and_grad(sparse, argnums=range(5)), *args)
+        want = compiled(jax.value_and_grad(dense, argnums=range(5)), *args)
     assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
     for g, w in zip(got[1], want[1]):
         assert rel_err(g, w) < 1e-5
@@ -224,34 +226,46 @@ def test_spread_and_collect_are_each_others_transpose(dtype, scaled):
     scale = jax.random.uniform(keys[2], (tokens * k,), minval=0.5) if scaled else None
     weight = scale[inverse].reshape(tokens, k) if scaled else None
     live = (jnp.arange(tokens * k) < n_live)[:, None]
+    def spread_(src, scale):
+        return compiled(lambda src, scale: spread(src, scale, order, k), src, scale)
+
+    def collect_(buffer, weight):
+        return compiled(lambda buffer, weight: collect(buffer, weight, order, k), buffer, weight)
+
     # the passes against their definitions, written plainly
     plain_scale = scale[:, None] if scaled else 1.0
-    want_rows = jnp.where(live, (plain_scale * src[perm // k]).astype(dtype), 0)
-    np.testing.assert_array_equal(spread(src, scale, order, k), want_rows)
-    taken = jnp.where(live, buffer, 0).astype(jnp.float32) * plain_scale
-    want_tokens = jnp.sum(taken[inverse].reshape(tokens, k, hidden), axis=1).astype(dtype)
+    want_rows = compiled(lambda src: jnp.where(
+        live, (plain_scale * src[perm // k]).astype(dtype), 0), src)
+    np.testing.assert_array_equal(spread_(src, scale), want_rows)
+    want_tokens = compiled(lambda buffer: jnp.sum((jnp.where(live, buffer, 0).astype(
+        jnp.float32) * plain_scale)[inverse].reshape(tokens, k, hidden), axis=1).astype(dtype), buffer)
     tol = dict(rtol=1e-6, atol=1e-6) if dtype == jnp.float32 else dict(rtol=1e-2, atol=1e-2)
-    np.testing.assert_allclose(np.asarray(collect(buffer, weight, order, k), np.float32),
+    np.testing.assert_allclose(np.asarray(collect_(buffer, weight), np.float32),
                                np.asarray(want_tokens, np.float32), **tol)
     # a dead row of the buffer is whatever it held: NaN here, and never read
     poisoned = jnp.where(live, buffer, jnp.nan)
-    np.testing.assert_array_equal(collect(poisoned, weight, order, k), collect(buffer, weight, order, k))
+    np.testing.assert_array_equal(collect_(poisoned, weight), collect_(buffer, weight))
     # the transpose of each is the other, to the last bit
-    got = jax.vjp(lambda s: spread(s, scale, order, k), src)[1](buffer)[0]
-    np.testing.assert_array_equal(got, collect(buffer, weight, order, k))
-    got = jax.vjp(lambda b: collect(b, weight, order, k), buffer)[1](src)[0]
-    np.testing.assert_array_equal(got, spread(src, scale, order, k))
+    got = compiled(lambda src, buffer: jax.vjp(
+        lambda s: spread(s, scale, order, k), src)[1](buffer)[0], src, buffer)
+    np.testing.assert_array_equal(got, collect_(buffer, weight))
+    got = compiled(lambda buffer, src: jax.vjp(
+        lambda b: collect(b, weight, order, k), buffer)[1](src)[0], buffer, src)
+    np.testing.assert_array_equal(got, spread_(src, scale))
     # and what autodiff makes of the plain definitions (a scatter-add)
-    plain = jax.vjp(lambda s: jnp.where(live, plain_scale * s.astype(jnp.float32)[perm // k], 0),
-                    src)[1](buffer.astype(jnp.float32))[0]
+    plain = compiled(lambda src, buffer: jax.vjp(
+        lambda s: jnp.where(live, plain_scale * s.astype(jnp.float32)[perm // k], 0),
+        src)[1](buffer.astype(jnp.float32))[0], src, buffer)
     np.testing.assert_allclose(
-        np.asarray(collect(buffer, weight, order, k), np.float32), plain, **tol)
+        np.asarray(collect_(buffer, weight), np.float32), plain, **tol)
     if scaled:  # the row-wise dot products: the gradients of the scale and of the weight
-        d_scale = jax.vjp(lambda c: spread(src, c, order, k), scale)[1](buffer)[0]
+        d_scale = compiled(lambda scale, buffer: jax.vjp(
+            lambda c: spread(src, c, order, k), scale)[1](buffer)[0], scale, buffer)
         want = jnp.where(live[:, 0], jnp.sum(
             buffer.astype(jnp.float32) * src.astype(jnp.float32)[perm // k], axis=-1), 0)
         np.testing.assert_allclose(d_scale, want, **tol)
-        d_weight = jax.vjp(lambda w: collect(poisoned, w, order, k), weight)[1](src)[0]
+        d_weight = compiled(lambda weight, src: jax.vjp(
+            lambda w: collect(poisoned, w, order, k), weight)[1](src)[0], weight, src)
         np.testing.assert_allclose(d_weight, want[inverse].reshape(tokens, k), **tol)
 
 
@@ -295,8 +309,10 @@ def test_layer_and_every_gradient_match_a_dense_evaluation_at_every_load(dtype, 
 
     args = (x, router, gate, up, down)
     with jax.default_matmul_precision("highest"):
-        (_, (got_out, chosen)), got = jax.value_and_grad(sparse, argnums=range(5), has_aux=True)(*args)
-        (_, (want_out, _)), want = jax.value_and_grad(dense, argnums=range(5), has_aux=True)(*args)
+        (_, (got_out, chosen)), got = compiled(
+            jax.value_and_grad(sparse, argnums=range(5), has_aux=True), *args)
+        (_, (want_out, _)), want = compiled(
+            jax.value_and_grad(dense, argnums=range(5), has_aux=True), *args)
     mine = (chosen >= held[0]) & (chosen < held[0] + held[1])
     if favoured:
         assert set(np.unique(chosen)) == set(favoured) and np.all(np.sum(mine, axis=-1) == 1)
@@ -311,86 +327,6 @@ def test_layer_and_every_gradient_match_a_dense_evaluation_at_every_load(dtype, 
 
 
 # -- attention ----------------------------------------------------------------
-
-
-def quadratic_attention(q, k, v, scale):
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-    t = q.shape[2]
-    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
-    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
-
-
-@pytest.mark.parametrize("block_q", [8, 16, 64])
-def test_blocked_attention_equals_quadratic_attention_in_value_and_gradient(block_q):
-    # a head of 12 dimensions without position and 4 rotary ones, as the toy model's
-    b, h, t, nope, rope = 2, 3, 64, 12, 4
-    keys = jax.random.split(jax.random.PRNGKey(9), 5)
-    q_nope, k_nope = (jax.random.normal(kk, (b, h, t, nope)) for kk in keys[:2])
-    q_rope = jax.random.normal(keys[2], (b, h, t, rope))
-    k_rope = jnp.broadcast_to(jax.random.normal(keys[3], (b, 1, t, rope)), (b, h, t, rope))
-    v = jax.random.normal(keys[4], (b, h, t, nope + rope))
-    q, k = jnp.concatenate([q_nope, q_rope], -1), jnp.concatenate([k_nope, k_rope], -1)
-    scale = 1 / math.sqrt(nope + rope)
-
-    def through(attn):
-        return lambda q, k, v: jnp.sum(jnp.cos(attn(q, k, v)))
-
-    with jax.default_matmul_precision("highest"):
-        got = blocked_causal_attention(q, k, v, scale, block_q)
-        want = quadratic_attention(q, k, v, scale)
-        got_grads = jax.grad(through(lambda *a: blocked_causal_attention(*a, scale, block_q)),
-                             argnums=(0, 1, 2))(q, k, v)
-        want_grads = jax.grad(through(lambda *a: quadratic_attention(*a, scale)),
-                              argnums=(0, 1, 2))(q, k, v)
-    assert rel_err(got, want) < 1e-6
-    for g, w in zip(got_grads, want_grads):
-        assert rel_err(g, w) < 1e-5
-    # off the chip the one entry point is the composition
-    np.testing.assert_array_equal(causal_attention(q, k, v, scale), got if block_q == 64 else
-                                  blocked_causal_attention(q, k, v, scale, 64))
-    with pytest.raises(ValueError, match="do not divide"):
-        blocked_causal_attention(q, k, v, scale, 48)
-
-
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
-@pytest.mark.parametrize("scale", [0.125, 0.11], ids=["scale_2^-3", "scale_0.11"])
-@pytest.mark.parametrize("b,h,tiles,d", [(2, 2, 2, 128), (1, 1, 4, 256)],
-                         ids=["2x2_two_tiles_d128", "1x1_four_tiles_d256"])
-def test_the_chips_attention_kernels_equal_quadratic_attention_in_interpret_mode(
-        b, h, tiles, d, scale, dtype):
-    """The TPU branch of ``causal_attention`` through Pallas' interpreter, at
-    the committed tile edges: blocks above the diagonal skipped, blocks on it
-    masked, blocks below it whole, and more than one partial of ``dQ``.  What
-    it computes is ``softmax((q * scale) k^T) v`` with ``q * scale`` rounded
-    to ``q``'s type (the docstring says so): that function in float32 is the
-    oracle, and beside it the stated function, which a scale that is no power
-    of two meets one bf16 rounding of ``q`` further off."""
-    t = tiles * causal_attention_module.SPLASH_BLOCK_MAJOR
-    keys = jax.random.split(jax.random.PRNGKey(3), 4)
-    q, k, v, d_out = (jax.random.normal(kk, (b, h, t, d), dtype) for kk in keys)
-
-    def f32(x):
-        return x.astype(jnp.float32)
-
-    def both_passes(attn):
-        out, vjp = jax.vjp(attn, q, k, v)
-        return (out,) + vjp(d_out.astype(out.dtype))
-
-    with jax.default_matmul_precision("highest"):
-        got = both_passes(lambda q, k, v: causal_attention_module._splash_causal_attention(
-            q, k, v, scale, interpret=True))
-        computed = both_passes(lambda q, k, v: quadratic_attention(
-            f32((q * scale).astype(q.dtype)), f32(k), f32(v), 1.0))
-        stated = both_passes(lambda q, k, v: quadratic_attention(f32(q), f32(k), f32(v), scale))
-    assert all(g.dtype == dtype and g.shape == q.shape for g in got)
-    # bf16: the probabilities and dS are rounded to the operands' type before each product
-    near, one_more_rounding = (1e-5, 1e-5) if dtype == jnp.float32 else (6e-3, 1e-2)
-    for g, c, s in zip(got, computed, stated):
-        assert rel_err(g, c) < near
-        assert rel_err(g, s) < (near if scale == 0.125 else one_more_rounding)
-    if scale == 0.125:  # a power of two: the two functions are one
-        for c, s in zip(computed, stated):
-            np.testing.assert_array_equal(c, s)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
@@ -436,7 +372,8 @@ def test_scores_from_columns_set_apart_equal_scores_from_the_stored_interleaved_
         def readout_and_scores(*weights):
             s = scores(*weights)
             return jnp.sum(readout * s), s
-        return jax.value_and_grad(readout_and_scores, argnums=(0, 1), has_aux=True)(q_up, rope_down)
+        return compiled(jax.value_and_grad(readout_and_scores, argnums=(0, 1), has_aux=True),
+                        q_up, rope_down)
 
     with jax.default_matmul_precision("highest"):
         (got, got_scores), got_grads = read(set_apart)
@@ -447,21 +384,6 @@ def test_scores_from_columns_set_apart_equal_scores_from_the_stored_interleaved_
     for g, w in zip(got_grads, want_grads):
         assert g.shape == w.shape and np.linalg.norm(w) > 0
         assert rel_err(g, w) < near
-
-
-def test_the_chips_attention_kernels_are_built_once_per_heads_and_positions():
-    """Five layers of one model share one kernel object: the mask's block
-    tables are numpy work on the host at trace time."""
-    build = causal_attention_module._splash_kernel
-    major = causal_attention_module.SPLASH_BLOCK_MAJOR
-    assert build(2, 2 * major, True) is build(2, 2 * major, True)
-    assert build(2, 2 * major, True) is not build(1, 2 * major, True)
-    text = str(jax.make_jaxpr(jax.grad(lambda q: jnp.sum(
-        causal_attention_module._splash_causal_attention(q, q, q, 0.125, interpret=True).astype(
-            jnp.float32))))(jax.ShapeDtypeStruct((1, 2, 2 * major, 128), jnp.bfloat16)))
-    # one forward kernel and one backward kernel that gives dq, dk and dv
-    assert text.count("pallas_call") == 2
-    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text and "splash_mha_dq" not in text
 
 
 # -- the scopes ---------------------------------------------------------------

@@ -1,7 +1,8 @@
 """``models.embedding.embed``: the one lookup of the three expert models and
 its hand-written gradient, against ``zeros.at[ids].add`` in float32, which is
 what autodiff made of ``table[ids]`` and is kept here as the reference.  The
-grouped form the chip takes runs here through Pallas' interpreter."""
+grouped form the chip takes runs here through Pallas' interpreter.  Both sides
+of every comparison run compiled (``helpers.compiled``)."""
 
 import jax
 import jax.numpy as jnp
@@ -10,6 +11,7 @@ import pytest
 
 from bagua_tpu.models import embedding
 from bagua_tpu.models.embedding import embed, grouped_table_gradient, grouped_tiling
+from helpers import compiled
 
 DTYPES = pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 HIDDEN = 128
@@ -36,7 +38,7 @@ def reference_gradient(g, ids, vocab):
 
 def through_the_interpreter(monkeypatch, tiles):
     """Steer ``embed``'s backward pass to the chip's form, run by Pallas'
-    interpreter: in the test, as ``tests/test_glm_moe.py`` reaches the chip's
+    interpreter: in the test, as ``tests/test_causal_attention.py`` reaches the chip's
     attention."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(embedding, "grouped_tiling", lambda _: tiles)
@@ -50,14 +52,19 @@ def through_the_interpreter(monkeypatch, tiles):
 @pytest.mark.parametrize("vocab", [1031, 1024])
 def test_value_and_gradient_equal_the_gather_and_its_scatter_add(vocab, shape, dtype):
     table, ids, g = draw(vocab, shape, dtype, repeated=7)
-    rows, vjp = jax.vjp(lambda t: embed(t, ids, dtype), table)
+    def both_passes(table, ids, g):
+        rows, vjp = jax.vjp(lambda t: embed(t, ids, dtype), table)
+        return rows, vjp(g)[0]
+
+    rows, grad = compiled(both_passes, table, ids, g)
     assert rows.dtype == dtype and rows.shape == shape + (HIDDEN,)
-    np.testing.assert_array_equal(np.asarray(rows, np.float32),
-                                  np.asarray(table[ids].astype(dtype), np.float32))
-    (grad,) = vjp(g)
+    np.testing.assert_array_equal(
+        np.asarray(rows, np.float32),
+        np.asarray(compiled(lambda table, ids: table[ids].astype(dtype), table, ids), np.float32))
     assert grad.dtype == table.dtype and grad.shape == table.shape
     # the same float32 additions in the same order: the scatter-add itself
-    np.testing.assert_array_equal(np.asarray(grad), np.asarray(reference_gradient(g, ids, vocab)))
+    np.testing.assert_array_equal(np.asarray(grad), np.asarray(compiled(
+        lambda g, ids: reference_gradient(g, ids, vocab), g, ids)))
     assert np.abs(np.asarray(grad[7])).max() > 0 and np.abs(np.asarray(grad[vocab - 1])).max() > 0
 
 
@@ -73,8 +80,9 @@ def test_grouped_form_equals_the_scatter_add(vocab, tiles, shape, dtype):
     zeroes an empty block and the slice to ``vocab`` rows, held off the chip:
     300 and 40 tokens are no multiple of a token tile."""
     _, ids, g = draw(vocab, shape, dtype, seed=1, repeated=vocab // 2)
-    got = grouped_table_gradient(g.reshape(-1, HIDDEN), ids.reshape(-1), vocab, tiles, interpret=True)
-    want = reference_gradient(g, ids, vocab)
+    got = compiled(lambda g, ids: grouped_table_gradient(
+        g.reshape(-1, HIDDEN), ids.reshape(-1), vocab, tiles, interpret=True), g, ids)
+    want = compiled(lambda g, ids: reference_gradient(g, ids, vocab), g, ids)
     assert got.dtype == jnp.float32 and got.shape == want.shape
     # a hundred repeats sum in another order: an ulp of the sum's largest partial
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=2e-5)
@@ -88,10 +96,12 @@ def test_an_id_outside_the_vocabulary_picks_nothing_in_either_form(vocab):
     outside = ids.at[0, 5].set(-1).at[0, 6].set(vocab).at[0, 7].set(vocab + 700)
     inside = np.ones(64, bool)
     inside[5:8] = False
-    want = reference_gradient(g[:, inside], ids[:, inside], vocab)
-    (plain,) = jax.vjp(lambda t: embed(t, outside, jnp.float32), table)[1](g)
+    want = compiled(lambda g, ids: reference_gradient(g, ids, vocab), g[:, inside], ids[:, inside])
+    (plain,) = compiled(lambda table, ids, g: jax.vjp(
+        lambda t: embed(t, ids, jnp.float32), table)[1](g), table, outside, g)
     np.testing.assert_array_equal(np.asarray(plain), np.asarray(want))
-    grouped = grouped_table_gradient(g[0], outside[0], vocab, (64, 128, 128), interpret=True)
+    grouped = compiled(lambda g, ids: grouped_table_gradient(
+        g, ids, vocab, (64, 128, 128), interpret=True), g[0], outside[0])
     np.testing.assert_allclose(np.asarray(grouped), np.asarray(want), rtol=1e-6, atol=1e-6)
 
 
@@ -109,10 +119,11 @@ def test_a_tied_table_sums_the_lookups_gradient_and_the_heads(form, monkeypatch)
     def plain(t):
         return t[ids].astype(jnp.bfloat16)
 
-    of_lookup, of_head = jax.grad(lambda a, b: loss(plain, a, b), argnums=(0, 1))(table, table)
+    of_lookup, of_head = compiled(
+        jax.grad(lambda a, b: loss(plain, a, b), argnums=(0, 1)), table, table)
     if form == "grouped":
         through_the_interpreter(monkeypatch, (16, 256, 128))
-    tied = jax.grad(lambda t: loss(lambda u: embed(u, ids, jnp.bfloat16), t, t))(table)
+    tied = compiled(jax.grad(lambda t: loss(lambda u: embed(u, ids, jnp.bfloat16), t, t)), table)
     np.testing.assert_allclose(np.asarray(tied), np.asarray(of_lookup + of_head),
                                rtol=1e-5, atol=1e-7)
     assert float(jnp.abs(of_lookup).max()) > 0 and float(jnp.abs(of_head).max()) > 0
@@ -145,11 +156,11 @@ def test_the_three_expert_models_look_their_tokens_up_here(family):
     model = model_cls(cfg)
     loss_fn = make_loss(model)
     ids = jnp.asarray(np.random.RandomState(5).randint(0, cfg.vocab_size, (2, 16)), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    params = compiled(model.init, jax.random.PRNGKey(0), ids)["params"]
     text = jax.jit(jax.grad(loss_fn)).lower(params, ids).as_text(debug_info=True)
     labelled = [line for line in text.splitlines() if format_model_label("embed") in line]
     assert labelled and any("transpose(" in line for line in labelled)
-    grad = np.asarray(jax.grad(loss_fn)(params, ids)["embedding"])
+    grad = np.asarray(compiled(jax.grad(loss_fn), params, ids)["embedding"])
     assert np.abs(grad[np.unique(np.asarray(ids))]).max() > 0
     if "lm_head" in params:
         unseen = np.setdiff1d(np.arange(cfg.vocab_size), np.asarray(ids))

@@ -1,7 +1,8 @@
 """``models.losses.softmax_cross_entropy``: the cross-entropy written from the
 logits (log-sum-exp minus the logit a one-hot select picks) against the form
 it replaced in the five model files, ``log_softmax`` then ``take_along_axis``,
-which is kept here as the reference."""
+which is kept here as the reference.  Both sides of every comparison run
+compiled (``helpers.compiled``)."""
 
 import dataclasses
 
@@ -12,6 +13,7 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from bagua_tpu.models.losses import softmax_cross_entropy
+from helpers import compiled
 
 DTYPES = pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 
@@ -50,11 +52,11 @@ def test_value_and_gradient_equal_the_log_softmax_form(shape, dtype):
     def weighted(fn):
         return lambda lg: jnp.sum(fn(lg, labels).astype(jnp.float32) * weights)
 
-    got = softmax_cross_entropy(logits, labels)
+    got = compiled(softmax_cross_entropy, logits, labels)
     assert got.dtype == dtype and got.shape == shape[:-1]
-    assert_close(got, reference_cross_entropy(logits, labels), VALUE_TOL[dtype])
-    assert_close(jax.grad(weighted(softmax_cross_entropy))(logits),
-                 jax.grad(weighted(reference_cross_entropy))(logits), GRAD_TOL[dtype])
+    assert_close(got, compiled(reference_cross_entropy, logits, labels), VALUE_TOL[dtype])
+    assert_close(compiled(jax.grad(weighted(softmax_cross_entropy)), logits),
+                 compiled(jax.grad(weighted(reference_cross_entropy)), logits), GRAD_TOL[dtype])
 
 
 @DTYPES
@@ -70,14 +72,15 @@ def test_loss_and_gradient_stay_finite_at_extreme_logits(case, dtype):
         logits[:, 4] = -np.inf
         labels = np.array([0, 1, 2, 3, 5, 11], np.int32)
     logits, labels = jnp.asarray(logits).astype(dtype), jnp.asarray(labels)
-    loss, grad = jax.value_and_grad(
-        lambda lg: jnp.mean(softmax_cross_entropy(lg, labels).astype(jnp.float32)))(logits)
+    loss, grad = compiled(jax.value_and_grad(
+        lambda lg: jnp.mean(softmax_cross_entropy(lg, labels).astype(jnp.float32))), logits)
     assert np.isfinite(float(loss))
     assert np.all(np.isfinite(np.asarray(grad, np.float32)))
     # ``max + log(sum)`` reaches 162 here and the form rounds there: the
     # value's absolute error is an ulp of the row maximum, not of the loss
     top = 128 if case == "plus_minus_80" else 4
-    assert_close(softmax_cross_entropy(logits, labels), reference_cross_entropy(logits, labels),
+    assert_close(compiled(softmax_cross_entropy, logits, labels),
+                 compiled(reference_cross_entropy, logits, labels),
                  dict(rtol=0, atol=2 * float(jnp.finfo(dtype).eps) * top))
     if case == "one_logit_minus_inf":
         assert np.all(np.asarray(grad, np.float32)[:, 4] == 0.0)
@@ -89,10 +92,11 @@ def test_labels_at_both_ends_of_the_vocabulary(label, dtype):
     vocab = 97
     logits, _ = draw((5, vocab), dtype, seed=4)
     labels = jnp.full((5,), label % vocab, jnp.int32)
-    got = softmax_cross_entropy(logits, labels)
-    assert_close(got, reference_cross_entropy(logits, labels), VALUE_TOL[dtype])
+    got = compiled(softmax_cross_entropy, logits, labels)
+    assert_close(got, compiled(reference_cross_entropy, logits, labels), VALUE_TOL[dtype])
     # the picked entry is that column and no neighbour's
-    want = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1) - logits[:, label].astype(jnp.float32)
+    want = compiled(lambda lg: jax.nn.logsumexp(lg.astype(jnp.float32), axis=-1)
+                    - lg[:, label].astype(jnp.float32), logits)
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), **VALUE_TOL[dtype])
 
 
@@ -116,8 +120,8 @@ def test_next_token_mean_over_one_sequence_equals_the_log_softmax_form(dtype):
     def mean_of(fn):
         return lambda lg: jnp.mean(fn(lg, labels)[:, :-1].astype(jnp.float32))
 
-    got, got_grad = jax.value_and_grad(mean_of(softmax_cross_entropy))(logits)
-    want, want_grad = jax.value_and_grad(mean_of(reference_cross_entropy))(logits)
+    got, got_grad = compiled(jax.value_and_grad(mean_of(softmax_cross_entropy)), logits)
+    want, want_grad = compiled(jax.value_and_grad(mean_of(reference_cross_entropy)), logits)
     # a mean of 47 values, each within VALUE_TOL
     assert_close(got, want, VALUE_TOL[dtype])
     assert_close(got_grad, want_grad, GRAD_TOL[dtype])
@@ -140,11 +144,13 @@ def test_gradient_is_softmax_minus_onehot_in_f32(reduce):
     def total(fn):
         return lambda lg: jnp.sum(fn(lg) * kept[..., 0]) * g
 
-    grad = np.asarray(jax.grad(total(lambda lg: softmax_cross_entropy(lg, labels)))(logits))
-    lse_grad = np.asarray(jax.grad(total(lambda lg: jax.nn.logsumexp(lg, axis=-1)))(logits))
+    grad = np.asarray(compiled(
+        jax.grad(total(lambda lg: softmax_cross_entropy(lg, labels))), logits))
+    lse_grad = np.asarray(compiled(
+        jax.grad(total(lambda lg: jax.nn.logsumexp(lg, axis=-1))), logits))
     onehot = kept * np.asarray(jax.nn.one_hot(labels, vocab, dtype=jnp.float32))
     np.testing.assert_array_equal(grad, lse_grad - g * onehot)
-    softmax = np.asarray(jax.nn.softmax(logits, axis=-1))
+    softmax = np.asarray(compiled(lambda lg: jax.nn.softmax(lg, axis=-1), logits))
     np.testing.assert_allclose(grad, g * (kept * softmax - onehot), rtol=1e-5, atol=1e-9)
     # each kept row's gradient sums to zero: one whole softmax less one
     np.testing.assert_allclose(grad.sum(axis=-1), 0.0, atol=1e-6)
@@ -160,8 +166,9 @@ def _mlp():
     params = init_mlp(jax.random.PRNGKey(0), [8, 16, 5])
     batch = (jnp.asarray(rng.randn(6, 8).astype(np.float32)),
              jnp.asarray(rng.randint(0, 5, 6).astype(np.int32)))
-    want = jnp.mean(reference_cross_entropy(mlp_apply(params, batch[0]), batch[1]))
-    return softmax_loss(params, batch), want
+    want = compiled(lambda params, batch: jnp.mean(
+        reference_cross_entropy(mlp_apply(params, batch[0]), batch[1])), params, batch)
+    return compiled(softmax_loss, params, batch), want
 
 
 def _vgg():
@@ -171,9 +178,10 @@ def _vgg():
     model = VGG(num_classes=10, cfg=(8, "M", 16, "M"), classifier_width=32)
     x = jnp.asarray(rng.randn(4, 8, 8, 3).astype(np.float32))
     y = jnp.asarray(rng.randint(0, 10, 4).astype(np.int32))
-    params = model.init(jax.random.PRNGKey(0), x)["params"]
-    want = jnp.mean(reference_cross_entropy(model.apply({"params": params}, x), y))
-    return vgg_loss_fn(model)(params, (x, y)), want
+    params = compiled(model.init, jax.random.PRNGKey(0), x)["params"]
+    want = compiled(lambda params, x, y: jnp.mean(
+        reference_cross_entropy(model.apply({"params": params}, x), y)), params, x, y)
+    return compiled(vgg_loss_fn(model), params, (x, y)), want
 
 
 def _resnet():
@@ -183,10 +191,10 @@ def _resnet():
     model = ResNet([1, 1], num_classes=10)
     x = jnp.asarray(rng.randn(4, 16, 16, 3).astype(np.float32))
     y = jnp.asarray(rng.randint(0, 10, 4).astype(np.int32))
-    variables = model.init(jax.random.PRNGKey(0), x)
-    logits, _ = model.apply(variables, x, mutable=["batch_stats"])
-    return (resnet_loss_fn(model)(variables, (x, y)),
-            jnp.mean(reference_cross_entropy(logits, y)))
+    variables = compiled(model.init, jax.random.PRNGKey(0), x)
+    want = compiled(lambda variables, x, y: jnp.mean(reference_cross_entropy(
+        model.apply(variables, x, mutable=["batch_stats"])[0], y)), variables, x, y)
+    return compiled(resnet_loss_fn(model), variables, (x, y)), want
 
 
 def _bert():
@@ -198,9 +206,10 @@ def _bert():
     model = BertForPreTraining(cfg)
     ids = jnp.asarray(rng.randint(0, 61, (3, 8)).astype(np.int32))
     labels = jnp.asarray(rng.randint(0, 61, (3, 8)).astype(np.int32))
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
-    want = jnp.mean(reference_cross_entropy(model.apply({"params": params}, ids), labels))
-    return mlm_loss_fn(model)(params, (ids, labels)), want
+    params = compiled(model.init, jax.random.PRNGKey(0), ids)["params"]
+    want = compiled(lambda params, ids, labels: jnp.mean(reference_cross_entropy(
+        model.apply({"params": params}, ids), labels)), params, ids, labels)
+    return compiled(mlm_loss_fn(model), params, (ids, labels)), want
 
 
 def _gpt_config(**kw):
@@ -215,10 +224,10 @@ def _gpt():
 
     model = GPTModel(_gpt_config())
     ids = jnp.asarray(np.random.RandomState(2).randint(0, 32, (2, 16)).astype(np.int32))
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
-    logits = model.apply({"params": params}, ids)
-    want = jnp.mean(reference_cross_entropy(logits[:, :-1], ids[:, 1:]))
-    return lm_loss_fn(model)(params, ids), want
+    params = compiled(model.init, jax.random.PRNGKey(0), ids)["params"]
+    want = compiled(lambda params, ids: jnp.mean(reference_cross_entropy(
+        model.apply({"params": params}, ids)[:, :-1], ids[:, 1:])), params, ids)
+    return compiled(lm_loss_fn(model), params, ids), want
 
 
 def _gpt_zigzag():
@@ -231,7 +240,7 @@ def _gpt_zigzag():
     model = GPTModel(cfg)
     ids = jnp.asarray(np.random.RandomState(2).randint(0, 32, (2, sp * t_local)).astype(np.int32))
     local = GPTModel(dataclasses.replace(cfg, sp_axis=None, sp_layout="contiguous"))
-    params = local.init(jax.random.PRNGKey(0), ids)["params"]
+    params = compiled(local.init, jax.random.PRNGKey(0), ids)["params"]
     mesh = Mesh(np.array(jax.devices()[:sp]), ("sp",))
 
     def per_rank(fn):

@@ -4,7 +4,11 @@ every kind of block and each kind alone; the mixer's convolution against a
 direct sum; **the shares add up to the model**: the mixer's output over the head
 shares, attention's over its head shares, the expert layer's over the expert
 shares with what every chip computes alike counted once; the engine on four
-devices; and the scopes that name the model's parts."""
+devices; and the scopes that name the model's parts.  The shared parts
+(``RMSNorm``, ``shift``, the next-token loss) have their tests in
+``test_decoder.py``, the attention kernel in ``test_causal_attention.py``, the
+scan in ``test_ssd_scan.py``; every comparison here runs both sides compiled
+(``helpers.compiled``)."""
 
 import os
 import sys
@@ -34,7 +38,8 @@ from bagua_tpu.observability.scope_grammar import format_model_label, parse_mode
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 from benchmark import manifest  # noqa: E402
-from test_lfm2_moe import rel_err  # noqa: E402
+from helpers import compiled  # noqa: E402
+from oracles import rel_err  # noqa: E402
 
 PARTS = ("ssm_proj", "ssm_conv", "ssm_core", "attn_proj", "attn_core", "moe_route", "moe_latent",
          "moe_dispatch", "moe_experts", "moe_combine", "moe_shared", "dense_mlp", "head")
@@ -83,9 +88,10 @@ def test_loss_and_every_gradient_leaf_match_the_reference_in_float32(
     ids = adapter.draw_batch(jax.random.PRNGKey(seed + 1), 2, sz)
     model = NemotronHModel(adapter.model_config(sz, compute_dtype=jnp.float32))
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(nemotron_h_loss_fn(model))(
-            adapter.to_program(ref_params, sz), ids)
-        ref_loss, ref_grads = jax.value_and_grad(reference.loss)(ref_params, ids, sz)
+        loss, grads = compiled(jax.value_and_grad(nemotron_h_loss_fn(model)),
+                               adapter.to_program(ref_params, sz), ids)
+        ref_loss, ref_grads = compiled(
+            jax.value_and_grad(lambda p, ids: reference.loss(p, ids, sz)), ref_params, ids)
     assert float(loss) == pytest.approx(float(ref_loss), abs=2e-6)
     want = adapter.to_program(ref_grads, sz, cast=False)
     assert jax.tree.structure(grads) == jax.tree.structure(want)
@@ -108,9 +114,10 @@ def test_the_whole_model_matches_the_reference_too(adapter, reference):
     ids = adapter.draw_batch(jax.random.PRNGKey(8), 2, sz)
     model = NemotronHModel(adapter.model_config(sz, compute_dtype=jnp.float32))
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(nemotron_h_loss_fn(model))(
-            adapter.to_program(ref_params, sz), ids)
-        ref_loss, ref_grads = jax.value_and_grad(reference.loss)(ref_params, ids, sz)
+        loss, grads = compiled(jax.value_and_grad(nemotron_h_loss_fn(model)),
+                               adapter.to_program(ref_params, sz), ids)
+        ref_loss, ref_grads = compiled(
+            jax.value_and_grad(lambda p, ids: reference.loss(p, ids, sz)), ref_params, ids)
     assert float(loss) == pytest.approx(float(ref_loss), abs=2e-6)
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
                             jax.tree.leaves(adapter.to_program(ref_grads, sz, cast=False))):
@@ -190,26 +197,28 @@ def test_the_convolution_equals_a_direct_sum_and_its_backward_pass_autodiffs():
     taps = jax.random.normal(keys[1], (4, 5), jnp.float32)
     bias = jax.random.normal(keys[2], (5,), jnp.float32)
     probe = jax.random.normal(keys[3], (2, 9, 5), jnp.float32)
-    got = causal_conv_silu(xbc, taps, bias)
+    got = compiled(causal_conv_silu, xbc, taps, bias)
     assert got.dtype == xbc.dtype and rel_err(got, direct_conv_silu(xbc, taps, bias)) < 1e-6
     # causal, the last tap on the current position: position 0 sees it alone
     first = np.asarray(xbc[:, 0] * taps[3] + bias)
     np.testing.assert_allclose(got[:, 0], first / (1 + np.exp(-first)), rtol=1e-5)
     later = xbc.at[:, 5:].set(7.0)
-    np.testing.assert_array_equal(causal_conv_silu(later, taps, bias)[:, :5], got[:, :5])
+    np.testing.assert_array_equal(compiled(causal_conv_silu, later, taps, bias)[:, :5], got[:, :5])
 
     def plain(xbc, taps, bias):
         shifted = [jnp.pad(xbc, ((0, 0), (3 - i, 0), (0, 0)))[:, :xbc.shape[1]] for i in range(4)]
         return jax.nn.silu(sum(taps[i] * shifted[i] for i in range(4)) + bias)
 
-    got_g = jax.grad(lambda *a: jnp.sum(probe * causal_conv_silu(*a)), argnums=(0, 1, 2))(xbc, taps, bias)
-    want_g = jax.grad(lambda *a: jnp.sum(probe * plain(*a)), argnums=(0, 1, 2))(xbc, taps, bias)
+    got_g = compiled(jax.grad(
+        lambda *a: jnp.sum(probe * causal_conv_silu(*a)), argnums=(0, 1, 2)), xbc, taps, bias)
+    want_g = compiled(jax.grad(
+        lambda *a: jnp.sum(probe * plain(*a)), argnums=(0, 1, 2)), xbc, taps, bias)
     for g, w in zip(got_g, want_g):
         assert rel_err(g, w) < 1e-5
     # bf16: computed in float32, rounded once; the input alone is kept
     low = xbc.astype(jnp.bfloat16)
     exact = direct_conv_silu(low.astype(jnp.float32), taps, bias)
-    np.testing.assert_array_equal(causal_conv_silu(low, taps, bias),
+    np.testing.assert_array_equal(compiled(causal_conv_silu, low, taps, bias),
                                   jnp.asarray(exact, jnp.float32).astype(jnp.bfloat16))
     _, residuals = jax.vjp(causal_conv_silu, low, taps, bias)
     kept = sorted((x.shape, str(x.dtype)) for x in jax.tree.leaves(residuals))
@@ -251,15 +260,17 @@ def test_the_mixers_head_shares_add_up_to_the_uncut_references_mixer(adapter, re
     a = jax.random.normal(jax.random.PRNGKey(6), (2, 32, sz["hidden_size"]), jnp.float32)
     shares = sz["mamba_heads_total"] // sz["mamba_heads_held"][1]
     with jax.default_matmul_precision("highest"):
-        want = reference.mixer(a, w, everything)
+        want = compiled(lambda a, w: reference.mixer(a, w, everything), a, w)
         total = jnp.zeros_like(a)
         for share in range(shares):
             mine = {**sz, "mamba_heads_held": (share * sz["mamba_heads_held"][1],
                                               sz["mamba_heads_held"][1])}
             cut = _mixer_share(w, sz, share)
-            out = Mamba2Mixer(adapter.model_config(mine, compute_dtype=jnp.float32)).apply(
-                {"params": adapter._block(cut)["mixer"]}, a)
-            assert rel_err(out, reference.mixer(a, cut, mine)) < 1e-5
+            cfg = adapter.model_config(mine, compute_dtype=jnp.float32)
+            out = compiled(lambda params, a: Mamba2Mixer(cfg).apply({"params": params}, a),
+                           adapter._block(cut)["mixer"], a)
+            assert rel_err(out, compiled(
+                lambda a, cut: reference.mixer(a, cut, mine), a, cut)) < 1e-5
             total = total + out
     assert shares == 2 and rel_err(total, want) < 1e-5
     assert rel_err(out, want) > 0.3  # no share alone is the mixer
@@ -278,7 +289,7 @@ def test_attentions_head_shares_add_up_to_the_uncut_references_attention(adapter
     w = {**w, "w_o": 30.0 * w["w_o"]}
     a = jax.random.normal(jax.random.PRNGKey(6), (2, 32, sz["hidden_size"]), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        want = reference.attention(a, w, everything)
+        want = compiled(lambda a, w: reference.attention(a, w, everything), a, w)
         total = jnp.zeros_like(a)
         for share in range(heads // held):
             first = share * held
@@ -288,9 +299,11 @@ def test_attentions_head_shares_add_up_to_the_uncut_references_attention(adapter
             cut = {"norm": w["norm"], "w_q": w["w_q"][:, q_cols], "w_k": w["w_k"][:, kv_cols],
                    "w_v": w["w_v"][:, kv_cols], "w_o": w["w_o"][q_cols]}
             mine = {**sz, "attention_heads_held": (first, held)}
-            out = Attention(adapter.model_config(mine, compute_dtype=jnp.float32)).apply(
-                {"params": adapter._block(cut)["attn"]}, a)
-            assert rel_err(out, reference.attention(a, cut, mine)) < 1e-5
+            cfg = adapter.model_config(mine, compute_dtype=jnp.float32)
+            out = compiled(lambda params, a: Attention(cfg).apply({"params": params}, a),
+                           adapter._block(cut)["attn"], a)
+            assert rel_err(out, compiled(
+                lambda a, cut: reference.attention(a, cut, mine), a, cut)) < 1e-5
             total = total + out
     assert rel_err(total, want) < 1e-5 and rel_err(out, want) > 0.3
 
@@ -309,23 +322,25 @@ def test_the_expert_shares_add_up_with_what_every_chip_computes_alike_counted_on
     assert sz["init_std"] == 0.125  # the toy's matrices: the routed part is no rounding error
     a = jax.random.normal(jax.random.PRNGKey(6), (2, 32, sz["hidden_size"]), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        want = reference.experts(a, w, everything)
-        shared = reference.relu2(a @ w["s_up"]) @ w["s_down"]
+        want = compiled(lambda a, w: reference.experts(a, w, everything), a, w)
+        shared = compiled(lambda a, w: reference.relu2(a @ w["s_up"]) @ w["s_down"], a, w)
         routed = jnp.zeros_like(a)
         for share in range(total_experts // held):
             mine = {**sz, "experts_held": (share * held, held)}
             cut = {**w, "e_up": w["e_up"][share * held:(share + 1) * held],
                    "e_down": w["e_down"][share * held:(share + 1) * held]}
-            out = LatentExperts(adapter.model_config(mine, compute_dtype=jnp.float32)).apply(
-                {"params": adapter._block(cut)["moe"]}, a)
-            assert rel_err(out, reference.experts(a, cut, mine)) < 1e-5
+            cfg = adapter.model_config(mine, compute_dtype=jnp.float32)
+            out = compiled(lambda params, a: LatentExperts(cfg).apply({"params": params}, a),
+                           adapter._block(cut)["moe"], a)
+            assert rel_err(out, compiled(
+                lambda a, cut: reference.experts(a, cut, mine), a, cut)) < 1e-5
             routed = routed + (out - shared)
     assert total_experts // held == 8 and rel_err(shared + routed, want) < 1e-5
     # no share alone is the routed part, and the routed part is no small term beside the shared
     assert rel_err(out - shared, want - shared) > 0.3
     assert float(jnp.linalg.norm(want - shared)) > 0.1 * float(jnp.linalg.norm(shared))
     # every token made 5 choices of 16 and each share held 2: a buffer of two rows a token
-    chosen, _ = reference.route(a, w, everything)
+    chosen, _ = compiled(lambda a, w: reference.route(a, w, everything), a, w)
     assert chosen.shape[-1] == 5 > held
 
 
@@ -336,8 +351,11 @@ def test_the_router_reads_the_hidden_state_and_the_gate_comes_before_the_norm(ad
     params = reference.init_params(jax.random.PRNGKey(0), sz)
     ids = adapter.draw_batch(jax.random.PRNGKey(1), 2, sz)
     model = NemotronHModel(adapter.model_config(sz, compute_dtype=jnp.float32))
-    base = float(nemotron_h_loss_fn(model)(adapter.to_program(params, sz), ids))
-    assert base == pytest.approx(float(reference.loss(params, ids, sz)), abs=2e-6)
+    def reference_loss(params):
+        return float(compiled(lambda p, ids: reference.loss(p, ids, sz), params, ids))
+
+    base = float(compiled(nemotron_h_loss_fn(model), adapter.to_program(params, sz), ids))
+    assert base == pytest.approx(reference_loss(params), abs=2e-6)
     # the gate's z columns scaled: a norm *after* the gate undoes a common scale of y * silu(z)
     # only in part, a norm *before* it not at all; the loss moves either way, and with the
     # reference's
@@ -345,8 +363,8 @@ def test_the_router_reads_the_hidden_state_and_the_gate_comes_before_the_norm(ad
     inner = sz["mamba_heads_held"][1] * sz["mamba_head_dim"]
     moved["layers"][0]["w_in"] = moved["layers"][0]["w_in"].at[:, :inner].multiply(3.0)
     moved["layers"][0]["w_out"] = 50.0 * moved["layers"][0]["w_out"]
-    got = float(nemotron_h_loss_fn(model)(adapter.to_program(moved, sz), ids))
-    assert got == pytest.approx(float(reference.loss(moved, ids, sz)), abs=2e-5)
+    got = float(compiled(nemotron_h_loss_fn(model), adapter.to_program(moved, sz), ids))
+    assert got == pytest.approx(reference_loss(moved), abs=2e-5)
     assert abs(got - base) > 1e-4
 
 
@@ -369,7 +387,8 @@ def test_four_devices_through_train_step_give_the_references_gradient_of_the_glo
         state = ddp.init(start)
         assert ddp.plan.num_buckets > 4
         state, losses = ddp.train_step(state, ddp.shard_batch(ids))
-        ref_loss, ref_grads = jax.value_and_grad(reference.loss)(ref_params, ids, sz)
+        ref_loss, ref_grads = compiled(
+            jax.value_and_grad(lambda p, ids: reference.loss(p, ids, sz)), ref_params, ids)
     assert float(np.mean(np.asarray(losses))) == pytest.approx(float(ref_loss), abs=2e-6)
     want = adapter.to_program(ref_grads, sz, cast=False)
     flat = lambda tree: {jax.tree_util.keystr(p): x  # noqa: E731

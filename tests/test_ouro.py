@@ -9,7 +9,9 @@ it against a version that keeps everything and against the exit that rebuilds
 its logits; the engine on four devices: one exchange a bucket of the summed
 gradient, with the exchange inside the backward pass and after it; and the
 scopes that name the model's parts and passes, with the summary's
-``recompute`` class and ``model_pass_ms``."""
+``recompute`` class and ``model_pass_ms``.  The shared parts (the attention
+layer, ``RMSNorm``, ``SwiGLU``) have their tests in ``test_decoder.py``; every
+comparison here runs both sides compiled (``helpers.compiled``)."""
 
 import os
 import re
@@ -27,7 +29,7 @@ from bagua_tpu.algorithms.gradient_allreduce import GradientAllReduceAlgorithm
 from bagua_tpu.analysis.verify import _abstract, collect_ir
 from bagua_tpu.ddp import DistributedDataParallel
 from bagua_tpu.models import ouro
-from bagua_tpu.models.llama import RMSNorm
+from bagua_tpu.models.decoder import RMSNorm
 from bagua_tpu.models.losses import softmax_cross_entropy
 from bagua_tpu.models.ouro import (
     OuroBlock,
@@ -53,7 +55,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "ci"))
 from benchmark import manifest  # noqa: E402
-from test_lfm2_moe import rel_err  # noqa: E402
+from helpers import compiled  # noqa: E402
+from oracles import rel_err  # noqa: E402
 from trim_capture import xspace_bytes  # noqa: E402
 
 PARTS = ("embed", "attn_proj", "attn_core", "dense_mlp", "head", "exit_gate")
@@ -97,9 +100,10 @@ def test_loss_and_every_gradient_leaf_match_the_reference_in_float32(
     ids = adapter.draw_batch(jax.random.PRNGKey(seed + 1), 2, sz)
     model = OuroModel(adapter.model_config(sz, compute_dtype=jnp.float32))
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(ouro_loss_fn(model))(
-            adapter.to_program(ref_params, sz), ids)
-        ref_loss, ref_grads = jax.value_and_grad(reference.loss)(ref_params, ids, sz)
+        loss, grads = compiled(jax.value_and_grad(ouro_loss_fn(model)),
+                               adapter.to_program(ref_params, sz), ids)
+        ref_loss, ref_grads = compiled(
+            jax.value_and_grad(lambda p, ids: reference.loss(p, ids, sz)), ref_params, ids)
     # the exits add rows weighted 1 / N where the reference divides a sum by N: another order
     assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
     want = adapter.to_program(ref_grads, sz, cast=False)
@@ -142,7 +146,8 @@ def test_the_seeded_gate_spreads_the_mass_over_the_passes(adapter, reference):
     for seed in (0, 1, 2):
         params = adapter.to_program(reference.init_params(jax.random.PRNGKey(seed), sz), sz)
         ids = adapter.draw_batch(jax.random.PRNGKey(seed + 10), 4, sz)
-        _, gates = OuroModel(adapter.model_config(sz, jnp.float32)).apply({"params": params}, ids)
+        _, gates = compiled(lambda params, ids: OuroModel(
+            adapter.model_config(sz, jnp.float32)).apply({"params": params}, ids), params, ids)
         shares = np.asarray(jnp.mean(exit_distribution(gates), axis=(1, 2)))
         assert shares.sum() == pytest.approx(1.0, abs=1e-5)
         assert shares.min() > 0.1 and np.min(np.abs(np.diff(np.sort(shares)))) > 0.01, shares
@@ -198,8 +203,9 @@ def test_the_loop_equals_an_untied_stack_and_a_shared_gradient_is_the_sum_of_its
     params["exit_gate_bias"] = jnp.float32(-0.4)
     shared = {k: v for k, v in params.items() if k != "embedding"}
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(ouro_loss_fn(model))(params, ids)
-        untied, (d_embedding, d_copies) = jax.value_and_grad(untied_loss(cfg, ids), argnums=(0, 1))(
+        loss, grads = compiled(jax.value_and_grad(ouro_loss_fn(model)), params, ids)
+        untied, (d_embedding, d_copies) = compiled(
+            jax.value_and_grad(untied_loss(cfg, ids), argnums=(0, 1)),
             params["embedding"], [shared] * passes)
     assert float(loss) == pytest.approx(float(untied), abs=1e-6)
     assert rel_err(grads["embedding"], d_embedding) < 1e-5
@@ -219,11 +225,14 @@ def test_the_next_pass_reads_the_normed_state_and_positions_start_over(adapter, 
     params = reference.init_params(jax.random.PRNGKey(0), sz)
     ids = adapter.draw_batch(jax.random.PRNGKey(1), 2, sz)
     model = OuroModel(adapter.model_config(sz, compute_dtype=jnp.float32))
-    got = float(ouro_loss_fn(model)(adapter.to_program(params, sz), ids))
-    assert got == pytest.approx(float(reference.loss(params, ids, sz)), abs=2e-5)
+    def reference_loss(params):
+        return float(compiled(lambda p, ids: reference.loss(p, ids, sz), params, ids))
+
+    got = float(compiled(ouro_loss_fn(model), adapter.to_program(params, sz), ids))
+    assert got == pytest.approx(reference_loss(params), abs=2e-5)
     # the same weights under another scale of the final norm give another second pass
     scaled = {**params, "final_norm": 2.0 * params["final_norm"]}
-    assert abs(float(reference.loss(scaled, ids, sz)) - got) > 1e-3
+    assert abs(reference_loss(scaled) - got) > 1e-3
 
 
 # -- the exits ----------------------------------------------------------------
@@ -242,16 +251,16 @@ def oracle_distribution(gates):
     [-40.0, 40.0, 5.0, -5.0], [0.0], [1.5, -0.5]], ids=str)
 def test_the_exit_distribution_equals_the_oracle_and_sums_to_one(logits):
     gate_logits = jnp.asarray(logits, jnp.float32)[:, None]
-    p = np.asarray(exit_distribution(gate_logits))[:, 0]
+    p = np.asarray(compiled(exit_distribution, gate_logits))[:, 0]
     want = oracle_distribution([1.0 / (1.0 + np.exp(-np.float64(x))) for x in logits])
     np.testing.assert_allclose(p, want, atol=1e-6)
     assert p.sum() == pytest.approx(1.0, abs=1e-6) and np.all(p >= 0)
     # the last pass's own gate decides nothing
     moved = gate_logits.at[-1].set(7.0)
-    np.testing.assert_array_equal(np.asarray(exit_distribution(moved))[:, 0], p)
+    np.testing.assert_array_equal(np.asarray(compiled(exit_distribution, moved))[:, 0], p)
     # the entropy and its gradient are finite where a share is exactly zero
-    value, grad = jax.value_and_grad(lambda g: jnp.sum(distribution_entropy(exit_distribution(g))))(
-        gate_logits)
+    value, grad = compiled(jax.value_and_grad(
+        lambda g: jnp.sum(distribution_entropy(exit_distribution(g)))), gate_logits)
     assert np.isfinite(float(value)) and np.all(np.isfinite(np.asarray(grad)))
     assert float(value) == pytest.approx(
         -sum(w * np.log(w) for w in want if w > 1e-30), abs=1e-5)
@@ -262,13 +271,14 @@ def test_one_pass_is_the_plain_cross_entropy():
     model = OuroModel(cfg)
     ids = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
     params = model.init(jax.random.PRNGKey(2), ids)["params"]
-    logits, gates = model.apply({"params": params}, ids)
+    logits, gates = compiled(lambda params, ids: model.apply({"params": params}, ids), params, ids)
     assert logits.shape == (1, 2, 16, cfg.vocab_size) and gates.shape == (1, 2, 16)
-    p = exit_distribution(gates)
+    p = compiled(exit_distribution, gates)
     np.testing.assert_array_equal(np.asarray(p), 1.0)
-    np.testing.assert_array_equal(np.asarray(distribution_entropy(p)), 0.0)
-    plain = jnp.mean(softmax_cross_entropy(logits[0], jnp.roll(ids, -1, axis=1))[:, :-1])
-    assert float(ouro_loss_fn(model)(params, ids)) == pytest.approx(float(plain), abs=1e-6)
+    np.testing.assert_array_equal(np.asarray(compiled(distribution_entropy, p)), 0.0)
+    plain = compiled(lambda logits, ids: jnp.mean(
+        softmax_cross_entropy(logits[0], jnp.roll(ids, -1, axis=1))[:, :-1]), logits, ids)
+    assert float(compiled(ouro_loss_fn(model), params, ids)) == pytest.approx(float(plain), abs=1e-6)
 
 
 def test_given_targets_the_model_returns_what_each_exit_adds_to_the_loss():
@@ -278,13 +288,16 @@ def test_given_targets_the_model_returns_what_each_exit_adds_to_the_loss():
     targets = jnp.roll(ids, -1, axis=1)
     params = model.init(jax.random.PRNGKey(4), ids)["params"]
     params["exit_gate_bias"] = jnp.float32(0.3)
-    logits, gates = model.apply({"params": params}, ids)
-    sums, gates_again = model.apply({"params": params}, ids, targets)
+    logits, gates = compiled(lambda params, ids: model.apply({"params": params}, ids), params, ids)
+    sums, gates_again = compiled(
+        lambda params, ids, targets: model.apply({"params": params}, ids, targets),
+        params, ids, targets)
     assert sums.shape == (3,) and sums.dtype == jnp.float32 and gates.shape == (3, 2, 16)
     np.testing.assert_allclose(np.asarray(gates_again), np.asarray(gates), atol=1e-6)
-    weighted = exit_distribution(gates) * softmax_cross_entropy(logits, targets[None])
-    np.testing.assert_allclose(
-        np.asarray(sums), np.asarray(jnp.mean(weighted[:, :, :-1], axis=(1, 2))), rtol=2e-6)
+    want = compiled(lambda gates, logits, targets: jnp.mean((
+        exit_distribution(gates) * softmax_cross_entropy(logits, targets[None]))[:, :, :-1],
+        axis=(1, 2)), gates, logits, targets)
+    np.testing.assert_allclose(np.asarray(sums), np.asarray(want), rtol=2e-6)
     # the mean's weights: one place, the last position of every sequence out
     weights = np.asarray(mean_weights((2, 16)))
     assert weights.shape == (2, 16) and not weights[:, -1].any()
@@ -294,15 +307,20 @@ def test_given_targets_the_model_returns_what_each_exit_adds_to_the_loss():
 @pytest.mark.parametrize("passes", [1, 2, 4])
 def test_the_shares_taken_pass_by_pass_are_the_exit_distribution(passes):
     gate_logits = 3.0 * jax.random.normal(jax.random.PRNGKey(passes), (passes, 2, 16), jnp.float32)
-    left, shares = jnp.ones((2, 16), jnp.float32), []
-    for t in range(passes):
-        share, left = exit_share(gate_logits[t], left, last=t == passes - 1)
-        shares.append(share)
+    def pass_by_pass(gate_logits):
+        left, shares = jnp.ones((2, 16), jnp.float32), []
+        for t in range(passes):
+            share, left = exit_share(gate_logits[t], left, last=t == passes - 1)
+            shares.append(share)
+        return jnp.stack(shares), left
+
+    shares, left = compiled(pass_by_pass, gate_logits)
     np.testing.assert_allclose(
-        np.asarray(jnp.stack(shares)), np.asarray(exit_distribution(gate_logits)), atol=1e-6)
+        np.asarray(shares), np.asarray(compiled(exit_distribution, gate_logits)), atol=1e-6)
     assert not np.asarray(left).any()  # the last pass leaves nothing
     # the first pass's share moves with its own gate, unless it is the last and takes everything
-    moved = exit_share(gate_logits[0] + 1.0, jnp.ones((2, 16)), last=passes == 1)[0]
+    moved = compiled(lambda gate: exit_share(gate + 1.0, jnp.ones((2, 16)), last=passes == 1)[0],
+                     gate_logits[0])
     assert (passes == 1) == bool(np.all(np.asarray(moved) == np.asarray(shares[0])))
 
 
@@ -345,12 +363,13 @@ def test_the_exits_three_gradients_are_autodiffs_of_the_plain_composition(dtype)
     assert not own[:, -1].any() and not own[0, 5].any() and own[0, 3].any()
     # a label outside picks nothing: the row reads its log-sum-exp, and so does its weight's gradient
     with jax.default_matmul_precision("highest"):
-        lse = jax.nn.logsumexp(ouro._logits(h, head, dtype), axis=-1)
+        lse = compiled(lambda h, head: jax.nn.logsumexp(ouro._logits(h, head, dtype), axis=-1),
+                       h, head)
     np.testing.assert_allclose(np.asarray(grads[2])[[0, 1], [3, 0]],
                                0.37 * np.asarray(lse)[[0, 1], [3, 0]], rtol=1e-5)
     # differentiated or not, the same value
-    assert float(ouro._exit_sum(h, head, targets, weights)[1]) == pytest.approx(
-        float(plain_exit_sum(h, head, targets, weights)[1]), rel=1e-6)
+    assert float(compiled(ouro._exit_sum, h, head, targets, weights)[1]) == pytest.approx(
+        float(compiled(plain_exit_sum, h, head, targets, weights)[1]), rel=1e-6)
 
 
 def per_position_model(cfg, exit_fn):
@@ -441,15 +460,17 @@ def test_a_model_of_per_position_entropies_and_the_exit_of_three_arguments_still
     ids = jax.random.randint(jax.random.PRNGKey(9), (2, 16), 0, cfg.vocab_size)
     params = OuroModel(cfg).init(jax.random.PRNGKey(10), ids)["params"]
     model = per_position_model(cfg, ouro._exit)
-    entropies, gates = model.apply({"params": params}, ids, jnp.roll(ids, -1, axis=1))
+    entropies, gates = compiled(
+        lambda params, ids: model.apply({"params": params}, ids, jnp.roll(ids, -1, axis=1)),
+        params, ids)
     assert entropies.shape == gates.shape == (3, 2, 16)
-    want = float(ouro_loss_fn(OuroModel(cfg))(params, ids))
-    assert float(ouro_loss_fn(model)(params, ids)) == pytest.approx(want, rel=1e-6)
+    want = float(compiled(ouro_loss_fn(OuroModel(cfg)), params, ids))
+    assert float(compiled(ouro_loss_fn(model), params, ids)) == pytest.approx(want, rel=1e-6)
     step = jax.jit(lambda p: jax.tree.map(
         lambda x, g: x - 0.5 * g, p, jax.grad(ouro_loss_fn(model))(p, ids)))
     for _ in range(3):
         params = step(params)
-    after = float(ouro_loss_fn(OuroModel(cfg))(params, ids))
+    after = float(compiled(ouro_loss_fn(OuroModel(cfg)), params, ids))
     assert np.isfinite(after) and after < want - 0.05
 
 
@@ -483,7 +504,8 @@ def test_four_devices_one_exchange_a_bucket_of_the_summed_gradient(adapter, refe
         program, _ = collect_ir(ddp._build_sharded("default"), (_abstract(state), _abstract(ids)),
                                 dict(group.mesh.shape))
         state, losses = ddp.train_step(state, ddp.shard_batch(ids))
-        ref_loss, ref_grads = jax.value_and_grad(reference.loss)(ref_params, ids, sz)
+        ref_loss, ref_grads = compiled(
+            jax.value_and_grad(lambda p, ids: reference.loss(p, ids, sz)), ref_params, ids)
     # one labelled exchange a bucket in either mode: the tuple fuse's one variadic psum is an
     # equation a leaf in the jaxpr, so a bucket of n leaves reads n, each of a whole leaf, once
     exchanges = program.by_bucket_phase()

@@ -1,14 +1,14 @@
 """SmallThinker-21BA3B at toy sizes on the CPU: the program's model against the
-benchmark's plain reference on seeded weights, once per kind of layer; windowed
-attention of both backends' code (the composition, and the chip's kernels
-through Pallas' interpreter) against a dense masked oracle at seven query heads
-a key-value head; ``window=None`` unchanged bit for bit; the softmax router
-against a one-line oracle; the ReLU gate at six choices a token; one chip's
-share against the whole expert layer; the grouped product's tile at width 768
-of 2,560; and the scopes that name the model's parts."""
+benchmark's plain reference on seeded weights, once per kind of layer; the
+softmax router against a one-line oracle; the ReLU gate at six choices a token;
+one chip's share against the whole expert layer; the grouped product's tile at
+width 768 of 2,560; and the scopes that name the model's parts.  The attention
+kernel under a window has its tests in ``test_causal_attention.py``, the shared
+attention layer (with and without positions, with and without a window) in
+``test_decoder.py``; every comparison here runs both sides compiled
+(``helpers.compiled``)."""
 
 import os
-import re
 import sys
 
 import jax
@@ -16,8 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bagua_tpu.kernels import causal_attention as causal_attention_module
-from bagua_tpu.kernels.causal_attention import blocked_causal_attention, causal_attention
 from bagua_tpu.models.smallthinker_moe import (
     PUBLISHED_LAYOUT,
     SmallThinkerBlock,
@@ -39,7 +37,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "ci"))
 from benchmark import manifest  # noqa: E402
-from test_lfm2_moe import _one_head_count_composition, both_passes, rel_err  # noqa: E402
+from helpers import compiled  # noqa: E402
+from oracles import rel_err  # noqa: E402
 from trim_capture import xspace_bytes  # noqa: E402
 
 PARTS = ("attn_proj", "attn_core", "attn_window_core", "moe_route", "moe_dispatch",
@@ -86,9 +85,10 @@ def test_loss_and_every_gradient_leaf_match_the_reference_in_float32(
     ids = adapter.draw_batch(jax.random.PRNGKey(seed + 1), 2, sz)
     model = SmallThinkerModel(adapter.model_config(sz, compute_dtype=jnp.float32))
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(smallthinker_loss_fn(model))(
-            adapter.to_program(ref_params, sz), ids)
-        ref_loss, ref_grads = jax.value_and_grad(reference.loss)(ref_params, ids, sz)
+        loss, grads = compiled(jax.value_and_grad(smallthinker_loss_fn(model)),
+                               adapter.to_program(ref_params, sz), ids)
+        ref_loss, ref_grads = compiled(
+            jax.value_and_grad(lambda p, ids: reference.loss(p, ids, sz)), ref_params, ids)
     assert float(loss) == pytest.approx(float(ref_loss), abs=2e-6)
     want = adapter.to_program(ref_grads, sz, cast=False)
     assert jax.tree.structure(grads) == jax.tree.structure(want)
@@ -113,8 +113,9 @@ def test_the_window_and_the_positions_change_the_loss(adapter, reference):
         for rope in (0, 1):
             kind = {**sz, "sliding_window_layout": (window,), "rope_layout": (rope,)}
             model = SmallThinkerModel(adapter.model_config(kind, compute_dtype=jnp.float32))
-            got = float(smallthinker_loss_fn(model)(adapter.to_program(params, kind), ids))
-            assert got == pytest.approx(float(reference.loss(params, ids, kind)), abs=2e-5)
+            got = float(compiled(smallthinker_loss_fn(model), adapter.to_program(params, kind), ids))
+            assert got == pytest.approx(float(compiled(
+                lambda p, ids: reference.loss(p, ids, kind), params, ids)), abs=2e-5)
             losses.add(round(got, 5))
     assert len(losses) == 4
 
@@ -157,159 +158,23 @@ def test_the_config_is_built_from_the_published_keys():
     assert toy.sliding_window_layout == toy.rope_layout == (0, 1)
 
 
-# -- attention under a window -------------------------------------------------
-
-
-def masked_attention(q, k, v, scale, window=None):
-    """Every score written down under the explicit mask, each key-value head
-    repeated for its group."""
-    group = q.shape[1] // k.shape[1]
-    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-    i, j = jnp.arange(q.shape[2])[:, None], jnp.arange(q.shape[2])[None, :]
-    seen = (i >= j) if window is None else (i >= j) & (i - j < window)
-    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1), v)
-
-
-@pytest.mark.parametrize("block_q", [8, 32, 64])
-@pytest.mark.parametrize("window", [1, 5, 24, 33, 64, 100],
-                         ids=lambda w: f"window{w}")
-def test_the_compositions_window_equals_the_dense_mask_at_seven_queries_a_key(window, block_q):
-    """Windows inside one block of queries, across blocks, of the whole
-    sequence (64) and beyond it; 14 query heads on 2 key-value heads."""
-    b, heads, kv_heads, t, d, scale = 2, 14, 2, 64, 16, 0.25
-    keys = jax.random.split(jax.random.PRNGKey(9), 4)
-    q, d_out = (jax.random.normal(kk, (b, heads, t, d)) for kk in keys[:2])
-    k, v = (jax.random.normal(kk, (b, kv_heads, t, d)) for kk in keys[2:])
-    with jax.default_matmul_precision("highest"):
-        got = both_passes(lambda *a: blocked_causal_attention(*a, scale, block_q, window),
-                          q, k, v, d_out)
-        want = both_passes(lambda *a: masked_attention(*a, scale, window), q, k, v, d_out)
-        causal = both_passes(lambda *a: masked_attention(*a, scale), q, k, v, d_out)
-    for g, w in zip(got, want):
-        # a window of one key is a softmax of one score: dq and dk are zero
-        assert g.shape == w.shape and np.linalg.norm(g - w) < 1e-5 * max(
-            np.linalg.norm(w), np.linalg.norm(d_out))
-    # the window is a mask of its own until it holds the sequence
-    assert (rel_err(want[0], causal[0]) > 0.05) == (window < t)
-    if block_q == 64:  # off the chip the one entry point is the composition
-        np.testing.assert_array_equal(
-            causal_attention(q, k, v, scale, window=window),
-            blocked_causal_attention(q, k, v, scale, 64, window if window < t else None))
-
-
-def test_a_window_leaves_the_keys_behind_it_out_of_the_blocks():
-    """The composition forms no score behind the window: the widest block of
-    scores is ``window + block_q - 1`` keys, not the sequence."""
-    q = jax.ShapeDtypeStruct((1, 7, 256, 16), jnp.float32)
-    kv = jax.ShapeDtypeStruct((1, 1, 256, 16), jnp.float32)
-
-    def widest(window):
-        text = str(jax.make_jaxpr(
-            lambda q, k, v: blocked_causal_attention(q, k, v, 1.0, 32, window))(q, kv, kv))
-        return max(int(shape.split(",")[3]) for shape in
-                   re.findall(r"f32\[(1,1,224,\d+)\]", text))
-
-    assert widest(None) == 256 and widest(64) == 64 + 31 and widest(32) == 32 + 31
-    with pytest.raises(ValueError, match="not even the current position"):
-        causal_attention(jnp.zeros(q.shape), jnp.zeros(kv.shape), jnp.zeros(kv.shape), 1.0, window=0)
-
-
-@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-def test_no_window_is_unchanged_bit_for_bit(dtype, jitted):
-    """``models/glm_moe.py`` and ``models/lfm2_moe.py`` call without a
-    window: result and gradients are the bits the composition gave before it
-    had one (PR 29's text, kept in ``test_lfm2_moe.py``), and a window that
-    holds the sequence is that call."""
-    b, h, t, d, scale, block_q = 2, 3, 128, 32, 0.17, 32
-    q, k, v, d_out = (jax.random.normal(kk, (b, h, t, d), dtype)
-                      for kk in jax.random.split(jax.random.PRNGKey(0), 4))
-
-    def now(q, k, v, d_out):
-        return both_passes(lambda *a: blocked_causal_attention(*a, scale, block_q), q, k, v, d_out)
-
-    def spelled(q, k, v, d_out):
-        return both_passes(lambda *a: blocked_causal_attention(*a, scale, block_q, None),
-                           q, k, v, d_out)
-
-    def before(q, k, v, d_out):
-        return _one_head_count_composition(q, k, v, d_out, scale, block_q)
-
-    if jitted:
-        now, spelled, before = jax.jit(now), jax.jit(spelled), jax.jit(before)
-    for got, same, want in zip(now(q, k, v, d_out), spelled(q, k, v, d_out), before(q, k, v, d_out)):
-        np.testing.assert_array_equal(got, want)
-        np.testing.assert_array_equal(same, want)
-    # the entry point: no window, None, and a window of the whole sequence trace to one program
-    texts = {str(jax.make_jaxpr(lambda q, k, v: causal_attention(q, k, v, scale, **kw))(q, k, v))
-             for kw in ({}, {"window": None}, {"window": t}, {"window": t + 5})}
-    assert len(texts) == 1
-    assert str(jax.make_jaxpr(lambda q, k, v: causal_attention(q, k, v, scale, window=t - 1))(
-        q, k, v)) not in texts
-
-
-@pytest.mark.parametrize("window", [600, 1024, 1500], ids=lambda w: f"window{w}")
-def test_the_chips_kernels_under_a_window_serve_seven_query_heads_in_interpret_mode(window):
-    """The TPU branch through Pallas' interpreter at two tiles of positions: a
-    window inside one tile, of one tile, and across the two; 7 query heads on
-    one key-value head, no key repeated."""
-    b, heads, kv_heads, d = 1, 7, 1, 32
-    t = 2 * causal_attention_module.SPLASH_BLOCK_MAJOR
-    keys = jax.random.split(jax.random.PRNGKey(3), 4)
-    q, d_out = (jax.random.normal(kk, (b, heads, t, d), jnp.float32) for kk in keys[:2])
-    k, v = (jax.random.normal(kk, (b, kv_heads, t, d), jnp.float32) for kk in keys[2:])
-    with jax.default_matmul_precision("highest"):
-        got = both_passes(lambda q, k, v: causal_attention_module._splash_causal_attention(
-            q, k, v, 0.125, interpret=True, window=window), q, k, v, d_out)
-        want = both_passes(lambda q, k, v: masked_attention(q, k, v, 0.125, window), q, k, v, d_out)
-    for g, w, like in zip(got, want, (q, q, k, v)):
-        assert g.shape == like.shape and rel_err(g, w) < 1e-5
-
-
-def test_a_window_is_a_kernel_of_its_own_and_no_window_the_one_there_was():
-    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
-
-    build = causal_attention_module._splash_kernel
-    major = causal_attention_module.SPLASH_BLOCK_MAJOR
-    t = 8 * major
-    causal = build(7, t, True, multi_query=True, window=None)
-    assert causal is build(7, t, True, multi_query=True, window=None)
-    windowed = build(7, t, True, multi_query=True, window=4 * major)
-    assert windowed is not causal and windowed is build(7, t, True, multi_query=True, window=4 * major)
-    assert windowed.kwargs["is_mqa"] and causal.kwargs["is_mqa"]
-    # the published shapes: 8,192 positions in tiles of 1,024, a window of 4,096 keys.  The
-    # causal mask keeps 36 of the 64 tiles; the window takes the 6 farthest from the diagonal
-    assert (t, 4 * major) == (8192, 4096)
-
-    def tiles(mask):
-        blocks = np.asarray(mask[:, :]).reshape(8, major, 8, major)
-        return int(blocks.any(axis=(1, 3)).sum())
-
-    assert tiles(masks.CausalMask((t, t))) == 36
-    local = masks.LocalMask((t, t), window_size=(4 * major - 1, 0), offset=0)
-    assert tiles(local) == 30
-    # and the mask is the issue's: i >= j and i - j < 4096
-    i, j = np.arange(5000, 5003)[:, None], np.arange(t)[None, :]
-    np.testing.assert_array_equal(np.asarray(local[5000:5003, :]), (i >= j) & (i - j < 4096))
-
-
 # -- the router ---------------------------------------------------------------
 
 
 def test_the_softmax_router_is_a_softmax_over_the_largest_logits():
     h = jax.random.normal(jax.random.PRNGKey(0), (64, 32))
     w = jax.random.normal(jax.random.PRNGKey(1), (32, 16))
-    chosen, weights = softmax_topk_route(h.astype(jnp.bfloat16), w, 6)
+    chosen, weights = compiled(lambda h, w: softmax_topk_route(h, w, 6), h.astype(jnp.bfloat16), w)
     with jax.default_matmul_precision("highest"):
-        top, want = jax.lax.top_k(h.astype(jnp.bfloat16).astype(jnp.float32) @ w, 6)
+        top, want = compiled(lambda h, w: jax.lax.top_k(
+            h.astype(jnp.bfloat16).astype(jnp.float32) @ w, 6), h, w)
     assert chosen.dtype == jnp.int32 and weights.dtype == jnp.float32
     np.testing.assert_array_equal(chosen, want)
     np.testing.assert_allclose(weights, jax.nn.softmax(top, axis=-1), rtol=1e-6)
     np.testing.assert_allclose(jnp.sum(weights, axis=-1), 1.0, rtol=1e-6)
     assert np.all(np.diff(np.asarray(weights), axis=-1) <= 0)  # largest first
     # the weights' gradient reaches the router through the chosen logits alone
-    grad = jax.grad(lambda w: jnp.sum(softmax_topk_route(h, w, 6)[1][:, 0]))(w)
+    grad = compiled(jax.grad(lambda w: jnp.sum(softmax_topk_route(h, w, 6)[1][:, 0])), w)
     assert np.linalg.norm(grad) > 0
 
 
@@ -342,7 +207,8 @@ def test_the_relu_gate_at_six_choices_a_token_equals_every_expert_on_every_token
     tokens, hidden, width, experts, fan = 48, 32, 16, 16, 6
     keys = jax.random.split(jax.random.PRNGKey(2), 6)
     x = jax.random.normal(keys[0], (tokens, hidden))
-    chosen, weights = softmax_topk_route(x, jax.random.normal(keys[1], (hidden, experts)), fan)
+    chosen, weights = compiled(lambda x, router: softmax_topk_route(x, router, fan),
+                               x, jax.random.normal(keys[1], (hidden, experts)))
     gate, up = (jax.random.normal(kk, (held[1], hidden, width)) * 0.2 for kk in keys[2:4])
     down = jax.random.normal(keys[4], (held[1], width, hidden)) * 0.2
     d_out = jax.random.normal(keys[5], (tokens, hidden))
@@ -356,14 +222,18 @@ def test_the_relu_gate_at_six_choices_a_token_equals_every_expert_on_every_token
         return lambda x, weights, gate, up, down: dense_experts(
             x, chosen, weights, gate, up, down, held[0], activation)
 
+    args = (x, weights, gate, up, down)
+
     def passes(fn):
-        out, vjp = jax.vjp(fn, x, weights, gate, up, down)
-        return (out,) + vjp(d_out)
+        def run(*args):
+            out, vjp = jax.vjp(fn, *args)
+            return (out,) + vjp(d_out)
+        return compiled(run, *args)
 
     with jax.default_matmul_precision("highest"):
         got, want = passes(layer(jax.nn.relu)), passes(oracle(jax.nn.relu))
-        silu = layer(None)(x, weights, gate, up, down)
-        silu_want = oracle(jax.nn.silu)(x, weights, gate, up, down)
+        silu = compiled(layer(None), *args)
+        silu_want = compiled(oracle(jax.nn.silu), *args)
     for g, w in zip(got, want):
         assert g.shape == w.shape and rel_err(g, w) < 1e-5
     # the default gate is SiLU still, and the two gates are two functions
@@ -401,24 +271,32 @@ def test_the_eight_shares_add_up_to_the_uncut_references_layer(adapter, referenc
     eps = sz["rms_norm_eps"]
     with jax.default_matmul_precision("highest"):
         # the reference's layer, taken apart: what attention adds, then the routed part
-        chosen, picked = reference.route(reference.rms_norm(x, w["norm_in"], eps), w, whole)
-        x1 = x + reference.attention(reference.rms_norm(x, w["norm_in"], eps), w, whole, True, True)
-        want = reference.experts(reference.rms_norm(x1, w["norm_post"], eps), chosen, picked, w, whole)
-        assert rel_err(x1 + want, reference.layer(x, w, whole, True, True)) < 1e-6
+        def taken_apart(x, w):
+            chosen, picked = reference.route(reference.rms_norm(x, w["norm_in"], eps), w, whole)
+            x1 = x + reference.attention(
+                reference.rms_norm(x, w["norm_in"], eps), w, whole, True, True)
+            want = reference.experts(
+                reference.rms_norm(x1, w["norm_post"], eps), chosen, picked, w, whole)
+            late, _ = reference.route(reference.rms_norm(x1, w["norm_post"], eps), w, whole)
+            return chosen, x1, want, late
+
+        chosen, x1, want, late = compiled(taken_apart, x, w)
+        assert rel_err(x1 + want, compiled(
+            lambda x, w: reference.layer(x, w, whole, True, True), x, w)) < 1e-6
         routed = jnp.zeros_like(x)
         for share in range(total):
             held = (share, 1)
             mine = {k: v[share:share + 1] for k, v in w.items() if k.startswith("e_")}
             cfg = adapter.model_config({**sz, "experts_held": held}, compute_dtype=jnp.float32)
-            out = SmallThinkerBlock(cfg, True, True).apply(
-                {"params": adapter._block({**w, **mine})}, x)
-            part = reference.layer(x, {**w, **mine}, {**sz, "experts_held": held}, True, True)
+            out = compiled(lambda params, x: SmallThinkerBlock(cfg, True, True).apply(
+                {"params": params}, x), adapter._block({**w, **mine}), x)
+            part = compiled(lambda x, w: reference.layer(
+                x, w, {**sz, "experts_held": held}, True, True), x, {**w, **mine})
             assert rel_err(out, part) < 1e-5
             routed = routed + (out - x1)
     assert total == 8 and rel_err(routed, want) < 1e-5
     # no share alone is the layer, and the router chose before attention: from norm_in(x)
     assert rel_err(out - x1, want) > 0.3
-    late, _ = reference.route(reference.rms_norm(x1, w["norm_post"], eps), w, whole)
     assert np.mean(np.asarray(late) != np.asarray(chosen)) > 0.05
 
 

@@ -3,7 +3,8 @@ position after the other: values and every gradient at a length of several
 chunks, with decays near 0 and near 1, in groups; which products are rounded;
 and that no length or decay overflows.  Two implementations of it: the plain
 ``jax.numpy`` form every backend but the TPU runs, and the pair of Pallas
-kernels the TPU runs, whose bodies run here under Pallas' interpreter."""
+kernels the TPU runs, whose bodies run here under Pallas' interpreter.  Every
+comparison runs both sides compiled (``helpers.compiled``)."""
 
 import logging
 
@@ -14,6 +15,8 @@ import pytest
 
 from bagua_tpu.kernels import ssd_scan as module
 from bagua_tpu.kernels.ssd_scan import ssd_scan
+from helpers import compiled
+from oracles import rel_err
 
 
 def interpreted(x, dt, a, b, c, chunk):
@@ -57,11 +60,6 @@ DECAYS = {"near_one": (-1e-3, -2e-3, -5e-3, -1e-2), "near_zero": (-16.0, -12.0, 
           "mixed": (-1e-3, -0.5, -4.0, -16.0)}
 
 
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30))
-
-
 #: the plain form at three chunk lengths of 64 positions; the kernels at one chunk of 128 and
 #: at three, where the carried state and its cotangent cross a chunk's edge, in two groups
 CASES = {
@@ -83,21 +81,22 @@ def test_the_chunked_scan_equals_the_recurrence_forward_and_in_every_gradient(de
     # difference five digits, and ``a``'s gradient, a sum of such terms, 7e-5; in a chunk of
     # 128 they pass six hundred and leave a digit less (the plain form as the kernels)
     forward, backward = (2e-6, 2e-4) if chunk < 128 else (1e-5, 1e-3)
+    args = (x, dt, a, b, c)
+
+    def value_and_gradients(fn):
+        """``fn``'s result and the five gradients of its probed sum, as one program."""
+        def both(*args):
+            out, pull = jax.vjp(fn, *args)
+            return out, pull(probe.astype(out.dtype))
+        return compiled(both, *args)
+
     with jax.default_matmul_precision("highest"):
-        want = recurrence(x, dt, a, b, c)
-        got = scan(x, dt, a, b, c, chunk)
+        want, want_g = value_and_gradients(recurrence)
+        got, got_g = value_and_gradients(lambda *args: scan(*args, chunk))
         assert got.shape == x.shape and got.dtype == x.dtype and rel_err(got, want) < forward
-
-        def scalar(fn):
-            return lambda *args: jnp.sum(probe * fn(*args))
-
-        want_g = jax.grad(scalar(recurrence), argnums=range(5))(x, dt, a, b, c)
-        got_g = jax.grad(scalar(lambda *args: scan(*args, chunk)), argnums=range(5))(
-            x, dt, a, b, c)
         if scan is interpreted:  # and the plain form it stands in for on the chip
-            assert rel_err(got, module._chunked(x, dt, a, b, c, chunk)) < forward
-            plain_g = jax.grad(scalar(lambda *args: module._chunked(*args, chunk)),
-                               argnums=range(5))(x, dt, a, b, c)
+            plain, plain_g = value_and_gradients(lambda *args: module._chunked(*args, chunk))
+            assert rel_err(got, plain) < forward
             for name, g, w in zip(("x", "dt", "a", "b", "c"), got_g, plain_g):
                 assert rel_err(g, w) < backward, (name, rel_err(g, w))
     for name, g, w in zip(("x", "dt", "a", "b", "c"), got_g, want_g):
@@ -111,8 +110,8 @@ def test_a_head_reads_its_own_groups_b_and_c(implementation):
     chunk = 128 if shape else 16
     x, dt, b, c = drawn(1, t=4 * chunk, **shape)
     a = jnp.asarray(DECAYS["mixed"])
-    base = scan(x, dt, a, b, c, chunk)
-    other = scan(x, dt, a, b.at[:, :, 1].add(1.0), c, chunk)
+    base = compiled(lambda *args: scan(*args, chunk), x, dt, a, b, c)
+    other = compiled(lambda *args: scan(*args, chunk), x, dt, a, b.at[:, :, 1].add(1.0), c)
     # heads 0 and 1 are group 0, heads 2 and 3 group 1
     np.testing.assert_array_equal(other[:, :, :2], base[:, :, :2])
     assert rel_err(other[:, :, 2:], base[:, :, 2:]) > 0.1
@@ -124,12 +123,15 @@ def test_the_scan_is_causal_and_carries_the_state_between_chunks(implementation)
     chunk = 128 if shape else 16
     x, dt, b, c = drawn(2, t=4 * chunk, **shape)
     a = jnp.asarray(DECAYS["near_one"])
-    base = scan(x, dt, a, b, c, chunk)
+    def run(x):
+        return compiled(lambda *args: scan(*args, chunk), x, dt, a, b, c)
+
+    base = run(x)
     inside = 2 * chunk + chunk // 2  # in the third chunk
-    later = scan(x.at[:, inside:].set(3.0), dt, a, b, c, chunk)
+    later = run(x.at[:, inside:].set(3.0))
     np.testing.assert_array_equal(later[:, :inside], base[:, :inside])
     # positions of the third chunk read what the first chunk wrote into the state
-    early = scan(x.at[:, :chunk].set(0.0), dt, a, b, c, chunk)
+    early = run(x.at[:, :chunk].set(0.0))
     assert rel_err(early[:, 2 * chunk:3 * chunk], base[:, 2 * chunk:3 * chunk]) > 0.05
 
 
@@ -146,17 +148,22 @@ def _equations(jaxpr, primitive):
 def test_the_kernels_round_the_products_alone_and_the_state_stays_float32():
     x, dt, b, c = drawn(3, dtype=jnp.bfloat16, t=1024, **KERNEL_SHAPE)
     a = jnp.asarray(DECAYS["mixed"])
-    got, pull = jax.vjp(lambda *args: interpreted(*args, 128), x, dt, a, b, c)
-    grads = pull(jnp.ones_like(got))
+    def under_ones(fn):
+        def both(*args):
+            out, pull = jax.vjp(fn, *args)
+            return out, pull(jnp.ones_like(out))
+        return both
+
+    got, grads = compiled(under_ones(lambda *args: interpreted(*args, 128)), x, dt, a, b, c)
     assert got.dtype == jnp.bfloat16
     assert [g.dtype for g in grads] == [v.dtype for v in (x, dt, a, b, c)]
     exact = [v.astype(jnp.float32) for v in (x, dt, a, b, c)]
-    want, exact_pull = jax.vjp(recurrence, *exact)
+    want, want_grads = compiled(under_ones(recurrence), *exact)
     assert rel_err(got.astype(jnp.float32), want) < 2e-2
     # the cotangents of dt and a are sums of differences that cancel on paper: rounded
     # operands must not keep them from cancelling (0.01 and 0.1 on the chip when the two ends
     # of a flow took differently rounded numbers, PERF.md section 6, PR 46)
-    for name, g, w in zip(("x", "dt", "a", "b", "c"), grads, exact_pull(jnp.ones_like(want))):
+    for name, g, w in zip(("x", "dt", "a", "b", "c"), grads, want_grads):
         assert rel_err(g.astype(jnp.float32), w) < 2e-2, (name, rel_err(g.astype(jnp.float32), w))
     # both kernels: a product takes bf16 operands and accumulates in float32, or sums float32
     # columns at the highest precision; the state between chunks is float32 and never a product
@@ -177,10 +184,10 @@ def test_the_kernels_round_the_products_alone_and_the_state_stays_float32():
 def test_bf16_operands_round_the_products_alone_and_the_state_stays_float32():
     x, dt, b, c = drawn(3, dtype=jnp.bfloat16)
     a = jnp.asarray(DECAYS["mixed"])
-    got = ssd_scan(x, dt, a, b, c, chunk=16)
+    got = compiled(lambda *args: ssd_scan(*args, chunk=16), x, dt, a, b, c)
     assert got.dtype == jnp.bfloat16
-    want = recurrence(*(v.astype(jnp.float32) for v in (x, dt)), a,
-                      *(v.astype(jnp.float32) for v in (b, c)))
+    want = compiled(recurrence, *(v.astype(jnp.float32) for v in (x, dt)), a,
+                    *(v.astype(jnp.float32) for v in (b, c)))
     assert rel_err(got.astype(jnp.float32), want) < 2e-2
     # the three chunk products take bf16 operands and accumulate in float32; the product that
     # carries the state between chunks is float32 at the highest precision
@@ -202,12 +209,12 @@ def test_no_length_and_no_decay_overflows(implementation):
     x, dt, b, c = drawn(4, batch=1, t=4096, heads=2, groups=1, **small)
     a = jnp.asarray([-16.0, -1e-4])
     dt = dt + 1.0
-    value, grads = jax.value_and_grad(
-        lambda *args: jnp.sum(jnp.square(scan(*args, 128))), argnums=range(5))(
-        x, dt, a, b, c)
+    value, grads = compiled(jax.value_and_grad(
+        lambda *args: jnp.sum(jnp.square(scan(*args, 128))), argnums=range(5)), x, dt, a, b, c)
     assert np.isfinite(float(value))
     assert all(np.all(np.isfinite(np.asarray(g))) for g in grads)
-    assert rel_err(scan(x, dt, a, b, c, 128), recurrence(x, dt, a, b, c)) < 1e-4
+    assert rel_err(compiled(lambda *args: scan(*args, 128), x, dt, a, b, c),
+                   compiled(recurrence, x, dt, a, b, c)) < 1e-4
 
 
 def test_positions_that_do_not_divide_into_chunks_and_heads_into_groups_are_refused():
@@ -215,8 +222,8 @@ def test_positions_that_do_not_divide_into_chunks_and_heads_into_groups_are_refu
     with pytest.raises(ValueError, match="no whole number"):
         ssd_scan(x, dt, jnp.asarray(DECAYS["mixed"]), b, c, chunk=16)
     # a sequence shorter than a chunk is one chunk
-    short = ssd_scan(x, dt, jnp.asarray(DECAYS["mixed"]), b, c, chunk=128)
-    assert rel_err(short, recurrence(x, dt, jnp.asarray(DECAYS["mixed"]), b, c)) < 1e-5
+    short = compiled(lambda *args: ssd_scan(*args, chunk=128), x, dt, jnp.asarray(DECAYS["mixed"]), b, c)
+    assert rel_err(short, compiled(recurrence, x, dt, jnp.asarray(DECAYS["mixed"]), b, c)) < 1e-5
     with pytest.raises(ValueError, match="no whole number"):
         ssd_scan(x[:, :, :3], dt[:, :, :3], jnp.asarray(DECAYS["mixed"][:3]), b, c, chunk=8)
 
@@ -240,6 +247,8 @@ def test_on_a_tpu_a_shape_the_kernels_refuse_runs_the_plain_form_and_says_nothin
     monkeypatch.setattr(module, "_scan_kernels", kernels)
     a = jnp.asarray(DECAYS["mixed"])
     taken = drawn(6, t=256, **KERNEL_SHAPE)
+    # eager: what is pinned is which form a call reaches, and a compiled ``ssd_scan`` that an
+    # earlier case has traced is not traced again
     assert rel_err(ssd_scan(taken[0], taken[1], a, *taken[2:]),
                    module._chunked(taken[0], taken[1], a, *taken[2:], 128)) < 1e-5
     assert reached == [128]
