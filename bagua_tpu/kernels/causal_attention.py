@@ -51,7 +51,12 @@ to the right, whose blocks behind the window leave the grid on the host and
 whose two edges are applied only in the blocks they cross.  At 8,192
 positions and a window of 4,096 that is 30 of a layer's 36 tiles of 1,024 x
 1,024 (``models/smallthinker_moe.py``: windowed and global layers in one
-stack, 7 query heads a key-value head).  A window that holds the whole
+stack, 7 query heads a key-value head).  The tile edges are a function of the
+mask (:func:`splash_blocks`): under a window narrower than those tiles
+(``models/laguna.py``: 512 keys, 8 query heads a key-value head) the edges are
+512 and the backward pass is two kernels, ``dK`` and ``dV`` in one and ``dQ``
+in the other, so that no ``dQ`` partial is written for the key blocks a query
+block never sees.  A window that holds the whole
 sequence *is* the causal mask and runs as ``window=None`` does; and
 ``window=None`` builds and runs what it did before there was a window.
 """
@@ -76,6 +81,37 @@ SPLASH_BLOCKS = dict(
     block_q_dkv=1024, block_kv_dkv=1024, block_kv_dkv_compute=512)
 #: the positions must divide by every edge; the edges are powers of two
 SPLASH_BLOCK_MAJOR = max(SPLASH_BLOCKS.values())
+#: the edges under a window narrower than a tile above, where those tiles are
+#: mostly masked scores: 512 keys at 8,192 positions leave 4.06 M pairs open, and
+#: tiles of 1,024 (8 on the diagonal, 7 beside it) run 15.7 M.  Decided on the
+#: v5e at 64 query heads on 8 key-value heads x 8,192 x 128 under a window of 512
+#: by a plain SGD step's time of one such layer (PERF.md section 6, PR 49,
+#: twenty-seven variants): every edge 512 and the backward pass as *two* kernels
+#: (``splash_mqa_dkv`` and ``splash_mqa_dq``) 56.36 ms, the tiles above 59.29;
+#: the fused backward writes one ``dQ`` partial per ``block_kv_dkv`` keys, sixteen
+#: at 512 of which two a query block hold anything, and read 60.18 at these edges
+#: and 80.54 at 256; 256 or 1,024 on any one side of any of the three kernels
+#: 56.96 to 58.12
+SPLASH_NARROW_BLOCKS = dict(
+    block_q=512, block_kv=512, block_kv_compute=512,
+    block_q_dkv=512, block_kv_dkv=512, block_kv_dkv_compute=512,
+    block_q_dq=512, block_kv_dq=512, use_fused_bwd_kernel=False)
+
+
+def splash_blocks(t: int, window=None) -> dict:
+    """The tile edges of the splash kernels for ``t`` positions under the
+    causal mask (``window=None``) or under a window of so many keys: the mask
+    decides how much of a tile is work.  No window, and a window of at least a
+    tile's keys (``models/smallthinker_moe.py``: 4,096 at 8,192), take
+    :data:`SPLASH_BLOCKS`, whose backward pass is the one fused kernel; a
+    narrower one (``models/laguna.py``: 512 at 8,192, the one measured) takes
+    :data:`SPLASH_NARROW_BLOCKS`, which says so where its backward is two,
+    wherever its edges divide the positions."""
+    narrow = SPLASH_NARROW_BLOCKS
+    if window is None or window >= SPLASH_BLOCKS["block_kv"] or t % narrow["block_q"]:
+        return SPLASH_BLOCKS
+    return narrow
+
 
 _NEG = -1e30  # finite: a masked score must not make ``exp(s - m)`` a NaN
 
@@ -122,10 +158,9 @@ def _splash_kernel(heads: int, t: int, interpret: bool = False, multi_query: boo
            else masks.LocalMask((t, t), window_size=(window - 1, 0), offset=0))
     mask = masks.MultiHeadMask([one] * heads)
     make = splash.make_splash_mqa if multi_query else splash.make_splash_mha
+    blocks = splash.BlockSizes(**{"use_fused_bwd_kernel": True, **splash_blocks(t, window)})
     with jax.ensure_compile_time_eval():  # the tables are constants of whatever trace asks first
-        return make(
-            mask, block_sizes=splash.BlockSizes(use_fused_bwd_kernel=True, **SPLASH_BLOCKS),
-            head_shards=1, q_seq_shards=1, interpret=interpret)
+        return make(mask, block_sizes=blocks, head_shards=1, q_seq_shards=1, interpret=interpret)
 
 
 def _splash_causal_attention(q, k, v, scale: float, interpret: bool = False, window=None):

@@ -12,8 +12,9 @@ scales (``q`` carries ``1 / sqrt(head size)``) and rounds each of ``q`` and
 no array with the positions in it is transposed.
 """
 
+import dataclasses
 import math
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -86,16 +87,47 @@ def shift(x, by: int, axis: int = 1):
     return jax.lax.slice_in_dim(jnp.pad(x, pad), max(-by, 0), max(-by, 0) + t, axis=axis)
 
 
+def rotary(x, inv_freq, scale: float = 1.0, factor: float = 1.0):
+    """The rotary embedding from its tables, in float32: the first ``2 *
+    len(inv_freq)`` columns of ``x (..., positions, size)`` are turned in the
+    rotate-half pairing (column ``i`` with ``i + len(inv_freq)``, by ``position
+    * inv_freq[i]``) with ``cos`` and ``sin`` times ``factor``; the columns
+    after them pass through unrotated and without the factor; all times
+    ``scale``."""
+    t, size = x.shape[-2:]
+    half = inv_freq.shape[0]
+    ang = jnp.arange(t).astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(ang) * (scale * factor), jnp.sin(ang) * (scale * factor)
+    first, second = x[..., :half].astype(jnp.float32), x[..., half:2 * half].astype(jnp.float32)
+    turned = [first * cos - second * sin, second * cos + first * sin]
+    if 2 * half < size:
+        turned.append(x[..., 2 * half:].astype(jnp.float32) * scale)
+    return jnp.concatenate(turned, axis=-1)
+
+
 def rotate_half(x, theta: float, scale: float = 1.0):
     """The rotary embedding on all columns of ``x (..., positions, size)`` in
     the rotate-half pairing (column ``i`` with ``i + size / 2``), in float32,
-    times ``scale``."""
-    t, size = x.shape[-2:]
+    times ``scale``: :func:`rotary` with the tables ``theta ** (-2i / size)``."""
+    size = x.shape[-1]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, size, 2, dtype=jnp.float32) / size))
-    ang = jnp.arange(t).astype(jnp.float32)[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
-    first, second = x[..., :size // 2].astype(jnp.float32), x[..., size // 2:].astype(jnp.float32)
-    return jnp.concatenate([first * cos - second * sin, second * cos + first * sin], axis=-1)
+    return rotary(x, inv_freq, scale)
+
+
+@dataclasses.dataclass(frozen=True)
+class RotaryTables:
+    """A rotary embedding as its tables: ``inv_freq`` a pair (twice as many
+    columns are rotated, the first of a head's; the others pass through) and
+    the ``factor`` on ``cos`` and ``sin``.  ``rope_theta`` of
+    :class:`GroupedQueryAttention` is the case ``theta ** (-2i / head size)``
+    over all columns with factor 1."""
+
+    inv_freq: Tuple[float, ...]
+    factor: float = 1.0
+
+    @property
+    def columns(self) -> int:
+        return 2 * len(self.inv_freq)
 
 
 class GroupedQueryAttention(Kernels):
@@ -104,10 +136,14 @@ class GroupedQueryAttention(Kernels):
     query heads.  The rest a model's configuration states: ``norm_eps``, an
     :class:`RMSNorm` over every head of ``q`` and of ``k`` (``q_norm``,
     ``k_norm``) or none; ``rope_theta``, :func:`rotate_half` on ``q`` and ``k``
-    with the score's scale in ``q``'s tables, or no position and ``q`` times
-    the scale; ``window``, so many keys counting the current one (under
+    with the score's scale in ``q``'s tables, or ``rope``, the tables
+    themselves (:class:`RotaryTables`: fewer columns than a head has, blended
+    frequencies, a factor), or no position and ``q`` times the scale;
+    ``window``, so many keys counting the current one (under
     ``attn_window_core``, so that a capture reads the two masks apart) or all
-    earlier keys (``attn_core``)."""
+    earlier keys (``attn_core``); ``gate``, a scalar a head and position,
+    ``sigmoid(x W_g)`` from the layer's own input, on the core's result before
+    ``W_o`` (under ``attn_gate``)."""
 
     heads: int
     kv_heads: int
@@ -116,6 +152,8 @@ class GroupedQueryAttention(Kernels):
     norm_eps: Optional[float] = None
     rope_theta: Optional[float] = None
     window: Optional[int] = None
+    rope: Optional[RotaryTables] = None
+    gate: bool = False
 
     @nn.nowrap
     def core(self, q, k, v):
@@ -128,18 +166,24 @@ class GroupedQueryAttention(Kernels):
     def __call__(self, x):
         dt, size = self.compute_dtype, self.head_size
         hidden = x.shape[-1]
+        if self.rope is not None and (self.rope_theta is not None or self.rope.columns > size):
+            raise ValueError(f"rope_theta {self.rope_theta} beside tables, or tables of "
+                             f"{self.rope.columns} columns for heads of {size}")
 
         def heads_of(name, count, scale=None):
             """One projection as the kernels read it: the product alone where nothing
             follows it, else normed, rotated and scaled in float32 and rounded once."""
             kernel = self.kernel(name + "_proj", hidden, count * size).reshape(hidden, count, size)
-            plain = self.norm_eps is None and self.rope_theta is None
+            plain = self.norm_eps is None and self.rope_theta is None and self.rope is None
             if scale is None or (plain and scale == 1.0):
                 return product(HEADS_MAJOR, x, kernel, dt)
             y = jnp.einsum(HEADS_MAJOR, x.astype(dt), kernel.astype(dt),
                            preferred_element_type=jnp.float32)
             if self.norm_eps is not None:
                 y = RMSNorm(self.norm_eps, name=name + "_norm")(y)
+            if self.rope is not None:
+                return rotary(y, jnp.asarray(self.rope.inv_freq, jnp.float32), scale,
+                              self.rope.factor).astype(dt)
             if self.rope_theta is None:
                 return (y * scale).astype(dt)
             return rotate_half(y, self.rope_theta, scale).astype(dt)
@@ -150,6 +194,12 @@ class GroupedQueryAttention(Kernels):
             v = heads_of("v", self.kv_heads)
             out = self.kernel("out_proj", self.heads * size, hidden).reshape(-1, size, hidden)
         ctx = self.core(q, k, v)
+        if self.gate:
+            with model_scope("attn_gate"):
+                opened = jax.nn.sigmoid(jnp.einsum(
+                    "btm,mh->bth", x.astype(dt), self.kernel("gate_proj", hidden, self.heads).astype(dt),
+                    preferred_element_type=jnp.float32))
+                ctx = (ctx * opened.swapaxes(1, 2)[..., None]).astype(dt)
         with model_scope("attn_proj"):
             return product("bhtd,hdm->btm", ctx, out, dt)
 

@@ -135,7 +135,9 @@ def model_scope(part: str):
     Mamba-2 mixer's two products, ``ssm_conv``, its depthwise causal
     convolution with the bias and SiLU, ``ssm_core``, the chunked scan, the
     ``D`` term, the gate and the group norm, and ``moe_latent``, the two
-    projections between the hidden and the experts' latent width).  Any name
+    projections between the hidden and the experts' latent width; in
+    ``models/laguna.py`` ``attn_gate``, the per-head output gate between the
+    core and ``W_o``: its product, sigmoid and multiplication).  Any name
     is a part: the summary keeps what it finds.
     Autodiff carries the frame into the backward pass's ops, and
     ``jax.checkpoint`` into those it runs again there, so the device trace

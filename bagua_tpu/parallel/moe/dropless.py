@@ -66,12 +66,15 @@ The grouped product is ``megablox.gmm`` (Pallas, ships with JAX) on a TPU
 and ``jax.lax.ragged_dot`` elsewhere; rows past the held groups are left
 unwritten by the one and zero by the other, so every use masks them.  The
 kernel's tile is a function of the product's shape (:func:`gmm_tiling`): the
-layer has four callers whose experts differ in width, in the model's hidden
-size, in the choices a token makes and in the rows a group gets
-(``models/glm_moe.py`` 1536 of 2048, 4 choices; ``models/lfm2_moe.py`` 1792 of
-2048, 4; ``models/smallthinker_moe.py`` 768 of 2560, 6: a buffer of 49,152
-rows; ``models/nemotron_h.py`` 2688 of a latent 1024, no gate, 22 choices of
-512 experts with 8 held: a buffer of 65,536 rows for 2,816 expected).
+layer has five callers whose experts differ in width, in the model's hidden
+size, in the choices a token makes, in the groups held and in the rows a group
+gets (``models/glm_moe.py`` 1536 of 2048, 4 choices; ``models/lfm2_moe.py``
+1792 of 2048, 4; ``models/smallthinker_moe.py`` 768 of 2560, 6: a buffer of
+49,152 rows; ``models/nemotron_h.py`` 2688 of a latent 1024, no gate, 22
+choices of 512 experts with 8 held: a buffer of 65,536 rows for 2,816
+expected; ``models/laguna.py`` 512 of 2048, 8 choices of 256 experts with 32
+held, the one caller with more than 8 groups: a buffer of 65,536 rows for
+8,192 expected, 256 a group).
 """
 
 import functools
@@ -105,12 +108,19 @@ from bagua_tpu.observability.annotations import model_scope
 #: one expert layer: at these rows the tile moves nothing that can be read,
 #: 61.53 to 61.58 ms over all ten, these two 61.53 and the default below, whose
 #: contraction of 2,688 falls to tiles of 128, 61.58; none refused, 2,688
-#: columns against the whole contraction among them).  Every tile of it
+#: columns against the whole contraction among them).  512 of 2,048
+#: (``laguna-xs.2``: 32 groups of 256 expected rows in a buffer of 65,536;
+#: PERF.md section 6, PR 49, eight tiles by a plain SGD step's time of one
+#: expert layer: the whole contraction and all columns in one tile both ways,
+#: at 128 rows 60.53 ms and at 256 rows 60.54; 1,024 columns the other way
+#: 60.67; a contraction of 1,024 or 512, or 512 rows, 61.23 to 61.47; the
+#: default below 62.39; none refused).  Every tile of it
 #: divides its dimension: a contraction tile that hangs over is masked in
 #: float32 at every step of the kernel's grid.
 GMM_TILES = {1536: (512, 1024, 768), 1792: (128, 0, 896),
              (2560, 768): (256, 1280, 768), (768, 2560): (256, 0, 1280),
-             (1024, 2688): (128, 0, 896), (2688, 1024): (128, 896, 1024)}
+             (1024, 2688): (128, 0, 896), (2688, 1024): (128, 896, 1024),
+             (2048, 512): (128, 0, 512), (512, 2048): (128, 0, 2048)}
 
 
 def gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:

@@ -21,8 +21,10 @@ import pytest
 from bagua_tpu.kernels import causal_attention as causal_attention_module
 from bagua_tpu.kernels.causal_attention import (
     SPLASH_BLOCKS,
+    SPLASH_NARROW_BLOCKS,
     blocked_causal_attention,
     causal_attention,
+    splash_blocks,
 )
 from helpers import compiled
 from oracles import both_passes, quadratic_attention, rel_err
@@ -385,3 +387,58 @@ def test_a_window_is_a_kernel_of_its_own_and_no_window_the_one_there_was():
     # and the mask is the issue's: i >= j and i - j < 4096
     i, j = np.arange(5000, 5003)[:, None], np.arange(t)[None, :]
     np.testing.assert_array_equal(np.asarray(local[5000:5003, :]), (i >= j) & (i - j < 4096))
+
+
+def test_the_tile_edges_are_a_function_of_the_mask_and_todays_where_a_tile_is_mostly_work():
+    """``splash_blocks(positions, window)``: the causal mask and SmallThinker's
+    4,096 keys at 8,192 keep the edges they had and the fused backward; Laguna's
+    512 keys take edges of 512 and a backward pass of two kernels."""
+    today = dict(block_q=1024, block_kv=1024, block_kv_compute=256,
+                 block_q_dkv=1024, block_kv_dkv=1024, block_kv_dkv_compute=512)
+    assert SPLASH_BLOCKS == today
+    for window in (None, 4096, 1024, 8192):
+        assert splash_blocks(8192, window) is SPLASH_BLOCKS
+    narrow = splash_blocks(8192, 512)
+    assert narrow is SPLASH_NARROW_BLOCKS is splash_blocks(8192, 1023) is splash_blocks(2048, 1)
+    assert narrow == dict(block_q=512, block_kv=512, block_kv_compute=512, block_q_dkv=512,
+                          block_kv_dkv=512, block_kv_dkv_compute=512, block_q_dq=512,
+                          block_kv_dq=512, use_fused_bwd_kernel=False)
+    edges = [v for k, v in narrow.items() if k.startswith("block")]
+    assert all(causal_attention_module.SPLASH_BLOCK_MAJOR % e == 0 for e in edges)
+    assert splash_blocks(8192 + 256, 512) is SPLASH_BLOCKS  # edges that do not divide: today's
+    # the pairs the tiles cover at 8,192 positions under 512 keys: 3.9 times the window's at
+    # today's edges, twice at these
+    pairs = 512 * 513 // 2 + (8192 - 512) * 512
+    assert pairs == 4_063_488
+    assert (8 + 7) * 1024 ** 2 / pairs == pytest.approx(3.87, abs=0.01)
+    assert (16 + 15) * 512 ** 2 / pairs == pytest.approx(2.0, abs=0.01)
+
+
+def test_a_narrow_window_builds_three_kernels_and_a_wide_one_the_two_it_built():
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
+
+    build = causal_attention_module._splash_kernel
+    major = causal_attention_module.SPLASH_BLOCK_MAJOR
+    t = 2 * major
+
+    def kernels(window):
+        q = jax.ShapeDtypeStruct((1, 8, t, 128), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((1, 1, t, 128), jnp.bfloat16)
+        return str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+            causal_attention_module._splash_causal_attention(
+                q, k, v, 1.0, interpret=True, window=window).astype(jnp.float32)),
+            argnums=(0, 1, 2)))(q, kv, kv))
+
+    narrow, wide = kernels(512), kernels(major)
+    assert narrow.count("pallas_call") == 3 and wide.count("pallas_call") == 2
+    for name in ("splash_mqa_fwd", "splash_mqa_dkv", "splash_mqa_dq"):
+        assert name in narrow
+    assert "splash_mqa_dq" not in wide and "splash_mqa_dkv" in wide
+    assert build(8, t, True, multi_query=True, window=512) is build(
+        8, t, True, multi_query=True, window=512)
+    # the mask is the issue's: i >= j and i - j < 512; 16 + 15 tiles of 512 at 8,192 positions
+    local = masks.LocalMask((8192, 8192), window_size=(511, 0), offset=0)
+    blocks = np.asarray(local[:, :]).reshape(16, 512, 16, 512)
+    assert int(blocks.any(axis=(1, 3)).sum()) == 31
+    i, j = np.arange(5000, 5003)[:, None], np.arange(8192)[None, :]
+    np.testing.assert_array_equal(np.asarray(local[5000:5003, :]), (i >= j) & (i - j < 512))

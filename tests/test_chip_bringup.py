@@ -380,6 +380,22 @@ for width_in, width_out in ((1024, 2688), (2688, 1024)):
         shape((65536, width_out)), shape((65536, width_in)), shape((8, width_in, width_out)),
         sizes).compile().as_text()
     print("LATENT-WIDTH-KERNELS", text.count("tpu_custom_call"), flush=True)
+# laguna-xs.2.dp1-s8192 (PR 49): 64 query heads on 8 key-value heads x 8,192 x 128 under a
+# window of 512 keys, at edges of 512 with the backward pass as two kernels, and 48 on 8
+# under the causal mask at the edges every other caller has; experts of width 512 of 2,048,
+# 32 groups in a buffer of eight rows a token, at the tiles measured for the two shapes
+kv = shape((1, 8, 8192, 128))
+for label, heads, window in (("NARROW-WINDOW-KERNELS", 64, 512), ("GROUP-OF-6-KERNELS", 48, None)):
+    q = shape((1, heads, 8192, 128))
+    text = jax.jit(both_passes(lambda q, k, v: causal_attention(q, k, v, 1.0, window=window))).lower(
+        q, q, kv, kv).compile().as_text()
+    print(label, text.count("tpu_custom_call"), flush=True)
+for width_in, width_out in ((2048, 512), (512, 2048)):
+    text = jax.jit(lambda out_grad, rows, kernels, group_sizes: both_passes(
+        lambda r, k: grouped_matmul(r, k, group_sizes))(out_grad, rows, kernels)).lower(
+        shape((65536, width_out)), shape((65536, width_in)), shape((32, width_in, width_out)),
+        shape((32,), jnp.int32)).compile().as_text()
+    print("WIDTH-512-KERNELS", text.count("tpu_custom_call"), flush=True)
 # the cell's whole step under the engine's compiler options: inside it the fused
 # backward kernel needs 0.3 to 0.4 MB more fast memory than compiled alone (PR 30)
 from bagua_tpu.ddp import STEP_COMPILER_OPTIONS
@@ -550,6 +566,14 @@ def test_the_expert_models_kernels_compile_for_the_chip_at_the_cells_shapes():
               if line.startswith(("GROUP-OF-4-AT-128-KERNELS", "LATENT-WIDTH-KERNELS"))]
     assert latent == [["GROUP-OF-4-AT-128-KERNELS", "2"], ["LATENT-WIDTH-KERNELS", "3"],
                       ["LATENT-WIDTH-KERNELS", "3"]], latent
+    # laguna-xs.2.dp1-s8192 (PR 49): under the 512-key window a forward kernel and a backward
+    # pass of two (dK and dV, then dQ: no partial of dQ for key blocks a query block never
+    # sees); under the causal mask the two every other caller runs; the grouped products over
+    # 32 groups at the tiles measured for 512 of 2,048
+    laguna = [line.split() for line in proc.stdout.splitlines()
+              if line.startswith(("NARROW-WINDOW-KERNELS", "GROUP-OF-6-KERNELS", "WIDTH-512-KERNELS"))]
+    assert laguna == [["NARROW-WINDOW-KERNELS", "3"], ["GROUP-OF-6-KERNELS", "2"],
+                      ["WIDTH-512-KERNELS", "3"], ["WIDTH-512-KERNELS", "3"]], laguna
     # five layers' forward and fused backward kernels in the step the cell runs
     step = next(line.split() for line in proc.stdout.splitlines()
                 if line.startswith("STEP-ATTENTION-KERNELS"))

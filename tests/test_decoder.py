@@ -24,15 +24,17 @@ from bagua_tpu.models import decoder, smallthinker_moe
 from bagua_tpu.models.decoder import (
     GroupedQueryAttention,
     RMSNorm,
+    RotaryTables,
     SwiGLU,
     next_token_loss_fn,
+    rotary,
     rotate_half,
     shift,
 )
 from helpers import REPO_ROOT, compiled, worker_env
 from oracles import quadratic_attention, rel_err
 
-MODELS = ("glm_moe", "lfm2_moe", "smallthinker_moe", "ouro", "nemotron_h")
+MODELS = ("glm_moe", "lfm2_moe", "smallthinker_moe", "ouro", "nemotron_h", "laguna")
 #: what a model file needs none of, and every one of them loaded until PR 48
 NOT_A_DECODERS = ("bagua_tpu.models.llama", "bagua_tpu.models.gpt",
                   "bagua_tpu.parallel.ring_attention", "bagua_tpu.parallel.tensor_parallel")
@@ -86,6 +88,29 @@ def test_rotate_half_turns_each_pair_as_a_complex_number_and_answers_in_float32(
     # a turn keeps every pair's length: the scale is all that changes a head's norm
     np.testing.assert_allclose(np.linalg.norm(got, axis=-1),
                                0.25 * np.linalg.norm(np.asarray(x, np.float32), axis=-1), rtol=1e-5)
+
+
+def test_rotary_turns_the_tables_columns_and_passes_the_others_through_unrotated_and_unscaled():
+    """Tables of three pairs on a head of ten columns: columns 0 to 5 turn (``i``
+    with ``i + 3``) with ``cos`` and ``sin`` times the factor, columns 6 to 9 pass
+    through with the scale alone; and ``rotate_half`` is the tables ``theta ** (-2i /
+    size)`` over all columns, bit for bit."""
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 2, 12, 10))
+    inv_freq = jnp.asarray([1.0, 0.3, 0.01])
+    got = compiled(lambda x: rotary(x, inv_freq, 0.5, 1.4), x)
+    assert got.dtype == jnp.float32 and got.shape == x.shape
+    angle = np.arange(12)[:, None] * np.asarray(inv_freq, np.float64)
+    z = (np.asarray(x[..., :3], np.float64) + 1j * np.asarray(x[..., 3:6], np.float64)) * np.exp(
+        1j * angle) * 0.5 * 1.4
+    assert rel_err(got[..., :6], np.concatenate([z.real, z.imag], axis=-1)) < 1e-6
+    np.testing.assert_array_equal(got[..., 6:], 0.5 * x[..., 6:])  # no turn, no factor
+    np.testing.assert_array_equal(got[..., 0, :6], np.float32(0.5 * 1.4) * x[..., 0, :6])
+    size, theta = 8, 1e4
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 3, 6, size))
+    tables = 1.0 / (theta ** (jnp.arange(0, size, 2, dtype=jnp.float32) / size))
+    np.testing.assert_array_equal(compiled(lambda x: rotate_half(x, theta, 0.25), x),
+                                  compiled(lambda x: rotary(x, tables, 0.25), x))
+    assert RotaryTables((1.0, 0.3, 0.01), 1.4).columns == 6 and RotaryTables((1.0,)).factor == 1.0
 
 
 @pytest.mark.parametrize("by", [0, 1, 3, -2, 9, -9])
@@ -149,21 +174,27 @@ def test_the_next_token_loss_is_the_mean_over_each_sequences_targets():
 
 # -- the attention layer ---------------------------------------------------------
 
-#: the layer as each model builds it: ``norm_eps``, ``rope_theta``, ``window``
+#: tables over half of a head's eight columns, blended frequencies, a factor
+TABLES = RotaryTables((1.0, 0.05), 1.4)
+#: the layer as each model builds it: ``norm_eps``, ``rope_theta``, ``window``, ``rope``, ``gate``
 SETTINGS = {
     "normed_heads_and_rotary": dict(norm_eps=1e-5, rope_theta=1e6),   # lfm2_moe
     "rotary": dict(rope_theta=1e6),                                  # ouro, smallthinker's windowed
     "no_positions": dict(),                                          # smallthinker's global layers
     "rotary_under_a_window": dict(rope_theta=1.5e6, window=5),       # smallthinker's windowed
     "no_positions_under_a_window": dict(window=5),                   # the two keys apart
+    "gated_tables_on_half_the_columns": dict(rope=TABLES, gate=True),  # laguna's global layers
+    "gated_rotary_under_a_window": dict(rope_theta=1e4, window=5, gate=True),  # its windowed
 }
 HEADS, KV_HEADS, SIZE, HIDDEN = 6, 2, 8, 32
 
 
-def plain_layer(params, x, norm_eps=None, rope_theta=None, window=None):
+def plain_layer(params, x, norm_eps=None, rope_theta=None, window=None, rope=None, gate=False):
     """The layer written down: three projections onto heads, a norm over each
-    head's columns if any, the rotation if any, every score under the mask,
-    and the output projection over ``(heads, head size)``."""
+    head's columns if any, the rotation if any (all columns at ``rope_theta``,
+    or the tables' columns with their factor, the others passing through), every
+    score under the mask, a scalar a head and position from the layer's input on
+    the result if gated, and the output projection over ``(heads, head size)``."""
     def heads_of(name, count):
         y = jnp.einsum("btm,mhd->bhtd", x, params[name + "_proj"].reshape(HIDDEN, count, SIZE))
         if norm_eps is not None and name != "v":
@@ -175,10 +206,18 @@ def plain_layer(params, x, norm_eps=None, rope_theta=None, window=None):
             a, b = y[..., :SIZE // 2], y[..., SIZE // 2:]
             y = jnp.concatenate([a * jnp.cos(angle) - b * jnp.sin(angle),
                                  b * jnp.cos(angle) + a * jnp.sin(angle)], axis=-1)
+        if rope is not None and name != "v":
+            half = len(rope.inv_freq)
+            angle = jnp.arange(y.shape[2])[:, None] * jnp.asarray(rope.inv_freq)
+            cos, sin = rope.factor * jnp.cos(angle), rope.factor * jnp.sin(angle)
+            a, b = y[..., :half], y[..., half:2 * half]
+            y = jnp.concatenate([a * cos - b * sin, b * cos + a * sin, y[..., 2 * half:]], axis=-1)
         return y
 
     ctx = quadratic_attention(heads_of("q", HEADS), heads_of("k", KV_HEADS), heads_of("v", KV_HEADS),
                               1.0 / math.sqrt(SIZE), window)
+    if gate:
+        ctx = ctx * jax.nn.sigmoid(jnp.einsum("btm,mh->bht", x, params["gate_proj"]))[..., None]
     return jnp.einsum("bhtd,hdm->btm", ctx, params["out_proj"].reshape(HEADS, SIZE, HIDDEN))
 
 
@@ -217,6 +256,7 @@ def test_the_attention_layer_equals_quadratic_attention_on_its_own_projections(s
         others = [compiled(lambda params, x: plain_layer(params, x, **SETTINGS[other]), params, x)
                   for other in SETTINGS
                   if "norm_eps" not in SETTINGS[other] and "norm_eps" not in SETTINGS[setting]
+                  and SETTINGS[other].get("gate") == SETTINGS[setting].get("gate")  # one tree
                   and other != setting]
     assert all(rel_err(other, want[0]) > 0.01 for other in others)
 
@@ -228,6 +268,8 @@ def test_the_attention_layers_parameters_are_four_kernels_and_the_head_norms_if_
                "v_proj": (HIDDEN, KV_HEADS * SIZE), "out_proj": (HEADS * SIZE, HIDDEN)}
     norms = {name: {"scale": (SIZE,)} for name in ("q_norm", "k_norm")
              } if "norm_eps" in SETTINGS[setting] else {}
+    if SETTINGS[setting].get("gate"):  # one column a query head
+        kernels["gate_proj"] = (HIDDEN, HEADS)
     assert jax.tree.map(lambda p: p.shape, params) == {**kernels, **norms}
     assert all(p.dtype == jnp.float32 for p in jax.tree.leaves(params))
 
@@ -245,10 +287,19 @@ def test_the_attention_layer_rounds_each_operand_once_and_names_its_core(setting
     assert any(f"part={core}" in name for name in names)
     assert not any(name.endswith(f"part={other}") or f"part={other}/" in name for name in names)
     assert any("part=attn_proj" in name for name in names)
+    assert any("part=attn_gate" in name for name in names) == bool(SETTINGS[setting].get("gate"))
     with jax.default_matmul_precision("highest"):
         got = compiled(lambda params, x: layer.apply({"params": params}, x), params, x)
         want = compiled(lambda params, x: plain_layer(params, x, **SETTINGS[setting]), params, x)
     assert got.dtype == jnp.bfloat16 and rel_err(got, want) < 3e-2
+
+
+def test_tables_beside_a_theta_or_wider_than_a_head_are_refused():
+    x = jnp.zeros((1, 4, HIDDEN))
+    for kwargs in (dict(rope=TABLES, rope_theta=1e4), dict(rope=RotaryTables((1.0,) * 5))):
+        with pytest.raises(ValueError, match="tables"):
+            GroupedQueryAttention(HEADS, KV_HEADS, SIZE, jnp.float32, **kwargs).init(
+                jax.random.PRNGKey(0), x)
 
 
 def test_smallthinkers_layer_is_the_shared_one_with_the_kernel_looked_up_in_its_module(
