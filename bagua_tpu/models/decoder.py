@@ -1,15 +1,33 @@
 """The parts the causal decoder models share, defined once: ``glm_moe.py``,
-``lfm2_moe.py``, ``smallthinker_moe.py``, ``ouro.py`` and ``nemotron_h.py``
-build on this module and on no other model file (``llama.py`` takes ``RMSNorm``
+``lfm2_moe.py``, ``smallthinker_moe.py``, ``ouro.py``, ``nemotron_h.py`` and
+``laguna.py`` build on this module and on no other model file (``llama.py`` takes ``RMSNorm``
 from here).  Tested in ``tests/test_decoder.py`` and, the kernel under the
 attention, ``tests/test_causal_attention.py``, and not again in a model's file.
 
-How the attention's operands are written (``PERF.md`` section 6, PR 32 and
-33).  The attention kernels read ``(batch, heads, positions, head size)``, so
+How the attention's operands are written (``PERF.md`` section 6, PR 32, 33 and
+50).  The attention kernels read ``(batch, heads, positions, head size)``, so
 the three products contract onto that layout, one float32 pass norms, rotates,
 scales (``q`` carries ``1 / sqrt(head size)``) and rounds each of ``q`` and
-``k``, and ``W_o`` contracts the kernels' result over ``(heads, head size)``:
-no array with the positions in it is transposed.
+``k``, and ``W_o`` contracts the kernels' result over ``(heads, head size)``.
+That no array with the positions in it is transposed on the way was read off
+the compiled steps of ``glm-4.7-flash`` (20 heads of 256, latent attention of
+its own) and ``lfm2-8b-a1b`` (32 heads of 64 on 8) when PR 32 and 33 wrote the
+layer, and holds for ``ouro-2.6b`` (16 heads of 128, a key-value head a query
+head: not one ``copy`` of a ``(16, 8192, 128)`` array; the census of ISSUE 50).
+It did not hold under *grouped queries at heads of 128*: at 28 heads on 4
+(``smallthinker-21ba3b``) and at 48 and 64 on 8 (``laguna-xs.2``) the TPU
+compiler wrote the ``q`` product's float32 result with the positions minor, so
+that the rotation's slices at column 64 would not cut a 128-lane tile, turned
+it back for the kernel, and did the same to the kernel's result around the
+gate and to both cotangents: 4.6 GB a windowed layer of Laguna's where 1.4
+would do.  So where ``head_size % 128 == 0 and heads > kv_heads`` (all of it
+the layer's own fields) the rotation and the gate are one pass each with a
+backward rule of its own, in the kernels' layout
+(``kernels/head_passes.py``: Pallas calls on a TPU, whose operands and
+results have the row-major layout; the same formulas in ``jax.numpy``
+elsewhere), and what no pass touches (``q`` without positions, ``ctx`` without
+a gate) is held to that layout by a constraint, which costs no pass.  Any other
+layer is written as it was, and its step's text is what it was.
 """
 
 import dataclasses
@@ -20,6 +38,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from bagua_tpu.kernels.head_passes import gate_heads, rotary_tables, row_major, turn_heads
 from bagua_tpu.kernels.causal_attention import causal_attention
 from bagua_tpu.models.losses import softmax_cross_entropy
 from bagua_tpu.observability.annotations import model_scope
@@ -105,13 +124,15 @@ def rotary(x, inv_freq, scale: float = 1.0, factor: float = 1.0):
     return jnp.concatenate(turned, axis=-1)
 
 
+def _inv_freq(theta: float, size: int):
+    return 1.0 / (theta ** (jnp.arange(0, size, 2, dtype=jnp.float32) / size))
+
+
 def rotate_half(x, theta: float, scale: float = 1.0):
     """The rotary embedding on all columns of ``x (..., positions, size)`` in
     the rotate-half pairing (column ``i`` with ``i + size / 2``), in float32,
     times ``scale``: :func:`rotary` with the tables ``theta ** (-2i / size)``."""
-    size = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, size, 2, dtype=jnp.float32) / size))
-    return rotary(x, inv_freq, scale)
+    return rotary(x, _inv_freq(theta, x.shape[-1]), scale)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,7 +164,9 @@ class GroupedQueryAttention(Kernels):
     ``attn_window_core``, so that a capture reads the two masks apart) or all
     earlier keys (``attn_core``); ``gate``, a scalar a head and position,
     ``sigmoid(x W_g)`` from the layer's own input, on the core's result before
-    ``W_o`` (under ``attn_gate``)."""
+    ``W_o`` (under ``attn_gate``).  Grouped queries at heads of whole lane tiles
+    take the rotation and the gate as ``kernels/head_passes.py``'s passes, the
+    same arithmetic (the module's text)."""
 
     heads: int
     kv_heads: int
@@ -166,6 +189,8 @@ class GroupedQueryAttention(Kernels):
     def __call__(self, x):
         dt, size = self.compute_dtype, self.head_size
         hidden = x.shape[-1]
+        # grouped queries at whole lane tiles: the entry and the exit are pinned (the module's text)
+        pinned = size % 128 == 0 and self.heads > self.kv_heads
         if self.rope is not None and (self.rope_theta is not None or self.rope.columns > size):
             raise ValueError(f"rope_theta {self.rope_theta} beside tables, or tables of "
                              f"{self.rope.columns} columns for heads of {size}")
@@ -181,12 +206,16 @@ class GroupedQueryAttention(Kernels):
                            preferred_element_type=jnp.float32)
             if self.norm_eps is not None:
                 y = RMSNorm(self.norm_eps, name=name + "_norm")(y)
+            if self.rope is None and self.rope_theta is None:
+                y = (y * scale).astype(dt)
+                return row_major(y) if pinned else y
             if self.rope is not None:
-                return rotary(y, jnp.asarray(self.rope.inv_freq, jnp.float32), scale,
-                              self.rope.factor).astype(dt)
-            if self.rope_theta is None:
-                return (y * scale).astype(dt)
-            return rotate_half(y, self.rope_theta, scale).astype(dt)
+                inv_freq, factor = jnp.asarray(self.rope.inv_freq, jnp.float32), self.rope.factor
+            else:
+                inv_freq, factor = _inv_freq(self.rope_theta, size), 1.0
+            if pinned:
+                return turn_heads(y, *rotary_tables(inv_freq, y.shape[2], size, scale, factor), dt)
+            return rotary(y, inv_freq, scale, factor).astype(dt)
 
         with model_scope("attn_proj"):
             q = heads_of("q", self.heads, 1.0 / math.sqrt(size))
@@ -199,7 +228,12 @@ class GroupedQueryAttention(Kernels):
                 opened = jax.nn.sigmoid(jnp.einsum(
                     "btm,mh->bth", x.astype(dt), self.kernel("gate_proj", hidden, self.heads).astype(dt),
                     preferred_element_type=jnp.float32))
-                ctx = (ctx * opened.swapaxes(1, 2)[..., None]).astype(dt)
+                if pinned:
+                    ctx = gate_heads(ctx, opened)
+                else:
+                    ctx = (ctx * opened.swapaxes(1, 2)[..., None]).astype(dt)
+        elif pinned:
+            ctx = row_major(ctx)
         with model_scope("attn_proj"):
             return product("bhtd,hdm->btm", ctx, out, dt)
 

@@ -3,6 +3,7 @@ that refuses unknown devices, Pallas wrappers that say when they decline,
 kernel dispatch that reads one record, ``chip_smoke.py`` and its dry run, and
 a tree free of the old probe harness's vocabulary."""
 
+import collections
 import json
 import logging
 import os
@@ -689,6 +690,136 @@ def test_the_mixers_step_holds_the_scans_two_kernels_and_no_array_of_decays():
     kernels = sorted(words[2:] for words in lines if words[0] == "SCAN-KERNEL")
     assert kernels == [["backward", "True"], ["forward", "True"]], proc.stdout
     assert not [words for words in lines if words[0] == "SCAN-DECAY-SIZED"], proc.stdout
+
+
+_PINNED_PASSES_CENSUS = """
+import re, sys, time
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import NamedSharding, PartitionSpec as P
+try:
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+except Exception as e:  # no libtpu, or it cannot start compile-only here
+    print("NO-TOPOLOGY", type(e).__name__, e)
+    sys.exit(3)
+# the passes' choice is by backend, and here the backend is the CPU: steer it, in the test
+jax.default_backend = lambda: "tpu"
+from benchmark import harness, manifest
+
+
+def engine_step(name):
+    # the engine's own step of the cell, as ``harness.Run`` builds it on one described chip
+    cell = manifest.load_cell(name)
+    run = harness.Run(cell, 1, time.perf_counter(), topo.devices[:cell.chips])
+    run.build()
+    ddp = run.trainer.ddp
+    ddp._plan_for(jax.eval_shape(run.make_params, run.params_key))
+    batch = jax.eval_shape(lambda key: cell.adapter.draw_batch(key, cell.global_batch, cell.sizes),
+                           run.data_key)
+    sharding = NamedSharding(run.group.mesh, P(run.group.data_axes))
+    batch = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), batch)
+    return ddp, ddp.state_template(), batch
+
+
+for name, head_counts in (("laguna-xs.2.dp1-s8192", (48, 64)), ("smallthinker-21ba3b.dp1-s8192", (28,))):
+    ddp, state, batch = engine_step(name)
+    compiled = ddp._compile_step("default", state, batch)[0]
+    print("TEMPORARIES", name, compiled.memory_analysis().temp_size_in_bytes, flush=True)
+    text = compiled.as_text()
+    for line in text[text.index("\\nENTRY "):].splitlines():
+        line = re.sub(r'"body":"[^"]*"', "", line)
+        m = re.match(r"\\s*(?:ROOT )?%([\\w.\\-]+) = (.*?) ([a-z][\\w-]*)\\(", line)
+        if not m or m.group(3) in ("get-tuple-element", "bitcast", "tuple", "parameter") or m.group(
+                3).endswith(("-start", "-done")):  # the last: prefetches into fast memory, no layout's
+            continue
+        kernel = re.match(r"(head_turn_backward|head_turn|head_gate_backward|head_gate)(\\.\\d+)?$", m.group(1))
+        if kernel:
+            part = re.search(r'op_name="[^"]*part=(\\w+)/', line)
+            print("PASS-CALL", name, kernel.group(1), part.group(1) if part else "-",
+                  "tpu_custom_call" in line, flush=True)
+        # every result of (heads, 8,192 positions, 128 columns) with one of the cell's query head counts
+        for dtype, dims, layout in re.findall(r"(\\w+)\\[([\\d,]+)\\]\\{([\\d,]*)", m.group(2)):
+            dims = [int(d) for d in dims.split(",")]
+            if len(dims) < 3 or dims[-2:] != [8192, 128]:
+                continue
+            heads = 1
+            for d in dims[:-2]:
+                heads *= d
+            if heads not in head_counts:
+                continue
+            print("HEAD-ARRAY", name, m.group(1), dtype, "x".join(map(str, dims)), flush=True)
+            if layout.split(",")[0] != str(len(dims) - 1):
+                print("POSITIONS-MINOR", name, m.group(1), dtype, layout, flush=True)
+            if m.group(3) == "copy" or m.group(1).startswith("copy"):
+                print("HEAD-COPY", name, m.group(1), dtype, layout, flush=True)
+# grouped queries at half a lane tile, and a key-value head a query head: the lowered step, not compiled
+for name in ("lfm2-8b-a1b.dp1-s8192", "ouro-2.6b.dp1-s8192"):
+    ddp, state, batch = engine_step(name)
+    text = ddp._build_step("default").lower(state, batch).as_text()
+    print("LOWERED", name, text.count("@tpu_custom_call"), len(re.findall(r'kernel_name = "head_', text)),
+          text.count("@LayoutConstraint"), flush=True)
+"""
+
+
+@pytest.fixture(scope="module")
+def pinned_passes_census():
+    """The engine's own step of the two cells whose attention takes the pinned
+    passes, compiled for a described v5e in one process of its own, and of the
+    two decoder cells that must not take them, lowered."""
+    proc = subprocess.run(
+        [sys.executable, "-c", _PINNED_PASSES_CENSUS],
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO_ROOT),
+        capture_output=True, text=True, timeout=900,
+    )
+    if proc.returncode == 3:
+        pytest.skip(proc.stdout.strip()[-300:])
+    assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-3000:]
+    return [line.split() for line in proc.stdout.splitlines()]
+
+
+@pytest.mark.parametrize("cell, calls, temporaries", [
+    # five layers x (q and k) entries and five exits, each way; under the parent's 9.52 GB
+    ("laguna-xs.2.dp1-s8192", {"head_turn": 10, "head_turn_backward": 10, "head_gate": 5,
+                               "head_gate_backward": 5}, 8.2e9),
+    # three windowed layers with positions x (q and k); no gate, and the global layer's q
+    # and every layer's ctx held by a layout constraint, which is no call (5.10 GB the parent)
+    ("smallthinker-21ba3b.dp1-s8192", {"head_turn": 6, "head_turn_backward": 6}, 5.3e9),
+])
+def test_the_step_holds_the_pinned_passes_and_no_head_array_with_the_positions_minor(
+        pinned_passes_census, cell, calls, temporaries):
+    """The engagement check of ``kernels/head_passes.py`` on a TPU (PR 50).  In
+    the parent's step of ``laguna-xs.2.dp1-s8192`` every windowed layer wrote
+    4.6 GB of ``(64, 8192, 128)`` arrays outside its products and kernels: the
+    ``q`` product's float32 result with the *positions* minor
+    (``convolution_bitcast_fusion f32[1,64,8192,128]{2,3,1,0}``), its transpose
+    back, three float32 copies of the kernel's result around the gate, and the
+    same on the way back; ``smallthinker-21ba3b.dp1-s8192`` did it at 28
+    heads.  Now: the passes as Mosaic calls under the parts a capture reads
+    (``attn_proj``, ``attn_gate``) in both directions, no result of a query
+    head count x 8,192 x 128 in any type whose layout has the positions minor,
+    and no ``copy`` of one."""
+    found = [(words[0], words[2:]) for words in pinned_passes_census if words[1:2] == [cell]
+             and words[0] in ("PASS-CALL", "HEAD-ARRAY", "POSITIONS-MINOR", "HEAD-COPY")]
+    passes = [words for kind, words in found if kind == "PASS-CALL"]
+    assert collections.Counter(words[0] for words in passes) == calls, passes
+    for words in passes:  # a Mosaic call, the gate's under attn_gate, the rotation's under attn_proj
+        assert words[1:] == ["attn_gate" if "gate" in words[0] else "attn_proj", "True"], words
+    assert sum(kind == "HEAD-ARRAY" for kind, _ in found) >= len(passes)  # the census saw the arrays
+    assert not [words for kind, words in found if kind in ("POSITIONS-MINOR", "HEAD-COPY")], [
+        words for kind, words in found if kind != "HEAD-ARRAY"]
+    held = next(int(words[2]) for words in pinned_passes_census if words[:2] == ["TEMPORARIES", cell])
+    assert held < temporaries, held
+
+
+@pytest.mark.parametrize("cell", ["lfm2-8b-a1b.dp1-s8192", "ouro-2.6b.dp1-s8192"])
+def test_heads_of_64_and_a_key_value_head_a_query_head_take_none_of_the_pinned_passes(
+        pinned_passes_census, cell):
+    """``lfm2-8b-a1b`` (grouped queries at heads of 64, half a lane tile) and
+    ``ouro-2.6b`` (16 heads of 128, as many key-value heads) keep the plain
+    entry: their lowered steps hold Mosaic calls (the attention's, the
+    embedding's) and none of this module's, and no layout constraint."""
+    lowered = next(words[2:] for words in pinned_passes_census if words[:2] == ["LOWERED", cell])
+    assert int(lowered[0]) > 0 and lowered[1:] == ["0", "0"], lowered
 
 
 _HEAD_CENSUS = """

@@ -4,8 +4,10 @@ plain oracle, once and compiled (``helpers.compiled``): ``RMSNorm``,
 ``shift``, ``SwiGLU``, the next-token loss against a slice then a mean, and
 the grouped-query attention layer at each of the settings a model builds it
 with, value and every gradient leaf, against quadratic attention on the
-layer's own projections.  And the module's place among the model files: who
-imports what, pinned in a subprocess."""
+layer's own projections; the pinned entry and exit passes
+(``kernels/head_passes.py``) against ``rotary`` and the plain gate, and which
+layers take them.  And the module's place among the model files: who imports
+what, pinned in a subprocess."""
 
 import ast
 import inspect
@@ -20,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from bagua_tpu.kernels import head_passes
 from bagua_tpu.models import decoder, smallthinker_moe
 from bagua_tpu.models.decoder import (
     GroupedQueryAttention,
@@ -189,21 +192,22 @@ SETTINGS = {
 HEADS, KV_HEADS, SIZE, HIDDEN = 6, 2, 8, 32
 
 
-def plain_layer(params, x, norm_eps=None, rope_theta=None, window=None, rope=None, gate=False):
+def plain_layer(params, x, norm_eps=None, rope_theta=None, window=None, rope=None, gate=False,
+                heads=HEADS, kv_heads=KV_HEADS, size=SIZE):
     """The layer written down: three projections onto heads, a norm over each
     head's columns if any, the rotation if any (all columns at ``rope_theta``,
     or the tables' columns with their factor, the others passing through), every
     score under the mask, a scalar a head and position from the layer's input on
     the result if gated, and the output projection over ``(heads, head size)``."""
     def heads_of(name, count):
-        y = jnp.einsum("btm,mhd->bhtd", x, params[name + "_proj"].reshape(HIDDEN, count, SIZE))
+        y = jnp.einsum("btm,mhd->bhtd", x, params[name + "_proj"].reshape(HIDDEN, count, size))
         if norm_eps is not None and name != "v":
             y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + norm_eps)
             y = y * params[name + "_norm"]["scale"]
         if rope_theta is not None and name != "v":
             t = y.shape[2]
-            angle = jnp.arange(t)[:, None] * rope_theta ** (-2.0 * jnp.arange(SIZE // 2) / SIZE)
-            a, b = y[..., :SIZE // 2], y[..., SIZE // 2:]
+            angle = jnp.arange(t)[:, None] * rope_theta ** (-2.0 * jnp.arange(size // 2) / size)
+            a, b = y[..., :size // 2], y[..., size // 2:]
             y = jnp.concatenate([a * jnp.cos(angle) - b * jnp.sin(angle),
                                  b * jnp.cos(angle) + a * jnp.sin(angle)], axis=-1)
         if rope is not None and name != "v":
@@ -214,11 +218,18 @@ def plain_layer(params, x, norm_eps=None, rope_theta=None, window=None, rope=Non
             y = jnp.concatenate([a * cos - b * sin, b * cos + a * sin, y[..., 2 * half:]], axis=-1)
         return y
 
-    ctx = quadratic_attention(heads_of("q", HEADS), heads_of("k", KV_HEADS), heads_of("v", KV_HEADS),
-                              1.0 / math.sqrt(SIZE), window)
+    ctx = quadratic_attention(heads_of("q", heads), heads_of("k", kv_heads), heads_of("v", kv_heads),
+                              1.0 / math.sqrt(size), window)
     if gate:
         ctx = ctx * jax.nn.sigmoid(jnp.einsum("btm,mh->bht", x, params["gate_proj"]))[..., None]
-    return jnp.einsum("bhtd,hdm->btm", ctx, params["out_proj"].reshape(HEADS, SIZE, HIDDEN))
+    return jnp.einsum("bhtd,hdm->btm", ctx, params["out_proj"].reshape(heads, size, HIDDEN))
+
+
+def _both_passes(fn, probe):
+    def run(*args):
+        out, pull = jax.vjp(fn, *args)
+        return (out,) + pull(probe.astype(out.dtype))
+    return run
 
 
 def drawn_layer(setting, cls=GroupedQueryAttention, dtype=jnp.float32):
@@ -236,16 +247,11 @@ def test_the_attention_layer_equals_quadratic_attention_on_its_own_projections(s
     layer, params, x = drawn_layer(setting)
     probe = jax.random.normal(jax.random.PRNGKey(14), x.shape)
 
-    def both_passes(fn):
-        def run(params, x):
-            out, pull = jax.vjp(fn, params, x)
-            return (out,) + pull(probe)
-        return run
-
     with jax.default_matmul_precision("highest"):
-        got = compiled(both_passes(lambda params, x: layer.apply({"params": params}, x)), params, x)
-        want = compiled(both_passes(lambda params, x: plain_layer(params, x, **SETTINGS[setting])),
-                        params, x)
+        got = compiled(_both_passes(lambda params, x: layer.apply({"params": params}, x), probe),
+                       params, x)
+        want = compiled(_both_passes(
+            lambda params, x: plain_layer(params, x, **SETTINGS[setting]), probe), params, x)
     assert got[0].dtype == x.dtype and rel_err(got[0], want[0]) < 1e-5
     assert jax.tree.structure(got[1]) == jax.tree.structure(want[1])
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got[1]), jax.tree.leaves(want[1])):
@@ -327,6 +333,163 @@ def test_smallthinkers_layer_is_the_shared_one_with_the_kernel_looked_up_in_its_
     assert rel_err(run(layer), run(shared)) > 0.01 and seen == [5]
 
 
+# -- the pinned entry and exit passes (kernels/head_passes.py) ----------------------
+
+#: what the entry turns: every column at ``rope_theta`` (laguna's windowed layers,
+#: smallthinker's), tables on half of a head's 128 columns with a factor (laguna's global)
+TURNS = {
+    "rotary_128": (lambda size: decoder._inv_freq(1e4, size), 1.0),
+    "tables_64_of_128_with_a_factor": (lambda size: jnp.asarray(
+        [1e4 ** (-i / 32.0) * (0.3 if i > 20 else 1.0) for i in range(size // 4)], jnp.float32), 1.4),
+}
+PASS_T, PASS_SIZE = 32, 128
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads", [1, 8], ids=["1_head", "8_heads"])  # k's side, q's side
+@pytest.mark.parametrize("what", [*TURNS, "gate"])
+def test_the_pinned_passes_are_todays_rotation_and_gate_in_both_directions(what, heads, dtype):
+    """The formulas (a rotation of the lanes against full-width tables; the
+    gate from ``a (batch, positions, heads)``) against ``rotary`` and the
+    multiplication the layer wrote until PR 50, *bit for bit* in both types,
+    value and every gradient but the gate's ``d a`` (a sum over 128 columns in
+    another order): eagerly, because XLA:CPU contracts ``a * b + c * d`` to
+    fused multiply-adds by the shape of the expression it compiles.  And the
+    Pallas kernels, through the interpreter, against the formulas: to float32
+    rounding, the rounded values bit for bit; the entry's cotangent in the
+    compute type's values where a product would round it so itself."""
+    keys = jax.random.split(jax.random.PRNGKey(20), 3)
+    shape = (2, heads, PASS_T, PASS_SIZE)
+    probe = jax.random.normal(keys[0], shape)
+    if what == "gate":
+        args = (jax.random.normal(keys[1], shape).astype(dtype),
+                jax.nn.sigmoid(jax.random.normal(keys[2], (2, PASS_T, heads))))
+        today = lambda ctx, a: (ctx * a.swapaxes(1, 2)[..., None]).astype(dtype)
+        plain, kernels = head_passes._gated, lambda ctx, a: head_passes._gate_kernels(ctx, a, True)
+    else:
+        make, factor = TURNS[what]
+        inv_freq, scale = make(PASS_SIZE), 1.0 / math.sqrt(PASS_SIZE)
+        args = (3.0 * jax.random.normal(keys[1], shape),)
+        today = lambda y: rotary(y, inv_freq, scale, factor).astype(dtype)
+
+        def tabled(turn):
+            return lambda y: turn(y, *head_passes.rotary_tables(
+                inv_freq, PASS_T, PASS_SIZE, scale, factor))
+        plain = tabled(lambda y, tables, shifts: head_passes._turned(y, tables, shifts, dtype))
+        kernels = tabled(lambda y, tables, shifts: head_passes._turn_kernels(
+            y, tables, shifts, jnp.dtype(dtype), True))
+    want = _both_passes(today, probe)(*args)  # eager: see above
+    got = _both_passes(plain, probe)(*args)
+    assert got[0].dtype == dtype and len(got) == len(want) == 1 + len(args)
+    for n, (g, w) in enumerate(zip(got, want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        if what == "gate" and n == 2:
+            assert rel_err(g, w) < 1e-6
+        else:
+            np.testing.assert_array_equal(np.asarray(g, np.float32), np.asarray(w, np.float32))
+    formulas = compiled(_both_passes(plain, probe), *args)
+    with jax.default_matmul_precision("highest"):  # read when the backward rule is traced
+        through = compiled(_both_passes(kernels, probe), *args)
+    np.testing.assert_array_equal(np.asarray(through[0], np.float32), np.asarray(formulas[0], np.float32))
+    for g, w in zip(through[1:], formulas[1:]):
+        assert g.dtype == w.dtype and rel_err(g, w) < 1e-6
+    if what != "gate":
+        # at the default precision the products that read the entry's cotangent round it to the
+        # compute type themselves: the kernel writes it so rounded, still as float32's cotangent
+        rounded = compiled(_both_passes(kernels, probe), *args)[1]
+        assert rounded.dtype == jnp.float32
+        np.testing.assert_array_equal(rounded, rounded.astype(dtype).astype(jnp.float32))
+        assert rel_err(rounded, formulas[1]) < (1e-6 if dtype == jnp.float32 else 4e-3)
+
+
+def test_the_tables_are_rotarys_over_the_whole_head_and_a_shape_the_kernels_refuse_takes_the_formulas():
+    inv_freq = jnp.asarray([1.0, 0.1, 0.01])
+    tables, shifts = head_passes.rotary_tables(inv_freq, 5, 8, 0.5, 1.4)
+    angle = np.arange(5)[:, None] * np.asarray(inv_freq)
+    cos, sin = 0.7 * np.cos(angle), 0.7 * np.sin(angle)
+    zeros, rest = np.zeros((5, 3)), np.zeros((5, 2))
+    assert shifts == (5, 3) and tables.shape == (3, 5, 8) and tables.dtype == jnp.float32
+    np.testing.assert_allclose(tables[0], np.concatenate([cos, cos, rest + 0.5], -1), rtol=1e-6)
+    np.testing.assert_allclose(tables[1], np.concatenate([-sin, zeros, rest], -1), rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(tables[2], np.concatenate([zeros, sin, rest], -1), rtol=1e-6, atol=1e-7)
+    whole, shifts = head_passes.rotary_tables(jnp.asarray([1.0, 0.1]), 5, 4)
+    assert shifts == (2,) and whole.shape == (2, 5, 4)  # either way round: one rotation
+    # 128 lanes and blocks of 16 positions or the formulas; float32 in, whatever the backend
+    y = jnp.ones((1, 2, 24, 128))
+    assert head_passes._turn_rows(y) is None and head_passes._gate_rows(y) is None
+    assert head_passes._turn_rows(jnp.ones((1, 2, 32, 64))) is None
+    assert head_passes._turn_rows(jnp.ones((1, 28, 8192, 128))) == 512  # 7 heads a block
+    assert head_passes._gate_rows(jnp.ones((1, 64, 8192, 128), jnp.bfloat16)) == 128
+    with pytest.raises(ValueError, match="float32"):
+        head_passes.turn_heads(y.astype(jnp.bfloat16), tables, shifts, jnp.bfloat16)
+    with pytest.raises(ValueError, match="float32"):
+        head_passes.gate_heads(y, jnp.ones((1, 24, 2), jnp.bfloat16))
+
+
+#: ``(heads, kv_heads, head size)`` and the layer's settings: which models build it so, and
+#: the calls the traced layer holds on a TPU: entry kernels, exit kernels, layout constraints
+PINNED = {
+    "laguna_windowed": ((16, 2, 128), dict(rope_theta=1e4, window=5, gate=True), (2, 1, 0)),
+    "laguna_global": ((12, 2, 128), dict(rope=RotaryTables(tuple(0.5 ** i for i in range(32)), 1.4),
+                                         gate=True), (2, 1, 0)),
+    "smallthinker_windowed": ((14, 2, 128), dict(rope_theta=1.5e6, window=5), (2, 0, 1)),
+    "smallthinker_global": ((14, 2, 128), dict(), (0, 0, 2)),  # q's product and ctx held, no pass
+    "grouped_at_256": ((4, 2, 256), dict(rope_theta=1e4), (2, 0, 1)),
+    "ouro": ((4, 4, 128), dict(rope_theta=1e6), (0, 0, 0)),  # a key-value head a query head
+    "lfm2": ((8, 2, 64), dict(norm_eps=1e-5, rope_theta=1e6), (0, 0, 0)),  # half a lane tile
+    "gated_heads_of_64": ((8, 2, 64), dict(rope_theta=1e4, gate=True), (0, 0, 0)),
+}
+
+
+@pytest.mark.parametrize("case", list(PINNED))
+def test_grouped_queries_at_whole_lane_tiles_take_the_pinned_passes_and_no_other_layer_does(
+        case, monkeypatch):
+    """The condition is the layer's own fields (``head_size % 128 == 0 and
+    heads > kv_heads``); the calls are chosen by backend, and here the backend
+    is the CPU: steered, in the test, for a trace that compiles nothing.  On
+    the CPU the same layer is the formulas, and equals the layer written down
+    whichever path it took."""
+    (heads, kv_heads, size), settings, (turns, gates, constraints) = PINNED[case]
+    layer = GroupedQueryAttention(heads, kv_heads, size, jnp.bfloat16, **settings)
+    x = jax.random.normal(jax.random.PRNGKey(21), (1, 32, HIDDEN))
+    params = layer.init(jax.random.PRNGKey(22), x)["params"]
+    params = jax.tree.map(lambda p: 6.0 * p if p.ndim == 2 else p, params)
+
+    def calls():
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda params, x: jnp.sum(layer.apply({"params": params}, x).astype(jnp.float32))))(params, x)
+        found = {"head_turn": 0, "head_turn_backward": 0, "head_gate": 0, "head_gate_backward": 0,
+                 "layout_constraint": 0}
+
+        def walk(jaxpr, outer=""):
+            for eqn in jaxpr.eqns:
+                scopes = f"{outer}/{eqn.source_info.name_stack}"
+                if eqn.primitive.name == "layout_constraint":
+                    found["layout_constraint"] += 1
+                name = eqn.params.get("name")
+                if eqn.primitive.name == "pallas_call" and name in found:
+                    found[name] += 1  # under its part in both directions
+                    assert f"part={'attn_gate' if 'gate' in name else 'attn_proj'}" in scopes, scopes
+                    continue
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub, scopes)
+
+        walk(jaxpr.jaxpr)
+        return found
+
+    assert not any(calls().values())  # the CPU: the formulas
+    with monkeypatch.context() as steered:
+        steered.setattr(jax, "default_backend", lambda: "tpu")
+        found = calls()
+    assert found == {"head_turn": turns, "head_turn_backward": turns, "head_gate": gates,
+                     "head_gate_backward": gates, "layout_constraint": 2 * constraints}, found
+    shape = dict(heads=heads, kv_heads=kv_heads, size=size)
+    with jax.default_matmul_precision("highest"):
+        got = compiled(lambda params, x: layer.apply({"params": params}, x), params, x)
+        want = compiled(lambda params, x: plain_layer(params, x, **settings, **shape), params, x)
+    assert got.dtype == jnp.bfloat16 and rel_err(got, want) < 3e-2
+
+
 # -- the module's place among the model files ------------------------------------
 
 
@@ -348,7 +511,8 @@ def test_no_model_file_imports_another_and_no_private_name_crosses_modules():
             assert not name.startswith("_") or not module.startswith("bagua_tpu"), (model, name)
     own = {module for module, _ in _imports(os.path.join(models, "decoder.py"))
            if module.startswith("bagua_tpu")}
-    assert own == {"bagua_tpu.kernels.causal_attention", "bagua_tpu.models.losses",
+    assert own == {"bagua_tpu.kernels.causal_attention", "bagua_tpu.kernels.head_passes",
+                   "bagua_tpu.models.losses",
                    "bagua_tpu.observability.annotations"}
     # the names the benchmark and its tests read are bound to the one definition
     from bagua_tpu.models import lfm2_moe, llama, nemotron_h, ouro
