@@ -108,13 +108,19 @@ def _gate_rows(ctx):
     return _rows(ctx, ctx.shape[1], ctx.dtype.itemsize)
 
 
-def row_major(x):
-    """``x`` held to the row-major layout on a TPU, and its cotangent with it:
-    no pass, a constraint on the compiler's choice for a product's result that
-    no kernel of this module reads or writes."""
+def held_to(x, major_to_minor):
+    """``x`` held to a layout on a TPU (its dimensions from the outermost to
+    the one along the lanes), and its cotangent with it: no pass, a constraint
+    on the compiler's choice for a product's result that no kernel of this
+    module reads or writes."""
     if jax.default_backend() != "tpu":
         return x
-    return with_layout_constraint(x, Layout(major_to_minor=tuple(range(x.ndim))))
+    return with_layout_constraint(x, Layout(major_to_minor=tuple(major_to_minor)))
+
+
+def row_major(x):
+    """:func:`held_to` the row-major layout."""
+    return held_to(x, range(x.ndim))
 
 
 # ---- the entry ----------------------------------------------------------------------------

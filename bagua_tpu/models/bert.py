@@ -13,16 +13,33 @@ TPU-native flax implementation with composable parallelism:
 
 ``tp_size`` is static so parameter shapes are rank-local; axes are checked
 at apply time.
+
+**The fused query-key-value result stays where its product wrote it**
+(``PERF.md`` section 6, PR 51).  A head of 64 columns fills half a 128-lane
+tile, so the TPU compiler lays the scores' operands out by the 128 positions;
+left alone it wrote the fused product's ``(batch, positions, 3 x hidden)``
+result with the columns minor and copied it into that layout, and copied the
+joined gradient back before the projection's two gradient products: 48 copies
+of 25 MB a step in BERT-Large.  Where the local core runs at heads narrower
+than a lane tile, on a TPU, :func:`_heads_apart` holds the product's result to
+the layout its reader takes (``kernels/head_passes.py::held_to``: no pass, a
+constraint on the compiler's choice) and writes the join of the three
+gradients itself, under the same constraint; no arithmetic changes.  Every
+other layer (ring attention, a ``kv_mask``, heads of whole lane tiles, another
+backend) is written as it was.
 """
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from bagua_tpu.kernels.head_passes import held_to
 from bagua_tpu.models.losses import softmax_cross_entropy
+from bagua_tpu.observability.annotations import model_scope
 from bagua_tpu.parallel.ring_attention import ring_attention, _block_attention_local
 from bagua_tpu.parallel.tensor_parallel import (
     ColumnParallelDense,
@@ -75,6 +92,41 @@ def _sp_offset(cfg: BertConfig, t_local: int):
         return 0
 
 
+#: ``(batch, positions, columns)`` with the positions along the lanes
+_POSITIONS_MINOR = (0, 2, 1)
+
+
+def _apart(qkv, heads: int):
+    """``q``, ``k``, ``v`` ``(batch, positions, heads, head size)`` out of the
+    fused product's ``(batch, positions, 3 x heads x head size)``."""
+    b, t, _ = qkv.shape
+    qkv = qkv.reshape(b, t, 3, heads, -1)
+    return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _heads_apart(qkv, heads: int):
+    """:func:`_apart` with the product's result held to the layout the scores
+    read it in.  Backward: the three gradients joined in the projection's
+    column order, held to the same layout, which the projection's gradient
+    products read as it stands (autodiff's join, the transpose of three
+    slices, writes the axis of three outermost and is copied)."""
+    return _apart(held_to(qkv, _POSITIONS_MINOR), heads)
+
+
+def _heads_apart_fwd(qkv, heads):
+    return _heads_apart(qkv, heads), None
+
+
+def _heads_apart_bwd(heads, _, grads):
+    b, t = grads[0].shape[:2]
+    joined = jnp.concatenate([g.reshape(b, t, -1) for g in grads], axis=-1)
+    return (held_to(joined, _POSITIONS_MINOR),)
+
+
+_heads_apart.defvjp(_heads_apart_fwd, _heads_apart_bwd)
+
+
 class BertSelfAttention(nn.Module):
     cfg: BertConfig
 
@@ -87,22 +139,27 @@ class BertSelfAttention(nn.Module):
         local_heads = cfg.num_heads // cfg.tp_size
         head_dim = cfg.hidden_size // cfg.num_heads
 
-        qkv = ColumnParallelDense(
-            3 * cfg.hidden_size, cfg.tp_size, cfg.tp_axis, dtype=cfg.compute_dtype,
-            name="qkv",
-        )(x)
-        qkv = qkv.reshape(b, t, 3, local_heads, head_dim)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        # the local core at heads narrower than a lane tile (the module's text)
+        held = (cfg.sp_axis is None and mask is None and head_dim % 128 != 0
+                and jax.default_backend() == "tpu")
+        with model_scope("attn_proj"):
+            qkv = ColumnParallelDense(
+                3 * cfg.hidden_size, cfg.tp_size, cfg.tp_axis, dtype=cfg.compute_dtype,
+                name="qkv",
+            )(x)
+            q, k, v = (_heads_apart if held else _apart)(qkv, local_heads)
 
-        if cfg.sp_axis is not None:
-            ctx = ring_attention(q, k, v, axis_name=cfg.sp_axis, causal=False, kv_mask=mask)
-        else:
-            ctx = _block_attention_local(q, k, v, causal=False, kv_mask=mask)
-        ctx = ctx.reshape(b, t, local_heads * head_dim)
-        return RowParallelDense(
-            cfg.hidden_size, cfg.tp_size, cfg.tp_axis, dtype=cfg.compute_dtype,
-            name="out",
-        )(ctx)
+        with model_scope("attn_core"):
+            if cfg.sp_axis is not None:
+                ctx = ring_attention(q, k, v, axis_name=cfg.sp_axis, causal=False, kv_mask=mask)
+            else:
+                ctx = _block_attention_local(q, k, v, causal=False, kv_mask=mask)
+        with model_scope("attn_proj"):
+            ctx = ctx.reshape(b, t, local_heads * head_dim)
+            return RowParallelDense(
+                cfg.hidden_size, cfg.tp_size, cfg.tp_axis, dtype=cfg.compute_dtype,
+                name="out",
+            )(ctx)
 
 
 class BertLayer(nn.Module):

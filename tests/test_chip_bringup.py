@@ -847,14 +847,14 @@ def entry_of(fn, layers, **jit_kwargs):
     text = jax.jit(fn(mlm_loss_fn(model)), **jit_kwargs).lower(
         params, (ids, ids)).compile().as_text()
     for line in text[text.index("\\nENTRY "):].splitlines():
-        m = re.match(r"\\s*(?:ROOT )?%(\\S+) = (.*?) (fusion|copy|convolution)\\(", line)
+        m = re.match(r"\\s*(?:ROOT )?%(\\S+) = (.*?) (fusion|copy|convolution|transpose)\\(", line)
         if m:
             op_name = re.search(r'op_name="([^"]*)"', line)
             yield (m.group(1), re.sub(r"\\{[^}]*\\}", "", m.group(2)),
-                   op_name.group(1) if op_name else "-")
+                   op_name.group(1) if op_name else "-", m.group(2).replace(" ", ""))
 
 
-for name, shape, op_name in entry_of(jax.value_and_grad, 2):
+for name, shape, op_name, _ in entry_of(jax.value_and_grad, 2):
     if "f32[32,128,30522]" in shape:
         print("VOCAB-WRITER", name, op_name, flush=True)
 
@@ -866,14 +866,24 @@ def sgd_step(loss_fn):
     return step
 
 
-# a backward matmul of a layer: W for a weight gradient (its result has a
-# kernel's shape), X for an input gradient, in the order they are scheduled
-for name, shape, op_name in entry_of(
-        sgd_step, 4, donate_argnums=(0,), compiler_options=STEP_COMPILER_OPTIONS["tpu"]):
+# a plain SGD step of SIX layers, traced as on the chip.  A backward matmul of a layer: W for a
+# weight gradient (its result has a kernel's shape), X for an input gradient, in the order they
+# are scheduled.  And what holds a layer's fused query-key-value result (32 x 128 x 3072
+# elements): the product that writes it, with its layout, and every copy or transpose of one
+# (left alone the compiler copies from five layers on, and not at four)
+the_backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+for name, shape, op_name, laid_out in entry_of(
+        sgd_step, 6, donate_argnums=(0,), compiler_options=STEP_COMPILER_OPTIONS["tpu"]):
     layer = re.search(r"transpose\\(.*/layer_(\\d+)/.*dot_general", op_name)
     if layer:
         kernel = re.match(r"(bf16|f32)\\[(1024|3072|4096),(1024|3072|4096)\\]", shape)
         print("BACKWARD", "W" if kernel else "X", layer.group(1), flush=True)
+    if re.search(r"bf16\\[32,128,(3072|3,16,64)\\]", shape):
+        if name.startswith(("copy", "transpose")):
+            print("QKV-COPY", name, laid_out, op_name, flush=True)
+        elif op_name.endswith("qkv/dot_general") and "transpose(" not in op_name:
+            print("QKV-PRODUCT", name, laid_out, flush=True)
+jax.default_backend = the_backend
 
 # the end of the three expert models at their cells' shapes, forward and backward: what has
 # tokens x vocabulary elements, and whether anything scatters
@@ -1025,6 +1035,24 @@ def test_bert_head_writes_one_vocabulary_sized_array(head_census):
     assert writers[0][1].endswith("mlm_decoder/dot_general"), writers
 
 
+def test_bert_step_holds_no_copy_of_a_fused_query_key_value_result(head_census):
+    """Guards the 48 copies of 25 MB a step (24 layers, one a pass; ``PERF.md``
+    section 6, PR 51) that stood between a BERT layer's fused query-key-value
+    product and its attention: a head of 64 columns fills half a lane tile, so
+    the compiler lays the scores' operands out by the 128 positions, and left
+    alone it wrote the product's result columns-minor and copied it, and
+    copied the joined gradient back.  In a plain SGD step of six layers
+    compiled for a v5e (twelve such copies before; at four layers the compiler
+    copies nothing, from five on two a layer) every layer's product writes
+    ``bf16[32,128,3072]`` with the positions minor itself, and no copy or
+    transpose of an array of that size is left."""
+    copies = [words[1:] for words in head_census if words[0] == "QKV-COPY"]
+    assert copies == [], copies
+    products = [words[2] for words in head_census if words[0] == "QKV-PRODUCT"]
+    assert len(products) == 6, products
+    assert all(re.match(r"bf16\[32,128,3072\]\{1,2,0[:}]", laid_out) for laid_out in products), products
+
+
 @pytest.mark.parametrize("model", ["lfm2-8b-a1b", "smallthinker-21ba3b", "glm-4.7-flash"])
 def test_expert_head_holds_the_forward_logits_and_no_other_array_of_their_size(
         head_census, model):
@@ -1077,8 +1105,8 @@ def test_step_options_keep_weight_gradients_inside_the_backward_pass(head_census
     ``ddp.STEP_COMPILER_OPTIONS`` each layer's weight gradients are scheduled
     before the layer below starts its backward pass."""
     order = [(words[1], int(words[2])) for words in head_census if words[0] == "BACKWARD"]
-    assert {layer for _, layer in order} == {0, 1, 2, 3}
-    for layer in (3, 2, 1):
+    assert {layer for _, layer in order} == {0, 1, 2, 3, 4, 5}
+    for layer in (5, 4, 3, 2, 1):
         last_weight = max(i for i, op in enumerate(order) if op == ("W", layer))
         first_below = min(i for i, op in enumerate(order) if op == ("X", layer - 1))
         assert last_weight < first_below, order

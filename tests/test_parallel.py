@@ -227,6 +227,171 @@ def test_bert_forward_shapes_and_parallel_consistency():
     np.testing.assert_allclose(got, ref, rtol=5e-3, atol=5e-3)
 
 
+# -- the fused query-key-value result held where its product wrote it (models/bert.py, PR 51) --
+
+
+def _bert_cut(**settings):
+    """``bert_base_config`` widths cut to two layers and a small vocabulary
+    (the attention layer meets neither)."""
+    import dataclasses
+    from bagua_tpu.models.bert import bert_base_config
+
+    return dataclasses.replace(bert_base_config(), num_layers=2, vocab_size=512,
+                               max_position_embeddings=256, **settings)
+
+
+def _as_the_layer_was(monkeypatch):
+    """``models/bert.py`` with its attention layer written as it stood before
+    PR 51: the oracle of what another layer's text was."""
+    import flax.linen as nn
+    from bagua_tpu.models import bert
+
+    class BertSelfAttention(nn.Module):
+        cfg: bert.BertConfig
+
+        @nn.compact
+        def __call__(self, x, mask=None):
+            cfg = self.cfg
+            b, t, _ = x.shape
+            local_heads = cfg.num_heads // cfg.tp_size
+            head_dim = cfg.hidden_size // cfg.num_heads
+            qkv = ColumnParallelDense(3 * cfg.hidden_size, cfg.tp_size, cfg.tp_axis,
+                                      dtype=cfg.compute_dtype, name="qkv")(x)
+            qkv = qkv.reshape(b, t, 3, local_heads, head_dim)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            if cfg.sp_axis is not None:
+                ctx = ring_attention(q, k, v, axis_name=cfg.sp_axis, causal=False, kv_mask=mask)
+            else:
+                ctx = _block_attention_local(q, k, v, causal=False, kv_mask=mask)
+            ctx = ctx.reshape(b, t, local_heads * head_dim)
+            return RowParallelDense(cfg.hidden_size, cfg.tp_size, cfg.tp_axis,
+                                    dtype=cfg.compute_dtype, name="out")(ctx)
+
+    monkeypatch.setattr(bert, "BertSelfAttention", BertSelfAttention)
+
+
+def _layout_constraints(jaxpr):
+    return sum(eqn.primitive.name == "layout_constraint" for eqn in jaxpr.eqns) + sum(
+        _layout_constraints(sub) for eqn in jaxpr.eqns for sub in jax.core.jaxprs_in_params(eqn.params))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("tokens", [(2, 128), (1, 256)], ids=["2x128", "1x256"])
+def test_bert_held_layer_is_the_layer_it_was_bit_for_bit(tokens, dtype, monkeypatch):
+    """The layer that holds the fused product's result to its layout and joins
+    the three gradients itself changes no arithmetic: steered onto the TPU's
+    path (the constraint itself is one the CPU takes too), the loss and every
+    leaf's gradient are those of the layer as it stood, to the bit."""
+    from bagua_tpu.models import bert
+    from tests.helpers import compiled
+
+    model = bert.BertForPreTraining(_bert_cut(compute_dtype=dtype))
+    ids = jax.random.randint(jax.random.PRNGKey(5), tokens, 0, 512)
+    labels = jax.random.randint(jax.random.PRNGKey(6), tokens, 0, 512)
+    params = model.init(jax.random.PRNGKey(7), ids)["params"]
+
+    def step():  # a function of its own a trace: a traced one is not traced again
+        return jax.value_and_grad(bert.mlm_loss_fn(bert.BertForPreTraining(model.cfg)))
+
+    with monkeypatch.context() as steered:
+        steered.setattr(jax, "default_backend", lambda: "tpu")
+        held = jax.make_jaxpr(step())(params, (ids, labels))
+        loss, grads = compiled(step(), params, (ids, labels))
+    assert _layout_constraints(held.jaxpr) == 2 * 2  # a layer's result and its cotangent
+    assert _layout_constraints(jax.make_jaxpr(step())(params, (ids, labels)).jaxpr) == 0
+    _as_the_layer_was(monkeypatch)
+    want_loss, want = compiled(step(), params, (ids, labels))
+    assert np.array_equal(np.asarray(loss), np.asarray(want_loss))
+    assert jax.tree.structure(grads) == jax.tree.structure(want)
+    for (path, got), expected in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(want)):
+        assert got.dtype == expected.dtype and np.array_equal(
+            np.asarray(got, np.float32), np.asarray(expected, np.float32)), jax.tree_util.keystr(path)
+    assert float(jnp.abs(grads["bert"]["layer_0"]["attention"]["qkv"]["kernel"].astype(jnp.float32)).max()) > 0
+
+
+def test_bert_parameter_tree_is_leaf_for_leaf_what_it_was(monkeypatch):
+    """``benchmark/configs/bert-large.py`` builds the tree leaf by leaf: no
+    name, shape or type of it moves with the path a layer takes."""
+    from bagua_tpu.models import bert
+
+    cfg = _bert_cut(compute_dtype=jnp.bfloat16)
+    ids = jnp.zeros((2, 128), jnp.int32)
+
+    def tree():
+        shapes = jax.eval_shape(lambda: bert.BertForPreTraining(cfg).init(jax.random.PRNGKey(0), ids))
+        return {jax.tree_util.keystr(path): (leaf.shape, leaf.dtype)
+                for path, leaf in jax.tree_util.tree_leaves_with_path(shapes["params"])}
+
+    here = tree()
+    with monkeypatch.context() as steered:
+        steered.setattr(jax, "default_backend", lambda: "tpu")
+        on_chip = tree()
+    _as_the_layer_was(monkeypatch)
+    assert here == on_chip == tree()
+    attention = {name.split("['attention']")[1]: leaf for name, leaf in here.items()
+                 if "['layer_1']['attention']" in name}
+    assert attention == {
+        "['qkv']['kernel']": ((768, 2304), jnp.bfloat16), "['qkv']['bias']": ((2304,), jnp.bfloat16),
+        "['out']['kernel']": ((768, 768), jnp.bfloat16), "['out']['bias']": ((768,), jnp.bfloat16)}
+
+
+#: settings of the configuration, whether a key mask is passed, the mesh's axes, and the layout
+#: constraints a layer and pass on a TPU
+_BERT_LAYERS = {
+    "one_rank": (dict(), False, None, 1),
+    "tp2": (dict(tp_size=2, tp_axis="tp"), False, ("tp",), 1),  # the same columns, half the heads
+    "sp2": (dict(sp_axis="sp"), False, ("sp",), 0),             # ring attention
+    "kv_mask": (dict(), True, None, 0),
+    "heads_of_128": (dict(num_heads=6), False, None, 0),        # whole lane tiles: nothing to hold
+}
+
+
+@pytest.mark.parametrize("case", list(_BERT_LAYERS))
+def test_bert_layers_that_hold_nothing_lower_to_the_text_they_lowered_to(case, monkeypatch):
+    """The path is chosen by the layer's own fields and the backend: the local
+    core at heads narrower than a lane tile, on a TPU.  Here, on the CPU, every
+    layer lowers to the text of the layer as it stood; steered to the TPU, ring
+    attention, a key mask and heads of whole lane tiles still do, and the rest
+    hold the product's result and its cotangent."""
+    from bagua_tpu.models import bert
+
+    settings, masked, axes, constraints = _BERT_LAYERS[case]
+    cfg = _bert_cut(compute_dtype=jnp.bfloat16, **settings)
+    ids = jnp.zeros((2, 128), jnp.int32)
+    mask = jnp.ones((2, 128), bool) if masked else None
+
+    def lowered():
+        model = bert.BertModel(cfg)
+
+        def loss(params, ids):
+            return jnp.sum(model.apply({"params": params}, ids, None, mask))
+
+        def grad(params, ids):
+            g = jax.grad(loss)(params, ids)
+            return jax.tree.map(lambda a: a[None], g) if axes else g
+
+        params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids)["params"])
+        if axes:  # every rank its own parameters, stacked as the engine holds them
+            mesh = Mesh(np.array(jax.devices()[:2]), axes)
+            params = jax.tree.map(lambda a: jax.ShapeDtypeStruct((2,) + a.shape, a.dtype), params)
+            fn = jax.shard_map(lambda p, i: grad(jax.tree.map(lambda a: a[0], p), i), mesh=mesh,
+                               in_specs=(P(axes), P()), out_specs=P(axes), check_vma=False)
+        else:
+            fn = grad
+        return (jax.jit(fn).lower(params, ids).as_text(),
+                _layout_constraints(jax.make_jaxpr(fn)(params, ids).jaxpr))
+
+    text, found = lowered()
+    with monkeypatch.context() as steered:
+        steered.setattr(jax, "default_backend", lambda: "tpu")
+        on_chip_text, on_chip = lowered()
+    _as_the_layer_was(monkeypatch)
+    was, _ = lowered()
+    assert found == 0 and text == was
+    assert on_chip == 2 * 2 * constraints
+    assert (on_chip_text == was) == (constraints == 0)
+
+
 def test_flash_block_pallas_matches_jnp():
     """The Pallas block kernel (interpret mode on CPU) reproduces the jnp
     reference contribution exactly up to float tolerance, incl. padding of
