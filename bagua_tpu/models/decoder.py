@@ -1,6 +1,6 @@
 """The parts the causal decoder models share, defined once: ``glm_moe.py``,
-``lfm2_moe.py``, ``smallthinker_moe.py``, ``ouro.py``, ``nemotron_h.py`` and
-``laguna.py`` build on this module and on no other model file (``llama.py`` takes ``RMSNorm``
+``lfm2_moe.py``, ``smallthinker_moe.py``, ``ouro.py``, ``nemotron_h.py``,
+``laguna.py`` and ``solar_open2.py`` build on this module and on no other model file (``llama.py`` takes ``RMSNorm``
 from here).  Tested in ``tests/test_decoder.py`` and, the kernel under the
 attention, ``tests/test_causal_attention.py``, and not again in a model's file.
 
@@ -106,6 +106,51 @@ def shift(x, by: int, axis: int = 1):
     return jax.lax.slice_in_dim(jnp.pad(x, pad), max(-by, 0), max(-by, 0) + t, axis=axis)
 
 
+def _taps_and_bias(xbc, taps, bias):
+    """``bias + sum_i taps[i] * xbc_{t - (L - 1) + i}`` in float32: the last
+    tap meets the current position."""
+    last = taps.shape[0] - 1
+    x = xbc.astype(jnp.float32)
+    def summed():
+        return sum(taps[i].astype(jnp.float32) * shift(x, last - i) for i in range(last + 1))
+
+    return summed() if bias is None else bias.astype(jnp.float32) + summed()
+
+
+@jax.custom_vjp
+def causal_conv_silu(xbc, taps, bias=None):
+    """``silu(conv(xbc) + bias)``, ``(batch, positions, channels)`` in
+    ``xbc``'s type: depthwise, causal (zeros before the start), ``taps (L,
+    channels)`` with the last tap on the current position, ``bias (channels,)``
+    or none; in float32 and rounded once.  The backward pass keeps ``xbc`` and
+    builds the sum again.  The short convolution of ``nemotron_h.py``'s Mamba-2
+    mixer (with its bias) and of ``solar_open2.py``'s KDA mixer (without);
+    ``lfm2_moe.gated_short_conv`` is another arithmetic, gates between the
+    taps."""
+    return jax.nn.silu(_taps_and_bias(xbc, taps, bias)).astype(xbc.dtype)
+
+
+def _causal_conv_silu_fwd(xbc, taps, bias):
+    return causal_conv_silu(xbc, taps, bias), (xbc, taps, bias)
+
+
+def _causal_conv_silu_bwd(res, dy):
+    xbc, taps, bias = res
+    last = taps.shape[0] - 1
+    pre = _taps_and_bias(xbc, taps, bias)
+    gate = jax.nn.sigmoid(pre)
+    d_pre = dy.astype(jnp.float32) * gate * (1.0 + pre * (1.0 - gate))
+    x = xbc.astype(jnp.float32)
+    # x_t feeds position t + (L - 1) - i through tap i: the taps run against time
+    d_x = sum(taps[i].astype(jnp.float32) * shift(d_pre, i - last) for i in range(last + 1))
+    d_taps = jnp.stack([jnp.sum(d_pre * shift(x, last - i), axis=(0, 1)) for i in range(last + 1)])
+    return (d_x.astype(xbc.dtype), d_taps.astype(taps.dtype),
+            None if bias is None else jnp.sum(d_pre, axis=(0, 1)).astype(bias.dtype))
+
+
+causal_conv_silu.defvjp(_causal_conv_silu_fwd, _causal_conv_silu_bwd)
+
+
 def rotary(x, inv_freq, scale: float = 1.0, factor: float = 1.0):
     """The rotary embedding from its tables, in float32: the first ``2 *
     len(inv_freq)`` columns of ``x (..., positions, size)`` are turned in the
@@ -162,9 +207,11 @@ class GroupedQueryAttention(Kernels):
     frequencies, a factor), or no position and ``q`` times the scale;
     ``window``, so many keys counting the current one (under
     ``attn_window_core``, so that a capture reads the two masks apart) or all
-    earlier keys (``attn_core``); ``gate``, a scalar a head and position,
-    ``sigmoid(x W_g)`` from the layer's own input, on the core's result before
-    ``W_o`` (under ``attn_gate``).  Grouped queries at heads of whole lane tiles
+    earlier keys (``attn_core``); ``gate``, ``sigmoid(x W_g)`` from the layer's
+    own input on the core's result before ``W_o`` (under ``attn_gate``), at one
+    of two widths: ``True``, a scalar a head and position (``W_g`` of ``hidden x
+    heads``), or ``"column"``, one value a head *column* and position (``hidden
+    x heads x head size``).  Grouped queries at heads of whole lane tiles
     take the rotation and the gate as ``kernels/head_passes.py``'s passes, the
     same arithmetic (the module's text)."""
 
@@ -176,7 +223,7 @@ class GroupedQueryAttention(Kernels):
     rope_theta: Optional[float] = None
     window: Optional[int] = None
     rope: Optional[RotaryTables] = None
-    gate: bool = False
+    gate: Any = False
 
     @nn.nowrap
     def core(self, q, k, v):
@@ -223,7 +270,15 @@ class GroupedQueryAttention(Kernels):
             v = heads_of("v", self.kv_heads)
             out = self.kernel("out_proj", self.heads * size, hidden).reshape(-1, size, hidden)
         ctx = self.core(q, k, v)
-        if self.gate:
+        if self.gate == "column":
+            with model_scope("attn_gate"):
+                by_column = self.kernel("gate_proj", hidden, self.heads * size)
+                opened = jax.nn.sigmoid(jnp.einsum(
+                    HEADS_MAJOR, x.astype(dt), by_column.reshape(hidden, -1, size).astype(dt),
+                    preferred_element_type=jnp.float32))
+                ctx = (ctx * opened).astype(dt)
+                ctx = row_major(ctx) if pinned else ctx
+        elif self.gate:
             with model_scope("attn_gate"):
                 opened = jax.nn.sigmoid(jnp.einsum(
                     "btm,mh->bth", x.astype(dt), self.kernel("gate_proj", hidden, self.heads).astype(dt),

@@ -14,7 +14,7 @@ a final norm and an output matrix of its own.  On ``a = RMSNorm(x)``:
   ``G = n_groups``, ``N = ssm_state_size``): ``[z | xBC | dt] = a W_in`` of
   widths ``d_inner``, ``d_inner + 2 G N`` and one a head; ``xBC <-
   silu(conv(xBC) + b)``, depthwise and causal over ``conv_kernel`` taps
-  (:func:`causal_conv_silu`: ``y_t = sum_i w_i xBC_{t - (taps - 1) + i}``,
+  (:func:`~bagua_tpu.models.decoder.causal_conv_silu`: ``y_t = sum_i w_i xBC_{t - (taps - 1) + i}``,
   zeros before the start), split into ``x`` (heads of ``mamba_head_dim``) and
   ``B``, ``C`` (``G`` groups of ``N``); ``dt <- softplus(dt + dt_bias)``, ``A =
   -exp(A_log)``; for head ``h`` of group ``g`` the state ``S`` (head size x
@@ -100,7 +100,7 @@ import jax.numpy as jnp
 from bagua_tpu.kernels.causal_attention import causal_attention
 from bagua_tpu.kernels.ssd_scan import ssd_scan
 from bagua_tpu.models.decoder import (
-    HEADS_MAJOR, Kernels, RMSNorm, matmul, next_token_loss_fn, product, shift)
+    HEADS_MAJOR, Kernels, RMSNorm, causal_conv_silu, matmul, next_token_loss_fn, product)
 from bagua_tpu.models.embedding import embed
 from bagua_tpu.observability.annotations import model_scope
 from bagua_tpu.parallel.moe.dropless import dropless_experts, sigmoid_topk_route
@@ -243,48 +243,6 @@ def nemotron_h_test_config(**overrides) -> NemotronHConfig:
     )
     kwargs.update(overrides)
     return NemotronHConfig(**kwargs)
-
-
-# -- the mixer's convolution ---------------------------------------------------
-
-
-def _taps_and_bias(xbc, taps, bias):
-    """``bias + sum_i taps[i] * xbc_{t - (L - 1) + i}`` in float32: the last
-    tap meets the current position."""
-    last = taps.shape[0] - 1
-    x = xbc.astype(jnp.float32)
-    return bias.astype(jnp.float32) + sum(
-        taps[i].astype(jnp.float32) * shift(x, last - i) for i in range(last + 1))
-
-
-@jax.custom_vjp
-def causal_conv_silu(xbc, taps, bias):
-    """``silu(conv(xbc) + bias)``, ``(batch, positions, channels)`` in
-    ``xbc``'s type: depthwise, causal (zeros before the start), ``taps (L,
-    channels)`` with the last tap on the current position; in float32 and
-    rounded once.  The backward pass keeps ``xbc`` and builds the sum again."""
-    return jax.nn.silu(_taps_and_bias(xbc, taps, bias)).astype(xbc.dtype)
-
-
-def _causal_conv_silu_fwd(xbc, taps, bias):
-    return causal_conv_silu(xbc, taps, bias), (xbc, taps, bias)
-
-
-def _causal_conv_silu_bwd(res, dy):
-    xbc, taps, bias = res
-    last = taps.shape[0] - 1
-    pre = _taps_and_bias(xbc, taps, bias)
-    gate = jax.nn.sigmoid(pre)
-    d_pre = dy.astype(jnp.float32) * gate * (1.0 + pre * (1.0 - gate))
-    x = xbc.astype(jnp.float32)
-    # x_t feeds position t + (L - 1) - i through tap i: the taps run against time
-    d_x = sum(taps[i].astype(jnp.float32) * shift(d_pre, i - last) for i in range(last + 1))
-    d_taps = jnp.stack([jnp.sum(d_pre * shift(x, last - i), axis=(0, 1)) for i in range(last + 1)])
-    return (d_x.astype(xbc.dtype), d_taps.astype(taps.dtype),
-            jnp.sum(d_pre, axis=(0, 1)).astype(bias.dtype))
-
-
-causal_conv_silu.defvjp(_causal_conv_silu_fwd, _causal_conv_silu_bwd)
 
 
 # -- the Mamba-2 mixer ----------------------------------------------------------

@@ -137,7 +137,12 @@ def model_scope(part: str):
     ``D`` term, the gate and the group norm, and ``moe_latent``, the two
     projections between the hidden and the experts' latent width; in
     ``models/laguna.py`` ``attn_gate``, the per-head output gate between the
-    core and ``W_o``: its product, sigmoid and multiplication).  Any name
+    core and ``W_o``: its product, sigmoid and multiplication; in
+    ``models/solar_open2.py`` ``kda_proj``, a KDA mixer's four wide products
+    and four narrow ones, ``kda_conv``, its three depthwise causal convolutions
+    with SiLU, ``kda_core``, the L2 norms, the decay, ``beta`` and the chunked
+    delta rule, all of it built again backward, and ``kda_gate_norm``, the head
+    norm and its gate, beside ``attn_gate`` at a value a head column).  Any name
     is a part: the summary keeps what it finds.
     Autodiff carries the frame into the backward pass's ops, and
     ``jax.checkpoint`` into those it runs again there, so the device trace

@@ -66,7 +66,7 @@ The grouped product is ``megablox.gmm`` (Pallas, ships with JAX) on a TPU
 and ``jax.lax.ragged_dot`` elsewhere; rows past the held groups are left
 unwritten by the one and zero by the other, so every use masks them.  The
 kernel's tile is a function of the product's shape (:func:`gmm_tiling`): the
-layer has five callers whose experts differ in width, in the model's hidden
+layer has six callers whose experts differ in width, in the model's hidden
 size, in the choices a token makes, in the groups held and in the rows a group
 gets (``models/glm_moe.py`` 1536 of 2048, 4 choices; ``models/lfm2_moe.py``
 1792 of 2048, 4; ``models/smallthinker_moe.py`` 768 of 2560, 6: a buffer of
@@ -74,7 +74,9 @@ gets (``models/glm_moe.py`` 1536 of 2048, 4 choices; ``models/lfm2_moe.py``
 choices of 512 experts with 8 held: a buffer of 65,536 rows for 2,816
 expected; ``models/laguna.py`` 512 of 2048, 8 choices of 256 experts with 32
 held, the one caller with more than 8 groups: a buffer of 65,536 rows for
-8,192 expected, 256 a group).
+8,192 expected, 256 a group; ``models/solar_open2.py`` 1280 of 4096, the widest
+rows yet, 8 choices of 320 experts with 8 held: a buffer of 65,536 rows for
+1,638 expected, 205 a group, 537 MB a copy in bf16).
 """
 
 import functools
@@ -114,13 +116,23 @@ from bagua_tpu.observability.annotations import model_scope
 #: expert layer: the whole contraction and all columns in one tile both ways,
 #: at 128 rows 60.53 ms and at 256 rows 60.54; 1,024 columns the other way
 #: 60.67; a contraction of 1,024 or 512, or 512 rows, 61.23 to 61.47; the
-#: default below 62.39; none refused).  Every tile of it
+#: default below 62.39; none refused).  1,280 of 4,096 (``solar-open2-250b``: 8
+#: groups of 205 expected rows in a buffer of 65,536; PERF.md section 6, PR 52,
+#: eight tile pairs by a plain SGD step's time of one expert layer under a GQA
+#: mixer: 256 rows against a contraction of 1,024 and all 1,280 columns, the
+#: whole contraction and 1,024 columns the other way, 81.91 ms, the same at 128
+#: rows 81.96; 640 columns a tile, a contraction of 2,048 or 512, or 512 rows
+#: 82.09 to 82.77; the default below 83.55; the whole contraction of 4,096
+#: against 640 columns or more, and 2,048 columns the other way, are refused for
+#: fast memory by the weights' gradient, whose float32 tile is contraction x
+#: columns).  Every tile of it
 #: divides its dimension: a contraction tile that hangs over is masked in
 #: float32 at every step of the kernel's grid.
 GMM_TILES = {1536: (512, 1024, 768), 1792: (128, 0, 896),
              (2560, 768): (256, 1280, 768), (768, 2560): (256, 0, 1280),
              (1024, 2688): (128, 0, 896), (2688, 1024): (128, 896, 1024),
-             (2048, 512): (128, 0, 512), (512, 2048): (128, 0, 2048)}
+             (2048, 512): (128, 0, 512), (512, 2048): (128, 0, 2048),
+             (4096, 1280): (256, 1024, 1280), (1280, 4096): (256, 0, 1024)}
 
 
 def gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:

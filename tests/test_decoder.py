@@ -116,6 +116,35 @@ def test_rotary_turns_the_tables_columns_and_passes_the_others_through_unrotated
     assert RotaryTables((1.0, 0.3, 0.01), 1.4).columns == 6 and RotaryTables((1.0,)).factor == 1.0
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_the_short_convolution_without_a_bias_is_the_one_with_a_bias_of_zero(dtype):
+    """``causal_conv_silu`` serves ``nemotron_h.py`` with a bias (its arithmetic
+    against a direct sum in ``test_nemotron_h.py``) and ``solar_open2.py``
+    without: the same values and gradients as a bias of zero gives, the last tap
+    on the current position, and ``xbc`` and the taps alone kept."""
+    keys = jax.random.split(jax.random.PRNGKey(2), 3)
+    xbc = jax.random.normal(keys[0], (2, 9, 5)).astype(dtype)
+    taps = jax.random.normal(keys[1], (4, 5))
+    probe = jax.random.normal(keys[2], (2, 9, 5))
+
+    def both(*bias):
+        def run(xbc, taps):
+            out, pull = jax.vjp(lambda xbc, taps: decoder.causal_conv_silu(xbc, taps, *bias), xbc, taps)
+            return (out,) + pull(probe.astype(out.dtype))
+        return compiled(run, xbc, taps)
+
+    without, with_zero = both(), both(jnp.zeros((5,)))
+    for a, b in zip(without, with_zero):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+    first = np.asarray(xbc[:, 0], np.float32) * np.asarray(taps[3])
+    np.testing.assert_allclose(np.asarray(without[0][:, 0], np.float32), first / (1 + np.exp(-first)),
+                               rtol=1e-2 if dtype == jnp.bfloat16 else 1e-5)
+    _, residuals = jax.vjp(decoder.causal_conv_silu, xbc, taps)
+    kept = sorted((x.shape, str(x.dtype)) for x in jax.tree.leaves(residuals))
+    assert kept == sorted([((2, 9, 5), jnp.dtype(dtype).name), ((4, 5), "float32")])
+
+
 @pytest.mark.parametrize("by", [0, 1, 3, -2, 9, -9])
 def test_shift_moves_positions_later_or_earlier_and_zeros_move_in(by):
     x = jax.random.normal(jax.random.PRNGKey(7), (2, 9, 4))
@@ -188,6 +217,7 @@ SETTINGS = {
     "no_positions_under_a_window": dict(window=5),                   # the two keys apart
     "gated_tables_on_half_the_columns": dict(rope=TABLES, gate=True),  # laguna's global layers
     "gated_rotary_under_a_window": dict(rope_theta=1e4, window=5, gate=True),  # its windowed
+    "gated_by_the_column_without_positions": dict(gate="column"),    # solar_open2's GQA layers
 }
 HEADS, KV_HEADS, SIZE, HIDDEN = 6, 2, 8, 32
 
@@ -220,7 +250,10 @@ def plain_layer(params, x, norm_eps=None, rope_theta=None, window=None, rope=Non
 
     ctx = quadratic_attention(heads_of("q", heads), heads_of("k", kv_heads), heads_of("v", kv_heads),
                               1.0 / math.sqrt(size), window)
-    if gate:
+    if gate == "column":  # one value a head column and position
+        ctx = ctx * jax.nn.sigmoid(jnp.einsum(
+            "btm,mhd->bhtd", x, params["gate_proj"].reshape(HIDDEN, heads, size)))
+    elif gate:  # a scalar a head and position
         ctx = ctx * jax.nn.sigmoid(jnp.einsum("btm,mh->bht", x, params["gate_proj"]))[..., None]
     return jnp.einsum("bhtd,hdm->btm", ctx, params["out_proj"].reshape(heads, size, HIDDEN))
 
@@ -274,8 +307,8 @@ def test_the_attention_layers_parameters_are_four_kernels_and_the_head_norms_if_
                "v_proj": (HIDDEN, KV_HEADS * SIZE), "out_proj": (HEADS * SIZE, HIDDEN)}
     norms = {name: {"scale": (SIZE,)} for name in ("q_norm", "k_norm")
              } if "norm_eps" in SETTINGS[setting] else {}
-    if SETTINGS[setting].get("gate"):  # one column a query head
-        kernels["gate_proj"] = (HIDDEN, HEADS)
+    if SETTINGS[setting].get("gate"):  # one column a query head, or one a head column
+        kernels["gate_proj"] = (HIDDEN, HEADS * (SIZE if SETTINGS[setting]["gate"] == "column" else 1))
     assert jax.tree.map(lambda p: p.shape, params) == {**kernels, **norms}
     assert all(p.dtype == jnp.float32 for p in jax.tree.leaves(params))
 
@@ -438,6 +471,8 @@ PINNED = {
     "ouro": ((4, 4, 128), dict(rope_theta=1e6), (0, 0, 0)),  # a key-value head a query head
     "lfm2": ((8, 2, 64), dict(norm_eps=1e-5, rope_theta=1e6), (0, 0, 0)),  # half a lane tile
     "gated_heads_of_64": ((8, 2, 64), dict(rope_theta=1e4, gate=True), (0, 0, 0)),
+    # no positions, the gate a value a column: q's product and the gated ctx held, no pass
+    "solar_open2": ((8, 1, 128), dict(gate="column"), (0, 0, 2)),
 }
 
 
