@@ -69,14 +69,18 @@ SiLU), ``kda_core`` (L2 norms, decay, ``beta`` and the chunked delta rule),
 ``moe_combine``, ``moe_shared``, ``head``, ``embed``.
 
 **What is kept for the backward pass and what is built again** (8,192
-positions, the benchmark's share; ``PERF.md`` section 6, PR 52).  Built again:
-the convolutions' float32 taps and SiLU (the shared function's own backward
-rule); all of ``kda_core`` from ``q~``, ``k~``, ``v``, the decay's and
-``beta``'s products (:func:`_kda_core`, one ``jax.checkpoint``: the chunk
-scores, the triangular systems, their solutions and the states at every chunk's
-start do not live from the forward pass to the backward); the head norm and
-gate from ``o`` and ``z`` (:func:`_gate_norm`).  The cell's step fits the chip
-with nothing else built again: 14.36 GB of its 16.9.
+positions, the benchmark's share; ``PERF.md`` section 6, PR 52 and PR 53).  Built
+again: the convolutions' float32 taps and SiLU (the shared function's own
+backward rule); all of ``kda_core`` from ``q~``, ``k~``, ``v``, the decay's and
+``beta``'s products (:func:`_kda_core`, one ``jax.checkpoint``: on a TPU the
+L2 norms, ``g``, ``beta`` and the rule's forward kernel run again in the
+backward pass, which is where the 67 MB of states at every chunk's start live,
+and the backward kernel builds the chunk's terms in fast memory; elsewhere the
+composition's chunk scores, triangular systems and their solutions are built
+again); the head norm and gate from ``o`` and ``z`` (:func:`_gate_norm`).  The
+cell's step fits the chip with nothing else built again: 14.22 GB of its 16.9
+(14.36 with the composition; keeping the kernel's states instead of running it
+again reads 8.9 ms a step less and 0.3 GB more).
 """
 
 import dataclasses

@@ -692,6 +692,75 @@ def test_the_mixers_step_holds_the_scans_two_kernels_and_no_array_of_decays():
     assert not [words for words in lines if words[0] == "SCAN-DECAY-SIZED"], proc.stdout
 
 
+_RULE_CENSUS = _SCAN_CENSUS[:_SCAN_CENSUS.index("cell = manifest")] + """
+cell = manifest.load_cell("solar-open2-250b.dp1-s8192")
+cell.config = {**cell.config, "num_hidden_layers": 1, "gqa_layers": []}
+sizes = cell.sizes
+on_chip = lambda tree: jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=device), tree)
+params = on_chip(jax.eval_shape(lambda k: cell.adapter.to_program(
+    cell.reference.init_params(k, sizes), sizes), jax.random.PRNGKey(0)))
+batch = on_chip(jax.eval_shape(lambda k: cell.adapter.draw_batch(k, 1, sizes), jax.random.PRNGKey(0)))
+loss_fn = cell.adapter.build_loss(sizes)
+
+
+def sgd_step(params, batch):
+    loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+    return jax.tree.map(lambda p, g: p - 0.01 * g.astype(p.dtype), params, grads), loss
+
+
+text = jax.jit(sgd_step, donate_argnums=(0,), compiler_options=STEP_COMPILER_OPTIONS["tpu"]).lower(
+    params, batch).compile().as_text()
+# every instruction, those inside the fusions and the loops' bodies too
+for line in text.splitlines():
+    m = re.match(r"\\s*(?:ROOT )?%(\\S+) = (.*?) ([a-z][\\w-]*)\\(", line)
+    op_name = re.search(r'op_name="([^"]*)"', line)
+    if not m or not op_name or "part=kda_core" not in op_name.group(1):
+        continue
+    op_name = op_name.group(1)
+    if "tpu_custom_call" in line:
+        print("RULE-KERNEL", "backward" if "transpose(" in op_name else "forward",
+              re.search(r"delta_rule_\\w+", op_name).group(0), flush=True)
+    if m.group(3) == "while":
+        print("RULE-LOOP", m.group(1), op_name[-60:].replace(" ", "_"), flush=True)
+    for dims in re.findall(r"f32\\[([\\d,]+)\\]", m.group(2)):
+        count = 1
+        for d in dims.split(","):
+            count *= int(d)
+        if count >= 8 * 128 * 4 * 16 * 16 * 128:
+            print("RULE-SCORE-SIZED", m.group(1), dims, op_name[-60:].replace(" ", "_"), flush=True)
+"""
+
+
+def test_the_kda_layers_step_holds_the_rules_two_kernels_no_loop_and_no_array_of_channel_scores():
+    """The engagement check of ``kernels/delta_rule.py`` on a TPU (PR 53): a
+    plain SGD step of one KDA layer between the slice's embedding and head, at
+    the shapes of ``solar-open2-250b.dp1-s8192`` (8,192 positions, 8 heads of
+    128 keys and values, chunks of 64), compiled for a described v5e with the
+    backend steered to the TPU.  Under ``bagua_model/part=kda_core`` it holds
+    Mosaic calls of the rule's two kernels and of no other, the backward one
+    (and the forward one a second time, rebuilt under the layer's
+    ``jax.checkpoint``) under autodiff's ``transpose(`` frame, where the
+    trace's reduction looks for it; no ``while`` loop (the plain form's text has three, 128 steps
+    each: forward, rebuilt, reverse); and no float32 array of ``heads x chunks
+    x 4 x 16 x 16 x 128`` = 134 M elements, the channel-by-channel scores of
+    the diagonal sub-blocks, inside a fusion or out of it."""
+    proc = subprocess.run(
+        [sys.executable, "-c", _RULE_CENSUS],
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO_ROOT),
+        capture_output=True, text=True, timeout=600,
+    )
+    if proc.returncode == 3:
+        pytest.skip(proc.stdout.strip()[-300:])
+    assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-3000:]
+    lines = [line.split() for line in proc.stdout.splitlines()]
+    kernels = {tuple(words[1:]) for words in lines if words[0] == "RULE-KERNEL"}
+    # the layer's core is under one jax.checkpoint: the forward kernel runs again before the backward one
+    assert kernels == {("forward", "delta_rule_forward"), ("backward", "delta_rule_forward"),
+                       ("backward", "delta_rule_backward")}, proc.stdout
+    assert not [words for words in lines if words[0] in ("RULE-LOOP", "RULE-SCORE-SIZED")], proc.stdout
+
+
 _PINNED_PASSES_CENSUS = """
 import re, sys, time
 import jax, jax.numpy as jnp
