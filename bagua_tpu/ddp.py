@@ -48,6 +48,7 @@ from bagua_tpu.communication import (
 from bagua_tpu.env import get_default_bucket_size, get_static_verify_mode
 from bagua_tpu.observability.annotations import step_scope, timed_host_span
 from bagua_tpu.observability.cold_start import cold_host_span, step_compile_seconds
+from bagua_tpu.observability.completions import read_health
 from bagua_tpu.observability.core import StepTimer
 from bagua_tpu.observability.metrics import (
     switch_reason_family,
@@ -93,6 +94,10 @@ class _StepVariant:
     #: the compiled steps by :func:`_batch_signature`: ``fn``, and one more
     #: for every batch of another shape or placement, as ``jit``'s cache held
     by_batch: dict = dataclasses.field(default_factory=dict)
+    #: ``(plan_version, what the hub is told of the wire every step)``: the
+    #: byte census by leg, precision and axis changes only with the variant
+    #: and the plan (:meth:`DistributedDataParallel._wire_census`)
+    census: Optional[tuple] = None
 
 
 class _OwnLeaf(NamedTuple):
@@ -282,11 +287,21 @@ class DistributedDataParallel:
         #: ``Trainer.fit``, which adds them here: next_batch (``next()`` on
         #: the caller's iterator) and loop (the rest of an iteration outside
         #: ``train_step``).  Two clock reads per span; read/reset via
-        #: host_overhead_snapshot().
+        #: host_overhead_snapshot().  Two more keys exist only with what
+        #: they count: flight (with a hub that has a flight recorder: its
+        #: replay and retire, a part of dispatch) and health_wait (with a
+        #: hub or a monitor: what the dispatching thread waits for the
+        #: *device* on the tracing's account, which is the monitor's read
+        #: where an action is registered or there is no hub, and a full
+        #: hand-over queue).
         self.host_overhead = {"pre": 0.0, "lock_wait": 0.0, "dispatch": 0.0,
                               "post": 0.0, "build": 0.0, "telemetry": 0.0,
                               "health": 0.0, "next_batch": 0.0, "loop": 0.0,
                               "steps": 0}
+        if telemetry is not None and telemetry.flight is not None:
+            self.host_overhead["flight"] = 0.0
+        if telemetry is not None or health_monitor is not None:
+            self.host_overhead["health_wait"] = 0.0
         #: the ``perf_counter`` instant of the last
         #: ``host_overhead_snapshot(reset=True)``: since when the counters
         #: above count, and where the set-up that the process's cold record
@@ -303,13 +318,17 @@ class DistributedDataParallel:
         #: attached the compiled step additionally returns the per-rank
         #: health scalars (loss / global grad-norm / nonfinite count — pure
         #: reads, the parameter path is bitwise-identical either way) and
-        #: the host feeds the aggregated values to the monitor after every
-        #: dispatch.
+        #: the host feeds the aggregated values to the monitor: once the step
+        #: is seen to complete, inside a later ``train_step``, so that the
+        #: dispatch runs ahead of the device; before ``train_step`` returns
+        #: where an action is registered on the monitor (it must see the
+        #: state its alert is about) or no hub is attached (no waiter).
         self.health_monitor = health_monitor
         if health_monitor is not None and telemetry is not None:
             health_monitor.bind_telemetry(telemetry)
-        #: host-observed full train_step wall times (ring-buffered) —
-        #: host_overhead_snapshot surfaces its p50/p95/p99 tail
+        #: walls of ``train_step``'s dispatch, from before ``pre`` to after
+        #: ``post`` (ring-buffered): the enqueue, not the step, which the
+        #: device runs later.  host_overhead_snapshot surfaces the tail.
         self.step_timer = StepTimer()
 
     def _validate_mesh_axes(self, **axis_kwargs):
@@ -1483,9 +1502,14 @@ class DistributedDataParallel:
         (pinned in tests)."""
         if flight is None or not prog:
             return fn(state, batch)
+        began = time.perf_counter()
         seqs = flight.record_program(prog, step=self._host_step - 1)
+        replayed = time.perf_counter()
         out = fn(state, batch)
+        enqueued = time.perf_counter()
         flight.retire(seqs)
+        self.host_overhead["flight"] += (
+            replayed - began + time.perf_counter() - enqueued)
         return out
 
     def _flight_crosscheck(self, variant, rec) -> None:
@@ -1658,28 +1682,76 @@ class DistributedDataParallel:
             )
         if tel is not None:
             with host("telemetry"):
-                self._telemetry_on_step(tel, batch, variant, wall, step_ov)
-        if self.health_monitor is not None and len(out) == 3:
+                waited = self._telemetry_on_step(
+                    tel, batch, rec, variant, t0, wall, step_ov, out)
+            if waited:
+                # a full hand-over queue: the device's lateness, not the hub's work
+                self.host_overhead["health_wait"] += waited
+                self.host_overhead["telemetry"] -= waited
+        monitor = self.health_monitor
+        if monitor is not None and len(out) == 3:
+            step = self._host_step - 1
+            if tel is None:
+                # no hub, no waiter: read before the next step is sent
+                with host("health_wait"):
+                    rows = [(step,) + read_health(out[2])]
+            else:
+                if monitor.actions:
+                    # an action must see the state its alert is about
+                    with host("health_wait"):
+                        tel.completions.drain()
+                rows = tel.completions.take_health(step)
             with host("health"):
-                loss_mean, gn_max, nonfinite = self._read_health(out[2])
-                self.health_monitor.observe(
-                    step=self._host_step - 1, loss=loss_mean, grad_norm=gn_max,
-                    nonfinite=nonfinite, state=new_state,
-                )
+                self._observe_health(rows, step, new_state)
         return new_state, losses
+
+    def _observe_health(self, rows, step: int, state) -> None:
+        """Feeds the monitor the steps seen to complete, oldest first; an
+        action is handed ``state`` only by the alert of the step that made
+        it."""
+        for k, loss, grad_norm, nonfinite in rows:
+            self.health_monitor.observe(
+                step=k, loss=loss, grad_norm=grad_norm, nonfinite=nonfinite,
+                state=state if k == step else None)
+
+    def drain_steps(self) -> None:
+        """Returns when every dispatched step has been seen to complete by
+        the hub and observed by the monitor: none is lost, none seen twice.
+        ``Trainer.fit`` ends with it.  Nothing to do without a hub, where
+        ``train_step`` itself has read each step's health."""
+        tel = self.telemetry
+        if tel is None:
+            return
+        tel.completions.drain()
+        if self.health_monitor is not None and self._host_step is not None:
+            step = self._host_step - 1
+            self._observe_health(tel.completions.take_health(step), step, None)
 
     def _host(self, key: str) -> timed_host_span:
         """The span ``bagua_host/step/<key>`` that also counts its time
         under ``host_overhead[key]``."""
         return timed_host_span("step", key, self.host_overhead)
 
-    def _telemetry_on_step(self, tel, batch, variant, wall, step_ov) -> None:
-        """What the hub is told after a dispatch: samples, the step's wall
-        and host phases, and the wire-byte census by leg, precision and
-        axis."""
+    def _telemetry_on_step(self, tel, batch, rec, variant, began, wall, step_ov, out) -> float:
+        """What the hub is told after a dispatch: the step's results to see
+        it complete by, samples, the dispatch's wall and host phases, and the
+        wire-byte census.  Returns the seconds the hand-over waited."""
         tel.enter_phase("wait")
         leaves = jax.tree_util.tree_leaves(batch)
         n_samples = int(leaves[0].shape[0]) if leaves and leaves[0].ndim else 0
+        step = self._host_step - 1
+        waited = tel.completions.watch(
+            step, began, n_samples, out[1], out[2] if len(out) == 3 else None)
+        if rec.census is None or rec.census[0] != self.plan_version:
+            rec.census = (self.plan_version, self._wire_census(variant))
+        tel.on_step(step=step, wall_s=wall, n_samples=n_samples, variant=variant,
+                    host_overhead=step_ov, **rec.census[1])
+        return waited
+
+    def _wire_census(self, variant) -> dict:
+        """The bytes a step of ``variant`` puts on the wire under the live
+        plan, whole and by leg, precision and axis, as ``Telemetry.on_step``
+        takes them."""
         wire_by_leg = None
         if self._sharded_updater is not None and self.plan is not None:
             # Ring-model bytes per leg: a reduce-scatter or all-gather of
@@ -1697,7 +1769,7 @@ class DistributedDataParallel:
             # captured flight program (records carry the exchange axes)
             # against its bytes — joint multi-axis exchanges split
             # evenly — falling back to the plan census spread over the
-            # group's data axes when no program was captured yet.
+            # group's data axes when no program was captured.
             by_axis = {}
             for rec in self.flight_program(variant) or ():
                 axes = [a for a in (rec.get("axes") or ()) if a]
@@ -1712,36 +1784,11 @@ class DistributedDataParallel:
                     share = self.plan.total_bytes() // len(axes)
                     by_axis = {ax: share for ax in axes}
             wire_by_axis = by_axis or None
-        tel.on_step(
-            step=self._host_step - 1,
-            wall_s=wall,
-            n_samples=n_samples,
+        return dict(
             wire_bytes=self.plan.total_bytes() if self.plan else 0,
-            variant=variant,
-            host_overhead=step_ov,
             wire_bytes_by_leg=wire_by_leg,
             wire_bytes_by_precision=wire_by_precision,
             wire_bytes_by_axis=wire_by_axis,
-        )
-
-    @staticmethod
-    def _read_health(arr):
-        """Aggregate the rank-stacked ``(size, 3)`` health vector host-side:
-        mean loss, max grad norm, summed nonfinite count.  On a multi-host
-        group only this process' shards are addressable; every rank reaches
-        the same alert decision from its own slice (all slices of a
-        replicated reduction agree, and per-rank values differ only in the
-        local loss/grad terms the detector thresholds are far above)."""
-        if isinstance(arr, jax.Array) and not arr.is_fully_addressable:
-            rows = np.concatenate(
-                [np.asarray(s.data).reshape(-1, 3) for s in arr.addressable_shards]
-            )
-        else:
-            rows = np.asarray(arr).reshape(-1, 3)
-        return (
-            float(np.mean(rows[:, 0])),
-            float(np.max(rows[:, 1])),
-            int(np.sum(rows[:, 2])),
         )
 
     # -- shard-layout migration (sharded-update algorithms) ------------------
@@ -1852,9 +1899,14 @@ class DistributedDataParallel:
         ``since``: the ``perf_counter`` instant of the last ``reset=True``
         (None before any), from which they count; and how much of the state
         the compiled steps take as each rank's own array (``_own_leaves``:
-        leaves, and their bytes on one device).  The reset leaves the
-        process's cold record whole: what it holds before ``since`` is the
-        set-up of the stretch this snapshot describes."""
+        leaves, and their bytes on one device).  ``step_wall_ms`` is the tail
+        of the *dispatch's* wall (``step_timer``), not of the step.  With a
+        hub attached, ``completions`` is what its waiter saw of the steps
+        completing since the last reset (``Completions.snapshot``: the
+        intervals' ``p50``/``p95``/``max``, ``run_ahead_mean``, ``stalls``,
+        ``stall_ms``, ``health_lag_steps_max``); absent without one.  The
+        reset leaves the process's cold record whole: what it holds before
+        ``since`` is the set-up of the stretch this snapshot describes."""
         ov = dict(self.host_overhead)
         n = max(1, ov.pop("steps"))
         out = {f"{k}_ms_per_step": round(v * 1e3 / n, 3) for k, v in ov.items()}
@@ -1865,6 +1917,8 @@ class DistributedDataParallel:
         out["since"] = self._overhead_since
         out["state_leaves_own"] = len(self._own_leaves)
         out["state_bytes_own"] = sum(leaf.nbytes for leaf in self._own_leaves)
+        if self.telemetry is not None:
+            out["completions"] = self.telemetry.completions.snapshot(reset=reset)
         if reset:
             for k in self.host_overhead:
                 self.host_overhead[k] = 0.0 if k != "steps" else 0

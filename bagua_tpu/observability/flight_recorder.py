@@ -43,6 +43,7 @@ Record labels reuse the named-scope grammar
 trace labels join on the same key.
 """
 
+import collections
 import json
 import logging
 import os
@@ -158,10 +159,11 @@ class FlightRecorder:
     The hot path (:meth:`record` / :meth:`record_program` / :meth:`retire`)
     runs on the engine's dispatch thread; :meth:`records` / :meth:`dump`
     may run concurrently on the watchdog thread.  Safety argument: every
-    slot holds either ``None`` or a complete immutable record (the dict is
-    fully built before the single reference assignment publishes it), so a
-    reader sees whole records only — at worst a mix of just-overwritten and
-    just-published ones, which the per-record ``seq`` sorts out.
+    slot holds either ``None`` or a complete record (the dict is fully built
+    before the single reference assignment publishes it, and only its
+    ``t_retire`` is ever set again, in place), so a reader sees whole
+    records only — at worst a mix of just-overwritten and just-published
+    ones, which the per-record ``seq`` sorts out.
     """
 
     def __init__(self, capacity: int = 4096, rank: int = 0, world_size: int = 1):
@@ -169,6 +171,11 @@ class FlightRecorder:
         self._seq = 0  # next sequence number == records ever appended
         self.rank = int(rank)
         self.world_size = int(world_size)
+        #: this rank's own host events (the hub's ``stall`` events), newest
+        #: last.  Beside the ring and not in it: the hang join compares the
+        #: ranks' rings sequence number by sequence number, and an event of
+        #: one rank would read as a desync.
+        self.host_events: collections.deque = collections.deque(maxlen=32)
 
     @property
     def capacity(self) -> int:
@@ -178,6 +185,10 @@ class FlightRecorder:
     def last_seq(self) -> int:
         """Sequence number of the newest record (-1 while empty)."""
         return self._seq - 1
+
+    def note(self, event: Dict) -> None:
+        """Keep one host event of this rank for the dump (``host_events``)."""
+        self.host_events.append(dict(event))
 
     def record(self, rec: Dict) -> int:
         """Append one collective record; returns its sequence number."""
@@ -189,31 +200,32 @@ class FlightRecorder:
         return seq
 
     def record_program(self, program: Sequence[Dict], *, step: int,
-                       enqueue_t: Optional[float] = None) -> List[int]:
+                       enqueue_t: Optional[float] = None) -> Sequence[int]:
         """Replay one step's captured collective program into the ring with
         ``t_retire=None`` (the dispatch is in flight); returns the sequence
         numbers for :meth:`retire`."""
         t = time.monotonic() if enqueue_t is None else float(enqueue_t)
-        seqs = []
-        for tmpl in program:
-            rec = dict(tmpl)
-            rec["step"] = int(step)
-            rec["t_enqueue"] = t
-            rec["t_retire"] = None
-            seqs.append(self.record(rec))
-        return seqs
+        step, slots, first = int(step), self._slots, self._seq
+        cap = len(slots)
+        for seq, tmpl in enumerate(program, first):
+            # one copy a record, whole before the assignment publishes it
+            slots[seq % cap] = {**tmpl, "step": step, "t_enqueue": t, "t_retire": None,
+                                "seq": seq}
+            self._seq = seq + 1
+        return range(first, self._seq)
 
     def retire(self, seqs: Sequence[int], retire_t: Optional[float] = None) -> None:
         """The dispatch window closed: stamp ``t_retire`` on the given
         records (skipping any the ring already evicted)."""
         t = time.monotonic() if retire_t is None else float(retire_t)
         cap = len(self._slots)
+        slots = self._slots
         for seq in seqs:
-            cur = self._slots[seq % cap]
-            if cur is not None and cur.get("seq") == seq and cur.get("t_retire") is None:
-                new = dict(cur)
-                new["t_retire"] = t
-                self._slots[seq % cap] = new
+            cur = slots[seq % cap]
+            if cur is not None and cur["seq"] == seq and cur["t_retire"] is None:
+                # in place: one assignment to a key the record already has,
+                # so a reader sees the record whole with either value
+                cur["t_retire"] = t
 
     def records(self) -> List[Dict]:
         """Snapshot of the ring's live records in sequence order.  Safe
@@ -242,6 +254,7 @@ class FlightRecorder:
             "capacity": len(self._slots),
             "last_seq": self.last_seq,
             "records": self.records(),
+            "host_events": list(self.host_events),
             "threads": thread_stacks(),
             "telemetry": telemetry,
             "plan_version": plan_version,

@@ -261,6 +261,17 @@ EVENT_PAYLOAD_FIELDS = {
         "reason": str,
         "last_phase": str,
     },
+    # a step completed later than twice the median of the last 32 intervals
+    # between completions (observability/completions.py): the step, its
+    # interval, that median, the excess over it, and the milliseconds of the
+    # interval the fit thread spent in each phase (data / dispatch / wait /
+    # gc / ...), which sum to the interval
+    "stall": {
+        "interval_ms": (int, float),
+        "median_ms": (int, float),
+        "excess_ms": (int, float),
+        "phases_ms": dict,
+    },
     # one retry_call backoff sleep (resilience/retry.py): the attempt that
     # failed, the delay about to be slept, and why (reason: "backpressure"
     # when a 429 Retry-After hint shaped the delay, "error" otherwise).

@@ -7,7 +7,11 @@ Attach a :class:`Telemetry` to the engine
 
 * step wall time, samples/s, wire bytes (from the bucket plan) into the
   :class:`~bagua_tpu.observability.metrics.MetricsRegistry` and the JSONL
-  event stream;
+  event stream.  The registry's ``step_wall_ms`` and ``samples_per_s`` are
+  intervals between steps *completing*, stamped by the hub's waiter thread
+  (:mod:`~bagua_tpu.observability.completions`), which also gives the
+  run-ahead, the health rows a monitor reads a step late, and ``stall``
+  events that name what the host was doing;
 * a **recompile detector** counting the engine's jit-cache misses per step
   variant — a silent retrace (batch-shape drift, a weak-typed scalar, an
   accidental plan change) is the top real-world TPU perf bug and is
@@ -18,14 +22,16 @@ Attach a :class:`Telemetry` to the engine
   embeds in its hang dump, so a timeout says *where* the step was stuck.
 
 Everything is host-side and optional — an unattached engine pays nothing,
-an attached one ~a few µs of clock reads and dict updates per step.
+an attached one clock reads, dict updates and a queue hand-over per step
+(``PERF.md`` section 5 has what that read on the chip).
 """
 
 import logging
 import time
 from typing import Dict, Optional
 
-from bagua_tpu.observability.core import StepTimer, Watchdog
+from bagua_tpu.observability.completions import Completions
+from bagua_tpu.observability.core import Watchdog
 from bagua_tpu.observability.metrics import JsonlSink, MetricsRegistry
 
 logger = logging.getLogger(__name__)
@@ -182,7 +188,9 @@ class Telemetry:
         self.recompile = RecompileDetector(
             window=retrace_window, max_retraces_per_window=max_retraces_per_window
         )
-        self.step_timer = StepTimer()
+        #: steps seen to complete: the waiter thread, the completion
+        #: intervals, the health rows read late, the stalls
+        self.completions = Completions(self.registry, self._emit_stall)
         if flight == "auto":
             from bagua_tpu.env import (
                 get_flight_recorder_enabled,
@@ -281,6 +289,7 @@ class Telemetry:
         """Mark the host's position in the step (``data`` → ``dispatch`` →
         ``wait`` → ...) and heartbeat the watchdog with the tag."""
         self.current_phase = phase
+        self.completions.note_phase(phase)
         if self.watchdog is not None:
             self.watchdog.beat(phase=phase)
         if self.goodput is not None:
@@ -293,6 +302,9 @@ class Telemetry:
         watchdog's timeout dump and exposed for debugging."""
         out = {
             "step": self.current_step,
+            # the last step seen to complete: a hang report's first question
+            "completed_step": self.completions.last_step,
+            "run_ahead": self.completions.run_ahead,
             "phase": self.current_phase,
             "variant": self.current_variant,
             "uptime_s": round(time.time() - self._t_start, 1),
@@ -363,7 +375,12 @@ class Telemetry:
         wire_bytes_by_precision: Optional[Dict[str, int]] = None,
         wire_bytes_by_axis: Optional[Dict[str, int]] = None,
     ) -> None:
-        """One dispatched training step's host-side evidence.
+        """One dispatched training step's host-side evidence.  ``wall_s`` is
+        the wall of the *dispatch* (the goodput meter, the tracer, the
+        sentinel and the JSONL ``step`` event take it as that).  The
+        registry's ``step_wall_ms`` and ``samples_per_s`` take it only from a
+        caller that handed no step to ``completions.watch``; behind an engine
+        they are completion intervals, absorbed here.
 
         ``wire_bytes_by_leg`` breaks ``wire_bytes`` down by wire pattern leg
         (sharded exchanges report ``{"rs": ..., "ag": ...}``); each leg gets
@@ -383,7 +400,7 @@ class Telemetry:
         self.current_step = int(step)
         self.current_variant = variant
         self.recompile.record_step()
-        self.step_timer.tick(wall_s, n_samples)
+        self.completions.absorb()
         if self.goodput is not None:
             self.goodput.on_step(wall_s, n_samples)
         r = self.registry
@@ -411,11 +428,13 @@ class Telemetry:
                     f"wire_bytes_axis_{ax}_total",
                     help=f"bytes communicated per rank on mesh axis {ax}",
                 ).inc(max(0, int(nbytes)))
-        r.histogram("step_wall_ms", help="host-observed step wall time").observe(
-            wall_s * 1e3
-        )
         sps = (n_samples / wall_s) if wall_s > 0 else 0.0
-        r.gauge("samples_per_s", help="instantaneous throughput").set(round(sps, 3))
+        if self.completions.watched != step:
+            # fed by hand: the caller's wall is the step's
+            r.histogram("step_wall_ms", help="host-observed step wall time").observe(
+                wall_s * 1e3
+            )
+            r.gauge("samples_per_s", help="instantaneous throughput").set(round(sps, 3))
         if self.tracer is not None:
             # Stamp the step's vitals on the open root but do NOT close it:
             # the trace stays open across the inter-step gap so the data
@@ -485,6 +504,15 @@ class Telemetry:
                     k: int(v) for k, v in sorted(wire_bytes_by_axis.items())
                 }
             self.jsonl.emit(event)
+
+    def _emit_stall(self, event: Dict) -> None:
+        """A completion interval over twice the recent median
+        (``Completions._stall``): to the JSONL stream and, beside the flight
+        ring, to what a hang dump carries."""
+        if self.jsonl:
+            self.jsonl.emit(dict(event))
+        if self.flight is not None:
+            self.flight.note(event)
 
     def on_rebucket(
         self,
@@ -938,6 +966,7 @@ class Telemetry:
     def close(self) -> None:
         from bagua_tpu.resilience.retry import get_retry_observer, set_retry_observer
 
+        self.completions.close()
         if get_retry_observer() == self.on_rpc_retry:
             set_retry_observer(None)
         if self.tracer is not None:

@@ -27,6 +27,32 @@ with cold_start.cold_host_span("setup", "import", detail=__name__):
 logger = logging.getLogger(__name__)
 
 
+def _from_plain(value, build):
+    """What ``Trainer(telemetry=, health_monitor=)`` make of a plain value,
+    the kind a launcher's config file can carry: ``True`` is ``build({})``
+    (the class's defaults), a dict ``build`` of the constructor's keywords;
+    an instance or ``None`` is taken as it is."""
+    if value is None or value is False:
+        return None
+    if value is True or isinstance(value, dict):
+        return build({} if value is True else dict(value))
+    return value
+
+
+def _build_health_monitor(telemetry):
+    """``HealthMonitor`` from keywords: bound to the hub, no action unless
+    given; ``config`` may itself be a dict of ``HealthConfig``'s fields."""
+    from bagua_tpu.observability.health import HealthConfig, HealthMonitor
+
+    def build(kwargs):
+        if isinstance(kwargs.get("config"), dict):
+            kwargs["config"] = HealthConfig(**kwargs["config"])
+        kwargs.setdefault("telemetry", telemetry)
+        return HealthMonitor(**kwargs)
+
+    return build
+
+
 class Trainer:
     """Minimal fit loop.
 
@@ -60,7 +86,13 @@ class Trainer:
             ``profile_summary``.
         telemetry: opt-in
             :class:`~bagua_tpu.observability.telemetry.Telemetry` hub, passed
-            through to the DDP engine.  The trainer additionally tags the
+            through to the DDP engine.  Beside an instance or ``None`` it
+            takes what a launcher's config file can carry: ``True`` (the
+            class's defaults: the hub with the flight recorder as
+            ``BAGUA_FLIGHT_RECORDER`` has it, no JSONL, no tracer, no
+            sentinel, no goodput meter) or a dict of the constructor's
+            keywords.  A hub the trainer built is closed by :meth:`close`.
+            The trainer additionally tags the
             watchdog's heartbeats with the fit loop's phase (``data`` while
             pulling the next batch) and points the watchdog's hang dump at
             the hub's snapshot, so a timeout names the step/phase/variant the
@@ -68,7 +100,11 @@ class Trainer:
         health_monitor: opt-in
             :class:`~bagua_tpu.observability.health.HealthMonitor`, passed
             through to the DDP engine (which computes the in-graph health
-            scalars and feeds the detector each step).  When a snapshotter
+            scalars and feeds the detector each step).  ``True`` builds one
+            with the default ``HealthConfig`` and no action registered, a
+            dict is the constructor's keywords (``config`` may be a dict of
+            ``HealthConfig``'s fields); either is bound to the hub.
+            When a snapshotter
             is configured the trainer registers
             :class:`~bagua_tpu.observability.health.SnapshotOnAnomalyAction`
             so the first anomaly leaves a restorable pre-divergence state.
@@ -116,8 +152,13 @@ class Trainer:
         from bagua_tpu.env import setup_compile_cache
 
         logger.info("persistent compilation cache at %s", setup_compile_cache())
+        from bagua_tpu.observability.telemetry import Telemetry
+
+        given, telemetry = telemetry, _from_plain(telemetry, lambda kw: Telemetry(**kw))
         self.telemetry = telemetry
-        self.health_monitor = health_monitor
+        self._owns_telemetry = telemetry is not given  # built here: closed here
+        health_monitor = self.health_monitor = _from_plain(
+            health_monitor, _build_health_monitor(telemetry))
         self.ddp = DistributedDataParallel(
             loss_fn, optimizer, algorithm, process_group=process_group,
             dp_filter=dp_filter, telemetry=telemetry,
@@ -299,7 +340,11 @@ class Trainer:
         of the device can be put down to what this loop was doing; the time
         in ``next()`` on ``batches`` and in the rest of the loop body outside
         ``train_step`` is counted in the engine's ``host_overhead`` under
-        ``next_batch`` and ``loop``."""
+        ``next_batch`` and ``loop``; what the dispatching thread waits for
+        the device on the tracing's account (a health monitor's read where
+        an action is registered) under ``health_wait``.  With a hub attached
+        the call returns once every step it dispatched has been seen to
+        complete and its health observed (``ddp.drain_steps``)."""
         overhead = self.ddp.host_overhead
         batches = iter(batches)
         stepped = False
@@ -335,6 +380,7 @@ class Trainer:
                 self._stop_capture(state, "captured")
         if stepped:
             jax.block_until_ready(self.last_losses)
+            self.ddp.drain_steps()
         if self._profiler is not None:
             # epoch ended inside the capture window: close it here (one
             # short trace kept) rather than recording every later epoch
@@ -381,6 +427,7 @@ class Trainer:
             # the loss sync here is what feeds its canary parity check
             with host_span("fit/autopilot"):
                 jax.block_until_ready(losses)
+                self.ddp.drain_steps()  # the controller reads this step's health
                 state = self.autopilot.tick(state, step, float(losses.mean()))
         if self.snapshotter is not None:
             with host_span("fit/snapshot"):
@@ -500,6 +547,7 @@ class Trainer:
                 except Exception:
                     logger.exception("flight dump on preemption failed")
         jax.block_until_ready(state)
+        self.ddp.drain_steps()
         try:
             self.snapshotter.force_snapshot(state, step)
             write_resumable_marker(self.snapshot_dir, step)
@@ -524,13 +572,19 @@ class Trainer:
             ("preemption watcher", lambda: self.preemption and self.preemption.uninstall()),
             ("watchdog", self._stop_watchdog),
             ("tracer", self._flush_tracer),
-            ("telemetry", lambda: self.telemetry and self.telemetry.flush()),
+            ("telemetry", self._close_telemetry),
             ("ddp", self.ddp.shutdown),
         ):
             try:
                 teardown()
             except Exception:
                 logger.exception("error closing %s (continuing teardown)", what)
+
+    def _close_telemetry(self) -> None:
+        """A hub the caller built is flushed and stays usable for a
+        post-mortem; one built here from a plain value is closed."""
+        if self.telemetry is not None:
+            self.telemetry.close() if self._owns_telemetry else self.telemetry.flush()
 
     def _flush_tracer(self) -> None:
         """Close the open step trace (if any) and flush the span JSONL so a

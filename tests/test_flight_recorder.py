@@ -330,12 +330,12 @@ def make_batch(seed=0):
     return x, y
 
 
-def run_steps(group, algo_name, flight, steps=3, overlap=True):
-    tel = Telemetry(flight=flight)
+def run_steps(group, algo_name, flight, steps=3, overlap=True, hub=True, monitor=None):
+    tel = Telemetry(flight=flight) if hub else None
     ddp = DistributedDataParallel(
         mse_loss, optax.sgd(0.1, momentum=0.9), build_algorithm(algo_name),
         process_group=group, bucket_size_bytes=1 << 9, overlap=overlap,
-        telemetry=tel,
+        telemetry=tel, health_monitor=monitor,
     )
     state = ddp.init(init_mlp(jax.random.PRNGKey(0), LAYERS))
     batch = make_batch()
@@ -343,7 +343,10 @@ def run_steps(group, algo_name, flight, steps=3, overlap=True):
     for _ in range(steps):
         state, losses = ddp.train_step(state, batch)
     jax.block_until_ready(losses)
+    ddp.drain_steps()
     ddp.shutdown()
+    if tel is not None:
+        tel.close()
     return ddp, state
 
 
@@ -391,6 +394,23 @@ def test_recorder_is_bitwise_inert(group, algo_name):
     _, state_on = run_steps(group, algo_name, fr, steps=3)
     assert fr.last_seq >= 0  # the recorder actually recorded
     assert state_sha(state_on) == state_sha(state_off)
+
+
+@pytest.mark.parametrize("algo_name", ["gradient_allreduce", "zero"])
+def test_hub_waiter_and_monitor_are_bitwise_inert(group, algo_name):
+    """The same pin for the whole of the operator's tracing: a bare engine
+    against one with the hub (its waiter thread holding every step's losses
+    and health vector), the recorder and a health monitor that reads a step
+    late."""
+    from bagua_tpu.observability import HealthMonitor
+
+    _, state_bare = run_steps(group, algo_name, None, steps=4, hub=False)
+    fr = FlightRecorder(capacity=128, rank=0, world_size=1)
+    monitor = HealthMonitor()
+    ddp, state_on = run_steps(group, algo_name, fr, steps=4, monitor=monitor)
+    assert fr.last_seq >= 0 and monitor.report()["observed_steps"] == 4
+    assert ddp.telemetry.snapshot()["completed_step"] == 3
+    assert state_sha(state_on) == state_sha(state_bare)
 
 
 def test_quantized_ring_records_hops(group, monkeypatch):

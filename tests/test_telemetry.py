@@ -245,8 +245,10 @@ def test_ddp_telemetry_steady_state_then_forced_retrace(group, tmp_path):
     rep = tel.recompile.report()
     assert rep["retraces"] == 1 and rep["alerts"] == 1
 
+    ddp.drain_steps()  # the hub counts a step's wall when it is seen to complete
     snap = tel.snapshot()
     assert snap["phase"] == "wait" and snap["step"] == 5
+    assert snap["completed_step"] == 5 and snap["run_ahead"] == 0
     assert snap["metrics"]["steps_total"] == 6
     assert snap["metrics"]["retrace_alerts_total"] == 1
     assert snap["metrics"]["step_wall_ms"]["count"] == 6
